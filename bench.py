@@ -1,35 +1,28 @@
-"""Benchmark harness — GBM training throughput on the local accelerator.
+"""Benchmark harness — GBM training throughput on one TPU chip.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...}.
 
 The reference publishes no numbers (BASELINE.json "published": {}), so
 vs_baseline is the ratio against the first number this harness ever
-recorded on the SAME platform at the SAME shape (BENCH_BASELINE.json
-keys entries by "<platform>:<rows>x<trees>").  A run with no matching
-baseline emits ``vs_baseline: null`` — a CPU fallback round can never
-again report a >1 ratio against an on-chip baseline (the round-3
-scoreboard defect).  Every run also emits ``last_tpu_value``: the most
-recent on-chip measurement on record, so the scoreboard always carries
-the real number even when the chip is down.
+recorded at the SAME shape (BENCH_BASELINE.json keys entries by
+"<platform>:<rows>x<trees>").  A run with no matching baseline emits
+``vs_baseline: null``.
 
 North-star metric (BASELINE.json:2): GBM rows/sec/chip. We measure
 steady-state boosting throughput (binning + per-tree grow + margin
 update) on a synthetic airlines-like binary-classification table.
 
-Robustness contract: this file IS the round scoreboard.  It probes the
-TPU backend in a subprocess (a hung client-init cannot take down the
-bench) and is STUBBORN: it keeps retrying with pauses for up to
-H2O_TPU_PROBE_BUDGET seconds (default 600 — a recovering chip must not
-cost the round its TPU number, the round-2 failure mode) before falling
-back to CPU, and on any exception still emits a single diagnostic JSON
-line instead of a traceback.
+A speed number comes from a chip run or not at all: without a TPU this
+script exits non-zero and prints no result (runtime/backend.require_tpu)
+— there is no CPU fallback, no retry with a knob flipped, and no echo
+of an older on-chip number. ROADMAP Queue 1 item 2 replaces it with the
+cell benchmark.
 """
 
 import json
 import os
 import sys
 import time
-import traceback
 
 import numpy as np
 
@@ -94,14 +87,15 @@ def main_score() -> None:
     score_numpy rows/s (flattened-tree scorer + jitted-predict cache)
     vs the per-call predict() Frame path, one JSON line.  The warm
     repeat must add 0 scorer-cache misses (recompile check)."""
-    from h2o_kubernetes_tpu.runtime.backend import ensure_live_backend
+    from h2o_kubernetes_tpu.runtime.backend import require_tpu
 
-    ensure_live_backend()
+    require_tpu("bench.py score")
     import jax
 
     import h2o_kubernetes_tpu as h2o
     from h2o_kubernetes_tpu.models import GBM
 
+    h2o.init()
     rows = int(os.environ.get("BENCH_SCORE_ROWS", 100_000))
     rng = np.random.default_rng(0)
     F = 10
@@ -121,18 +115,17 @@ def main_score() -> None:
 
 
 def main() -> None:
-    from h2o_kubernetes_tpu.runtime.backend import ensure_live_backend
+    from h2o_kubernetes_tpu.runtime.backend import require_tpu
 
-    ensure_live_backend()
+    require_tpu("bench.py")
     import jax
 
     import h2o_kubernetes_tpu as h2o
     from h2o_kubernetes_tpu.models import GBM
 
+    h2o.init()
     n_chips = len(jax.devices())
-    on_tpu = jax.default_backend() == "tpu"
-    default_rows = 1_000_000 if on_tpu else 50_000
-    rows = int(os.environ.get("BENCH_ROWS", default_rows))
+    rows = int(os.environ.get("BENCH_ROWS", 1_000_000))
     ntrees = int(os.environ.get("BENCH_TREES", 10))
     rng = np.random.default_rng(0)
     F = 10
@@ -153,31 +146,7 @@ def main() -> None:
     # warm-up with the SAME ntrees: the fused boosting loop compiles a
     # scan whose length is the tree count, so a shorter warm-up would
     # leave the timed run paying a fresh XLA compile
-    try:
-        run(ntrees)
-    except Exception:
-        # a KERNEL-COMPILE regression must degrade, not zero, the
-        # scoreboard: drop the grid dimension_semantics annotation
-        # (the one compile-affecting knob CPU CI cannot validate) and
-        # retry once. Non-compile failures (OOM, bad data, mesh
-        # health) re-raise immediately — retrying them doubles
-        # time-to-failure for no possible gain.
-        from h2o_kubernetes_tpu.ops import histogram as H
-
-        err = traceback.format_exc()
-        # annotation-specific markers only: a generic "vmem" match also
-        # catches genuine VMEM OOMs that dropping dimension_semantics
-        # cannot fix, wasting a second compile+run before failing
-        compileish = any(s in err for s in (
-            "Mosaic", "mosaic", "dimension_semantics", "remote_compile"))
-        if not H._DIMSEM or not compileish:
-            raise
-        traceback.print_exc()
-        print("warm-up failed; retrying without dimension_semantics",
-              file=sys.stderr)
-        H._DIMSEM = False
-        jax.clear_caches()
-        run(ntrees)
+    run(ntrees)
     t0 = time.perf_counter()
     run(ntrees)
     dt = time.perf_counter() - t0
@@ -187,33 +156,17 @@ def main() -> None:
     shape_key = f"{platform}:{rows}x{ntrees}"
     base_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "BENCH_BASELINE.json")
-    store = {"metric": METRIC, "baselines": {}, "last_tpu": None}
+    store = {"metric": METRIC, "baselines": {}}
     if os.path.exists(base_path):
         with open(base_path) as f:
-            raw = json.load(f)
-        if "baselines" in raw:
-            store = raw
-        else:
-            # legacy single-value file: that number was the round-1
-            # on-chip capture at the TPU default shape (1M rows x 10)
-            store["baselines"] = {"tpu:1000000x10": {"value": raw["value"]}}
+            store = json.load(f)
     entry = store["baselines"].get(shape_key)
     if entry is None:
+        # first run at this shape: record it, no ratio yet
         store["baselines"][shape_key] = {"value": rows_per_sec_per_chip}
-        base = None  # first run at this platform+shape: no ratio yet
-    else:
-        base = entry["value"]
-    # H2O_TPU_BENCH_NO_STORE=1: measure without touching the baseline
-    # store — experimental-mode runs (the watcher's 2-term capture)
-    # must not overwrite last_tpu, the headline full-precision number
-    if os.environ.get("H2O_TPU_BENCH_NO_STORE") != "1":
-        if on_tpu:
-            store["last_tpu"] = {"value": rows_per_sec_per_chip,
-                                 "rows": rows, "trees": ntrees,
-                                 "recorded": time.strftime(
-                                     "%Y-%m-%dT%H:%M:%S")}
         with open(base_path, "w") as f:
             json.dump(store, f, indent=1)
+    base = entry["value"] if entry else None
 
     print(json.dumps({
         "metric": METRIC,
@@ -222,9 +175,9 @@ def main() -> None:
         "vs_baseline": (round(rows_per_sec_per_chip / base, 3)
                         if base else None),
         "baseline_key": shape_key if base else None,
-        "last_tpu_value": (round(store["last_tpu"]["value"], 1)
-                           if store["last_tpu"] else None),
         "platform": platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "chips": n_chips,
         "rows": rows,
         "trees": ntrees,
         "seconds": round(dt, 3),
@@ -232,15 +185,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    score_mode = "score" in sys.argv[1:]
-    try:
-        main_score() if score_mode else main()
-    except Exception as e:  # scoreboard must emit a JSON line, always
-        traceback.print_exc()
-        print(json.dumps({
-            "metric": SCORE_METRIC if score_mode else METRIC,
-            "value": 0.0,
-            "unit": "rows/s" if score_mode else UNIT,
-            "vs_baseline": 0.0, "error": repr(e)[:300],
-        }))
-        sys.exit(0)
+    main_score() if "score" in sys.argv[1:] else main()

@@ -1,4 +1,4 @@
-"""Configuration tiers — the reference's flag system, TPU-shaped.
+r"""Configuration tiers — the reference's flag system, TPU-shaped.
 
 Reference (SURVEY.md §5.6): three tiers — CRD spec (declarative),
 CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
@@ -18,7 +18,6 @@ CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
 | H2O_TPU_NUM_PROCESSES | 1 | multi-host process count (runtime/mesh) |
 | H2O_TPU_PROCESS_ID | 0 | this host's process id (runtime/mesh) |
 | H2O_TPU_HIST_TERMS | 3 | bf16 mantissa terms (2 = throughput mode, ~2⁻¹⁶ products; ops/histogram) |
-| H2O_TPU_HIST_DIMSEM | 1 | 0 drops the Pallas grid dimension_semantics annotation (compile-regression escape hatch) |
 | H2O_TPU_HIST_BYTES_BUDGET | 2³⁰ | deep-tree level-histogram memory budget (models/gbm validation + grouped-DRF sizing) |
 | H2O_TPU_CV_SHAPE_SHARE_ROWS | tpu≤1M | weights-masked CV row threshold; 0 disables, N forces on any backend (models/cv) |
 | H2O_TPU_ARROW_CSV | 1 | 0 disables the pyarrow CSV fast path (frame/parse) |
@@ -42,7 +41,6 @@ CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
 | H2O_TPU_SLO_DEFAULT | standard | SLO class (interactive/standard/batch) when neither the X-H2O-SLO header nor the model's registry default applies (rest.py) |
 | H2O_TPU_MODEL_RATE_LIMIT | 0 (off) | per-tenant token bucket: sustained scoring requests/second any ONE model key may submit (burst = 1 s of traffic); past it 429 + Retry-After at admission, counted in /3/Stats `rate_limited` (rest.py, docs/SERVING.md) |
 | H2O_TPU_PCACHE_MIN_SECS | — | persistent-XLA-cache compile-time threshold override; serving pods pin 0 so every tenant compile persists and evictions re-promote from disk (runtime/backend.py) |
-| H2O_TPU_PROBE_BUDGET | 600 | backend-probe stubbornness seconds (runtime/backend) |
 | H2O_TPU_SCORE_BATCH_US | 2000 | REST scoring micro-batcher window, µs; 0 = dispatch immediately (rest.py, docs/SERVING.md) |
 | H2O_TPU_SCORE_TIMEOUT | 60 | seconds a scoring request may wait for its micro-batched result before 503 (rest.py) |
 | H2O_TPU_SCORE_MAX_ROWS | 100000 | per-request row cap on the inline scoring route (413 past it — one oversized dispatch must not lock the cloud) |

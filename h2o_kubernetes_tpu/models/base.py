@@ -622,6 +622,25 @@ class Model:
         # between this return and the caller's read mid-trace
         return st, ct
 
+    def contrib_plan(self, rows: int) -> list[str]:
+        """Which implementation each virtual-tree group takes for a
+        ``rows``-row contributions dispatch: "dp" (no pattern table —
+        the direct weight recurrence), "kernel" (the Pallas
+        `flat_shap_tab_kernel`) or "xla" (lowered `flat_shap_tab`).
+        THE one decision `_contrib_matrix` traces with, so a caller
+        can COUNT how many groups left the kernel (chip_smoke.py
+        fails when the kernel is the resolved impl and none took it).
+        Resolves at TRACE time (H2O_TPU_SHAP_KERNEL, same semantics as
+        hist_impl): the executable cached under this model's scorer
+        key keeps its impl until evict/re-promote."""
+        from ..ops.shap_kernel import kernel_fits, resolve_impl
+
+        groups, ctabs = self._contrib_prepare()
+        use_kernel = resolve_impl() == "pallas"
+        return ["dp" if ct is None
+                else "kernel" if use_kernel and kernel_fits(g, ct, rows)
+                else "xla" for g, ct in zip(groups, ctabs)]
+
     def _contrib_matrix(self, X: jax.Array) -> jax.Array:
         """[rows, F+1] contributions on raw features via the jitted
         path-enumeration TreeSHAP kernel (the pattern-table fast path
@@ -629,25 +648,25 @@ class Model:
         of ``predict_contributions``, which keeps the f64 host
         recursion as the parity oracle the way predict() stays
         eager."""
-        from ..ops.shap_kernel import (flat_shap_tab_kernel, kernel_fits,
-                                       resolve_impl)
+        from ..ops.shap_kernel import flat_shap_tab_kernel
         from .tree.shap import flat_shap, flat_shap_tab
 
         groups, ctabs = self._contrib_prepare()
         em = self._contrib_enum_mask()
-        # impl resolves at TRACE time (H2O_TPU_SHAP_KERNEL, same
-        # semantics as hist_impl): the executable cached under this
-        # model's scorer key keeps its impl until evict/re-promote.
-        use_kernel = resolve_impl() == "pallas"
-        rows = int(X.shape[0])
         phi = None
-        for g, ct in zip(groups, ctabs):
-            if ct is None:
+        for g, ct, impl in zip(groups, ctabs,
+                               self.contrib_plan(int(X.shape[0]))):
+            if impl == "dp":
                 p = flat_shap(g, X, em)
-            elif use_kernel and kernel_fits(g, ct, rows):
+            elif impl == "kernel":
                 p = flat_shap_tab_kernel(g, ct, X, em)
             else:
                 p = flat_shap_tab(g, ct, X, em)
+            # each group accumulates from zero in ITS order, then the
+            # groups sum in ascending-D order: without the barrier XLA
+            # folds this add into a one-trip group's scatter chain,
+            # and the served bytes depend on which impl ran the group
+            p = jax.lax.optimization_barrier(p)
             phi = p if phi is None else phi + p
         scale, init = self._contrib_scale_init()
         phi = phi * jnp.float32(scale)

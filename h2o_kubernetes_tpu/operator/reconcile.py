@@ -254,7 +254,10 @@ class ScorerReplica:
         env = dict(os.environ)
         env.update(self.env_overrides)
         env["H2O_TPU_POOL_REPLICA"] = "1"
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # no JAX_PLATFORMS default: a scorer pod is the process that
+        # owns a chip — it inherits the platform it was told to use
+        # and fails if it finds no such device (tests run under
+        # JAX_PLATFORMS=cpu and their pods inherit that)
         env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         out = subprocess.DEVNULL
         if self.log_dir:

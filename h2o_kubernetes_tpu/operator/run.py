@@ -85,7 +85,14 @@ def main(argv: list[str] | None = None) -> int:
                     "— the operator's Prometheus scrape surface")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the control plane must stay OFF the chip: a chip belongs to one
+    # process, and that process is a scorer pod this one spawns. Pin
+    # THIS process through jax's config — not os.environ, which the
+    # pods inherit (Replica.spawn) and which jax, already imported by
+    # the package, no longer reads
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     from .reconcile import Reconciler
     from .registry import ModelRegistry
     from .store import DurablePoolStore

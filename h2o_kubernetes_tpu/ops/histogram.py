@@ -25,6 +25,7 @@ result across the ROWS mesh axis.
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -42,14 +43,11 @@ ROW_TILE = 1024     # bin-blocked kernel's row tile (its [T, nbt] one-hot
 #                     is VMEM-bounded: 4 MB bf16 at T=1024, nbt=2048)
 
 
-def _out_struct(shape, dtype, vma) -> jax.ShapeDtypeStruct:
-    """ShapeDtypeStruct threading the vma set where the running jax
-    supports it; older builds have neither the kwarg nor the vma check
-    that needs it (runtime/compat.py disables check_rep there)."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+def _interpret() -> bool:
+    """Pallas interpret mode off-chip — the CPU-test path of every
+    kernel in ops/. Read at TRACE time; tests/test_chip_compile.py
+    patches it to compile the kernels for a described TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _fact_row_tile(n_hi: int, rows: int) -> int:
@@ -66,13 +64,6 @@ def _fact_row_tile(n_hi: int, rows: int) -> int:
 # stays resident; past this budget F is split into 8-aligned groups
 _OUT_BUDGET = 3 << 20
 
-# grid dimension_semantics opt-out: a backend-compile regression from
-# the annotation must be recoverable without a code change (bench.py
-# flips this and retries rather than scoring 0.0 on the round board)
-import os as _os
-
-_DIMSEM = _os.environ.get("H2O_TPU_HIST_DIMSEM", "1") != "0"
-
 # mantissa terms for the f32-precision bf16 emulation. 3 (default)
 # reproduces f32 products to ~2^-24 (parity-gated at 1e-6 vs the
 # segment path). 2 is the throughput mode (~2^-16 product precision —
@@ -82,18 +73,11 @@ _DIMSEM = _os.environ.get("H2O_TPU_HIST_DIMSEM", "1") != "0"
 # and the A-build VPU cost falls by a third. Gain argmaxes are robust
 # at 2^-16 relative noise; the kernel gate checks the 2-term path at
 # its own looser tolerance.
-_TERMS = 2 if _os.environ.get("H2O_TPU_HIST_TERMS", "3") == "2" else 3
-
-
-# renamed TPUCompilerParams -> CompilerParams across pallas releases;
-# same dimension_semantics kwarg either way
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
+_TERMS = 2 if os.environ.get("H2O_TPU_HIST_TERMS", "3") == "2" else 3
 
 
 def _dimsem(*sems):
-    return _COMPILER_PARAMS(dimension_semantics=sems) \
-        if _DIMSEM and _COMPILER_PARAMS is not None else None
+    return pltpu.CompilerParams(dimension_semantics=sems)
 
 
 def _hist_segment(binned, rel, vals, n_nodes: int, n_bins: int):
@@ -268,13 +252,13 @@ def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
     binned4 = binned.astype(jnp.int32).T.reshape(
         F_pad, rbb, 1, rt_size)
     rel32 = rel.astype(jnp.int32)
-    vma = getattr(jax.typeof(vals), "vma", frozenset()) or frozenset()
+    vma = jax.typeof(vals).vma
     grid = (n_fg, binned_tile, rbb)
     out = pl.pallas_call(
         functools.partial(_hist_fact_kernel, n_bins=n_bins, n_hi=n_hi,
                           n_ch=C, fg=fg, terms=_TERMS),
-        out_shape=_out_struct((n_fg, fg, C * n_hi, 128),
-                              jnp.float32, vma),
+        out_shape=jax.ShapeDtypeStruct((n_fg, fg, C * n_hi, 128),
+                                       jnp.float32, vma=vma),
         grid=grid,
         in_specs=[
             pl.BlockSpec((fg, 1, 1, rt_size),
@@ -290,7 +274,7 @@ def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
         # may pipeline them); copies and row blocks ACCUMULATE into the
         # same block (arbitrary = sequential)
         compiler_params=_dimsem("parallel", "arbitrary", "arbitrary"),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(binned4, rel32, vals)
     # [n_fg, fg, C·n_hi, 128] -> [F, C, n_hi·128] -> [n, F, B, C]
     out = out.reshape(F_pad, C, n_hi * 128)[:F, :, :nB]
@@ -369,11 +353,11 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
     grid = (F, nB // nbt, rblocks)
     # under shard_map the output varies per shard: propagate the input's
     # varying-mesh-axes set or jax's vma check rejects the call
-    vma = getattr(jax.typeof(vals), "vma", frozenset()) or frozenset()
+    vma = jax.typeof(vals).vma
     out = pl.pallas_call(
         functools.partial(_hist_kernel, n_bins=n_bins, nbt=nbt,
                           terms=_TERMS),
-        out_shape=_out_struct((F, C, nB), jnp.float32, vma),
+        out_shape=jax.ShapeDtypeStruct((F, C, nB), jnp.float32, vma=vma),
         grid=grid,
         in_specs=[
             pl.BlockSpec((ROW_TILE,),
@@ -385,7 +369,7 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
         # features and bin blocks write distinct out blocks; only the
         # row-block axis accumulates
         compiler_params=_dimsem("parallel", "parallel", "arbitrary"),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(binned_flat, rel32, vals)
     # [F, C, n*B] -> [n, F, B, C]
     return out.reshape(F, C, n_nodes, n_bins).transpose(2, 0, 3, 1)

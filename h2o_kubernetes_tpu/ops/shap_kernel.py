@@ -23,21 +23,31 @@ integration pattern end to end:
   with Precision.HIGHEST and f32 accumulation — which is EXACT
   selection (0/1 against f32), the same trick the histogram kernel
   rides the MXU with.
-- the per-slot scatter keeps the XLA reference's ORDERED f32
-  accumulation (leaves outer, depth slots inner — XLA folds duplicate
-  scatter indices in row-major update order), so results are
-  deterministic and BITWISE-equal to `flat_shap_tab`: the feature row
-  is fetched with a dynamic sublane slice (a matmul gather would
-  poison on NaN features), and phi rows accumulate one dynamic slice
-  at a time in slot order.
+- the per-slot scatter is an ORDERED f32 accumulation (leaves outer,
+  depth slots inner, trees in grid order), so results are
+  deterministic: the feature row is fetched with a dynamic sublane
+  slice (a matmul gather would poison on NaN features), and phi rows
+  accumulate one dynamic slice at a time in slot order.
+
+Contract with the XLA path: the kernel's order is fixed by the code
+above; `flat_shap_tab`'s is whatever order XLA folds duplicate scatter
+indices in. On XLA:CPU that is row-major update order — the same
+order — so the two are BITWISE-equal there (tier-1 pins it, kernel in
+interpret mode), given the barrier `Model._contrib_matrix` puts
+between groups (without it XLA folds the cross-group add into a
+one-trip group's scatter chain and reorders the XLA leg's own sum —
+the jax-0.9.0 failure of tests/test_shap_kernel.py). On a TPU the
+compiler owns the scatter's order, so there the contract is a stated
+tolerance, 1e-5 (a tenth of the 1e-4 bound that ties both to the f64
+host oracle); `tools/kernel_gate.py --check shap_kernel_parity`
+enforces it on the chip and reports whether bitwise held.
 
 `resolve_impl("auto")` picks the kernel on TPU and the lowered-XLA
 `flat_shap_tab` elsewhere; `H2O_TPU_SHAP_KERNEL=1/0` forces/kills it
 (the kill switch restores the XLA path bitwise — same executable, not
-a lookalike). On non-TPU backends the kernel runs in interpret mode,
-which is how tier-1 (`tests/test_shap_kernel.py`) and
-`kernel_gate.py --check shap_kernel_parity` pin bitwise parity on CPU;
-the gate compiles it non-interpret when a chip is attached.
+a lookalike). On non-TPU backends the kernel runs in interpret mode
+(`ops/histogram._interpret`); Mosaic first accepted it in PR 22
+(tests/test_chip_compile.py compiles it for a described v5e).
 
 Like `hist_impl`, the knob is read when the serving program is TRACED:
 a model's cached contributions executable keeps the impl it was traced
@@ -55,7 +65,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram import _COMPILER_PARAMS, _dimsem
+from .histogram import _dimsem, _interpret
 
 __all__ = ["flat_shap_tab_kernel", "kernel_fits", "resolve_impl"]
 
@@ -99,9 +109,12 @@ def resolve_impl(impl: str = "auto") -> str:
 def kernel_fits(tables, ctab, rows: int | None = None) -> bool:
     """Static eligibility of ONE virtual-tree group for the kernel.
 
-    Ineligible groups silently take the XLA path even under =1 — the
-    env knob selects an implementation, it must not turn a large-P
-    group (or a non-pow2 debug batch) into a trace error."""
+    Ineligible groups take the XLA path even under =1 — the env knob
+    selects an implementation, it must not turn a large-P group (or a
+    non-pow2 debug batch) into a trace error. The choice is visible:
+    `Model.contrib_plan(rows)` names each group's impl. What this
+    accepts, Mosaic compiles (checked at the largest accepted shape,
+    L=32 D=11 at a 512-row tile, tests/test_chip_compile.py)."""
     if ctab is None:
         return False
     T, L, D, P = ctab.shape
@@ -122,7 +135,7 @@ def _shap_tab_kernel(feat_ref, lo_ref, hi_ref, na_ref, bias_ref,
     """One (row-block, virtual-tree) grid step.
 
     feat/lo/hi/na: [1, L, D] SMEM scalar tables (one virtual tree);
-    bias: [1, 1] SMEM; xt: [F, rt] VMEM transposed canonical features;
+    bias: [1, 1, 1] SMEM; xt: [F, rt] VMEM transposed canonical features;
     ct: [1, L, D, P] VMEM pattern table; phi: [F+1, rt] accumulator.
     """
     L, D = feat_ref.shape[1], feat_ref.shape[2]
@@ -167,7 +180,7 @@ def _shap_tab_kernel(feat_ref, lo_ref, hi_ref, na_ref, bias_ref,
         return carry
 
     lax.fori_loop(0, L, leaf, 0)
-    phi_ref[F:F + 1, :] = phi_ref[F:F + 1, :] + bias_ref[0, 0]
+    phi_ref[F:F + 1, :] = phi_ref[F:F + 1, :] + bias_ref[0, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("row_tile",))
@@ -194,15 +207,17 @@ def flat_shap_tab_kernel(tables, ctab, X, enum_mask,
             smem((1, L, D), lambda r, t: (t, 0, 0)),          # lo
             smem((1, L, D), lambda r, t: (t, 0, 0)),          # hi
             smem((1, L, D), lambda r, t: (t, 0, 0)),          # na_ok
-            smem((1, 1), lambda r, t: (t, 0)),                # bias
+            # [T, 1, 1]: an SMEM block's last two dims must tile
+            # (8, 128) or span the array — (1, 1) of [T, 1] does not
+            smem((1, 1, 1), lambda r, t: (t, 0, 0)),          # bias
             pl.BlockSpec((F, rt), lambda r, t: (0, r)),       # Xᵀ
             pl.BlockSpec((1, L, D) + ctab.shape[3:],
                          lambda r, t: (t, 0, 0, 0)),          # ctab
         ],
         out_specs=pl.BlockSpec((F + 1, rt), lambda r, t: (0, r)),
         compiler_params=_dimsem("parallel", "arbitrary"),
-        interpret=jax.default_backend() != "tpu",
+        interpret=_interpret(),
     )(tables.feat.astype(jnp.int32), tables.lo, tables.hi,
-      tables.na_ok.astype(jnp.int32), tables.bias.reshape(T, 1),
+      tables.na_ok.astype(jnp.int32), tables.bias.reshape(T, 1, 1),
       Xc.T, ctab)
     return phi.T

@@ -1,6 +1,4 @@
-from . import compat  # noqa: F401 — jax.shard_map alias on old jax
 from . import faults, lifecycle, retry
-from .backend import ensure_live_backend, force_cpu_devices
 from .mesh import (COLS, ROWS, global_mesh, initialize_distributed, make_mesh,
                    n_row_shards, replicated, row_sharding, set_global_mesh,
                    use_mesh)
@@ -11,8 +9,8 @@ from .mrtask import doall, shard_rows
 __all__ = [
     "COLS", "ROWS", "global_mesh", "initialize_distributed", "make_mesh",
     "n_row_shards", "replicated", "row_sharding", "set_global_mesh",
-    "use_mesh", "doall", "shard_rows", "ensure_live_backend",
-    "force_cpu_devices", "ClusterHealthError", "device_dispatch",
-    "heartbeat", "health_status", "start_heartbeat", "stop_heartbeat",
+    "use_mesh", "doall", "shard_rows", "ClusterHealthError",
+    "device_dispatch", "heartbeat", "health_status", "start_heartbeat",
+    "stop_heartbeat",
     "faults", "lifecycle", "retry",
 ]

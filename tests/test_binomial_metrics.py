@@ -111,3 +111,37 @@ def test_multinomial_perf_includes_macro_auc_and_mpce():
                             average="macro", labels=range(len(dom)))
     assert abs(perf["auc"] - want) < 2e-3
     assert 0 <= perf["mean_per_class_error"] <= 1
+
+
+@pytest.mark.parametrize("impl", ["segment", "pallas"])
+def test_score_histogram_over_a_mesh(mesh8, impl):
+    """Rows spread over the mesh (a train margin, a Frame column) are
+    binned per shard under shard_map and psum-ed — same histogram, same
+    AUC as on one device. A plain jit over sharded rows would ask the
+    compiler to partition the Pallas kernel, which Mosaic refuses: the
+    4-chip failure of PR 22 (interpret mode stands in for Mosaic here;
+    tests/test_chip_compile.py compiles the real thing)."""
+    import h2o_kubernetes_tpu as h2o
+    from h2o_kubernetes_tpu.runtime import shard_rows
+
+    rng = np.random.default_rng(11)
+    n = 1 << 17                      # past _AUC_EXACT_MAX: histogram path
+    y = (rng.random(n) < 0.4).astype(np.float32)
+    s = (y * 0.3 + rng.normal(scale=0.35, size=n)).astype(np.float32)
+    w = (rng.random(n) < 0.9).astype(np.float32)
+    prev = h2o.get_config("hist_impl")
+    h2o.set_config("hist_impl", impl)
+    try:
+        one = M._score_hist(*(np.asarray(a) for a in (y, s, w)))
+        over = M._score_hist(*(shard_rows(a, mesh=mesh8)
+                               for a in (y, s, w)))
+        auc = M.roc_auc(shard_rows(y, mesh=mesh8),
+                        shard_rows(s, mesh=mesh8),
+                        w=shard_rows(w, mesh=mesh8))
+    finally:
+        h2o.set_config("hist_impl", prev)
+    for a, b in zip(one, over):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-3)
+    assert len(over[0].sharding.device_set) == 8    # replicated result
+    assert abs(auc - M.roc_auc(y, s, w=w, exact=True)) < 2e-3

@@ -1,0 +1,186 @@
+"""The main path's kernels and jitted steps, compiled by the chip's own
+compiler for a DESCRIBED v5e — no chip attached, nothing runs (ISSUE 22;
+`on-chip-measurement` guide, section 2). Interpret mode accepts what
+Mosaic refuses (a block shape that does not tile, too much VMEM), so
+these compiles guard every later PR at no chip time: real widths (HIGGS'
+28 features, 256 bins, depth 6), modest row counts.
+
+Code that asks `jax.default_backend()` sees the CPU here, so the tests
+call the kernel or the jitted step itself with `hist_impl="pallas"` and
+steer `ops/histogram._interpret` — the test steers, the package gets no
+option. The topology is described inside a module-scoped fixture (never
+at import, in a `skipif`, or in `parametrize`): only the xdist worker
+that is handed this file loads the TPU's library.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import h2o_kubernetes_tpu as h2o
+from h2o_kubernetes_tpu.models.tree import core
+from h2o_kubernetes_tpu.models.tree.shap import ShapTables
+from h2o_kubernetes_tpu.ops import histogram, shap_kernel
+from h2o_kubernetes_tpu.runtime.mesh import COLS, ROWS
+
+F, BINS, DEPTH = 28, 256, 6      # chip_smoke.py's widths
+ROWS_N = 65_536
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compile what the CHIP would run: kernels non-interpret. The
+    # choice is made at trace time, so no trace from before or after
+    # this module may be reused across the switch. A compile for a
+    # described device can be written to the persistent cache but not
+    # read back without a chip — keep these out of it.
+    mp = pytest.MonkeyPatch()
+    mp.setattr(histogram, "_interpret", lambda: False)
+    mp.setattr(shap_kernel, "_interpret", lambda: False)
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+    yield t
+    mp.undo()
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _s(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("n_nodes,unit_hess", [
+    (32, False), (32, True),        # factorized kernel, 3 and 2 channels
+    (512, False), (512, True),      # bin-blocked kernel (deep levels)
+])
+def test_histogram_kernels_compile(one_chip, n_nodes, unit_hess):
+    assert (-(-n_nodes * BINS // 128) <= histogram._FACT_MAX_NHI) == \
+        (n_nodes == 32)             # the two shapes take the two kernels
+    fn = jax.jit(lambda b, r, g, h, w: histogram.build_histogram(
+        b, r, g, h, w, n_nodes, BINS, "pallas", unit_hess=unit_hess))
+    f32 = _s((ROWS_N,), jnp.float32, one_chip)
+    c = fn.lower(_s((ROWS_N, F), jnp.uint8, one_chip),
+                 _s((ROWS_N,), jnp.int32, one_chip), f32, f32,
+                 f32).compile()
+    assert _kernels(c) == 1
+
+
+def _boost_args(mesh, rows, ntrees):
+    rs, rep = NamedSharding(mesh, P(ROWS)), NamedSharding(mesh, P())
+    tp = core.TreeParams(max_depth=DEPTH, n_bins=BINS, min_rows=10.0,
+                         reg_lambda=0.0, reg_alpha=0.0, gamma=1e-5,
+                         mtries=-1, min_child_weight=0.0,
+                         hist_impl="pallas", unit_hess=False)
+    bp = core.BoostParams(distribution="bernoulli", learn_rate=0.1,
+                          sample_rate=1.0, col_sample_rate_per_tree=1.0,
+                          drf_mode=False, goss_a=0.0, goss_b=0.0)
+    keys = jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), ntrees))
+    f32 = _s((rows,), jnp.float32, rs)
+    return (_s((rows, F), jnp.uint8, rs), f32, f32, f32,
+            _s(keys.shape, keys.dtype, rep), None, tp, bp, mesh)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_boost_scan_compiles(topo, n_dev):
+    """`_boost_jit` — the fused boost scan `GBM.train()` dispatches —
+    binomial at HIGGS width and depth 6, on one chip and row-sharded
+    over the 2x2 host with the level histograms psum-ed."""
+    mesh = Mesh(np.array(topo.devices[:n_dev]).reshape(n_dev, 1),
+                (ROWS, COLS))
+    c = core._boost_jit.lower(
+        *_boost_args(mesh, ROWS_N * n_dev, ntrees=3)).compile()
+    txt = c.as_text()
+    assert txt.count("tpu_custom_call") == DEPTH   # one kernel a level
+    assert ("all-reduce" in txt) == (n_dev > 1)
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_train_metric_compiles(topo, n_dev):
+    """The AUC `GBM.train()` ends with bins 2M scores through the same
+    histogram kernel. Over four chips it must do so per shard under
+    shard_map: a jit over sharded rows asks the compiler to partition
+    the Mosaic kernel, which it refuses — the fault the first 4-chip
+    run of PR 22 found, after the boost scan had already passed."""
+    from h2o_kubernetes_tpu import metrics
+
+    mesh = Mesh(np.array(topo.devices[:n_dev]).reshape(n_dev, 1),
+                (ROWS, COLS))
+    sh = NamedSharding(mesh, P(ROWS)) if n_dev > 1 else \
+        SingleDeviceSharding(topo.devices[0])
+    rows = _s((1 << 21,), jnp.float32, sh)
+    prev = h2o.get_config("hist_impl")
+    h2o.set_config("hist_impl", "pallas")
+    try:
+        fn = metrics._score_hist_on(mesh, "pallas") if n_dev > 1 \
+            else metrics._score_hist_one
+        c = fn.lower(rows, rows, rows).compile()
+    finally:
+        h2o.set_config("hist_impl", prev)
+    assert _kernels(c) == 1
+    assert ("all-reduce" in c.as_text()) == (n_dev > 1)
+
+
+def test_flat_scorer_compiles(one_chip):
+    """`flat_margin` at a serving bucket: 50 depth-6 trees, 8192 rows."""
+    T, M = 50, 2 ** (DEPTH + 1) - 1
+    flat = core.FlatTrees(*(_s((T, M), d, one_chip) for d in (
+        jnp.int32, jnp.float32, jnp.int32, jnp.bool_, jnp.float32)))
+    core.flat_margin.lower(flat, _s((8192, F), jnp.float32, one_chip),
+                           _s((F,), jnp.bool_, one_chip), DEPTH,
+                           1).compile()
+
+
+@pytest.mark.parametrize("rows,T,L,D", [
+    (1024, 50, 32, 6),      # the smoke's ensemble at a serving bucket
+    (128, 50, 32, 6),       # smallest bucket: one row tile of 128
+    (512, 4, 32, 11),       # the largest group `kernel_fits` accepts
+])
+def test_shap_kernel_compiles(one_chip, rows, T, L, D):
+    """`flat_shap_tab_kernel`: Mosaic refused it at every shape until
+    PR 22 (the per-tree bias block (1, 1) of a [T, 1] SMEM array does
+    not tile). What `kernel_fits` accepts must compile."""
+    tb = ShapTables(*(_s((T, L, D), d, one_chip) for d in (
+        jnp.int32, jnp.float32, jnp.float32, jnp.bool_, jnp.float32)),
+        _s((T, L), jnp.float32, one_chip),
+        _s((T,), jnp.float32, one_chip))
+    ct = _s((T, L, D, 1 << D), jnp.float32, one_chip)
+    assert shap_kernel.kernel_fits(tb, ct, rows)
+    c = shap_kernel.flat_shap_tab_kernel.lower(
+        tb, ct, _s((rows, F), jnp.float32, one_chip),
+        _s((F,), jnp.bool_, one_chip)).compile()
+    assert _kernels(c) == 1
+
+
+def test_kernel_fits_refuses_what_cannot_fit():
+    """One past the largest accepted group (D=12: a 4096-pattern
+    one-hot over a 512-row tile) is refused before any trace."""
+    class G:
+        feat = np.zeros((4, 32, 12), np.int32)
+
+    ct = jax.ShapeDtypeStruct((4, 32, 12, 1 << 12), jnp.float32)
+    assert not shap_kernel.kernel_fits(G, ct, 512)

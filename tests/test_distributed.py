@@ -18,23 +18,6 @@ import pytest
 _WORKER = os.path.join(os.path.dirname(__file__), "dcn_worker.py")
 
 
-def _cpu_multiprocess_supported() -> bool:
-    """jax < 0.5 CPU backends reject multi-process computations
-    outright ("Multiprocess computations aren't implemented on the CPU
-    backend") — the cross-host CPU collective transport landed later.
-    The DCN tests are then unrunnable on this toolchain, not broken."""
-    import jax
-
-    ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
-    return ver >= (0, 5)
-
-
-pytestmark = pytest.mark.skipif(
-    not _cpu_multiprocess_supported(),
-    reason="this jax's CPU backend cannot run multi-process "
-           "computations (needs jax >= 0.5 cross-host CPU collectives)")
-
-
 def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("localhost", 0))

@@ -1,3 +1,5 @@
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -216,5 +218,50 @@ def test_compile_cache_dir_keyed_by_host_features(monkeypatch):
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     backend.enable_persistent_compile_cache()
-    got = __import__("os").environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    got = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
     assert f"hostfp-{backend.host_features_fingerprint()}" in got
+    # ONE fixed place inside the checkout: the path is part of what
+    # makes a cache hit, so a second run must resolve the same one
+    assert got == backend.repo_cache_dir()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert got.startswith(os.path.join(repo, "tools", "_jax_cache"))
+
+
+def test_compile_cache_dir_placed_from_outside(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the program keeps its cache
+    there and names no other directory."""
+    from h2o_kubernetes_tpu.runtime import backend
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    backend.enable_persistent_compile_cache()
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    assert list(tmp_path.iterdir()) == []       # nothing probed/written
+
+
+def test_require_tpu_refuses_cpu():
+    """The on-chip scripts (bench.py, kernel_gate, boost_profile) have
+    no CPU fallback: without a TPU they exit, naming what they found."""
+    from h2o_kubernetes_tpu.runtime.backend import require_tpu
+
+    with pytest.raises(SystemExit, match="platform 'cpu'"):
+        require_tpu("test")
+
+
+def test_dispatch_runahead_is_bounded(mesh8):
+    """Canary for conftest._bound_cpu_runahead: 200 back-to-back psum
+    programs from one thread, never awaited in between, complete. On
+    jaxlib 0.9.0's XLA:CPU this loop deadlocks past 32 in flight
+    without the bound (the tier-1 hang of ISSUE 22) — if a jax upgrade
+    drops the hook, this test times out by XLA's rendezvous abort
+    instead of 26 others hanging at random."""
+    from jax.sharding import PartitionSpec as P
+
+    step = jax.jit(jax.shard_map(
+        lambda a: a + jax.lax.psum(jnp.sum(a), ROWS) * 1e-9,
+        mesh=mesh8, in_specs=P(ROWS), out_specs=P(ROWS)))
+    x = shard_rows(np.ones(80_000, np.float32), mesh=mesh8)
+    for _ in range(200):
+        x = step(x)
+    assert np.isfinite(float(x[0]))

@@ -288,12 +288,18 @@ class TestCompileAhead:
             b = compile_watch_snapshot(ident)
             train(5)                 # the prepared config
             a = compile_watch_snapshot(ident)
-            hits = a["thread_pcache_hits"] - b["thread_pcache_hits"]
             misses = a["thread_pcache_misses"] \
                 - b["thread_pcache_misses"]
-            assert hits >= 2, \
-                f"pre-lowered boost programs missed (hits={hits})"
-            assert misses < ctrl_miss
+            # the two pre-lowered boost programs are NOT compiled again
+            # on the device thread. On jax 0.9.0 they arrive through
+            # jit's in-memory lowering cache (the AOT `lower()` and the
+            # call share one computation object, whose executable is
+            # memoized), so the dispatch never asks the persistent
+            # cache: no hit is counted, and none is needed — what the
+            # stream promises is the missing compiles.
+            assert misses <= ctrl_miss - 2, \
+                f"pre-lowered boost programs were compiled again " \
+                f"(misses={misses}, control={ctrl_miss})"
 
             # warm resubmission: the promised no-op (hit accounting)
             thunks2 = GBM(ntrees=4, max_depth=5, seed=1, nfolds=2,

@@ -4,9 +4,8 @@ The north star (`BASELINE.json` config #5) is AutoML wall-clock on an
 Airlines-10M-shaped table. This harness produces the round's evidence
 either way:
 
-- on a live TPU (``--rows 10000000 --max-models 12``, run by
-  tools/tpu_watch.py after a bench capture): the on-chip wall-clock +
-  leaderboard the north star is phrased in;
+- on a TPU (``--rows 10000000 --max-models 12``): the on-chip
+  wall-clock + leaderboard the north star is phrased in;
 - on the CPU mesh (default): a rows-scaling curve with XLA
   **compile-count accounting** — the count must NOT grow with
   max_models (no per-model recompiles; dispatch-budget chunking and
@@ -142,9 +141,10 @@ def main() -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    from h2o_kubernetes_tpu.runtime.backend import ensure_live_backend
+    from h2o_kubernetes_tpu.runtime.backend import \
+        enable_persistent_compile_cache
 
-    ensure_live_backend()
+    enable_persistent_compile_cache()
     import jax
 
     on_tpu = jax.default_backend() == "tpu"

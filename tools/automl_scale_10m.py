@@ -7,8 +7,7 @@ multi-day, so the 10M point uses the harness's fixed-budget framing
 (tools/automl_scale.py --max-runtime-secs docstring): ONE plan family
 (GBM — the north-star algo), no CV (the leaderboard ranks on training
 metrics, the documented nfolds<2 fallback), and the recorded metric is
-models + leader quality + wall at 10M. On a real chip
-tools/tpu_watch.py runs the full-plan 10M capture instead.
+models + leader quality + wall at 10M.
 
 Writes AUTOML_SCALE_r06.json = the r05 curve + the 10M point.
 """
@@ -22,9 +21,10 @@ sys.path.insert(0, REPO)
 
 
 def main() -> int:
-    from h2o_kubernetes_tpu.runtime.backend import ensure_live_backend
+    from h2o_kubernetes_tpu.runtime.backend import \
+        enable_persistent_compile_cache
 
-    ensure_live_backend()
+    enable_persistent_compile_cache()
     from tools.automl_scale import run_shape
 
     point = run_shape(

@@ -1,4 +1,4 @@
-"""Per-phase + per-op GBM profile on the live accelerator.
+"""Per-phase + per-op GBM profile on the chip.
 
 The bench number (bench.py) times the whole ``train()``; this tool
 breaks it down so kernel work is attacked where the time actually is:
@@ -10,9 +10,8 @@ breaks it down so kernel work is attacked where the time actually is:
    top-op self-times (no tensorboard needed — the trace JSON is parsed
    directly).
 
-Writes ``PROFILE_TPU_r05.json`` (or ``PROFILE_CPU_r05.json``) at the
-repo root and prints one JSON summary line. Run by tools/tpu_watch.py
-once per chip window after the bench capture.
+Writes ``PROFILE_TPU_r05.json`` at the repo root and prints one JSON
+summary line. Exits non-zero without a TPU.
 """
 
 import glob
@@ -92,10 +91,11 @@ def _parse_trace(log_dir: str, top: int = 30):
 
 
 def main() -> int:
-    from h2o_kubernetes_tpu.runtime.backend import ensure_live_backend
+    from h2o_kubernetes_tpu.runtime.backend import (
+        enable_persistent_compile_cache, require_tpu)
 
-    ensure_live_backend(budget=float(
-        os.environ.get("H2O_TPU_PROBE_BUDGET", "300")))
+    enable_persistent_compile_cache()
+    require_tpu("boost_profile")
     import jax
     import numpy as np
 
@@ -109,8 +109,7 @@ def main() -> int:
     from h2o_kubernetes_tpu.models.base import resolve_xy
 
     platform = jax.default_backend()
-    rows = int(os.environ.get("BENCH_ROWS",
-                              1_000_000 if platform == "tpu" else 50_000))
+    rows = int(os.environ.get("BENCH_ROWS", 1_000_000))
     ntrees = int(os.environ.get("BENCH_TREES", 10))
     rng = np.random.default_rng(0)
     F = 10
@@ -168,8 +167,7 @@ def main() -> int:
     out = {"platform": platform, "rows": rows, "trees": ntrees,
            "phases": phases, "op_profile": op_profile,
            "captured_at": time.strftime("%Y-%m-%dT%H:%M:%S")}
-    path = os.path.join(
-        REPO, f"PROFILE_{'TPU' if platform == 'tpu' else 'CPU'}_r05.json")
+    path = os.path.join(REPO, "PROFILE_TPU_r05.json")
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps({"profile": "ok", "platform": platform,

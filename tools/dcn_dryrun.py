@@ -4,8 +4,8 @@ Spawns a REAL 2-process `jax.distributed` cluster on localhost (4
 virtual CPU devices per process → one global 8-device mesh) and runs,
 in sequence: a cross-process psum MRTask, a full fused-scan GBM train,
 a GLM IRLSM fit, and the member-drop fail-fast check. This is the
-driver-facing analog of `dryrun_multichip` for the PROCESS-boundary
-path that a single-process virtual mesh cannot exercise (SURVEY.md §2d
+PROCESS-boundary path that a single-process virtual mesh cannot
+exercise (SURVEY.md §2d
 multi-host row; the round-2 DRF worker-crash class lives here).
 
 Usage: python tools/dcn_dryrun.py   → prints one JSON line + exit 0/1.

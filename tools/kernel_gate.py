@@ -1,17 +1,17 @@
-"""TPU kernel-compile gate — run at round start, BEFORE the bench.
+"""TPU kernel-compile gate — run on the chip, next to chip_smoke.py.
 
 CPU CI can only exercise the Pallas kernels in interpret mode
-(`ops/histogram.py` sets `interpret=jax.default_backend() != "tpu"`),
-so a Mosaic-lowering regression lands green and is discovered on the
-bench chip at round's end.  This script closes that hole: on a TPU it
+(`ops/histogram._interpret`), and tests/test_chip_compile.py only
+COMPILES them for a described chip; neither runs Mosaic's output.
+This script does, on a TPU:
 
 1. pallas-compiles the FACTORIZED histogram kernel (interpret=False is
    automatic on tpu) at a bench-like shape and asserts parity vs the
    segment_sum reference path;
 2. same for the BIN-BLOCKED kernel (deep-tree shape past the
    factorized VMEM cap) and the TreeSHAP serving kernel
-   (`ops/shap_kernel.py`, bitwise vs the lowered-XLA
-   `flat_shap_tab`);
+   (`ops/shap_kernel.py`, to 1e-5 of the lowered-XLA
+   `flat_shap_tab`, bitwise reported);
 3. jit-compiles and runs the fused boost scan (binomial AND
    multinomial) end to end on small shapes.
 
@@ -21,13 +21,14 @@ without the full sweep — and `--list` prints the names. The `N/N PASS`
 summary counts only what RAN, and a filtered run says so in the JSON
 (`"filtered": [...]`) so a 2/2 can't masquerade as the full gate.
 
-Prints one JSON line {"gate": "pass"|"fail", ...} LAST on stdout
-(tpu_watch parses bottom-up); exit code 0 on pass.  On CPU it still
-runs (interpret-mode parity) and reports platform="cpu" so the ritual
-can tell the gate did not see a chip.
+A check that raises is that check's failure (its error is in the
+JSON); the others still run. Prints one JSON line
+{"gate": "pass"|"fail", ...} LAST on stdout; exit code 0 on pass.
+Without a TPU it exits non-zero before any check (interpret-mode
+parity is tier-1's job, tests/test_histogram.py and
+tests/test_shap_kernel.py).
 
 Usage: python tools/kernel_gate.py [--check NAME ...] [--list]
-       (H2O_TPU_PROBE_BUDGET honored)
 """
 
 import argparse
@@ -68,10 +69,11 @@ def main(argv=None) -> int:
         if unknown:
             ap.error(f"unknown check(s) {unknown}; --list shows names")
 
-    from h2o_kubernetes_tpu.runtime.backend import ensure_live_backend
+    from h2o_kubernetes_tpu.runtime.backend import (
+        enable_persistent_compile_cache, require_tpu)
 
-    ensure_live_backend(budget=float(
-        os.environ.get("H2O_TPU_PROBE_BUDGET", "300")))
+    enable_persistent_compile_cache()
+    require_tpu("kernel_gate")
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -273,15 +275,16 @@ def main(argv=None) -> int:
                        "host_err": shap_err, "additivity_err": add_err})
 
     def chk_shap_kernel_parity():
-        # chip-native TreeSHAP kernel (ops/shap_kernel.py) must be
-        # BITWISE-equal to the lowered-XLA `flat_shap_tab` it
-        # hand-places — per virtual-tree group at a pow2 serving
-        # shape, AND end-to-end through contrib_numpy with the env
-        # knob forcing each impl on a fresh model copy (the scorer
-        # cache keys on shape, not impl, so each leg needs its own
-        # executables). On TPU this compiles real Mosaic
-        # (interpret=False); on CPU it pins the interpret-mode path
-        # tier-1 also covers.
+        # chip-native TreeSHAP kernel (ops/shap_kernel.py) against the
+        # lowered-XLA `flat_shap_tab` it hand-places — per virtual-
+        # tree group at a pow2 serving shape, AND end-to-end through
+        # contrib_numpy with the env knob forcing each impl on a fresh
+        # model copy (the scorer cache keys on shape, not impl, so
+        # each leg needs its own executables). The kernel's f32
+        # accumulation order is fixed; on a TPU the compiler owns the
+        # XLA scatter's, so the contract here is the stated tolerance
+        # (1e-5, ops/shap_kernel.py docstring) and whether BITWISE
+        # held is reported, not required.
         import pickle
 
         from h2o_kubernetes_tpu.models.tree.shap import flat_shap_tab
@@ -293,7 +296,7 @@ def main(argv=None) -> int:
         em = mf._contrib_enum_mask()
         Xp = jnp.asarray(np.asarray(Xf)[:1024])
         ngr = 0
-        ok = True
+        bitwise = True
         err = 0.0
         for g, ct in zip(groups, ctabs):
             if ct is None or not kernel_fits(g, ct, 1024):
@@ -301,9 +304,8 @@ def main(argv=None) -> int:
             ngr += 1
             want = np.asarray(flat_shap_tab(g, ct, Xp, em))
             got = np.asarray(flat_shap_tab_kernel(g, ct, Xp, em))
-            ok &= bool(np.array_equal(want, got))
+            bitwise &= bool(np.array_equal(want, got))
             err = max(err, float(np.nanmax(np.abs(want - got))))
-        ok &= ngr > 0   # the rich fixture must actually exercise it
 
         def _leg(env):
             mc = pickle.loads(pickle.dumps(mf))
@@ -313,12 +315,15 @@ def main(argv=None) -> int:
             finally:
                 os.environ.pop("H2O_TPU_SHAP_KERNEL", None)
 
-        e2e = bool(np.array_equal(_leg("1"), _leg("0")))
+        on, off = _leg("1"), _leg("0")
+        e2e_err = float(np.abs(on - off).max())
         checks.append({"check": "shap_kernel_parity",
-                       "ok": bool(ok and e2e),
-                       "kernel_groups": ngr, "e2e_bitwise": e2e,
-                       "max_abs_err": err,
-                       "interpret": platform != "tpu"})
+                       # ngr > 0: the fixture must exercise the kernel
+                       "ok": bool(ngr > 0 and err <= 1e-5
+                                  and e2e_err <= 1e-5),
+                       "kernel_groups": ngr, "group_bitwise": bitwise,
+                       "e2e_bitwise": bool(np.array_equal(on, off)),
+                       "max_abs_err": err, "e2e_max_abs_err": e2e_err})
 
     def chk_efb_parity():
         # EFB parity on chip: bundled vs unbundled training must pick
@@ -422,7 +427,12 @@ def main(argv=None) -> int:
     assert list(registry) == CHECK_NAMES
     for name in CHECK_NAMES:
         if name in selected:
-            registry[name]()
+            try:
+                registry[name]()
+            except Exception as e:   # this check failed; run the rest
+                traceback.print_exc()
+                checks.append({"check": name, "ok": False,
+                               "error": repr(e)[:600]})
 
     passed = sum(1 for c in checks if c["ok"])
     total = len(checks)
@@ -439,9 +449,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    try:
-        sys.exit(main())
-    except Exception as e:     # the gate must report, not traceback-die
-        traceback.print_exc()
-        print(json.dumps({"gate": "fail", "error": repr(e)[:300]}))
-        sys.exit(1)
+    sys.exit(main())

@@ -58,7 +58,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # automl_scale_10m.py found at 72% CPU during the PR-4 round did
 # exactly that — CHANGES.md PR 4 ops note)
 _ORPHAN_PATTERNS = ("automl_scale", "bench_suite", "bench.py",
-                    "boost_profile", "tpu_watch", "score_load",
+                    "boost_profile", "score_load",
                     "automl_wall", "operator.pod")
 
 # operator scorer-pool pods are REAPED (SIGKILL), not just reported —
