@@ -33,7 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..runtime import mesh as meshlib
-from ..runtime.mrtask import doall, shard_rows
+from ..runtime.mrtask import doall, pad_rows, put_rows, shard_rows
+from ..runtime.telemetry import phase_span
 
 NA_ENUM = -1  # NA/pad sentinel for enum codes
 
@@ -91,9 +92,9 @@ class Vec:
     # -- construction -------------------------------------------------------
 
     @staticmethod
-    def from_numpy(x: np.ndarray, name: str = "", domain=None,
-                   kind: str | None = None) -> "Vec":
-        x = np.asarray(x)
+    def _host_rows(x: np.ndarray, domain=None, kind: str | None = None):
+        """The host half of `from_numpy`: (the column in its storage
+        dtype, kind, origin, the value its pad rows take)."""
         if kind is None:
             if domain is not None:
                 kind = "enum"
@@ -101,26 +102,27 @@ class Vec:
                 kind = "time"
             else:
                 kind = "numeric"
-        origin = 0.0
         if kind == "enum":
             if x.dtype.kind == "f":  # pre-encoded float codes: NaN is NA
                 x = np.where(np.isnan(x), NA_ENUM, x)
-            arr = x.astype(np.int32)
-            data = shard_rows(arr, pad_value=NA_ENUM)
-        elif kind == "time":
+            return x.astype(np.int32), kind, 0.0, NA_ENUM
+        if kind == "time":
             if x.dtype.kind == "M":
                 ms = x.astype("datetime64[ms]").astype(np.float64)
                 ms[np.isnat(x)] = np.nan  # NaT would otherwise become 2^63-
             else:
                 ms = x.astype(np.float64)
             origin = float(np.nanmin(ms)) if len(ms) else 0.0
-            arr = (ms - origin).astype(np.float32)
-            data = shard_rows(arr, pad_value=np.nan)
-        else:
-            arr = x.astype(np.float32)
-            data = shard_rows(arr, pad_value=np.nan)
-        return Vec(data, nrows=len(x), kind=kind, domain=domain, name=name,
-                   origin=origin)
+            return (ms - origin).astype(np.float32), kind, origin, np.nan
+        return x.astype(np.float32), kind, 0.0, np.nan
+
+    @staticmethod
+    def from_numpy(x: np.ndarray, name: str = "", domain=None,
+                   kind: str | None = None) -> "Vec":
+        x = np.asarray(x)
+        arr, kind, origin, pad = Vec._host_rows(x, domain, kind)
+        return Vec(shard_rows(arr, pad_value=pad), nrows=len(x), kind=kind,
+                   domain=domain, name=name, origin=origin)
 
     # -- basics -------------------------------------------------------------
 
@@ -450,21 +452,29 @@ class Frame:
         """Build from {name: array-like}. Object/str columns become enums."""
         domains = dict(domains or {})
         vecs: dict[str, Vec] = {}
-        for name, col in cols.items():
-            arr = np.asarray(col)
-            if name in domains:
-                if arr.dtype.kind in "OUS":  # encode against given domain
-                    codes, _ = _factorize(arr, domain=domains[name])
-                else:
-                    codes = arr
-                vecs[name] = Vec.from_numpy(codes, name, domain=domains[name])
-            elif arr.dtype.kind in "OUS":  # strings -> enum with built vocab
-                codes, domain = _factorize(arr)
-                vecs[name] = Vec.from_numpy(codes, name, domain=domain)
-            elif arr.dtype.kind == "b":
-                vecs[name] = Vec.from_numpy(arr.astype(np.float32), name)
-            else:
-                vecs[name] = Vec.from_numpy(arr, name)
+        # spans (runtime/telemetry.phase_span): per column the host's
+        # part (`frame.encode`: factorize, casts, padding) apart from
+        # the transfer it queues (`frame.put`)
+        with phase_span("frame.from_arrays", columns=len(cols)) as root:
+            for name, col in cols.items():
+                with phase_span("frame.encode") as enc:
+                    arr = np.asarray(col)
+                    domain = domains.get(name)
+                    if arr.dtype.kind in "OUS":
+                        # strings -> enum codes, against the given
+                        # domain or a built vocab
+                        arr, domain = _factorize(arr, domain=domain)
+                    elif arr.dtype.kind == "b" and domain is None:
+                        arr = arr.astype(np.float32)
+                    host, kind, origin, pad = Vec._host_rows(arr, domain)
+                    host = pad_rows(host, pad_value=pad)
+                    enc["bytes"] = host.nbytes
+                with phase_span("frame.put", kind="enqueue",
+                                bytes=host.nbytes):
+                    vecs[name] = Vec(put_rows(host), nrows=len(arr),
+                                     kind=kind, domain=domain, name=name,
+                                     origin=origin)
+            root["rows"] = len(arr) if cols else 0
         return Frame(vecs)
 
     @staticmethod

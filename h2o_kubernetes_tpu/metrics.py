@@ -76,6 +76,7 @@ def roc_auc(y_true, score, w=None, exact: bool | None = None) -> float:
     return float(_auc_hist_impl(y, s, wt))
 
 
+@jax.named_scope("auc")
 def _score_hist_shard(y, s, wt, axis=None):
     """Shared score-binning pass: [NB, 2] (pos, neg) mass per bin +
     (smin, smax, bad). `bad` flags NaN on a live row — callers must
@@ -151,6 +152,7 @@ def _auc_hist_impl(y, s, wt):
 
 
 @jax.jit
+@jax.named_scope("auc")
 def _auc_of_score_hist(hist, bad):
     posb, negb = hist[:, 0], hist[:, 1]
     below = jnp.cumsum(negb) - negb
@@ -160,6 +162,7 @@ def _auc_of_score_hist(hist, bad):
 
 
 @jax.jit
+@jax.named_scope("auc")
 def _auc_impl(y, s, wt):
     # one compiled program: eagerly this is ~15 dispatches, each with
     # its own first-call compile
@@ -284,6 +287,7 @@ def logloss(y_true, p, eps: float = 1e-7, w=None) -> float:
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
+@jax.named_scope("logloss")
 def _logloss_unw(y, p, eps):
     # eps must stay f32-representable: with 1e-15, 1-eps rounds to 1.0
     # and the (1-y)*log1p(-1) term produces 0*inf = NaN
@@ -292,6 +296,7 @@ def _logloss_unw(y, p, eps):
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
+@jax.named_scope("logloss")
 def _logloss_w(y, p, wt, eps):
     p = jnp.clip(p, eps, 1 - eps)
     bad = jnp.any((wt > 0) & jnp.isnan(y))     # NaN on live rows surfaces

@@ -32,6 +32,7 @@ from jax import lax
 from ..frame import Frame
 from ..runtime.health import device_dispatch, require_healthy
 from ..runtime.mesh import global_mesh
+from ..runtime.telemetry import phase_span
 from .base import Model, TrainData, resolve_xy
 from .tree.binning import (BinSpec, apply_bins, apply_bins_jit, fit_bins,
                            fused_binning_enabled, fused_fit_bins)
@@ -514,167 +515,182 @@ class GBM:
               weights_column: str | None = None,
               validation_frame: Frame | None = None,
               offset_column: str | None = None) -> GBMModel:
+        # one `train` root span a job (runtime/telemetry.phase_span:
+        # histogram, /3/Timeline, GET /3/Trace/{id}, the profiler's
+        # trace); `_train` opens one child per phase
         p = self.params
-        if p.ntrees < 1:
-            raise ValueError(f"ntrees must be >= 1, got {p.ntrees}")
-        if not 4 <= p.nbins <= 256:
-            # fit_bins validates this too; checking up front keeps the
-            # error first whichever binning path (classic/fused) runs
-            raise ValueError(f"n_bins must be in [4, 256] (uint8 bin "
-                             f"codes), got {p.nbins}")
-        if offset_column and p._drf_mode:
-            # the reference rejects offsets for DRF too (trees vote —
-            # there is no additive margin for an offset to join)
-            raise ValueError("offset_column is not supported for DRF")
-        if self.cv_args.fold_column:
-            ignored_columns = list(ignored_columns or []) + \
-                [self.cv_args.fold_column]
-        # materialize_x=False: the tree learners never touch a full
-        # [n, F] float32 design matrix — binning happens column-block-
-        # wise straight from the Frame columns (Frame.binned), and
-        # gradients come from the y/weights/offset columns alone. The
-        # uint8 binned matrix is the only full-width training-resident
-        # array (docs/SCALING.md).
-        data = resolve_xy(training_frame, y, x, ignored_columns,
-                          weights_column, p.distribution, offset_column,
-                          materialize_x=False)
-        if offset_column and data.distribution in ("multinomial",
-                                                   "laplace"):
-            raise ValueError("offset_column is not supported for "
-                             f"{data.distribution} GBM")
-        if data.distribution in ("gamma", "tweedie", "poisson"):
-            ymin = float(_jit_min_pos(data.y, data.w))
-            if data.distribution == "gamma" and ymin <= 0:
+        with phase_span("train", estimator=type(self).__name__,
+                        ntrees=p.ntrees, max_depth=p.max_depth) as root:
+            return self._train(root, y, training_frame, x,
+                               ignored_columns, weights_column,
+                               validation_frame, offset_column)
+
+    def _train(self, root, y, training_frame, x, ignored_columns,
+               weights_column, validation_frame, offset_column):
+        p = self.params
+        with phase_span("train.prepare"):
+            if p.ntrees < 1:
+                raise ValueError(f"ntrees must be >= 1, got {p.ntrees}")
+            if not 4 <= p.nbins <= 256:
+                # fit_bins validates this too; checking up front keeps the
+                # error first whichever binning path (classic/fused) runs
+                raise ValueError(f"n_bins must be in [4, 256] (uint8 bin "
+                                 f"codes), got {p.nbins}")
+            if offset_column and p._drf_mode:
+                # the reference rejects offsets for DRF too (trees vote —
+                # there is no additive margin for an offset to join)
+                raise ValueError("offset_column is not supported for DRF")
+            if self.cv_args.fold_column:
+                ignored_columns = list(ignored_columns or []) + \
+                    [self.cv_args.fold_column]
+            # materialize_x=False: the tree learners never touch a full
+            # [n, F] float32 design matrix — binning happens column-block-
+            # wise straight from the Frame columns (Frame.binned), and
+            # gradients come from the y/weights/offset columns alone. The
+            # uint8 binned matrix is the only full-width training-resident
+            # array (docs/SCALING.md).
+            data = resolve_xy(training_frame, y, x, ignored_columns,
+                              weights_column, p.distribution, offset_column,
+                              materialize_x=False)
+            if offset_column and data.distribution in ("multinomial",
+                                                       "laplace"):
+                raise ValueError("offset_column is not supported for "
+                                 f"{data.distribution} GBM")
+            if data.distribution in ("gamma", "tweedie", "poisson"):
+                ymin = float(_jit_min_pos(data.y, data.w))
+                if data.distribution == "gamma" and ymin <= 0:
+                    raise ValueError(
+                        "gamma distribution needs a strictly positive "
+                        "response")
+                if ymin < 0:
+                    raise ValueError(f"{data.distribution} distribution "
+                                     "needs a non-negative response")
+            margin_scale = 1.0
+            ckpt = p.checkpoint
+            if ckpt is not None:
+                if self.cv_args.enabled:
+                    # H2O forbids checkpoint+CV: fold models would inherit
+                    # trees that already saw their holdout rows
+                    raise ValueError(
+                        "checkpoint cannot be combined with cross-validation")
+                if ckpt.feature_names != data.feature_names:
+                    raise ValueError(
+                        "checkpoint model was trained on different features "
+                        f"({ckpt.feature_names} vs {data.feature_names})")
+                if ckpt.distribution != data.distribution:
+                    raise ValueError("checkpoint distribution mismatch")
+                if ckpt.nclasses != data.nclasses or \
+                        (ckpt.response_domain or []) != \
+                        (data.response_domain or []):
+                    raise ValueError(
+                        "checkpoint response mismatch: "
+                        f"{ckpt.nclasses} classes {ckpt.response_domain} vs "
+                        f"{data.nclasses} classes {data.response_domain}")
+                K0 = ckpt.nclasses if ckpt.nclasses > 2 else 1
+                if p.ntrees * K0 <= len(ckpt.trees.value):
+                    raise ValueError(
+                        f"ntrees={p.ntrees} must exceed the checkpoint's "
+                        f"{len(ckpt.trees.value) // K0} trees")
+                bin_spec = ckpt.bin_spec     # same binning → trees compose
+            else:
+                bin_spec = None              # fit below, fused when eligible
+
+            K = data.nclasses if data.nclasses > 2 else 1
+            tp = _make_tree_params(p, data.distribution)
+            key = jax.random.key(p.seed)
+            F = len(data.feature_names)
+
+            # GOSS (H2O_TPU_GOSS): validated up front so a bad knob or a
+            # conflicting sample_rate fails before any binning work; the
+            # per-round key stream is derived OUTSIDE the dispatch-chunk
+            # key schedule (goss_round_keys) so the fused in-HBM path and
+            # the ooc stream draw identical keep patterns at one seed
+            goss_a, goss_b = goss_params(p, data.distribution)
+            if goss_b > 0 and p.sample_rate < 1.0:
                 raise ValueError(
-                    "gamma distribution needs a strictly positive "
-                    "response")
-            if ymin < 0:
-                raise ValueError(f"{data.distribution} distribution "
-                                 "needs a non-negative response")
-        margin_scale = 1.0
-        ckpt = p.checkpoint
-        if ckpt is not None:
-            if self.cv_args.enabled:
-                # H2O forbids checkpoint+CV: fold models would inherit
-                # trees that already saw their holdout rows
+                    "H2O_TPU_GOSS replaces row subsampling — train with "
+                    f"sample_rate=1.0 (got {p.sample_rate}) or disable "
+                    "the GOSS knob")
+            goss_keys = goss_round_keys(key, p.ntrees) if goss_b > 0 \
+                else None
+
+            # Exclusive Feature Bundling (models/tree/efb.py,
+            # docs/SCALING.md "Wide sparse frames"): on wide frames
+            # dominated by one-hot / near-empty columns, mutually
+            # exclusive sparse features pack into single bundle columns at
+            # bin time, so the binned matrix, every per-level scatter-add,
+            # and the cross-shard histogram psum all run at the bundled
+            # width.  Splits decode back to ORIGINAL (feature, bin) before
+            # tree emission — bin_spec/trees/artifacts/serving are
+            # bundle-free.  H2O_TPU_EFB=0 kills it; plan-less frames fall
+            # through to the fused prologue unchanged.
+            from .tree import efb as efb_mod
+
+            efb_plan = None
+            efb = None
+            F_eff = F
+            if bin_spec is None and efb_mod.efb_eligible(F, ckpt):
+                spec_efb, efb_plan = efb_mod.fit_plan_cached(
+                    training_frame, data.feature_names, p.nbins)
+                # reuse the fitted spec either way: when the plan is
+                # rejected (shrink gate / no exclusive sets) re-fitting
+                # through the fused prologue would just duplicate the
+                # quantile fit this pass already paid
+                bin_spec = spec_efb
+                if efb_plan is not None:
+                    efb = efb_plan.device_luts()
+                    F_eff = efb_plan.fb
+
+            # deep-tree memory validation: the dense heap's per-level
+            # histogram working set is O(2^d·F·B·C) — the SAME accounting
+            # (core.level_hist_bytes) the multinomial vmap branch and the
+            # grouped-DRF G sizing use, so this validator and the actual
+            # branch decisions cannot drift. The reference reaches depth 20
+            # via dynamic row partitions; here ANY depth whose level
+            # histograms fit the budget trains fine (e.g. depth 16 with 4
+            # features × 16 bins is ~25 MB), and one that cannot fit fails
+            # HERE with sizing guidance instead of an opaque device OOM
+            # mid-boost.
+            from .tree.core import level_hist_bytes, multi_grow_vmapped
+
+            # histogram accounting at the width histograms actually have:
+            # the BUNDLED width when EFB engaged (the memory win is exactly
+            # what buys deeper trees / more grouped-DRF parallelism on
+            # wide sparse frames)
+            hist_bytes = level_hist_bytes(tp, F_eff)
+            if K > 1 and multi_grow_vmapped(tp, F_eff, K):
+                # validate the memory that will actually be live: K× only
+                # when the grower really vmaps (past its budget it falls
+                # to lax.map with one class's histograms live)
+                hist_bytes *= K
+            budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET",
+                                          2 ** 30))
+            if hist_bytes > budget:
+                need_mb = hist_bytes / 2 ** 20
                 raise ValueError(
-                    "checkpoint cannot be combined with cross-validation")
-            if ckpt.feature_names != data.feature_names:
-                raise ValueError(
-                    "checkpoint model was trained on different features "
-                    f"({ckpt.feature_names} vs {data.feature_names})")
-            if ckpt.distribution != data.distribution:
-                raise ValueError("checkpoint distribution mismatch")
-            if ckpt.nclasses != data.nclasses or \
-                    (ckpt.response_domain or []) != \
-                    (data.response_domain or []):
-                raise ValueError(
-                    "checkpoint response mismatch: "
-                    f"{ckpt.nclasses} classes {ckpt.response_domain} vs "
-                    f"{data.nclasses} classes {data.response_domain}")
-            K0 = ckpt.nclasses if ckpt.nclasses > 2 else 1
-            if p.ntrees * K0 <= len(ckpt.trees.value):
-                raise ValueError(
-                    f"ntrees={p.ntrees} must exceed the checkpoint's "
-                    f"{len(ckpt.trees.value) // K0} trees")
-            bin_spec = ckpt.bin_spec     # same binning → trees compose
-        else:
-            bin_spec = None              # fit below, fused when eligible
+                    f"max_depth={p.max_depth} with {F_eff} histogram "
+                    f"columns x {p.nbins} bins needs ~{need_mb:.0f} MiB of "
+                    f"level histograms (> budget "
+                    f"{budget / 2 ** 20:.0f} MiB). "
+                    "Lower max_depth or nbins, drop features, or raise "
+                    "H2O_TPU_HIST_BYTES_BUDGET if the device has room.")
 
-        K = data.nclasses if data.nclasses > 2 else 1
-        tp = _make_tree_params(p, data.distribution)
-        key = jax.random.key(p.seed)
-        F = len(data.feature_names)
-
-        # GOSS (H2O_TPU_GOSS): validated up front so a bad knob or a
-        # conflicting sample_rate fails before any binning work; the
-        # per-round key stream is derived OUTSIDE the dispatch-chunk
-        # key schedule (goss_round_keys) so the fused in-HBM path and
-        # the ooc stream draw identical keep patterns at one seed
-        goss_a, goss_b = goss_params(p, data.distribution)
-        if goss_b > 0 and p.sample_rate < 1.0:
-            raise ValueError(
-                "H2O_TPU_GOSS replaces row subsampling — train with "
-                f"sample_rate=1.0 (got {p.sample_rate}) or disable "
-                "the GOSS knob")
-        goss_keys = goss_round_keys(key, p.ntrees) if goss_b > 0 \
-            else None
-
-        # Exclusive Feature Bundling (models/tree/efb.py,
-        # docs/SCALING.md "Wide sparse frames"): on wide frames
-        # dominated by one-hot / near-empty columns, mutually
-        # exclusive sparse features pack into single bundle columns at
-        # bin time, so the binned matrix, every per-level scatter-add,
-        # and the cross-shard histogram psum all run at the bundled
-        # width.  Splits decode back to ORIGINAL (feature, bin) before
-        # tree emission — bin_spec/trees/artifacts/serving are
-        # bundle-free.  H2O_TPU_EFB=0 kills it; plan-less frames fall
-        # through to the fused prologue unchanged.
-        from .tree import efb as efb_mod
-
-        efb_plan = None
-        efb = None
-        F_eff = F
-        if bin_spec is None and efb_mod.efb_eligible(F, ckpt):
-            spec_efb, efb_plan = efb_mod.fit_plan_cached(
-                training_frame, data.feature_names, p.nbins)
-            # reuse the fitted spec either way: when the plan is
-            # rejected (shrink gate / no exclusive sets) re-fitting
-            # through the fused prologue would just duplicate the
-            # quantile fit this pass already paid
-            bin_spec = spec_efb
-            if efb_plan is not None:
-                efb = efb_plan.device_luts()
-                F_eff = efb_plan.fb
-
-        # deep-tree memory validation: the dense heap's per-level
-        # histogram working set is O(2^d·F·B·C) — the SAME accounting
-        # (core.level_hist_bytes) the multinomial vmap branch and the
-        # grouped-DRF G sizing use, so this validator and the actual
-        # branch decisions cannot drift. The reference reaches depth 20
-        # via dynamic row partitions; here ANY depth whose level
-        # histograms fit the budget trains fine (e.g. depth 16 with 4
-        # features × 16 bins is ~25 MB), and one that cannot fit fails
-        # HERE with sizing guidance instead of an opaque device OOM
-        # mid-boost.
-        from .tree.core import level_hist_bytes, multi_grow_vmapped
-
-        # histogram accounting at the width histograms actually have:
-        # the BUNDLED width when EFB engaged (the memory win is exactly
-        # what buys deeper trees / more grouped-DRF parallelism on
-        # wide sparse frames)
-        hist_bytes = level_hist_bytes(tp, F_eff)
-        if K > 1 and multi_grow_vmapped(tp, F_eff, K):
-            # validate the memory that will actually be live: K× only
-            # when the grower really vmaps (past its budget it falls
-            # to lax.map with one class's histograms live)
-            hist_bytes *= K
-        budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET",
-                                      2 ** 30))
-        if hist_bytes > budget:
-            need_mb = hist_bytes / 2 ** 20
-            raise ValueError(
-                f"max_depth={p.max_depth} with {F_eff} histogram "
-                f"columns x {p.nbins} bins needs ~{need_mb:.0f} MiB of "
-                f"level histograms (> budget "
-                f"{budget / 2 ** 20:.0f} MiB). "
-                "Lower max_depth or nbins, drop features, or raise "
-                "H2O_TPU_HIST_BYTES_BUDGET if the device has room.")
-
-        # out-of-core mode: when the uint8 binned matrix would not fit
-        # the headroom the histogram budget leaves, keep it host-
-        # resident in chunks and stream per boosting iteration
-        # (models/tree/ooc.py). `binned` is only materialized on device
-        # for the in-HBM path.
-        ooc_chunk = _ooc_chunk_rows(p, data, K, F_eff, hist_bytes,
-                                    budget, ckpt)
-        binned = None
-        # the bin phase is a telemetry span (h2o_train_phase_seconds
-        # {phase="bin"} + /3/Timeline): the prologue whose blocking
-        # quantile sync PR 5 removed stays observable in production
-        from ..runtime.telemetry import phase_span
-
-        with phase_span("bin", rows=data.y.shape[0], features=F_eff):
+            # out-of-core mode: when the uint8 binned matrix would not fit
+            # the headroom the histogram budget leaves, keep it host-
+            # resident in chunks and stream per boosting iteration
+            # (models/tree/ooc.py). `binned` is only materialized on device
+            # for the in-HBM path.
+            ooc_chunk = _ooc_chunk_rows(p, data, K, F_eff, hist_bytes,
+                                        budget, ckpt)
+            binned = None
+        root.update(rows=training_frame.nrows, features=F_eff,
+                    chips=global_mesh().size)
+        # no span blocks on the device for its own sake (the dispatch
+        # pipeline below is the loop's design): `enqueue` spans read the
+        # dispatch, the device's side is in the device trace under
+        # telemetry.TRAIN_PROGRAMS' names
+        with phase_span("train.bin", kind="enqueue",
+                        rows=data.y.shape[0], features=F_eff):
             if efb_plan is not None:
                 # bundled training matrix [padded, Fb] (host-built
                 # during planning, device-cached on the plan); the
@@ -700,77 +716,78 @@ class GBM:
             if ooc_chunk is None and binned is None:
                 binned = training_frame.binned(bin_spec)
 
-        off = data.offset if data.offset is not None \
-            else jnp.zeros_like(data.y)
-        if ckpt is not None:
-            if ckpt.params.nbins != p.nbins or \
-                    ckpt.params.max_depth != p.max_depth:
-                raise ValueError(
-                    "checkpoint nbins/max_depth must match "
-                    f"({ckpt.params.nbins}/{ckpt.params.max_depth} vs "
-                    f"{p.nbins}/{p.max_depth})")
-            if (getattr(ckpt, "offset_column", None) or None) != \
-                    (offset_column or None):
-                raise ValueError(
-                    "checkpoint offset_column mismatch: "
-                    f"{getattr(ckpt, 'offset_column', None)!r} vs "
-                    f"{offset_column!r}")
-            init = ckpt.init_score
-            if p._drf_mode:
+        with phase_span("train.init_margin", kind="enqueue"):
+            off = data.offset if data.offset is not None \
+                else jnp.zeros_like(data.y)
+            if ckpt is not None:
+                if ckpt.params.nbins != p.nbins or \
+                        ckpt.params.max_depth != p.max_depth:
+                    raise ValueError(
+                        "checkpoint nbins/max_depth must match "
+                        f"({ckpt.params.nbins}/{ckpt.params.max_depth} vs "
+                        f"{p.nbins}/{p.max_depth})")
+                if (getattr(ckpt, "offset_column", None) or None) != \
+                        (offset_column or None):
+                    raise ValueError(
+                        "checkpoint offset_column mismatch: "
+                        f"{getattr(ckpt, 'offset_column', None)!r} vs "
+                        f"{offset_column!r}")
+                init = ckpt.init_score
+                if p._drf_mode:
+                    margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
+                        else jnp.zeros_like(data.y)
+                elif K == 1:
+                    margin = init + off + _stack_predict(
+                        ckpt.trees, binned, p.max_depth, p.nbins)
+                else:
+                    outs = [init[k] + _stack_predict(
+                        jax.tree.map(lambda a: a[k::K], ckpt.trees),
+                        binned, p.max_depth, p.nbins) for k in range(K)]
+                    margin = jnp.stack(outs, axis=1)
+            elif p._drf_mode:
+                # DRF: no boosting — leaves are in-leaf target means, init 0
+                init = np.zeros(K, dtype=np.float32) if K > 1 else 0.0
                 margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
                     else jnp.zeros_like(data.y)
-            elif K == 1:
-                margin = init + off + _stack_predict(
-                    ckpt.trees, binned, p.max_depth, p.nbins)
+            elif data.distribution == "laplace":
+                # L1 leaf steps are bounded by learn_rate, so fit in
+                # median/MAD-scaled space: |y-f| is scale-equivariant and
+                # the minimizer is unchanged; predictions rescale on read
+                yv = np.asarray(data.y)[np.asarray(data.w) > 0]
+                init = float(np.median(yv)) if len(yv) else 0.0
+                mad = float(np.median(np.abs(yv - init))) if len(yv) else 1.0
+                # MAD degenerates to 0 on zero-inflated data (>=50% of y at
+                # one value) — only then fall back to the non-robust std,
+                # otherwise keep the outlier-insensitive scale
+                if mad * 1.4826 > 1e-8:
+                    margin_scale = mad * 1.4826
+                else:
+                    std = float(np.std(yv)) if len(yv) else 1.0
+                    margin_scale = max(std, 1e-8)
+                import dataclasses
+
+                data = dataclasses.replace(
+                    data, y=(data.y - init) / margin_scale)
+                margin = jnp.zeros_like(data.y)
             else:
-                outs = [init[k] + _stack_predict(
-                    jax.tree.map(lambda a: a[k::K], ckpt.trees),
-                    binned, p.max_depth, p.nbins) for k in range(K)]
-                margin = jnp.stack(outs, axis=1)
-        elif p._drf_mode:
-            # DRF: no boosting — leaves are in-leaf target means, init 0
-            init = np.zeros(K, dtype=np.float32) if K > 1 else 0.0
-            margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
-                else jnp.zeros_like(data.y)
-        elif data.distribution == "laplace":
-            # L1 leaf steps are bounded by learn_rate, so fit in
-            # median/MAD-scaled space: |y-f| is scale-equivariant and
-            # the minimizer is unchanged; predictions rescale on read
-            yv = np.asarray(data.y)[np.asarray(data.w) > 0]
-            init = float(np.median(yv)) if len(yv) else 0.0
-            mad = float(np.median(np.abs(yv - init))) if len(yv) else 1.0
-            # MAD degenerates to 0 on zero-inflated data (>=50% of y at
-            # one value) — only then fall back to the non-robust std,
-            # otherwise keep the outlier-insensitive scale
-            if mad * 1.4826 > 1e-8:
-                margin_scale = mad * 1.4826
-            else:
-                std = float(np.std(yv)) if len(yv) else 1.0
-                margin_scale = max(std, 1e-8)
-            import dataclasses
+                # bernoulli/multinomial/poisson/gamma/tweedie/gaussian:
+                # init + margin in one device dispatch, no host sync before
+                # the first boost chunk (init is read back at model build)
+                init, margin = _init_margin(data.y, data.w, off,
+                                            data.distribution, K)
 
-            data = dataclasses.replace(
-                data, y=(data.y - init) / margin_scale)
-            margin = jnp.zeros_like(data.y)
-        else:
-            # bernoulli/multinomial/poisson/gamma/tweedie/gaussian:
-            # init + margin in one device dispatch, no host sync before
-            # the first boost chunk (init is read back at model build)
-            init, margin = _init_margin(data.y, data.w, off,
-                                        data.distribution, K)
+            if ckpt is not None and data.distribution == "laplace":
+                # continuation must reuse the checkpoint's robust scaling or
+                # the new trees' leaf units would not compose; the working
+                # margin lives in SCALED units (tree leaves), so drop the
+                # init the generic ckpt branch added above
+                init = ckpt.init_score
+                margin_scale = getattr(ckpt, "margin_scale", 1.0)
+                import dataclasses
 
-        if ckpt is not None and data.distribution == "laplace":
-            # continuation must reuse the checkpoint's robust scaling or
-            # the new trees' leaf units would not compose; the working
-            # margin lives in SCALED units (tree leaves), so drop the
-            # init the generic ckpt branch added above
-            init = ckpt.init_score
-            margin_scale = getattr(ckpt, "margin_scale", 1.0)
-            import dataclasses
-
-            data = dataclasses.replace(
-                data, y=(data.y - init) / margin_scale)
-            margin = margin - init
+                data = dataclasses.replace(
+                    data, y=(data.y - init) / margin_scale)
+                margin = margin - init
 
         start_t = 0
         if ckpt is not None:
@@ -794,7 +811,8 @@ class GBM:
 
             require_healthy()
             with device_dispatch("gbm out-of-core boost"), \
-                    phase_span("boost", mode="ooc", trees=p.ntrees):
+                    phase_span("train.boost", kind="enqueue", mode="ooc",
+                               trees=p.ntrees):
                 cks = make_chunks(training_frame, bin_spec, data.y,
                                   data.w, margin, ooc_chunk,
                                   plan=efb_plan)
@@ -804,51 +822,55 @@ class GBM:
             _warn_goss_overflow(goss_dropped)
             margin = shard_rows(margin_np)
         else:
-            with phase_span("boost", mode="in_hbm", trees=p.ntrees):
+            with phase_span("train.boost", kind="enqueue", mode="in_hbm",
+                            trees=p.ntrees):
                 trees, margin, history = self._boost_in_hbm(
                     p, tp, bp, data, binned, margin, key, K, F_eff,
                     ckpt, start_t, history, efb=efb,
                     goss_keys=goss_keys)
-        if isinstance(init, jax.Array):
-            # read the device init back AFTER the boost chunks are
-            # enqueued (async dispatch: this blocks only on the tiny
-            # init computation, not on training)
-            init = jax.device_get(init)
-            init = init if init.ndim else float(init)
-            if not np.all(np.isfinite(np.atleast_1d(init))):
-                # 0/0 on device (every row weight zero / every response
-                # NA) must surface as an error, not a silently-NaN model
-                raise ValueError(
-                    "no rows with positive weight and a non-NA response "
-                    "— cannot fit a prior")
-        model = self.model_cls(data, p, bin_spec, trees,
-                               init_score=init, varimp=None)
-        model.margin_scale = margin_scale
-        model.offset_column = offset_column
-        model._varimp = _stacked_varimp(model.trees, data.feature_names)
-        if p._drf_mode:
-            perf = model.model_performance(training_frame, y)
-            history.append({"ntrees": p.ntrees,
-                            **{f"train_{k}": v for k, v in perf.items()}})
-        elif not (history and history[-1].get("ntrees") == p.ntrees):
-            # (when score_every divides ntrees the loop already scored
-            # the final round — don't duplicate the row)
-            history.append({"ntrees": p.ntrees, **_margin_metrics(
-                data.distribution, margin, data.y, data.w)})
-        if margin_scale != 1.0 and history:
-            # report rmse in ORIGINAL units, not MAD units
-            for hrow in history:
-                if "train_rmse" in hrow:
-                    hrow["train_rmse"] *= margin_scale
-        model.scoring_history = history
+        with phase_span("train.read_model", kind="wait"):
+            if isinstance(init, jax.Array):
+                # read the device init back AFTER the boost chunks are
+                # enqueued (async dispatch: this blocks only on the tiny
+                # init computation, not on training)
+                init = jax.device_get(init)
+                init = init if init.ndim else float(init)
+                if not np.all(np.isfinite(np.atleast_1d(init))):
+                    # 0/0 on device (every row weight zero / every response
+                    # NA) must surface as an error, not a silently-NaN model
+                    raise ValueError(
+                        "no rows with positive weight and a non-NA response "
+                        "— cannot fit a prior")
+            model = self.model_cls(data, p, bin_spec, trees,
+                                   init_score=init, varimp=None)
+            model.margin_scale = margin_scale
+            model.offset_column = offset_column
+            model._varimp = _stacked_varimp(model.trees, data.feature_names)
+        with phase_span("train.metric", kind="wait"):
+            if p._drf_mode:
+                perf = model.model_performance(training_frame, y)
+                history.append({"ntrees": p.ntrees,
+                                **{f"train_{k}": v for k, v in perf.items()}})
+            elif not (history and history[-1].get("ntrees") == p.ntrees):
+                # (when score_every divides ntrees the loop already scored
+                # the final round — don't duplicate the row)
+                history.append({"ntrees": p.ntrees, **_margin_metrics(
+                    data.distribution, margin, data.y, data.w)})
+            if margin_scale != 1.0 and history:
+                # report rmse in ORIGINAL units, not MAD units
+                for hrow in history:
+                    if "train_rmse" in hrow:
+                        hrow["train_rmse"] *= margin_scale
+            model.scoring_history = history
         from .cv import finalize_train
 
-        return finalize_train(
-            self, model, y, training_frame,
-            {"x": x, "ignored_columns": ignored_columns,
-             "weights_column": weights_column,
-             "offset_column": offset_column},
-            validation_frame)
+        with phase_span("train.finalize"):
+            return finalize_train(
+                self, model, y, training_frame,
+                {"x": x, "ignored_columns": ignored_columns,
+                 "weights_column": weights_column,
+                 "offset_column": offset_column},
+                validation_frame)
 
     def _boost_in_hbm(self, p, tp, bp, data, binned, margin, key, K, F,
                       ckpt, start_t, history, efb=None, goss_keys=None):
@@ -884,7 +906,9 @@ class GBM:
             # and is escalated to the same locked-cloud failure by
             # AutoML's step_failed device-error check
             gk = None if goss_keys is None else goss_keys[t: t + n]
-            with device_dispatch("gbm boost dispatch"):
+            with device_dispatch("gbm boost dispatch"), \
+                    phase_span("train.dispatch", kind="enqueue",
+                               first_tree=t, trees=n):
                 if K == 1 and p._drf_mode:
                     # independent forest trees grow in vmapped GROUPS
                     # (the class-flattening kernel rule): G× fuller MXU

@@ -85,6 +85,7 @@ _QUANTILE_SAMPLE = 1 << 16
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
+@jax.named_scope("fit_quantiles")
 def _device_quantiles(Xn: jax.Array, n_q: int) -> jax.Array:
     """Per-column quantile edges on device: [n, Fn] → [Fn, n_q].
 
@@ -101,7 +102,9 @@ def _device_quantiles(Xn: jax.Array, n_q: int) -> jax.Array:
 # _device_quantiles — at 10M rows that transient alone is ~1.1 GB and
 # was one of the ~5x-working-set peaks the chunked training path
 # removes. Gathering the sample per column keeps the peak at O(sample).
-_col_sample_jit = jax.jit(lambda c, idx: c[idx])
+@jax.jit
+def _col_sample(c, idx):
+    return c[idx]
 
 
 def _sampled_feature_matrix(num_cols: list) -> jax.Array:
@@ -115,7 +118,7 @@ def _sampled_feature_matrix(num_cols: list) -> jax.Array:
     if n > _QUANTILE_SAMPLE:
         idx = jax.random.randint(jax.random.key(0x51BB),
                                  (_QUANTILE_SAMPLE,), 0, n)
-        num_cols = [_col_sample_jit(c, idx) for c in num_cols]
+        num_cols = [_col_sample(c, idx) for c in num_cols]
     return jnp.stack(num_cols, axis=1)
 
 
@@ -185,6 +188,7 @@ def fit_bins(frame, feature_names: list[str],
                    is_enum=is_enum, n_bins=n_bins, edges_dev=M)
 
 
+@jax.named_scope("apply_bins")
 def apply_bins(X: jax.Array, edges_matrix: jax.Array, enum_mask: jax.Array,
                na_bin: int) -> jax.Array:
     """Bin a [rows, F] float matrix → [rows, F] uint8 codes (jittable).
@@ -243,8 +247,9 @@ def _bin_block_jit(cols: tuple, edges_block, na_bin: int, enum_block):
                       na_bin)
 
 
-_concat_blocks_jit = jax.jit(
-    lambda *blocks: jnp.concatenate(blocks, axis=1))
+@jax.jit
+def _concat_blocks(*blocks):
+    return jnp.concatenate(blocks, axis=1)
 
 
 def bin_frame(frame, bin_spec: BinSpec) -> jax.Array:
@@ -264,7 +269,7 @@ def bin_frame(frame, bin_spec: BinSpec) -> jax.Array:
         cols = tuple(frame.vec(n).as_float() for n in names[lo:hi])
         out.append(_bin_block_jit(cols, edges[lo:hi], bin_spec.na_bin,
                                   enum_mask[lo:hi]))
-    return out[0] if len(out) == 1 else _concat_blocks_jit(*out)
+    return out[0] if len(out) == 1 else _concat_blocks(*out)
 
 
 # ---------------------------------------------------------------------------
@@ -300,11 +305,12 @@ def _fused_fit_bin_jit(base_M, num_idx, sample, cols: tuple,
     features) skips the quantile half at trace time."""
     M = base_M
     if sample is not None:
-        n_q = M.shape[1] - 1                      # n_bins - 3
-        qs = jnp.linspace(0.0, 1.0, n_q + 2)[1:-1]
-        Q = jax.vmap(lambda c: jnp.nanquantile(c, qs))(sample.T)
-        Q = jnp.where(jnp.isnan(Q), jnp.inf, Q.astype(jnp.float32))
-        M = M.at[num_idx, : n_q].set(Q)
+        with jax.named_scope("fit_quantiles"):
+            n_q = M.shape[1] - 1                  # n_bins - 3
+            qs = jnp.linspace(0.0, 1.0, n_q + 2)[1:-1]
+            Q = jax.vmap(lambda c: jnp.nanquantile(c, qs))(sample.T)
+            Q = jnp.where(jnp.isnan(Q), jnp.inf, Q.astype(jnp.float32))
+            M = M.at[num_idx, : n_q].set(Q)
     binned = apply_bins(jnp.stack(cols, axis=1), M[: len(cols)],
                         enum_block, na_bin)
     return M, binned
@@ -349,7 +355,7 @@ def fused_fit_bins(frame, feature_names: list[str],
                      for nm in feature_names[lo:hi])
         outs.append(_bin_block_jit(cols, M[lo:hi], n_bins - 1,
                                    jnp.asarray(enum_arr[lo:hi])))
-    binned = outs[0] if len(outs) == 1 else _concat_blocks_jit(*outs)
+    binned = outs[0] if len(outs) == 1 else _concat_blocks(*outs)
     spec = BinSpec(names=list(feature_names), edges=None,
                    is_enum=is_enum, n_bins=n_bins, edges_dev=M)
     while len(cache) >= 2:                  # tiny LRU: drop oldest

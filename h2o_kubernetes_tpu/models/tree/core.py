@@ -284,6 +284,12 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
     ``efb``: optional bundle LUTs (models/tree/efb.py) — ``binned`` is
     then the BUNDLED matrix, histograms/psums run at bundled width,
     and splits/descents are decoded to original feature space.
+
+    Each phase of a level traces under a `jax.named_scope` —
+    `level_hist`, `hist_psum`, `sibling`, `split_find`, `descend`, and
+    `leaves` for the last level (the words ooc.py's host spans use):
+    metadata only, the operations' `op_name` in a compiled program and
+    in a profile.
     """
     F = col_mask.shape[0]       # ORIGINAL feature count (== binned
     #                             width only when efb is None)
@@ -312,33 +318,36 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
             # built a histogram here (full at first — half the tree's
             # matmul work — then single-bin); now it costs NOTHING:
             # no row-stream pass, no psum.
-            if d == 0:
-                # depth-0 stump: no parent level exists — one
-                # single-bin pass for the root totals
-                zero_bin = jnp.zeros((binned.shape[0], 1),
-                                     dtype=binned.dtype)
-                tot = _build_histogram_op(zero_bin, rel, g, h, w, 1, 1,
-                                          impl=p.hist_impl,
-                                          unit_hess=p.unit_hess)
-                tot = lax.psum(tot, ROWS)
-                if p.unit_hess:
-                    tot = _expand_unit_hess(tot)
-                tot = tot[:, 0, 0, :]
-            else:
-                tot = jnp.where(can_prev[:, None, None],
-                                jnp.stack([left_prev, right_prev],
-                                          axis=1),
-                                0.0).reshape(n_nodes, 3)  # child order
-            idx = off + jnp.arange(n_nodes)
-            value = value.at[idx].set(
-                _leaf_value(tot[:, 0], tot[:, 1], p))
-            cover = cover.at[idx].set(tot[:, 2])
+            with jax.named_scope("leaves"):
+                if d == 0:
+                    # depth-0 stump: no parent level exists — one
+                    # single-bin pass for the root totals
+                    zero_bin = jnp.zeros((binned.shape[0], 1),
+                                         dtype=binned.dtype)
+                    tot = _build_histogram_op(zero_bin, rel, g, h, w, 1,
+                                              1, impl=p.hist_impl,
+                                              unit_hess=p.unit_hess)
+                    tot = lax.psum(tot, ROWS)
+                    if p.unit_hess:
+                        tot = _expand_unit_hess(tot)
+                    tot = tot[:, 0, 0, :]
+                else:
+                    tot = jnp.where(can_prev[:, None, None],
+                                    jnp.stack([left_prev, right_prev],
+                                              axis=1),
+                                    0.0).reshape(n_nodes, 3)  # child order
+                idx = off + jnp.arange(n_nodes)
+                value = value.at[idx].set(
+                    _leaf_value(tot[:, 0], tot[:, 1], p))
+                cover = cover.at[idx].set(tot[:, 2])
             break
         if d == 0:
-            hist = _build_histogram_op(binned, rel, g, h, w, 1,
-                                       p.n_bins, impl=p.hist_impl,
-                                       unit_hess=p.unit_hess)
-            hist = lax.psum(hist, ROWS)                 # MRTask reduce
+            with jax.named_scope("level_hist"):
+                hist = _build_histogram_op(binned, rel, g, h, w, 1,
+                                           p.n_bins, impl=p.hist_impl,
+                                           unit_hess=p.unit_hess)
+            with jax.named_scope("hist_psum"):
+                hist = lax.psum(hist, ROWS)             # MRTask reduce
             if p.unit_hess:
                 hist = _expand_unit_hess(hist)
         else:
@@ -349,53 +358,63 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
             # exactly one child; children of non-split parents are
             # zeroed so _find_splits can't fabricate splits from the
             # stale parent mass.
-            left_rel = jnp.where((rel >= 0) & (rel % 2 == 0), rel // 2, -1)
-            hist_l = _build_histogram_op(binned, left_rel, g, h, w,
-                                         n_nodes // 2, p.n_bins,
-                                         impl=p.hist_impl,
-                                         unit_hess=p.unit_hess)
-            hist_l = lax.psum(hist_l, ROWS)
+            with jax.named_scope("level_hist"):
+                left_rel = jnp.where((rel >= 0) & (rel % 2 == 0),
+                                     rel // 2, -1)
+                hist_l = _build_histogram_op(binned, left_rel, g, h, w,
+                                             n_nodes // 2, p.n_bins,
+                                             impl=p.hist_impl,
+                                             unit_hess=p.unit_hess)
+            with jax.named_scope("hist_psum"):
+                hist_l = lax.psum(hist_l, ROWS)
             if p.unit_hess:
                 hist_l = _expand_unit_hess(hist_l)
-            parent = jnp.where(can_prev[:, None, None, None], hist_prev,
-                               0.0)
-            hist_l = jnp.where(can_prev[:, None, None, None], hist_l, 0.0)
-            hist_r = parent - hist_l
-            hist = jnp.stack([hist_l, hist_r], axis=1).reshape(
-                n_nodes, binned.shape[1], p.n_bins, 3)
-        feat_ok = jnp.broadcast_to(col_mask[None, :], (n_nodes, F))
-        if p.mtries > 0 and p.mtries < F:
-            # DRF: exactly mtries features per node (reference: DTree
-            # per-split feature sampling with mtries, SURVEY.md §2b C10)
-            r = jax.random.uniform(jax.random.fold_in(key, d), (n_nodes, F))
-            r = jnp.where(feat_ok, r, jnp.inf)
-            kth = jnp.sort(r, axis=1)[:, p.mtries - 1: p.mtries]
-            feat_ok = feat_ok & (r <= kth)
-        (feat, bin_, na_l, can, val, g_best, cov, left_ch,
-         right_ch) = _find_splits(hist, p, feat_ok, efb)
-        idx = off + jnp.arange(n_nodes)
-        split_feat = split_feat.at[idx].set(jnp.where(can, feat, -1))
-        split_bin = split_bin.at[idx].set(bin_)
-        na_left = na_left.at[idx].set(na_l)
-        is_split = is_split.at[idx].set(can)
-        value = value.at[idx].set(val)
-        gain = gain.at[idx].set(jnp.where(can, g_best, 0.0))
-        cover = cover.at[idx].set(cov)
+            with jax.named_scope("sibling"):
+                parent = jnp.where(can_prev[:, None, None, None],
+                                   hist_prev, 0.0)
+                hist_l = jnp.where(can_prev[:, None, None, None], hist_l,
+                                   0.0)
+                hist_r = parent - hist_l
+                hist = jnp.stack([hist_l, hist_r], axis=1).reshape(
+                    n_nodes, binned.shape[1], p.n_bins, 3)
+        with jax.named_scope("split_find"):
+            feat_ok = jnp.broadcast_to(col_mask[None, :], (n_nodes, F))
+            if p.mtries > 0 and p.mtries < F:
+                # DRF: exactly mtries features per node (reference:
+                # DTree per-split feature sampling with mtries,
+                # SURVEY.md §2b C10)
+                r = jax.random.uniform(jax.random.fold_in(key, d),
+                                       (n_nodes, F))
+                r = jnp.where(feat_ok, r, jnp.inf)
+                kth = jnp.sort(r, axis=1)[:, p.mtries - 1: p.mtries]
+                feat_ok = feat_ok & (r <= kth)
+            (feat, bin_, na_l, can, val, g_best, cov, left_ch,
+             right_ch) = _find_splits(hist, p, feat_ok, efb)
+            idx = off + jnp.arange(n_nodes)
+            split_feat = split_feat.at[idx].set(jnp.where(can, feat, -1))
+            split_bin = split_bin.at[idx].set(bin_)
+            na_left = na_left.at[idx].set(na_l)
+            is_split = is_split.at[idx].set(can)
+            value = value.at[idx].set(val)
+            gain = gain.at[idx].set(jnp.where(can, g_best, 0.0))
+            cover = cover.at[idx].set(cov)
         hist_prev, can_prev = hist, can
         left_prev, right_prev = left_ch, right_ch
         # descend rows: dead rows stay dead; rows in non-split nodes die
-        live = rel >= 0
-        safe_rel = jnp.where(live, rel, 0)
-        f = feat[safe_rel]
-        b = bin_[safe_rel]
-        nl = na_l[safe_rel]
-        rowbin = row_orig_bins(binned, f, efb)
-        is_na = rowbin == p.n_bins - 1
-        go_right = jnp.where(is_na, ~nl, rowbin > b)
-        child = 2 * rel + go_right.astype(jnp.int32)  # rel index at d+1
-        moved = live & can[safe_rel]
-        rel = jnp.where(moved, child, -1)
-        abs_node = jnp.where(moved, (2 ** (d + 1) - 1) + child, abs_node)
+        with jax.named_scope("descend"):
+            live = rel >= 0
+            safe_rel = jnp.where(live, rel, 0)
+            f = feat[safe_rel]
+            b = bin_[safe_rel]
+            nl = na_l[safe_rel]
+            rowbin = row_orig_bins(binned, f, efb)
+            is_na = rowbin == p.n_bins - 1
+            go_right = jnp.where(is_na, ~nl, rowbin > b)
+            child = 2 * rel + go_right.astype(jnp.int32)  # rel at d+1
+            moved = live & can[safe_rel]
+            rel = jnp.where(moved, child, -1)
+            abs_node = jnp.where(moved, (2 ** (d + 1) - 1) + child,
+                                 abs_node)
 
     return Tree(split_feat, split_bin, na_left, is_split, value, gain,
                 cover), abs_node
@@ -683,34 +702,41 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, *,
         if goss:
             kt, kg = kt
         k_row, k_col, k_tree = jax.random.split(kt, 3)
-        w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
-        if bp.drf_mode:
-            g, h = -y, jnp.ones_like(y)
-        else:
-            g, h = _boost_grad_hess(bp, margin, y, w)
+        with jax.named_scope("sample"):
+            w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
+        with jax.named_scope("grad_hess"):
+            if bp.drf_mode:
+                g, h = -y, jnp.ones_like(y)
+            else:
+                g, h = _boost_grad_hess(bp, margin, y, w)
         if goss:
             # GOSS: amplified weights → static-cap compaction → the
             # grower streams only the sampled rows. The margin update
             # re-descends the FULL binned matrix through the grown
             # tree (the grower's leaf walk only covers sampled rows).
-            w_amp = goss_amplified_w(g, w_t, kg, bp)
-            cap = goss_cap_rows(binned.shape[0], bp.goss_a, bp.goss_b)
-            bC, gC, hC, wC, dropped = goss_compact(binned, g, h,
-                                                   w_amp, cap)
+            with jax.named_scope("sample"):
+                w_amp = goss_amplified_w(g, w_t, kg, bp)
+                cap = goss_cap_rows(binned.shape[0], bp.goss_a,
+                                    bp.goss_b)
+                bC, gC, hC, wC, dropped = goss_compact(binned, g, h,
+                                                       w_amp, cap)
             tree, _ = _grow_tree_shard(bC, gC, hC, wC, col_mask,
                                        k_tree, p, efb)
-            tree = tree._replace(value=bp.learn_rate * tree.value)
-            if not bp.drf_mode:
-                margin = margin + tree.value[descend_tree(
-                    tree, binned, p.max_depth, p.n_bins, efb)]
+            with jax.named_scope("margin"):
+                tree = tree._replace(value=bp.learn_rate * tree.value)
+                if not bp.drf_mode:
+                    margin = margin + tree.value[descend_tree(
+                        tree, binned, p.max_depth, p.n_bins, efb)]
             return margin, (tree, lax.psum(dropped, ROWS))
         tree, leaf = _grow_tree_shard(binned, g, h, w_t, col_mask,
                                       k_tree, p, efb)
-        tree = tree._replace(value=bp.learn_rate * tree.value)
-        if not bp.drf_mode:
-            # the grower already walked each row to its leaf: one gather
-            # replaces a full predict_tree heap re-descent per tree
-            margin = margin + tree.value[leaf]
+        with jax.named_scope("margin"):
+            tree = tree._replace(value=bp.learn_rate * tree.value)
+            if not bp.drf_mode:
+                # the grower already walked each row to its leaf: one
+                # gather replaces a full predict_tree heap re-descent
+                # per tree
+                margin = margin + tree.value[leaf]
         return margin, tree
 
     if goss:
@@ -764,25 +790,29 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
         k_row, k_col, k_tree = jax.random.split(kt, 3)
         # one row-sample per ROUND, shared by its K trees (the
         # reference samples per iteration, not per class tree)
-        w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
-        # NaN responses (w=0 pad rows) compare False for every class
-        yk = (y[:, None] == jnp.arange(K, dtype=y.dtype)[None, :]
-              ).astype(jnp.float32)                      # [rows, K]
-        if bp.drf_mode:
-            g = -yk.T
-            h = jnp.ones_like(g)
-        else:
-            probs = jax.nn.softmax(margin, axis=1)
-            g = (probs - yk).T                           # [K, rows]
-            h = (probs * (1.0 - probs)).T
+        with jax.named_scope("sample"):
+            w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
+        with jax.named_scope("grad_hess"):
+            # NaN responses (w=0 pad rows) compare False for every class
+            yk = (y[:, None] == jnp.arange(K, dtype=y.dtype)[None, :]
+                  ).astype(jnp.float32)                  # [rows, K]
+            if bp.drf_mode:
+                g = -yk.T
+                h = jnp.ones_like(g)
+            else:
+                probs = jax.nn.softmax(margin, axis=1)
+                g = (probs - yk).T                       # [K, rows]
+                h = (probs * (1.0 - probs)).T
         if goss:
             # one GOSS draw per ROUND (rows ranked by the class-L1
             # gradient norm), shared by its K class trees — the same
             # per-iteration discipline as the row sample above
-            w_amp = goss_amplified_w(g, w_t, kg, bp)
-            cap = goss_cap_rows(binned.shape[0], bp.goss_a, bp.goss_b)
-            bC, gC, hC, wC, dropped = goss_compact(binned, g, h,
-                                                   w_amp, cap)
+            with jax.named_scope("sample"):
+                w_amp = goss_amplified_w(g, w_t, kg, bp)
+                cap = goss_cap_rows(binned.shape[0], bp.goss_a,
+                                    bp.goss_b)
+                bC, gC, hC, wC, dropped = goss_compact(binned, g, h,
+                                                       w_amp, cap)
         else:
             bC, gC, hC, wC = binned, g, h, None
 
@@ -804,15 +834,17 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
         else:
             trees, leaf = lax.map(lambda a: grow_one(*a),
                                   (gC, hC, keys_k))
-        trees = trees._replace(value=bp.learn_rate * trees.value)
-        if not bp.drf_mode:
-            if goss:
-                # sampled grow → full-row leaf values by re-descent
-                upd = jax.vmap(lambda tr: tr.value[descend_tree(
-                    tr, binned, p.max_depth, p.n_bins, efb)])(trees)
-            else:
-                upd = jax.vmap(lambda v, lf: v[lf])(trees.value, leaf)
-            margin = margin + upd.T
+        with jax.named_scope("margin"):
+            trees = trees._replace(value=bp.learn_rate * trees.value)
+            if not bp.drf_mode:
+                if goss:
+                    # sampled grow → full-row leaf values by re-descent
+                    upd = jax.vmap(lambda tr: tr.value[descend_tree(
+                        tr, binned, p.max_depth, p.n_bins, efb)])(trees)
+                else:
+                    upd = jax.vmap(lambda v, lf: v[lf])(trees.value,
+                                                        leaf)
+                margin = margin + upd.T
         if goss:
             return margin, (trees, lax.psum(dropped, ROWS))
         return margin, trees
@@ -841,7 +873,8 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
     def body(carry, kt_group):
         def grow_one(kt):
             k_row, k_col, k_tree = jax.random.split(kt, 3)
-            w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
+            with jax.named_scope("sample"):
+                w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
             tree, _ = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
                                        k_tree, p, efb)
             return tree

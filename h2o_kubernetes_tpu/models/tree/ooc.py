@@ -470,19 +470,22 @@ def _grow_tree_chunked(chunks: BinnedChunks, gs, hs, wts, col_key,
         # level is a host loop over chunk programs)
         if d == 0:
             hist2 = None
-            with telemetry.phase_span("level_hist", depth=d):
+            with telemetry.phase_span("level_hist", kind="enqueue",
+                                      depth=d):
                 for ci, bc in enumerate(_stream(chunks, mesh)):
                     hc = _chunk_root_hist_jit(bc, gs[ci], hs[ci],
                                               wts[ci], rel[ci], True,
                                               p, mesh)
                     hist2 = hc if hist2 is None \
                         else _add_jit(hist2, hc)
-            with telemetry.phase_span("split_find", depth=d):
+            with telemetry.phase_span("split_find", kind="enqueue",
+                                      depth=d):
                 hist, found = _root_logic_jit(hist2, col_key, p, d,
                                               efb)
         else:
             hist_l2 = None
-            with telemetry.phase_span("level_hist", depth=d):
+            with telemetry.phase_span("level_hist", kind="enqueue",
+                                      depth=d):
                 for ci, bc in enumerate(_stream(chunks, mesh)):
                     rel[ci], absn[ci], hc = _chunk_desc_hist_jit(
                         bc, rel[ci], absn[ci], gs[ci], hs[ci],
@@ -490,7 +493,8 @@ def _grow_tree_chunked(chunks: BinnedChunks, gs, hs, wts, col_key,
                         p, mesh, efb)
                     hist_l2 = hc if hist_l2 is None \
                         else _add_jit(hist_l2, hc)
-            with telemetry.phase_span("split_find", depth=d):
+            with telemetry.phase_span("split_find", kind="enqueue",
+                                      depth=d):
                 hist, found = _level_logic_jit(hist_l2, hist_prev,
                                                can_prev, col_key, p,
                                                d, efb)

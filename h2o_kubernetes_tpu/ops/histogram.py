@@ -275,6 +275,9 @@ def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
         # same block (arbitrary = sequential)
         compiler_params=_dimsem("parallel", "arbitrary", "arbitrary"),
         interpret=_interpret(),
+        # the instruction's name in the compiled program and in a
+        # profile (`hist_fact.N custom-call`)
+        name="hist_fact", metadata={"kernel": "hist_fact"},
     )(binned4, rel32, vals)
     # [n_fg, fg, C·n_hi, 128] -> [F, C, n_hi·128] -> [n, F, B, C]
     out = out.reshape(F_pad, C, n_hi * 128)[:F, :, :nB]
@@ -370,6 +373,7 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
         # row-block axis accumulates
         compiler_params=_dimsem("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="hist_blocked", metadata={"kernel": "hist_blocked"},
     )(binned_flat, rel32, vals)
     # [F, C, n*B] -> [n, F, B, C]
     return out.reshape(F, C, n_nodes, n_bins).transpose(2, 0, 3, 1)

@@ -217,6 +217,7 @@ def flat_shap_tab_kernel(tables, ctab, X, enum_mask,
         out_specs=pl.BlockSpec((F + 1, rt), lambda r, t: (0, r)),
         compiler_params=_dimsem("parallel", "arbitrary"),
         interpret=_interpret(),
+        name="shap_tab", metadata={"kernel": "shap_tab"},
     )(tables.feat.astype(jnp.int32), tables.lo, tables.hi,
       tables.na_ok.astype(jnp.int32), tables.bias.reshape(T, 1, 1),
       Xc.T, ctab)

@@ -132,8 +132,9 @@ def _padded_len(n: int, shards: int) -> int:
     return ((n + shards - 1) // shards) * shards
 
 
-def shard_rows(x, mesh: Mesh | None = None, pad_value=None) -> jax.Array:
-    """Pad the leading dim to a multiple of the ROWS axis and shard it.
+def pad_rows(x, mesh: Mesh | None = None, pad_value=None):
+    """The host half of `shard_rows`: pad the leading dim to a multiple
+    of the ROWS axis.
 
     Default padding is NaN for floats, -1 for signed ints, 0 otherwise
     (np.full would silently turn NaN into INT_MIN for int dtypes).
@@ -150,14 +151,29 @@ def shard_rows(x, mesh: Mesh | None = None, pad_value=None) -> jax.Array:
             pad_value = (np.nan if kind == "f" else -1 if kind == "i" else 0)
         pad = np.full((m - n,) + tuple(x.shape[1:]), pad_value, dtype=x.dtype)
         x = np.concatenate([np.asarray(x), pad], axis=0)
+    return x
+
+
+def put_rows(x, mesh: Mesh | None = None) -> jax.Array:
+    """The device half of `shard_rows`: rows already padded, sharded
+    over the ROWS axis."""
     from jax.sharding import NamedSharding
 
-    sharding = NamedSharding(mesh, P(ROWS))
+    sharding = NamedSharding(mesh or global_mesh(), P(ROWS))
     if not sharding.is_fully_addressable:
         # multi-host (DCN) mesh: device_put cannot target devices owned
         # by other processes; every process holds the same host array
         # and contributes its local shards (multi-controller SPMD)
+        import numpy as np
+
         xnp = np.asarray(x)
         return jax.make_array_from_callback(
             xnp.shape, sharding, lambda idx: xnp[idx])
     return jax.device_put(jnp.asarray(x), sharding)
+
+
+def shard_rows(x, mesh: Mesh | None = None, pad_value=None) -> jax.Array:
+    """Pad the leading dim to a multiple of the ROWS axis and shard it
+    (`pad_rows`, then `put_rows`)."""
+    mesh = mesh or global_mesh()
+    return put_rows(pad_rows(x, mesh, pad_value), mesh)

@@ -27,11 +27,16 @@ the single source of truth those surfaces now register through:
   wait / batcher queue wait / batch assembly / device dispatch / total)
   into a bounded ring served at ``GET /3/Trace/{id}`` — "why was this
   p99 slow" decomposes into queue-vs-device-vs-hedge.
-- **Training phase spans** (`phase_span`): bin / per-level histogram /
-  split find / chunk upload / compile-ahead fill feed the existing
-  `diagnostics.TimeLine` AND per-phase latency histograms, and the
-  out-of-core stream reports the upload/compute overlap-efficiency
-  gauge the SCALING docs previously estimated by hand.
+- **Training spans** (`phase_span`): one span function for the training
+  path — `Frame.from_arrays`, every phase of `train()`, the out-of-core
+  levels, compile-ahead fills. A span feeds the per-phase histogram,
+  the `diagnostics.TimeLine`, the same `TRACER` ring as a request (a
+  job's span tree at ``GET /3/Trace/{id}``) and, while jax is loaded,
+  the profiler's trace (`h2o.<name>`, beside the device operations).
+  `TRAIN_PROGRAMS` names the jitted programs of each phase as a device
+  trace shows them. The out-of-core stream also reports the
+  upload/compute overlap-efficiency gauge the SCALING docs previously
+  estimated by hand.
 
 Deliberately JAX-free and numpy-free: the router and operator processes
 scrape and serve this without paying a device import.
@@ -41,7 +46,9 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import contextvars
 import os
+import sys
 import threading
 import time
 import uuid
@@ -52,6 +59,7 @@ __all__ = [
     "REGISTRY", "TRACER", "MetricsRegistry", "TraceRing",
     "register_group", "group_snapshot", "prometheus_text",
     "parse_prometheus_text", "build_info", "phase_span",
+    "SPAN_KINDS", "TRAIN_PROGRAMS",
     "record_request_phases", "new_trace_id", "trace_id_from",
     "count_event", "ooc_stream_account", "start_status_listener",
     "metric_name", "CONTENT_TYPE", "write_metrics",
@@ -757,6 +765,14 @@ class TraceRing:
             return None if rec is None else {
                 **rec, "spans": list(rec["spans"])}
 
+    def by_root(self, name: str) -> list[dict]:
+        """The records whose root span is ``name`` (`phase_span` files
+        a training job under its root's name), oldest first."""
+        with self._lock:
+            return [{**rec, "spans": list(rec["spans"])}
+                    for rec in self._ring.values()
+                    if rec.get("root") == name]
+
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
@@ -823,32 +839,121 @@ def record_request_phases(trace_id: str | None, marks: dict,
 # Training phase spans
 # ---------------------------------------------------------------------------
 
+# What a span's seconds are, on a backend that runs device work
+# asynchronously. No span ends in a block_until_ready of its own: that
+# would serialise the dispatch pipeline the boost loop and the
+# out-of-core double buffer are built on. The device's side of a phase
+# is in the device trace, under the names below.
+#   host     the block computes on the host
+#   enqueue  the block only queues device work: its seconds are the
+#            dispatch, not the work (a dispatch can itself block while
+#            the runtime's queue is full: an upper bound on host work)
+#   wait     the block reads a device result back, so it also waits
+#            for whatever was queued before it
+SPAN_KINDS = ("host", "enqueue", "wait")
+
+# The jitted functions of the training path by phase: their `__name__`s,
+# which a profiler trace shows as module names (`jit_<name>(<hash>)`).
+# Eager one-operation programs (`jit_concatenate`, `jit__threefry_split`,
+# `jit__unstack`, ...) belong to no phase. tests/test_telemetry.py holds
+# every entry to a jitted function that exists, so a rename breaks a
+# test and not a metric.
+TRAIN_PROGRAMS = {
+    "bin": ("_fused_fit_bin_jit", "_bin_block_jit", "_concat_blocks",
+            "_col_sample", "_device_quantiles", "apply_bins"),
+    "init": ("_init_margin", "_stack_predict"),
+    "boost": ("_boost_jit", "_boost_multi_jit", "_boost_drf_jit"),
+    # the out-of-core stream's programs, a few per level and chunk
+    "boost_ooc": ("_chunk_grads_jit", "_chunk_root_hist_jit",
+                  "_chunk_desc_hist_jit", "_root_logic_jit",
+                  "_level_logic_jit", "_final_leaves_jit",
+                  "_chunk_finish_jit", "_chunk_goss_max_jit",
+                  "_chunk_goss_counts_jit", "_goss_threshold_jit",
+                  "_chunk_goss_compact_jit", "_chunk_goss_margin_jit"),
+    "metric": ("sigmoid", "softmax", "exp", "_logloss_w", "_logloss_unw",
+               "_pad_jit", "_auc_impl", "_score_hist_shard",
+               "_auc_of_score_hist", "_rmse_w", "_rmse_unw"),
+}
+
+_SPAN_FIELDS = frozenset(
+    {"name", "id", "parent", "kind", "t0_ns", "t1_ns", "ms"})
+
+# the open span of this thread of control: (trace id, the root's span
+# list, this span's id)
+_OPEN_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "h2o_open_span", default=None)
+
 
 def train_phase_histogram() -> Histogram:
     return REGISTRY.histogram(
         "h2o_train_phase_seconds",
-        "training phase durations (bin|boost|level_hist|split_find|"
-        "chunk_upload|compile_ahead_fill)", label="phase",
+        "host seconds of a training span, by span name (train.bin, "
+        "train.dispatch, frame.encode, level_hist, ...); a span that "
+        "only enqueues device work reads the dispatch, one that reads "
+        "a result back reads the wait for the device",
+        label="phase",
         buckets=(0.001, 0.005, 0.025, 0.1, 0.5, 2.0, 10.0, 60.0,
                  300.0))
 
 
 @contextlib.contextmanager
-def phase_span(phase: str, **data):
-    """Time a training/scheduler phase into the per-phase histogram
-    AND the diagnostics TimeLine (kind="phase") — the /3/Timeline ring
-    keeps the sequence, the histogram keeps the distribution."""
-    t0 = time.monotonic()
+def phase_span(phase: str, kind: str = "host", **data):
+    """THE span of the training path: times a block on
+    ``time.perf_counter_ns()`` into the per-phase histogram, the
+    diagnostics TimeLine (kind="phase"), the trace ring and — while
+    jax is loaded — the profiler's own trace.
+
+    A span opened inside another becomes its child; one opened with
+    none open is a root, mints a trace id, and files the whole tree in
+    `TRACER` when it ends, so ``GET /3/Trace/{id}`` serves a training
+    job as it serves a request. A span record holds ``name``, ``id``
+    and ``parent`` (ids count from 0 within the trace), ``kind`` (see
+    SPAN_KINDS), ``t0_ns``/``t1_ns``, ``ms`` and the attributes; the
+    block may add attributes to the dict it is given. The same span
+    lies in any profile taken meanwhile (`diagnostics.profile()`) as
+    ``h2o.<name>``, on the profiler's clock beside the device
+    operations. ``H2O_TPU_TRACE=0`` switches the ring and the
+    annotation off; histogram and TimeLine stay."""
+    if kind not in SPAN_KINDS:
+        raise ValueError(f"span kind {kind!r} not in {SPAN_KINDS}")
+    rec = dict(data)
+    token = note = None
+    if _trace_on():
+        tid, spans, parent = _OPEN_SPAN.get() or (new_trace_id(), [], None)
+        ident = len(spans)
+        spans.append(rec)               # in the order the spans opened
+        token = _OPEN_SPAN.set((tid, spans, ident))
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            # never imports jax: the router serves this module without
+            # a device runtime
+            note = jax.profiler.TraceAnnotation(
+                "h2o." + phase,
+                **{k: v for k, v in data.items() if v is not None})
+            note.__enter__()
+    t0 = time.perf_counter_ns()
     try:
-        yield
+        yield rec
     finally:
-        dur = time.monotonic() - t0
+        t1 = time.perf_counter_ns()
+        dur = (t1 - t0) / 1e9
+        if token is not None:
+            if note is not None:
+                note.__exit__(None, None, None)
+            _OPEN_SPAN.reset(token)
+            rec.update(name=phase, id=ident, parent=parent, kind=kind,
+                       t0_ns=t0, t1_ns=t1, ms=round(dur * 1000.0, 3))
+            if parent is None:
+                TRACER.record(tid, spans, root=phase)
         train_phase_histogram().observe(dur, label_value=phase)
         try:
             from ..diagnostics import timeline
 
-            timeline.record("phase", phase, phase=phase,
-                            dur_ms=round(dur * 1000.0, 3), **data)
+            timeline.record(
+                "phase", phase, phase=phase,
+                dur_ms=round(dur * 1000.0, 3),
+                **{k: v for k, v in rec.items()
+                   if k not in _SPAN_FIELDS})
         except Exception:  # noqa: BLE001 — accounting only
             pass
 

@@ -13,6 +13,8 @@ at import, in a `skipif`, or in `parametrize`): only the xdist worker
 that is handed this file loads the TPU's library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,6 +74,21 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _kernel_names(compiled) -> set:
+    """The instruction names of the Mosaic calls (`hist_fact.42` →
+    `hist_fact`): what a profile's event and the benchmark's breakdown
+    show for a kernel."""
+    return {m[1] for m in re.finditer(
+        r"%([A-Za-z_]+)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text())}
+
+
+def _scopes(text: str) -> set:
+    """Every component of every operation's JAX name stack."""
+    return {part for name in re.findall(r'op_name="([^"]+)"', text)
+            for part in name.split("/")}
+
+
 @pytest.mark.parametrize("n_nodes,unit_hess", [
     (32, False), (32, True),        # factorized kernel, 3 and 2 channels
     (512, False), (512, True),      # bin-blocked kernel (deep levels)
@@ -86,6 +103,8 @@ def test_histogram_kernels_compile(one_chip, n_nodes, unit_hess):
                  _s((ROWS_N,), jnp.int32, one_chip), f32, f32,
                  f32).compile()
     assert _kernels(c) == 1
+    assert _kernel_names(c) == {
+        "hist_fact" if n_nodes == 32 else "hist_blocked"}
 
 
 def _boost_args(mesh, rows, ntrees):
@@ -104,19 +123,83 @@ def _boost_args(mesh, rows, ntrees):
             _s(keys.shape, keys.dtype, rep), None, tp, bp, mesh)
 
 
+@pytest.fixture(scope="module")
+def boost_scan(topo):
+    """n_dev -> the compiled `_boost_jit`, compiled once a module."""
+    done = {}
+
+    def compiled(n_dev):
+        if n_dev not in done:
+            mesh = Mesh(np.array(topo.devices[:n_dev]).reshape(n_dev, 1),
+                        (ROWS, COLS))
+            done[n_dev] = core._boost_jit.lower(
+                *_boost_args(mesh, ROWS_N * n_dev, ntrees=3)).compile()
+        return done[n_dev]
+
+    return compiled
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
-def test_boost_scan_compiles(topo, n_dev):
+def test_boost_scan_compiles(boost_scan, n_dev):
     """`_boost_jit` — the fused boost scan `GBM.train()` dispatches —
     binomial at HIGGS width and depth 6, on one chip and row-sharded
     over the 2x2 host with the level histograms psum-ed."""
-    mesh = Mesh(np.array(topo.devices[:n_dev]).reshape(n_dev, 1),
-                (ROWS, COLS))
-    c = core._boost_jit.lower(
-        *_boost_args(mesh, ROWS_N * n_dev, ntrees=3)).compile()
+    c = boost_scan(n_dev)
     txt = c.as_text()
     assert txt.count("tpu_custom_call") == DEPTH   # one kernel a level
     assert ("all-reduce" in txt) == (n_dev > 1)
     assert c.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_boost_scan_names_its_kernel_and_scopes(boost_scan, n_dev):
+    """What a profile of the chip shows for the boost program: the
+    histogram kernel's instruction is `hist_fact.N` (it was the name
+    stack's innermost component, `closed_call.N`), still a
+    `tpu_custom_call`, with the kernel's name in `kernel_metadata`; and
+    every phase of the step is a component of its operations'
+    `op_name`. A scope whose operations the compiler folds away leaves
+    no name: `sample` at sample_rate 1, `hist_psum` on one chip."""
+    c = boost_scan(n_dev)
+    txt = c.as_text()
+    assert _kernel_names(c) == {"hist_fact"}
+    assert txt.count('"kernel":"hist_fact"') == DEPTH
+    scopes = _scopes(txt)
+    want = {"grad_hess", "margin", "level_hist", "sibling", "split_find",
+            "descend", "leaves", "hist_fact"}
+    if n_dev > 1:
+        want.add("hist_psum")
+    assert want <= scopes, want - scopes
+    assert any(n.endswith("level_hist/hist_fact/pallas_call")
+               for n in re.findall(r'op_name="([^"]+)"', txt))
+
+
+def test_every_scope_is_traced(topo):
+    """Before the compiler folds anything: the traced programs of the
+    training path hold every scope name PERF.md lists."""
+    from h2o_kubernetes_tpu import metrics
+    from h2o_kubernetes_tpu.models.tree import binning
+
+    def traced(fn, *args):
+        return {part for name in re.findall(
+            r'loc\("([^"]+)"', fn.lower(*args).as_text(debug_info=True))
+            for part in name.split("/")}
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
+    boost = traced(core._boost_jit, *_boost_args(mesh, ROWS_N, ntrees=3))
+    assert {"sample", "grad_hess", "margin", "level_hist", "hist_psum",
+            "sibling", "split_find", "descend", "leaves"} <= boost
+    col = jax.ShapeDtypeStruct((ROWS_N,), jnp.float32)
+    fit = traced(
+        binning._fused_fit_bin_jit,
+        jax.ShapeDtypeStruct((F, BINS - 2), jnp.float32),
+        jax.ShapeDtypeStruct((F,), jnp.int32),
+        jax.ShapeDtypeStruct((4096, F), jnp.float32), (col,) * F,
+        jax.ShapeDtypeStruct((F,), jnp.bool_), BINS - 1)
+    assert {"fit_quantiles", "apply_bins"} <= fit
+    assert "logloss" in traced(metrics._logloss_w, col, col, col, 1e-7)
+    assert "auc" in traced(metrics._auc_impl, col, col, col)
+    assert "auc" in traced(metrics._score_hist_one, col, col, col)
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
@@ -174,6 +257,7 @@ def test_shap_kernel_compiles(one_chip, rows, T, L, D):
         tb, ct, _s((rows, F), jnp.float32, one_chip),
         _s((F,), jnp.bool_, one_chip)).compile()
     assert _kernels(c) == 1
+    assert _kernel_names(c) == {"shap_tab"}
 
 
 def test_kernel_fits_refuses_what_cannot_fit():

@@ -23,6 +23,7 @@ Contracts pinned here:
 """
 
 import json
+import os
 import socket
 import threading
 import time
@@ -439,6 +440,161 @@ def test_request_trace_spans_and_echo(stats_server):
     # unknown id: clean 404
     code, _, _ = _get(stats_server, "/3/Trace/doesnotexist")
     assert code == 404
+
+
+# ---------------------------------------------------------------------------
+# Training spans: one span function from Frame.from_arrays down to
+# finalize_train, filed in the same ring as a request (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+# the contract PERF.md lists: every child of a `train` root, its kind
+TRAIN_SPANS = {
+    "train.prepare": "host", "train.bin": "enqueue",
+    "train.init_margin": "enqueue", "train.boost": "enqueue",
+    "train.dispatch": "enqueue", "train.read_model": "wait",
+    "train.metric": "wait", "train.finalize": "host",
+}
+
+
+def _check_tree(spans):
+    """ids count from 0, one root, t0 <= t1, children inside parents."""
+    by_id = {s["id"]: s for s in spans}
+    assert sorted(by_id) == list(range(len(spans)))
+    assert [s["id"] for s in spans if s["parent"] is None] == [0]
+    for s in spans:
+        assert s["t0_ns"] <= s["t1_ns"]
+        assert s["ms"] == pytest.approx(
+            (s["t1_ns"] - s["t0_ns"]) / 1e6, abs=1e-3)
+        if s["parent"] is not None:
+            up = by_id[s["parent"]]
+            assert up["t0_ns"] <= s["t0_ns"] and s["t1_ns"] <= up["t1_ns"]
+
+
+def test_train_leaves_one_span_tree(stats_server):
+    telemetry.TRACER.clear()
+    _train_tiny(seed=7)
+    (rec,) = telemetry.TRACER.by_root("train")
+    spans = rec["spans"]
+    _check_tree(spans)
+    root = spans[0]
+    assert (root["name"], root["kind"]) == ("train", "host")
+    assert root["estimator"] == "GBM" and root["ntrees"] == 2
+    assert root["rows"] == 300 and root["features"] == 4
+    assert root["chips"] == 8 and root["max_depth"] == 2
+    assert {s["name"]: s["kind"] for s in spans[1:]} == TRAIN_SPANS
+    by_id = {s["id"]: s for s in spans}
+    for s in spans[1:]:
+        want = "train.boost" if s["name"] == "train.dispatch" else "train"
+        assert by_id[s["parent"]]["name"] == want
+    sent = [s for s in spans if s["name"] == "train.dispatch"]
+    assert sum(s["trees"] for s in sent) == 2 and sent[0]["first_tree"] == 0
+    # the same record a request's would be, at the same endpoint
+    code, tr, _ = _get(stats_server, f"/3/Trace/{rec['trace_id']}")
+    assert code == 200 and tr["root"] == "train"
+    assert [s["name"] for s in tr["spans"]] == [s["name"] for s in spans]
+    # the job's frame is a record of its own, listed oldest first
+    (fr,) = telemetry.TRACER.by_root("frame.from_arrays")
+    _check_tree(fr["spans"])
+    assert fr["spans"][0]["columns"] == 5 and fr["spans"][0]["rows"] == 300
+    kinds = [(s["name"], s["kind"]) for s in fr["spans"][1:]]
+    assert kinds == [("frame.encode", "host"),
+                     ("frame.put", "enqueue")] * 5
+    # 300 rows pad to 304 over the 8 shards, 4 bytes a cell
+    assert all(s["bytes"] == 304 * 4 for s in fr["spans"][1:])
+    assert fr["spans"][0]["t1_ns"] <= root["t0_ns"]
+
+
+def test_trace_off_leaves_no_spans_and_the_same_model(mesh8, monkeypatch):
+    on = _train_tiny(seed=11)
+    telemetry.TRACER.clear()
+    monkeypatch.setenv("H2O_TPU_TRACE", "0")
+    hist = telemetry.train_phase_histogram()
+    before = hist.snapshot("train.bin")["count"]
+    off = _train_tiny(seed=11)
+    assert telemetry.TRACER.by_root("train") == []
+    assert telemetry.TRACER.by_root("frame.from_arrays") == []
+    # the histogram is a counter, not a span record: it stays on
+    assert hist.snapshot("train.bin")["count"] == before + 1
+    for a, b in zip(on.trees, off.trees):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert on.scoring_history == off.scoring_history
+
+
+def test_span_kinds_nest_and_list_by_root():
+    ring_before = len(telemetry.TRACER.by_root("unit_root"))
+    with pytest.raises(ValueError, match="kind"):
+        with telemetry.phase_span("unit_root", kind="device"):
+            pass
+    for i in range(2):
+        with telemetry.phase_span("unit_root", n=i) as root:
+            with telemetry.phase_span("level_hist", kind="enqueue",
+                                      depth=0):
+                pass
+            root["late"] = True         # attributes the block adds
+    recs = telemetry.TRACER.by_root("unit_root")[ring_before:]
+    assert [r["spans"][0]["n"] for r in recs] == [0, 1]   # oldest first
+    assert recs[0]["trace_id"] != recs[1]["trace_id"]
+    for r in recs:
+        _check_tree(r["spans"])
+        assert r["spans"][0]["late"] is True
+        assert (r["spans"][1]["name"], r["spans"][1]["parent"],
+                r["spans"][1]["depth"]) == ("level_hist", 0, 0)
+
+
+def test_telemetry_module_needs_no_jax():
+    """The router serves this module without a device runtime: loaded
+    beside empty parent packages it imports no jax, and a span works
+    without the profiler's annotation."""
+    import subprocess
+    import sys
+
+    pkg = os.path.dirname(os.path.abspath(h2o.__file__))
+    code = (
+        "import sys, types, importlib\n"
+        f"pkg = {pkg!r}\n"
+        "for name, path in (('h2o_kubernetes_tpu', pkg),\n"
+        "                   ('h2o_kubernetes_tpu.runtime',\n"
+        "                    pkg + '/runtime')):\n"
+        "    m = types.ModuleType(name); m.__path__ = [path]\n"
+        "    sys.modules[name] = m\n"
+        "t = importlib.import_module(\n"
+        "    'h2o_kubernetes_tpu.runtime.telemetry')\n"
+        "with t.phase_span('r'):\n"
+        "    with t.phase_span('c', kind='wait'):\n"
+        "        pass\n"
+        "(rec,) = t.TRACER.by_root('r')\n"
+        "assert [s['name'] for s in rec['spans']] == ['r', 'c']\n"
+        "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def test_train_programs_name_jitted_functions():
+    """A trace shows a jitted function as module `jit_<__name__>`:
+    every entry of the table is the name of one that exists, so a
+    rename breaks this test and not a metric."""
+    from h2o_kubernetes_tpu import metrics
+    from h2o_kubernetes_tpu.models import gbm
+    from h2o_kubernetes_tpu.models.tree import binning, core, ooc
+
+    jitted = {v.__name__ for mod in (gbm, core, binning, ooc, metrics)
+              for v in vars(mod).values()
+              if callable(v) and hasattr(v, "lower")
+              and hasattr(v, "__wrapped__")}
+    listed = [n for names in telemetry.TRAIN_PROGRAMS.values()
+              for n in names]
+    assert len(listed) == len(set(listed))
+    assert set(listed) <= jitted, set(listed) - jitted
+    assert "<lambda>" not in listed
+    # the programs a one-chip GBM job runs, by the recorded trace
+    for phase, name in (("bin", "_fused_fit_bin_jit"),
+                        ("bin", "_bin_block_jit"),
+                        ("init", "_init_margin"),
+                        ("boost", "_boost_jit"),
+                        ("metric", "_logloss_w"),
+                        ("metric", "_score_hist_shard")):
+        assert name in telemetry.TRAIN_PROGRAMS[phase]
 
 
 def test_trace_ring_bounded(monkeypatch):
