@@ -202,6 +202,39 @@ def test_every_scope_is_traced(topo):
     assert "auc" in traced(metrics._score_hist_one, col, col, col)
 
 
+@pytest.mark.parametrize("entry", ["_bin_block_jit", "_fused_fit_bin_jit"])
+def test_binning_compiles_without_a_gather_loop(one_chip, entry):
+    """The cell's first column block (16 of HIGGS' 28 columns x
+    4,194,304 rows, 256 bins) through both binning programs. The codes
+    are a count of compares: the chip ran `searchsorted`'s binary search
+    as a `while` of 8 steps, each a per-element gather of one edge,
+    6.3 s a block against 11.5 ms (PERF.md section 6, PR 27). The
+    temporaries stay under what the search took, far under the boost
+    program's reservation."""
+    from h2o_kubernetes_tpu.models.tree import binning
+
+    rows, block = 1 << 22, 16
+    cols = (_s((rows,), jnp.float32, one_chip),) * block
+    enum = _s((block,), jnp.bool_, one_chip)
+    if entry == "_bin_block_jit":
+        args = (cols, _s((block, BINS - 2), jnp.float32, one_chip),
+                BINS - 1, enum)
+    else:
+        args = (_s((F, BINS - 2), jnp.float32, one_chip),
+                _s((F,), jnp.int32, one_chip),
+                # the quantile half is not what this guards, and its
+                # sort compiles in 17 s at 4096 rows: a sliver of a sample
+                _s((128, F), jnp.float32, one_chip),
+                cols, enum, BINS - 1)
+    c = getattr(binning, entry).lower(*args).compile()
+    binned = [ln for ln in c.as_text().split("\n") if re.search(
+        r'op_name="[^"]*\bapply_bins\b', ln)]
+    assert binned, "no operation carries the apply_bins scope"
+    assert not [ln for ln in binned if "searchsorted" in ln]
+    assert not [ln for ln in binned if re.search(r" gather\(", ln)]
+    assert c.memory_analysis().temp_size_in_bytes <= 1.75 * (1 << 30)
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_train_metric_compiles(topo, n_dev):
     """The AUC `GBM.train()` ends with bins 2M scores through the same
