@@ -18,7 +18,7 @@ CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
 | H2O_TPU_NUM_PROCESSES | 1 | multi-host process count (runtime/mesh) |
 | H2O_TPU_PROCESS_ID | 0 | this host's process id (runtime/mesh) |
 | H2O_TPU_HIST_TERMS | 3 | bf16 mantissa terms (2 = throughput mode, ~2⁻¹⁶ products; ops/histogram) |
-| H2O_TPU_HIST_BYTES_BUDGET | 2³⁰ | deep-tree level-histogram memory budget (models/gbm validation + grouped-DRF sizing) |
+| H2O_TPU_HIST_BYTES_BUDGET | 2³⁰ | deep-tree level-histogram memory budget (models/gbm validation, the out-of-core trigger) |
 | H2O_TPU_CV_SHAPE_SHARE_ROWS | tpu≤1M | weights-masked CV row threshold; 0 disables, N forces on any backend (models/cv) |
 | H2O_TPU_ARROW_CSV | 1 | 0 disables the pyarrow CSV fast path (frame/parse) |
 | H2O_TPU_INGEST_CHUNK_BYTES | 16 MiB | pyarrow record-batch size for streamed CSV ingest (frame/parse, docs/SCALING.md) |

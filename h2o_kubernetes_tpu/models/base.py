@@ -1088,8 +1088,14 @@ class Model:
         raise ValueError("confusion_matrix needs a classification model")
 
     def model_performance(self, frame: Frame, y: str) -> dict[str, float]:
+        return self.performance_of(frame, y, self.predict_raw(frame))
+
+    def performance_of(self, frame: Frame, y: str,
+                       out: np.ndarray) -> dict[str, float]:
+        """The metrics of predictions ``out`` (`predict_raw`'s form,
+        padding rows allowed past the frame's) against ``frame[y]``."""
         yv = frame.vec(y)
-        out = self.predict_raw(frame)
+        out = out[: frame.nrows]
         ok = ~np.isnan(yv.as_float().__array__()[: frame.nrows]) \
             if not yv.is_enum() else yv.to_numpy() >= 0
         return score_predictions(self.nclasses, self.distribution,

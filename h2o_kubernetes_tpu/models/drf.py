@@ -8,8 +8,17 @@ average across trees. With g = -y, h = 1 the shared core's leaf value
 so classification leaves hold P(class) directly — no link function.
 
 Depth note: the reference allows max_depth up to 20 via dynamic row
-partitions; the dense-heap TPU layout is per-level O(2^d · F · B), so
-the practical default here is 12 with 64 bins (XRT-style capped depth).
+partitions; the dense-heap TPU layout is per-level O(2^d · F · B) — 147
+MB of level histograms at depth 12 with 64 bins on 28 features, 11.7 GB
+at depth 20 with 20 — so the practical default here is 12 with 64 bins
+(XRT-style capped depth). Past 512 histogrammed nodes a level (depth 12
+at 64 bins) the bin-blocked kernel takes over, and one level of it
+costs more than the eleven above it together (PERF.md §5).
+
+What each tree saw: a forest's model keeps its trees' keys and hands
+out each tree's bag (`tree_bag(t)`) and each node's candidate features
+(`tree_candidates(t)`) on demand — what scoring out of bag starts from
+(models/gbm.py `TreeDraws`).
 """
 
 from __future__ import annotations

@@ -22,7 +22,7 @@ import contextlib
 import functools
 import os
 from dataclasses import dataclass, field
-from typing import Any, Sequence
+from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -31,16 +31,16 @@ from jax import lax
 
 from ..frame import Frame
 from ..runtime.health import device_dispatch, require_healthy
-from ..runtime.mesh import global_mesh
+from ..runtime.mesh import ROWS, global_mesh
 from ..runtime.telemetry import phase_span
 from .base import Model, TrainData, resolve_xy
 from .tree.binning import (BinSpec, apply_bins, apply_bins_jit, fit_bins,
                            fused_binning_enabled, fused_fit_bins)
 from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
                         _grad_hess, boost_trees, boost_trees_drf,
-                        boost_trees_multi, descend_tree, drf_group_size,
-                        flat_margin, flatten_cover, flatten_trees,
-                        goss_round_keys, predict_tree)
+                        boost_trees_multi, descend_tree, flat_margin,
+                        flatten_cover, flatten_trees, goss_round_keys,
+                        predict_tree, round_keys)
 
 
 @dataclass
@@ -132,6 +132,13 @@ def _make_boost_params(p: "GBMParams", distribution: str) -> BoostParams:
         col_sample_rate_per_tree=p.col_sample_rate_per_tree,
         drf_mode=p._drf_mode,
         goss_a=goss_a, goss_b=goss_b)
+
+
+def _draws_from_keys(p: "GBMParams", F: int) -> bool:
+    """True when a round draws anything from its key: a row sample, a
+    column sample, or `mtries` candidates a node."""
+    return p.sample_rate < 1.0 or p.col_sample_rate_per_tree < 1.0 \
+        or 0 < p.mtries < F
 
 
 def _chunk_sizes(p: "GBMParams", padded: int, F: int, K: int,
@@ -248,6 +255,63 @@ def _stack_leaf_nodes(trees: Tree, binned, max_depth: int, n_bins: int):
     return nodes
 
 
+class TreeDraws(NamedTuple):
+    """What a trained ensemble keeps to say what each tree saw. Every
+    draw a boosting round makes — its row sample, its column sample,
+    its nodes' `mtries` candidate features — is a pure function of the
+    round's key, so the keys (a few bytes a round), the layout the
+    rows were sharded in and the sampling parameters are enough:
+    `tree_bag` and `tree_candidates` draw again, on demand, with the
+    functions the grower itself calls (core.row_keep,
+    core.level_candidates). Nothing is kept per row. All fields are
+    plain numbers and lists, so the record goes through JSON as it
+    is; a multinomial round's ``classes`` trees share one row sample.
+    """
+
+    keys: list              # [rounds][2] uint32 key data
+    shards: int             # row shards of the training mesh
+    padded: int             # padded rows over all shards
+    rows: int               # the training frame's rows
+    sample_rate: float
+    col_rate: float
+    mtries: int
+    features: int
+    max_depth: int
+    classes: int = 1        # class trees a round (1: one tree a round)
+
+    def _tree_keys(self, t: int):
+        """(k_row, k_col, k_tree) of tree ``t``, as the boost scan's
+        body splits them off the round's key."""
+        if not 0 <= t < len(self.keys) * self.classes:
+            raise IndexError(
+                f"tree {t} of {len(self.keys) * self.classes}")
+        kt = jax.random.wrap_key_data(
+            jnp.asarray(self.keys[t // self.classes], dtype=jnp.uint32))
+        k_row, k_col, k_tree = jax.random.split(kt, 3)
+        if self.classes > 1:
+            k_tree = jax.random.split(k_tree, self.classes)[
+                t % self.classes]
+        return k_row, k_col, k_tree
+
+    def tree_bag(self, t: int) -> np.ndarray:
+        from .tree.core import tree_bag
+
+        k_row = self._tree_keys(t)[0]
+        with phase_span("model.tree_bag", kind="wait", tree=t):
+            return np.asarray(tree_bag(
+                k_row, self.shards, self.padded // self.shards,
+                float(self.sample_rate)))[:self.rows]
+
+    def tree_candidates(self, t: int) -> np.ndarray:
+        from .tree.core import tree_candidates
+
+        _, k_col, k_tree = self._tree_keys(t)
+        with phase_span("model.tree_candidates", kind="wait", tree=t):
+            return np.asarray(tree_candidates(
+                k_col, k_tree, self.features, self.max_depth,
+                self.mtries, float(self.col_rate)))
+
+
 class GBMModel(Model):
     algo = "gbm"
     _serving_jit = True     # predict routes through the jitted-scorer cache
@@ -278,6 +342,28 @@ class GBMModel(Model):
         self._varimp = varimp
         self._edges = jnp.asarray(bin_spec.edges_matrix())
         self._enum_mask = jnp.asarray(np.array(bin_spec.is_enum))
+
+    # -- what each tree saw (TreeDraws, above) ---------------------------
+    tree_draws = None
+
+    def _draws(self) -> "TreeDraws":
+        if self.tree_draws is None:
+            raise ValueError(
+                "this model keeps no tree keys: it was trained without "
+                "row, column or per-node feature sampling, or continues "
+                "a checkpoint that kept none")
+        return self.tree_draws
+
+    def tree_bag(self, t: int) -> np.ndarray:
+        """bool [rows]: the training rows tree ``t`` kept (its bag; all
+        of them without row sampling), in the training frame's order —
+        what scoring a tree out of bag starts from."""
+        return self._draws().tree_bag(t)
+
+    def tree_candidates(self, t: int) -> np.ndarray:
+        """bool [2^(max_depth+1)-1, F]: the features each heap node of
+        tree ``t`` was offered when it looked for its split."""
+        return self._draws().tree_candidates(t)
 
     def _flat(self) -> FlatTrees:
         """The ONE flattening of this ensemble (serving scorer + MOJO
@@ -320,8 +406,18 @@ class GBMModel(Model):
         """Legacy per-tree heap re-descent over binned codes — the
         training-structure scorer the flat path must match bitwise
         (tests/test_flat_scorer.py, tools/kernel_gate.py)."""
-        binned = apply_bins(X, self._edges, self._enum_mask,
-                            self.bin_spec.na_bin)
+        return self._margins_of_binned(
+            apply_bins(X, self._edges, self._enum_mask,
+                       self.bin_spec.na_bin), offset)
+
+    def _margins_of_binned(self, binned: jax.Array,
+                           offset: jax.Array | None = None) -> jax.Array:
+        """`_margins_binned` from the bin codes themselves. Its program
+        depends on the ensemble's dense shape [T, N] alone, where the
+        flat scorer's width is the model's own (its reachable nodes):
+        training reads a forest's train metric through this one, from
+        the binned matrix it already holds, so that a new forest does
+        not compile a new scorer."""
         K = self.nclasses if self.nclasses > 2 else 1
         p = self.params
         if K == 1:
@@ -343,7 +439,11 @@ class GBMModel(Model):
 
     def _score_matrix(self, X: jax.Array,
                       offset: jax.Array | None = None) -> jax.Array:
-        m = self._margins(X, offset)
+        return self._response(self._margins(X, offset))
+
+    def _response(self, m: jax.Array) -> jax.Array:
+        """Margins to what `predict_raw` hands out: class probabilities
+        or the response's own scale."""
         d = self.distribution
         if d == "bernoulli":
             p1 = jnp.clip(m, 0.0, 1.0) if self.params._drf_mode \
@@ -643,9 +743,9 @@ class GBM:
 
             # deep-tree memory validation: the dense heap's per-level
             # histogram working set is O(2^d·F·B·C) — the SAME accounting
-            # (core.level_hist_bytes) the multinomial vmap branch and the
-            # grouped-DRF G sizing use, so this validator and the actual
-            # branch decisions cannot drift. The reference reaches depth 20
+            # (core.level_hist_bytes) the multinomial vmap branch uses,
+            # so this validator and the actual branch decision cannot
+            # drift. The reference reaches depth 20
             # via dynamic row partitions; here ANY depth whose level
             # histograms fit the budget trains fine (e.g. depth 16 with 4
             # features × 16 bins is ~25 MB), and one that cannot fit fails
@@ -655,8 +755,7 @@ class GBM:
 
             # histogram accounting at the width histograms actually have:
             # the BUNDLED width when EFB engaged (the memory win is exactly
-            # what buys deeper trees / more grouped-DRF parallelism on
-            # wide sparse frames)
+            # what buys deeper trees on wide sparse frames)
             hist_bytes = level_hist_bytes(tp, F_eff)
             if K > 1 and multi_grow_vmapped(tp, F_eff, K):
                 # validate the memory that will actually be live: K× only
@@ -793,6 +892,7 @@ class GBM:
         if ckpt is not None:
             start_t = len(ckpt.trees.value) // K
         history: list[dict] = []
+        key_chunks: list = []
         # fused loop: all boosting rounds of a chunk build inside ONE
         # compiled shard_map (scan over rounds; for K>2 classes the K
         # trees of a round grow via vmap inside the scan) — the margin
@@ -824,7 +924,7 @@ class GBM:
         else:
             with phase_span("train.boost", kind="enqueue", mode="in_hbm",
                             trees=p.ntrees):
-                trees, margin, history = self._boost_in_hbm(
+                trees, margin, history, key_chunks = self._boost_in_hbm(
                     p, tp, bp, data, binned, margin, key, K, F_eff,
                     ckpt, start_t, history, efb=efb,
                     goss_keys=goss_keys)
@@ -845,10 +945,28 @@ class GBM:
                                    init_score=init, varimp=None)
             model.margin_scale = margin_scale
             model.offset_column = offset_column
+            if key_chunks:
+                model.tree_draws = TreeDraws(
+                    keys=np.concatenate(
+                        [np.asarray(k) for k in key_chunks]).tolist(),
+                    shards=global_mesh().shape[ROWS],
+                    padded=int(data.y.shape[0]), rows=int(data.nrows),
+                    sample_rate=p.sample_rate,
+                    col_rate=p.col_sample_rate_per_tree, mtries=p.mtries,
+                    features=len(data.feature_names),
+                    max_depth=p.max_depth, classes=K)
             model._varimp = _stacked_varimp(model.trees, data.feature_names)
         with phase_span("train.metric", kind="wait"):
             if p._drf_mode:
-                perf = model.model_performance(training_frame, y)
+                if binned is not None and efb is None:
+                    # the forest over the binned matrix training holds
+                    # (bitwise what scoring the frame gives)
+                    perf = model.performance_of(
+                        training_frame, y,
+                        np.asarray(model._response(
+                            model._margins_of_binned(binned))))
+                else:
+                    perf = model.model_performance(training_frame, y)
                 history.append({"ntrees": p.ntrees,
                                 **{f"train_{k}": v for k, v in perf.items()}})
             elif not (history and history[-1].get("ntrees") == p.ntrees):
@@ -882,14 +1000,27 @@ class GBM:
         depends on the _DISPATCH_BUDGET chunk schedule."""
         chunks: list[Tree] = [] if ckpt is None else [ckpt.trees]
         goss_overflow: list = []      # per-dispatch device scalars
+        # the keys of the rounds grown, kept while a round draws
+        # anything from its key (the model hands out each tree's bag
+        # and candidates from them): a checkpoint's first, where it
+        # kept them (one that did not leaves the new model without)
+        sampled = _draws_from_keys(p, len(data.feature_names))
+        key_chunks: list = []
+        if sampled and ckpt is not None:
+            prior = getattr(ckpt, "tree_draws", None)
+            sampled = prior is not None
+            if sampled:
+                key_chunks.append(np.asarray(prior.keys, np.uint32))
         # cap ONE compiled dispatch's work: the TPU worker (behind
-        # its RPC deadline) kills executions that run for minutes —
-        # observed: 25 depth-12 trees on 1M rows crash the worker,
-        # 10 pass. Work/round ~ rows·F·nbins·2^depth·K (deepest level
-        # dominates with sibling subtraction); the budget keeps a
-        # dispatch around ~10s on v5e and leaves shallow/bench
-        # shapes in a single dispatch. The chunk schedule lives in
-        # _chunk_sizes — compile-ahead pre-lowers exactly these shapes.
+        # its RPC deadline) kills executions that run for minutes.
+        # Work/round ~ rows·F·nbins·2^depth·K (deepest level
+        # dominates with sibling subtraction): 1.9e12 units are 1.0 s
+        # on a v5e at depth 6 x 256 bins (the factorized kernel), so
+        # the budget keeps a dispatch within seconds and leaves
+        # shallow/bench shapes in a single dispatch. A forest's trees
+        # go by the same rule, one a scan step. The chunk schedule
+        # lives in _chunk_sizes — compile-ahead pre-lowers exactly
+        # these shapes.
         score = p.score_every if (p.score_every and not p._drf_mode) \
             else 0
         t = start_t
@@ -910,10 +1041,9 @@ class GBM:
                     phase_span("train.dispatch", kind="enqueue",
                                first_tree=t, trees=n):
                 if K == 1 and p._drf_mode:
-                    # independent forest trees grow in vmapped GROUPS
-                    # (the class-flattening kernel rule): G× fuller MXU
-                    # M at shallow levels, G× fewer sequential steps
-                    margin, tchunk = boost_trees_drf(
+                    # a forest's trees are independent: no margin
+                    # update, and the trees' own keys come back
+                    margin, tchunk, kchunk = boost_trees_drf(
                         binned, data.y, data.w, margin, kc, n, tp, bp,
                         efb=efb)
                 elif K == 1:
@@ -936,6 +1066,10 @@ class GBM:
                     tchunk = jax.tree.map(
                         lambda a: a.reshape((-1,) + a.shape[2:]), tchunk)
             chunks.append(tchunk)
+            if sampled:
+                if not (K == 1 and p._drf_mode):
+                    kchunk = round_keys(kc, n)
+                key_chunks.append(jax.random.key_data(kchunk))
             t += n
             if score and (t - start_t) % score == 0:
                 history.append({"ntrees": t, **_margin_metrics(
@@ -946,7 +1080,7 @@ class GBM:
         if goss_overflow:
             _warn_goss_overflow(
                 int(sum(int(jax.device_get(o)) for o in goss_overflow)))
-        return trees, margin, history
+        return trees, margin, history, key_chunks
 
     # -- compile-ahead (runtime/scheduler.py) ---------------------------
 
@@ -1085,14 +1219,12 @@ class GBM:
                 # unbundled dispatch shapes (EFB plans are
                 # data-dependent, and the auto gate keeps narrow
                 # frames — everything this mirror serves — unbundled)
+                keys_s = jax.ShapeDtypeStruct((nt,), keydt)
                 if K == 1 and p._drf_mode:
-                    G, rounds = drf_group_size(nt, tp, F)
-                    keys_s = jax.ShapeDtypeStruct((rounds, G), keydt)
                     thunks.append(functools.partial(
                         _aot, _core._boost_drf_jit, binned_s, row_s,
-                        row_s, margin_s, keys_s, None, tp, bp, G, mesh))
+                        row_s, margin_s, keys_s, None, tp, bp, mesh))
                     continue
-                keys_s = jax.ShapeDtypeStruct((nt,), keydt)
                 if bp.goss_b > 0:
                     # GOSS scans a (round keys, goss keys) pair —
                     # mirror boost_trees' operand structure exactly

@@ -272,6 +272,31 @@ def row_orig_bins(binned, f, efb):
     return jnp.where(sf == f, sb, efb.feat_default[f]).astype(jnp.int32)
 
 
+def level_candidates(key, d: int, col_mask, mtries: int):
+    """[2^d, F] bool: the features each node of level ``d`` may split
+    on, a pure function of the tree's key. Every node has the tree's
+    column sample ``col_mask``; with ``mtries`` in (0, F) each node
+    keeps exactly the ``mtries`` of them that draw lowest (DRF;
+    reference: DTree per-split feature sampling, SURVEY.md §2b C10).
+    Equal draws are told apart by the feature's index: two of a
+    node's float32 draws are equal in about one node of 20,000 at 28
+    features, and a tie at the ``mtries``-th place would offer that
+    node one feature more. The grower calls this, and so does
+    `tree_candidates` when a model is asked what a tree's nodes were
+    offered."""
+    F = col_mask.shape[0]
+    feat_ok = jnp.broadcast_to(col_mask[None, :], (2 ** d, F))
+    if 0 < mtries < F:
+        r = jax.random.uniform(jax.random.fold_in(key, d), (2 ** d, F))
+        r = jnp.where(feat_ok, r, jnp.inf)
+        f = jnp.broadcast_to(jnp.arange(F), r.shape)
+        r_sorted, f_sorted = lax.sort((r, f), dimension=1, num_keys=2)
+        kth_r = r_sorted[:, mtries - 1: mtries]
+        kth_f = f_sorted[:, mtries - 1: mtries]
+        feat_ok = feat_ok & ((r < kth_r) | ((r == kth_r) & (f <= kth_f)))
+    return feat_ok
+
+
 def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
                      efb=None):
     """Per-shard tree build (runs under shard_map; histograms psum'd).
@@ -291,8 +316,8 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
     metadata only, the operations' `op_name` in a compiled program and
     in a profile.
     """
-    F = col_mask.shape[0]       # ORIGINAL feature count (== binned
-    #                             width only when efb is None)
+    # col_mask is in ORIGINAL feature space (== binned width only when
+    # efb is None)
     N = 2 ** (p.max_depth + 1) - 1
     split_feat = jnp.full(N, -1, dtype=jnp.int32)
     split_bin = jnp.zeros(N, dtype=jnp.int32)
@@ -378,16 +403,7 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
                 hist = jnp.stack([hist_l, hist_r], axis=1).reshape(
                     n_nodes, binned.shape[1], p.n_bins, 3)
         with jax.named_scope("split_find"):
-            feat_ok = jnp.broadcast_to(col_mask[None, :], (n_nodes, F))
-            if p.mtries > 0 and p.mtries < F:
-                # DRF: exactly mtries features per node (reference:
-                # DTree per-split feature sampling with mtries,
-                # SURVEY.md §2b C10)
-                r = jax.random.uniform(jax.random.fold_in(key, d),
-                                       (n_nodes, F))
-                r = jnp.where(feat_ok, r, jnp.inf)
-                kth = jnp.sort(r, axis=1)[:, p.mtries - 1: p.mtries]
-                feat_ok = feat_ok & (r <= kth)
+            feat_ok = level_candidates(key, d, col_mask, p.mtries)
             (feat, bin_, na_l, can, val, g_best, cov, left_ch,
              right_ch) = _find_splits(hist, p, feat_ok, efb)
             idx = off + jnp.arange(n_nodes)
@@ -512,17 +528,51 @@ def _round_sampling(bp: BoostParams, w, F: int, k_row, k_col):
     in sync."""
     w_t = w
     if bp.sample_rate < 1.0:
-        # fold in the shard index: every shard holds different rows
-        # and must draw an independent keep-pattern
-        k_row_s = jax.random.fold_in(k_row, lax.axis_index(ROWS))
-        keep = jax.random.uniform(k_row_s, w.shape) < bp.sample_rate
-        w_t = w * keep
-    col_mask = jnp.ones(F, dtype=bool)
-    if bp.col_sample_rate_per_tree < 1.0:
-        # same key on every shard → consistent replicated mask
-        col_mask = jax.random.uniform(
-            k_col, (F,)) < bp.col_sample_rate_per_tree
-    return w_t, col_mask
+        w_t = w * row_keep(k_row, lax.axis_index(ROWS), w.shape[0],
+                           bp.sample_rate)
+    return w_t, tree_col_mask(k_col, F, bp.col_sample_rate_per_tree)
+
+
+def row_keep(k_row, shard, rows: int, sample_rate: float):
+    """[rows] bool: the rows of shard ``shard`` that one tree keeps
+    (its bag). The shard index is folded in: every shard holds
+    different rows and draws a keep-pattern of its own."""
+    return jax.random.uniform(jax.random.fold_in(k_row, shard),
+                              (rows,)) < sample_rate
+
+
+def tree_col_mask(k_col, F: int, rate: float):
+    """[F] bool: the tree's column sample — the same key on every
+    shard, so the mask is replicated."""
+    if rate < 1.0:
+        return jax.random.uniform(k_col, (F,)) < rate
+    return jnp.ones(F, dtype=bool)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def tree_bag(k_row, shards: int, rows_per_shard: int,
+             sample_rate: float):
+    """[shards * rows_per_shard] bool: the rows a tree kept, in the
+    padded frame's row order — the draw `_round_sampling` makes on
+    every shard, made again from the tree's row key."""
+    if sample_rate >= 1.0:
+        return jnp.ones(shards * rows_per_shard, dtype=bool)
+    return jax.vmap(lambda s: row_keep(k_row, s, rows_per_shard,
+                                       sample_rate))(
+        jnp.arange(shards)).reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def tree_candidates(k_col, k_tree, F: int, max_depth: int, mtries: int,
+                    col_rate: float):
+    """[2^(max_depth+1)-1, F] bool: the features each heap node of a
+    tree was offered (the deepest level's nodes are leaves and were
+    offered none), from the tree's column and node keys."""
+    col_mask = tree_col_mask(k_col, F, col_rate)
+    return jnp.concatenate(
+        [level_candidates(k_tree, d, col_mask, mtries)
+         for d in range(max_depth)]
+        + [jnp.zeros((2 ** max_depth, F), dtype=bool)])
 
 
 # ---------------------------------------------------------------------------
@@ -684,6 +734,15 @@ def goss_compact(binned, g, h, w_amp, cap: int):
     return binned[idx], gC, hC, wC, dropped
 
 
+def round_keys(key, n_rounds: int):
+    """[n_rounds] keys of one dispatch's boosting rounds. Everything a
+    round draws — its row sample, its column sample, its nodes'
+    candidate features — is a pure function of its key, so a model
+    that keeps the keys can say what each tree saw (`tree_bag`,
+    `tree_candidates`)."""
+    return jax.random.split(key, n_rounds)
+
+
 def _boost_shard(binned, y, w, margin, keys, efb=None, *,
                  p: TreeParams, bp: BoostParams):
     """Scan over trees INSIDE one shard_map: grad/hess → grow → local
@@ -756,8 +815,8 @@ def level_hist_bytes(p: TreeParams, F: int) -> int:
     covers hist_prev, hist_l, hist_r (2^(d-1) nodes each) and the
     stacked level (2^d nodes) live at once. THE single accounting used
     by the up-front budget validation (models/gbm.py), the multinomial
-    vmap-vs-lax.map branch, and grouped-DRF G sizing — one formula so
-    the validator and the branch decisions cannot drift."""
+    vmap-vs-lax.map branch — one formula so the validator and the
+    branch decision cannot drift."""
     C = 2 if p.unit_hess else 3
     return 5 * (2 ** max(p.max_depth - 1, 0)) * F * p.n_bins * C * 4
 
@@ -857,41 +916,35 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
 
 
 def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
-                     p: TreeParams, bp: BoostParams, G: int):
-    """DRF grouped growth: forest trees are INDEPENDENT (no margin
-    coupling), so G trees grow per scan step via vmap — the
-    class-flattening custom_vmap rule relabels tree g's rows to nodes
-    [g·n_nodes, (g+1)·n_nodes) and ONE kernel call covers the group.
-    Two wins over the sequential scan: the MXU M dimension (channels ×
-    hi-slots) is G× fuller at shallow tree levels (PROFILE.md names
-    sub-128 M as a main MFU lever), and the per-level sequencing
-    overhead amortizes over G trees. keys: [rounds, G]."""
+                     p: TreeParams, bp: BoostParams):
+    """Forest growth: the trees are INDEPENDENT (no margin coupling),
+    one a scan step, each on the bag and the candidate features its
+    own key draws. Growing several a step under vmap was measured on a
+    v5e and never won (PERF.md §6, PR 28: a tie while every merged
+    level stays in the factorized kernel, a loss once one reaches the
+    bin-blocked kernel, at G times the temporaries), so there is one
+    path. keys: [n_trees]."""
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     g0 = -y
     h0 = jnp.ones_like(y)
 
-    def body(carry, kt_group):
-        def grow_one(kt):
-            k_row, k_col, k_tree = jax.random.split(kt, 3)
-            with jax.named_scope("sample"):
-                w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
-            tree, _ = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
-                                       k_tree, p, efb)
-            return tree
-
-        return carry, jax.vmap(grow_one)(kt_group)
+    def body(carry, kt):
+        k_row, k_col, k_tree = jax.random.split(kt, 3)
+        with jax.named_scope("sample"):
+            w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
+        tree, _ = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
+                                   k_tree, p, efb)
+        return carry, tree
 
     _, trees = lax.scan(body, 0, keys)
-    # [rounds, G, N] -> [rounds*G, N]
-    return margin, jax.tree.map(
-        lambda a: a.reshape((-1,) + a.shape[2:]), trees)
+    return margin, trees
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
 def _boost_drf_jit(binned, y, w, margin, keys, efb, p: TreeParams,
-                   bp: BoostParams, G: int, mesh):
+                   bp: BoostParams, mesh):
     fn = jax.shard_map(
-        functools.partial(_boost_shard_drf, p=p, bp=bp, G=G),
+        functools.partial(_boost_shard_drf, p=p, bp=bp),
         mesh=mesh,
         in_specs=(P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P()),
         out_specs=(P(ROWS), P()),
@@ -899,55 +952,18 @@ def _boost_drf_jit(binned, y, w, margin, keys, efb, p: TreeParams,
     return fn(binned, y, w, margin, keys, efb)
 
 
-def drf_group_size(n_trees: int, p: TreeParams, F: int) -> tuple[int, int]:
-    """(G, rounds) for the grouped DRF grow — the ONE sizing used by
-    boost_trees_drf and by compile-ahead (models/gbm.py), so the
-    pre-lowered executable's key shape cannot drift from the dispatch.
-
-    Same live-histogram accounting as the multinomial path: vmap
-    multiplies per-level histogram memory by G. Grouping only pays on
-    the MXU (fuller M, fewer kernel launches); under the segment impl
-    (CPU mesh) it just multiplies live memory on a shared host — and
-    the virtual-device mesh multiplies it again by the shard count —
-    so grow sequentially there."""
-    hist_bytes = level_hist_bytes(p, F)
-    if _resolve_impl(p.hist_impl) != "pallas":
-        G = 1
-    else:
-        # the user's histogram-memory budget (gbm.py validates single-
-        # tree fit against it) also caps the GROUP's live memory — a
-        # grouped grow must not exceed what the validation promised
-        import os as _os
-
-        budget = min(_MULTI_HIST_BUDGET,
-                     int(float(_os.environ.get(
-                         "H2O_TPU_HIST_BYTES_BUDGET", 2 ** 30))))
-        G = int(max(1, min(n_trees, 16, budget // hist_bytes)))
-    rounds = -(-n_trees // G)
-    # rebalance: n_trees=20, G=16 would grow 2 rounds x 16 = 32 trees
-    # and throw 12 away; G = ceil(n_trees / rounds) keeps the same
-    # round count (and stays under the old G, hence under budget) with
-    # minimal padded work
-    return -(-n_trees // rounds), rounds
-
-
 def boost_trees_drf(binned, y, w, margin, key, n_trees: int,
                     p: TreeParams, bp: BoostParams, mesh=None,
                     efb=None):
-    """Grouped DRF forest growth: n_trees independent trees in ONE
-    dispatch, vmapped in groups sized to the histogram memory budget
-    (drf_group_size). Returns (margin unchanged, trees [n_trees, N]).
-    Group sizing uses the HISTOGRAM width — the bundled width under
-    EFB, which is the whole point: more trees fit a group."""
+    """A forest's trees in ONE dispatch, one a scan step. Returns
+    (margin unchanged, trees [n_trees, N], the trees' keys [n_trees]):
+    every draw a tree makes — its bag, its nodes' candidate features —
+    is a pure function of its key (`tree_bag`, `tree_candidates`)."""
     assert bp.drf_mode
-    F = binned.shape[1]
-    G, rounds = drf_group_size(n_trees, p, F)
-    keys = jax.random.split(key, rounds * G).reshape(rounds, G)
+    keys = round_keys(key, n_trees)
     margin, trees = _boost_drf_jit(binned, y, w, margin, keys, efb,
-                                   p, bp, G, mesh or global_mesh())
-    if rounds * G != n_trees:       # drop the last group's padding
-        trees = jax.tree.map(lambda a: a[:n_trees], trees)
-    return margin, trees
+                                   p, bp, mesh or global_mesh())
+    return margin, trees, keys
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
@@ -971,7 +987,7 @@ def boost_trees_multi(binned, y, w, margin, key, n_trees: int, K: int,
     compiled dispatch. Returns (margin [rows, K], trees [T, K, N]) —
     plus the GOSS overflow scalar when sampling is active (see
     boost_trees)."""
-    keys = jax.random.split(key, n_trees)
+    keys = round_keys(key, n_trees)
     if bp.goss_b > 0.0:
         if goss_keys is None:
             goss_keys = goss_round_keys(key, n_trees)
@@ -1006,7 +1022,7 @@ def boost_trees(binned, y, w, margin, key, n_trees: int, p: TreeParams,
     with GOSS off the scanned operand is the plain key array,
     byte-identical to a build without the feature.
     """
-    keys = jax.random.split(key, n_trees)
+    keys = round_keys(key, n_trees)
     if bp.goss_b > 0.0:
         if goss_keys is None:
             goss_keys = goss_round_keys(key, n_trees)

@@ -174,6 +174,32 @@ def test_boost_scan_names_its_kernel_and_scopes(boost_scan, n_dev):
                for n in re.findall(r'op_name="([^"]+)"', txt))
 
 
+@pytest.mark.parametrize("depth,kernels", [
+    (6, {"hist_fact"}), (12, {"hist_fact", "hist_blocked"})])
+def test_forest_scan_holds_one_trees_temporaries(topo, depth, kernels):
+    """`_boost_drf_jit` — what `DRF.train()` dispatches — grows one
+    tree a scan step, so six trees a dispatch reserve what one does
+    (grouped under vmap they reserved six times that: 25 G of a 16 G
+    chip at 4,194,304 rows, PERF.md section 6, PR 28). At depth 12 x 64
+    bins the deepest histogram level (1,024 left children) is past the
+    factorized kernel's reach and the bin-blocked kernel takes it: the
+    one path of `drf-higgs.train` that no other cell runs."""
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
+    args = _boost_args(mesh, ROWS_N, ntrees=1)
+    tp = args[6]._replace(max_depth=depth, n_bins=64, min_rows=1.0,
+                          mtries=5, unit_hess=True)
+    bp = args[7]._replace(sample_rate=0.632, drf_mode=True,
+                          learn_rate=1.0)
+    temp = {}
+    for ntrees in (1, 6):
+        a = _boost_args(mesh, ROWS_N, ntrees)
+        c = core._boost_drf_jit.lower(*a[:6], tp, bp, mesh).compile()
+        assert _kernels(c) == depth and _kernel_names(c) == kernels
+        temp[ntrees] = c.memory_analysis().temp_size_in_bytes
+    # the trees stacked for the way out are the difference
+    assert temp[6] < 1.5 * temp[1]
+
+
 def test_every_scope_is_traced(topo):
     """Before the compiler folds anything: the traced programs of the
     training path hold every scope name PERF.md lists."""
