@@ -12,8 +12,9 @@ partitions; the dense-heap TPU layout is per-level O(2^d · F · B) — 147
 MB of level histograms at depth 12 with 64 bins on 28 features, 11.7 GB
 at depth 20 with 20 — so the practical default here is 12 with 64 bins
 (XRT-style capped depth). Past 512 histogrammed nodes a level (depth 12
-at 64 bins) the bin-blocked kernel takes over, and one level of it
-costs more than the eleven above it together (PERF.md §5).
+at 64 bins) the histogram kernel serves the level in several blocks of
+hi slots (`hist_blocked` in a profile), each at the 512-node level's
+cost: a level costs by its nodes x bins and no more (PERF.md §5).
 
 What each tree saw: a forest's model keeps its trees' keys and hands
 out each tree's bag (`tree_bag(t)`) and each node's candidate features

@@ -921,9 +921,9 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
     one a scan step, each on the bag and the candidate features its
     own key draws. Growing several a step under vmap was measured on a
     v5e and never won (PERF.md §6, PR 28: a tie while every merged
-    level stays in the factorized kernel, a loss once one reaches the
-    bin-blocked kernel, at G times the temporaries), so there is one
-    path. keys: [n_trees]."""
+    level stays within one hi block of the histogram kernel, a loss
+    once one reached the bin-blocked kernel of the time, at G times
+    the temporaries), so there is one path. keys: [n_trees]."""
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     g0 = -y
     h0 = jnp.ones_like(y)

@@ -9,13 +9,17 @@ Two implementations:
 
 - `segment`: jax.ops.segment_sum per feature — XLA lowers this to
   scatter-add, which is fine on CPU but serializes on TPU.
-- `pallas`: scatter-free MXU formulation. For a row tile, the one-hot
-  membership matrix over (node·B + bin) is built in VMEM and multiplied
-  against the per-row value rows: histᵀ += valsᵀ @ onehot — a [3,T] x
-  [T, NBT] matmul per (feature, bin-block, row-tile) grid cell, so the
-  entire histogram build rides the systolic array (the GPU literature's
+- `pallas`: scatter-free MXU formulation, ONE kernel for every depth.
+  A row's cell seg = node·B + bin is factorized as seg = hi·128 + lo;
+  for a row tile the value channels are packed against the hi one-hot
+  (A[c·ht + hi, t], ht hi slots a block) and multiplied with the exact
+  lo one-hot [T, 128], so the whole histogram build rides the systolic
+  array with full MXU rows and one lane pass (the GPU literature's
   shared-memory atomics have no TPU analog; matmul inflation is the
-  right trade — see PAPERS.md GBDT-on-accelerator entries).
+  right trade — see PAPERS.md GBDT-on-accelerator entries). A level
+  with more than `_FACT_MAX_NHI` hi slots is served in blocks of hi
+  slots along one grid axis: a row whose slot lies in another block
+  matches nothing there, as a dead row does.
 
 `build_histogram(..., impl="auto")` picks pallas on TPU, segment
 elsewhere. Both run under shard_map (per-shard rows); callers psum the
@@ -35,12 +39,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # Precision: a plain bf16 multiply loses ~0.4% on the gradient sums, so
-# both kernels reproduce f32 products with THREE explicit bf16 mantissa
+# the kernel reproduces f32 products with THREE explicit bf16 mantissa
 # terms of the values against the exactly-representable 0/1 one-hot —
 # the same arithmetic HIGHEST would emulate, minus the wasted passes on
 # the one-hot operand (it is already bf16-exact).
-ROW_TILE = 1024     # bin-blocked kernel's row tile (its [T, nbt] one-hot
-#                     is VMEM-bounded: 4 MB bf16 at T=1024, nbt=2048)
 
 
 def _interpret() -> bool:
@@ -50,13 +52,13 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _fact_row_tile(n_hi: int, rows: int) -> int:
-    """Row tile for the factorized kernel. Wider tiles amortize
+def _fact_row_tile(ht: int, rows: int) -> int:
+    """Row tile for a hi block of ``ht`` slots. Wider tiles amortize
     per-grid-step overhead (the bench shape runs ~250 steps/level at
-    4096 instead of ~1000), but the [3·C·n_hi, T] A operand scales with
-    T — stay at 1024 when n_hi is large (VMEM ~16 MB/core) or the rows
-    wouldn't fill a wide tile anyway."""
-    return 4096 if n_hi <= 64 and rows >= 8192 else 1024
+    4096 instead of ~1000), but the [3·C·ht, T] A operand scales with
+    T — stay at 1024 when the block is large (VMEM ~16 MB/core) or the
+    rows wouldn't fill a wide tile anyway."""
+    return 4096 if ht <= 64 and rows >= 8192 else 1024
 
 
 # out-block VMEM budget for the fused-feature kernel: features are
@@ -95,18 +97,6 @@ def _hist_segment(binned, rel, vals, n_nodes: int, n_bins: int):
     return jax.vmap(per_feature, in_axes=1, out_axes=1)(binned)
 
 
-def _bin_block(n_nodes: int, n_bins: int) -> int:
-    """Bin-block width: B times the largest power-of-2 node group that
-    keeps the one-hot tile around ~2k lanes (VMEM-bounded). The group
-    must divide n_nodes so the grid tiles evenly — n_nodes is 2^d for
-    plain trees but K·2^d under the flattened class batching."""
-    k = 1
-    while k * 2 <= n_nodes and (k * 2) * n_bins <= 2048 \
-            and n_nodes % (k * 2) == 0:
-        k *= 2
-    return k * n_bins
-
-
 def _mantissa_terms(vals_t, terms: int):
     """Split [n_ch, T] f32 values into `terms` stacked bf16 mantissa
     terms whose products against a 0/1 operand sum back to the f32
@@ -123,26 +113,26 @@ def _mantissa_terms(vals_t, terms: int):
 
 
 def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
-                      n_hi, n_ch, fg, terms):
-    """Factorized one-hot histogram matmul (the fast path).
+                      ht, n_ht, n_ch, fg, terms):
+    """Factorized one-hot histogram matmul, one block of ``ht`` hi slots.
 
     seg = rel·B + bin is split as seg = hi·128 + lo.  The LHS packs the
-    three weighted value channels against the hi one-hot —
-    A[c·n_hi + hi, t] = v_c[t]·1[hi_t = hi] — and the RHS is the exact
-    lo one-hot [T, 128], so hist[c, seg] = (A @ B)[c·n_hi + hi, lo].
-    Against the bin-blocked kernel below this turns the MXU shape from
-    [3, T]x[T, ≤2048] (3/128 row occupancy, ≤16 lane passes) into
-    [3·n_hi, T]x[T, 128] (full rows for n_hi ≥ 43, ONE lane pass).  A is
-    split into three bf16 terms (hi/mid/lo mantissa) so the f32 products
-    match the segment path to ~2^-24; B is 0/1 and thus exact in bf16.
+    weighted value channels against the hi one-hot —
+    A[c·ht + hi, t] = v_c[t]·1[hi_t = hi] — and the RHS is the exact
+    lo one-hot [T, 128], so hist[c, seg] = (A @ B)[c·ht + hi, lo]: the
+    MXU sees [3·C·ht, T]x[T, 128] (full rows for ht ≥ 43, ONE lane
+    pass), where a one-hot over the cells themselves would fill C of
+    its 128 rows.  A is split into three bf16 terms (hi/mid/lo mantissa)
+    so the f32 products match the segment path to ~2^-24; B is 0/1 and
+    thus exact in bf16.
     """
-    # grid (feature_groups, n_copies, row_blocks): one step covers a
-    # whole FEATURE GROUP of fg features for its row block — the
-    # row-stream operands (rel, vals, mantissa split) load and compute
-    # ONCE per row block instead of once per (feature, row block), and
-    # the grid shrinks F× (per-step sequencing overhead, not FLOPs, was
-    # the round-2/3 bench bottleneck).
-    first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+    # grid (feature_groups, hi_blocks, n_copies, row_blocks): one step
+    # covers a whole FEATURE GROUP of fg features for its row block —
+    # the row-stream operands (rel, vals, mantissa split) load and
+    # compute ONCE per row block instead of once per (feature, row
+    # block), and the grid shrinks F× (per-step sequencing overhead,
+    # not FLOPs, was the round-2/3 bench bottleneck).
+    first = (pl.program_id(2) == 0) & (pl.program_id(3) == 0)
 
     @pl.when(first)
     def _():
@@ -150,21 +140,25 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
 
     rel = rel_ref[:]                                 # [T]
     rel_base = rel * n_bins
+    if n_ht > 1:
+        # this step's hi block starts at slot program_id·ht: shift the
+        # cell index so the block's slots read 0..ht-1 below
+        rel_base = rel_base - pl.program_id(1) * (ht * 128)
     T = rel.shape[0]
     vals_t = vals_ref[:].T                           # [n_ch, T]
     # f32-precision via `terms` bf16 mantissa terms, split on the TINY
     # [n_ch, T] values and masked by the 0/1 one-hot IN bf16 —
     # bit-identical to splitting the big masked A (0/1 masking commutes
-    # with rounding) but skips materializing a [n_ch*n_hi, T] f32 A
+    # with rounding) but skips materializing a [n_ch*ht, T] f32 A
     # plus two subtract passes over it: the A-build drops from ~6
     # f32-width VPU passes to `terms` bf16-width multiplies.
     V = _mantissa_terms(vals_t, terms)               # [terms·n_ch, T]
-    iota_hi = lax.broadcasted_iota(jnp.int32, (n_hi, T), 0)
+    iota_hi = lax.broadcasted_iota(jnp.int32, (ht, T), 0)
     iota_lo = lax.broadcasted_iota(jnp.int32, (T, 128), 1)
     dn = (((1,), (0,)), ((), ()))
 
     # REAL loop over the feature group, not a static unroll: Mosaic
-    # stack-allocates every unrolled iteration's [3·n_ch·n_hi, T] A
+    # stack-allocates every unrolled iteration's [3·n_ch·ht, T] A
     # operand separately (fg=10 at T=4096 → 22 MB, past the 16 MB
     # scoped-vmem limit — caught by the on-chip gate), while a
     # fori_loop body's buffers are reused across iterations. The
@@ -175,41 +169,53 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
         seg = rel_base + bins
         hi = lax.shift_right_arithmetic(seg, 7)      # floor(seg/128)
         lo = seg - hi * 128                          # seg mod 128, >= 0
-        # hi one-hot, transposed [n_hi, T]. Dead rows (rel=-1) have
-        # hi < 0 and match no slot; their vals are zeroed upstream.
+        # hi one-hot, transposed [ht, T]. Dead rows (rel=-1) have
+        # hi < 0 and match no slot, and neither does a row whose slot
+        # lies in another hi block (hi < 0 or hi >= ht); dead rows'
+        # vals are zeroed upstream.
         oh_hi = (iota_hi == hi[None, :]).astype(jnp.bfloat16)
         B = (iota_lo == lo[:, None]).astype(jnp.bfloat16)
         # ONE matmul with all mantissa terms stacked into M — the
-        # MXU's row occupancy multiplies (terms·n_ch·n_hi rows instead
-        # of `terms` passes of n_ch·n_hi); the per-term partial sums
-        # recombine with one cheap VPU add over [n_ch·n_hi, 128]. Same
+        # MXU's row occupancy multiplies (terms·n_ch·ht rows instead
+        # of `terms` passes of n_ch·ht); the per-term partial sums
+        # recombine with one cheap VPU add over [n_ch·ht, 128]. Same
         # bf16 products, same f32 accumulation.
         a = jnp.concatenate(
             [oh_hi * V[k][None, :] for k in range(terms * n_ch)],
-            axis=0)                             # [terms·n_ch·n_hi, T]
+            axis=0)                             # [terms·n_ch·ht, T]
         acc = lax.dot_general(a, B, dimension_numbers=dn,
                               preferred_element_type=jnp.float32)
-        acc = acc.reshape(terms, n_ch * n_hi, 128)
-        out_ref[0, j] += acc.sum(axis=0)             # [n_ch·n_hi, 128]
+        acc = acc.reshape(terms, n_ch * ht, 128)
+        out_ref[0, 0, j] += acc.sum(axis=0)          # [n_ch·ht, 128]
         return carry
 
     lax.fori_loop(0, fg, _feature, 0)
 
 
-# VMEM cap for the factorized kernel's working set. With the stacked-
-# term matmul the peak is the bf16 A [3·n_ch·n_hi, T] (4.7 MB at
-# n_hi=256, C=3, T=1024 — _fact_row_tile drops to 1024 past n_hi=64)
-# plus the [n_hi, T] hi one-hot, the [T, 128] lo one-hot, the f32
-# [3·n_ch·n_hi, 128] dot result (1.2 MB) and the resident out block
-# (_OUT_BUDGET) — ~10 MB worst case against ~16 MB/core VMEM. TIGHT:
-# the on-chip kernel gate compiles exactly this cap shape as
-# `fact_kernel_cap`; if it fails there, lower this cap. Deeper trees
-# (n_nodes·n_bins > 2^15) take the bin-blocked kernel below.
+# Most hi slots ONE grid step holds: the VMEM cap of the kernel's
+# working set, not its reach. With the stacked-term matmul the peak is
+# the bf16 A [3·n_ch·ht, T] (4.7 MB at ht=256, C=3, T=1024 —
+# _fact_row_tile drops to 1024 past ht=64) plus the [ht, T] hi one-hot,
+# the [T, 128] lo one-hot, the f32 [3·n_ch·ht, 128] dot result (1.2 MB)
+# and the resident out block (_OUT_BUDGET) — ~10 MB worst case against
+# ~16 MB/core VMEM. TIGHT: the on-chip kernel gate compiles exactly
+# this cap shape as `fact_kernel_cap`; if it fails there, lower this
+# cap. Deeper levels (n_nodes·n_bins > 2^15) run as several hi blocks
+# of at most this many slots, each at the cap shape's VMEM.
 _FACT_MAX_NHI = 256
 
 
-def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
-                      binned_tile: int = 1, row_tile: int | None = None):
+def _hi_blocks(n_cells: int) -> tuple:
+    """(n_ht, ht): the hi blocks that serve ``n_cells`` = nodes·bins
+    histogram cells, and the slots in each — the fewest blocks the cap
+    allows, evenly filled (the last may hold junk slots)."""
+    n_hi = -(-n_cells // 128)                        # ceil
+    n_ht = -(-n_hi // _FACT_MAX_NHI)
+    return n_ht, -(-n_hi // n_ht)
+
+
+def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
+                 binned_tile: int = 1, row_tile: int | None = None):
     """``binned_tile`` > 1: rel/vals carry ``binned_tile`` consecutive
     copies of the row range (the flattened class batch) while binned is
     stored ONCE — the grid index map re-reads the same bin blocks per
@@ -219,8 +225,8 @@ def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
     r, F = binned.shape
     C = vals.shape[1]
     nB = n_nodes * n_bins
-    n_hi = -(-nB // 128)                             # ceil
-    rt_size = row_tile or _fact_row_tile(n_hi, r)
+    n_ht, ht = _hi_blocks(nB)
+    rt_size = row_tile or _fact_row_tile(ht, r)
     pad = (-r) % rt_size
     if pad:
         assert binned_tile == 1     # tiled callers pre-align rows
@@ -229,14 +235,14 @@ def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
         vals = jnp.pad(vals, ((0, pad), (0, 0)))
     rp = r + pad
     rbb = rp // rt_size                 # row blocks per binned copy
-    # feature grouping: each grid step holds [fg, C·n_hi, 128] f32 of
+    # feature grouping: each grid step holds [fg, C·ht, 128] f32 of
     # output resident; wide tables split into 8-aligned groups (padded
     # feature columns histogram into junk rows that are sliced away).
     # fg is also capped at 64 outright: the row-stream-reuse win
     # saturates long before that, and the resident out block is the
     # only cost that grows with fg (the kernel's fori_loop reuses one
     # iteration's buffers)
-    per_f = C * n_hi * 128 * 4
+    per_f = C * ht * 128 * 4
     fg_cap = min(F, 64, max(1, _OUT_BUDGET // per_f))
     if fg_cap >= F:
         fg, F_pad = F, F
@@ -252,130 +258,43 @@ def _hist_pallas_fact(binned, rel, vals, n_nodes: int, n_bins: int,
     binned4 = binned.astype(jnp.int32).T.reshape(
         F_pad, rbb, 1, rt_size)
     rel32 = rel.astype(jnp.int32)
-    vma = jax.typeof(vals).vma
-    grid = (n_fg, binned_tile, rbb)
-    out = pl.pallas_call(
-        functools.partial(_hist_fact_kernel, n_bins=n_bins, n_hi=n_hi,
-                          n_ch=C, fg=fg, terms=_TERMS),
-        out_shape=jax.ShapeDtypeStruct((n_fg, fg, C * n_hi, 128),
-                                       jnp.float32, vma=vma),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((fg, 1, 1, rt_size),
-                         lambda g, k, rt: (g, rt, 0, 0)),
-            pl.BlockSpec((rt_size,),
-                         lambda g, k, rt, rb=rbb: (k * rb + rt,)),
-            pl.BlockSpec((rt_size, C),
-                         lambda g, k, rt, rb=rbb: (k * rb + rt, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, fg, C * n_hi, 128),
-                               lambda g, k, rt: (g, 0, 0, 0)),
-        # feature groups write DISTINCT out blocks (parallel — Mosaic
-        # may pipeline them); copies and row blocks ACCUMULATE into the
-        # same block (arbitrary = sequential)
-        compiler_params=_dimsem("parallel", "arbitrary", "arbitrary"),
-        interpret=_interpret(),
-        # the instruction's name in the compiled program and in a
-        # profile (`hist_fact.N custom-call`)
-        name="hist_fact", metadata={"kernel": "hist_fact"},
-    )(binned4, rel32, vals)
-    # [n_fg, fg, C·n_hi, 128] -> [F, C, n_hi·128] -> [n, F, B, C]
-    out = out.reshape(F_pad, C, n_hi * 128)[:F, :, :nB]
-    return out.reshape(F, C, n_nodes, n_bins).transpose(2, 0, 3, 1)
-
-
-def _hist_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins, nbt,
-                 terms):
-    nb = pl.program_id(1)
-    rt = pl.program_id(2)
-
-    @pl.when(rt == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    bins = binned_ref[:]                             # [T]
-    rel = rel_ref[:]                                 # [T]
-    seg = rel * n_bins + bins
-    base = nb * nbt
-    iota = lax.broadcasted_iota(jnp.int32, (bins.shape[0], nbt), 1)
-    # dead rows (rel=-1) give seg in [-n_bins, -1], which can never equal
-    # a non-negative iota slot — no explicit liveness mask needed (a bool
-    # [:, None] broadcast is also unsupported by Mosaic for non-32-bit)
-    onehot = ((seg[:, None] - base) == iota).astype(jnp.bfloat16)
-    vals_t = vals_ref[:].T                           # [C, T]
-    # same f32-precision recipe as the factorized kernel: the one-hot
-    # RHS is 0/1 (bf16-exact) and the [C, T] values split into `terms`
-    # bf16 mantissa terms — explicit bf16 passes replace the implicit
-    # ~6-pass f32 HIGHEST emulation on BOTH operands
-    dn = (((1,), (0,)), ((), ()))
-
-    # single matmul with the mantissa terms stacked into M (terms·C
-    # rows, one pass) instead of separate C-row passes; the per-term
-    # sums recombine with one VPU add — same products, f32 accumulate
-    C = vals_t.shape[0]
-    V = _mantissa_terms(vals_t, terms)               # [terms·C, T] bf16
-    acc = lax.dot_general(V, onehot, dimension_numbers=dn,
-                          preferred_element_type=jnp.float32)
-    acc = acc.reshape(terms, C, nbt)
-    out_ref[0] += acc.sum(axis=0)                    # [C, NBT] on the MXU
-
-
-def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
-                 binned_tile: int = 1, row_tile: int | None = None):
-    r, F = binned.shape
-    C = vals.shape[1]
-    nB = n_nodes * n_bins
-    if -(-nB // 128) <= _FACT_MAX_NHI:
-        return _hist_pallas_fact(binned, rel, vals, n_nodes, n_bins,
-                                 binned_tile, row_tile)
-    if binned_tile > 1:
-        # deep-tree (blocked-kernel) shapes are rare for the flattened
-        # class batch — materialize the bin copies rather than widen
-        # the blocked kernel's grid to 4-D
-        binned = jnp.tile(binned, (binned_tile, 1))
-        r = binned.shape[0]
-    nbt = _bin_block(n_nodes, n_bins)
-    if nbt % 128 and nbt != nB:
-        # un-tileable bin block (non-power-of-2 n_bins hitting the lane
-        # cap mid-range) — Mosaic requires the last block dim be a
-        # multiple of 128 or the whole array; fall back off the MXU path
-        return _hist_segment(binned, rel, vals, n_nodes, n_bins)
-    pad = (-r) % ROW_TILE
-    if pad:
-        binned = jnp.pad(binned, ((0, pad), (0, 0)))
-        rel = jnp.pad(rel, (0, pad), constant_values=-1)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-    rp = r + pad
-    # feature-major flat row stream: 1-D blocks of ROW_TILE satisfy the
-    # TPU lane tiling where a (1, ROW_TILE) 2-D block cannot (its
-    # sublane dim 1 is neither 8-divisible nor the full axis)
-    binned_flat = binned.T.astype(jnp.int32).reshape(F * rp)
-    rel32 = rel.astype(jnp.int32)
-    rblocks = rp // ROW_TILE
-
-    grid = (F, nB // nbt, rblocks)
+    # the instruction's name in the compiled program and in a profile
+    # (`hist_fact.N custom-call`). A level past the cap, blocked over
+    # its hi slots, is `hist_blocked`: a trace's count of those calls
+    # is how often the blocking engages
+    name = "hist_fact" if n_ht == 1 else "hist_blocked"
     # under shard_map the output varies per shard: propagate the input's
     # varying-mesh-axes set or jax's vma check rejects the call
     vma = jax.typeof(vals).vma
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, n_bins=n_bins, nbt=nbt,
-                          terms=_TERMS),
-        out_shape=jax.ShapeDtypeStruct((F, C, nB), jnp.float32, vma=vma),
-        grid=grid,
+        functools.partial(_hist_fact_kernel, n_bins=n_bins, ht=ht,
+                          n_ht=n_ht, n_ch=C, fg=fg, terms=_TERMS),
+        # one (fg, C·ht, 128) block per (feature group, hi block),
+        # contiguous
+        out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),
+                                       jnp.float32, vma=vma),
+        grid=(n_fg, n_ht, binned_tile, rbb),
         in_specs=[
-            pl.BlockSpec((ROW_TILE,),
-                         lambda f, nb, rt, rb=rblocks: (f * rb + rt,)),
-            pl.BlockSpec((ROW_TILE,), lambda f, nb, rt: (rt,)),
-            pl.BlockSpec((ROW_TILE, C), lambda f, nb, rt: (rt, 0)),
+            pl.BlockSpec((fg, 1, 1, rt_size),
+                         lambda g, b, k, rt: (g, rt, 0, 0)),
+            pl.BlockSpec((rt_size,),
+                         lambda g, b, k, rt, rb=rbb: (k * rb + rt,)),
+            pl.BlockSpec((rt_size, C),
+                         lambda g, b, k, rt, rb=rbb: (k * rb + rt, 0)),
         ],
-        out_specs=pl.BlockSpec((1, C, nbt), lambda f, nb, rt: (f, 0, nb)),
-        # features and bin blocks write distinct out blocks; only the
-        # row-block axis accumulates
-        compiler_params=_dimsem("parallel", "parallel", "arbitrary"),
+        out_specs=pl.BlockSpec((1, 1, fg, C * ht, 128),
+                               lambda g, b, k, rt: (g, b, 0, 0, 0)),
+        # feature groups and hi blocks write DISTINCT out blocks
+        # (parallel — Mosaic may pipeline them); copies and row blocks
+        # ACCUMULATE into the same block (arbitrary = sequential)
+        compiler_params=_dimsem("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
         interpret=_interpret(),
-        name="hist_blocked", metadata={"kernel": "hist_blocked"},
-    )(binned_flat, rel32, vals)
-    # [F, C, n*B] -> [n, F, B, C]
+        name=name, metadata={"kernel": name},
+    )(binned4, rel32, vals)
+    # [n_fg, n_ht, fg, C·ht, 128] -> [F, C, n_ht·ht·128] -> [n, F, B, C]
+    out = out.reshape(n_fg, n_ht, fg, C, ht * 128).transpose(
+        0, 2, 3, 1, 4).reshape(F_pad, C, n_ht * ht * 128)[:F, :, :nB]
     return out.reshape(F, C, n_nodes, n_bins).transpose(2, 0, 3, 1)
 
 
@@ -421,11 +340,8 @@ def _hist_vmappable(binned, rel, vals, n_nodes: int, n_bins: int,
 
         r = rel_b.shape[1] if rb else rel_b.shape[0]
         # pad each class's rows to the row tile the flat kernel will
-        # pick for the MERGED node count (fact kernel when it fits,
-        # blocked kernel otherwise)
-        n_hi_t = -(-K * n_nodes * n_bins // 128)
-        rt = _fact_row_tile(n_hi_t, r) if n_hi_t <= _FACT_MAX_NHI \
-            else ROW_TILE
+        # pick for the MERGED node count
+        rt = _fact_row_tile(_hi_blocks(K * n_nodes * n_bins)[1], r)
         pad = (-r) % rt
         C = vals_b.shape[-1]
         F = binned_b.shape[-1]

@@ -91,11 +91,11 @@ def _scopes(text: str) -> set:
 
 @pytest.mark.parametrize("n_nodes,unit_hess", [
     (32, False), (32, True),        # factorized kernel, 3 and 2 channels
-    (512, False), (512, True),      # bin-blocked kernel (deep levels)
+    (512, False), (512, True),      # deep level: four hi blocks
 ])
 def test_histogram_kernels_compile(one_chip, n_nodes, unit_hess):
     assert (-(-n_nodes * BINS // 128) <= histogram._FACT_MAX_NHI) == \
-        (n_nodes == 32)             # the two shapes take the two kernels
+        (n_nodes == 32)             # one hi block, and several
     fn = jax.jit(lambda b, r, g, h, w: histogram.build_histogram(
         b, r, g, h, w, n_nodes, BINS, "pallas", unit_hess=unit_hess))
     f32 = _s((ROWS_N,), jnp.float32, one_chip)
@@ -181,9 +181,10 @@ def test_forest_scan_holds_one_trees_temporaries(topo, depth, kernels):
     tree a scan step, so six trees a dispatch reserve what one does
     (grouped under vmap they reserved six times that: 25 G of a 16 G
     chip at 4,194,304 rows, PERF.md section 6, PR 28). At depth 12 x 64
-    bins the deepest histogram level (1,024 left children) is past the
-    factorized kernel's reach and the bin-blocked kernel takes it: the
-    one path of `drf-higgs.train` that no other cell runs."""
+    bins the deepest histogram level (1,024 left children) is past what
+    one block of hi slots holds and the kernel serves it in two, under
+    the name `hist_blocked`: the one path of `drf-higgs.train` that no
+    other cell runs."""
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
     args = _boost_args(mesh, ROWS_N, ntrees=1)
     tp = args[6]._replace(max_depth=depth, n_bins=64, min_rows=1.0,

@@ -24,6 +24,21 @@ def _random_case(r, F, n_nodes, n_bins, seed, dead_frac=0.2):
             jnp.asarray(h), jnp.asarray(w))
 
 
+def _class_batch_case(K, rows, F, n_nodes, n_bins, seed):
+    """One stored `binned`, K classes' node ids and gradients: the
+    shape the multinomial grower vmaps `build_histogram` over."""
+    rng = np.random.default_rng(seed)
+    binned = jnp.asarray(
+        rng.integers(0, n_bins, size=(rows, F)).astype(np.uint8))
+    relK = jnp.asarray(np.where(
+        rng.random((K, rows)) < 0.85,
+        rng.integers(0, n_nodes, size=(K, rows)), -1).astype(np.int32))
+    gK = jnp.asarray(rng.normal(size=(K, rows)).astype(np.float32))
+    hK = jnp.asarray(rng.random((K, rows)).astype(np.float32))
+    w = jnp.asarray((rng.random(rows) < 0.9).astype(np.float32))
+    return binned, relK, gK, hK, w
+
+
 @pytest.mark.parametrize("r,F,n_nodes,n_bins", [
     (300, 4, 1, 16),
     (1000, 3, 4, 64),
@@ -41,35 +56,107 @@ def test_pallas_matches_segment(r, F, n_nodes, n_bins):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_blocked_kernel_matches_segment(monkeypatch):
-    """The bin-blocked fallback kernel (taken when the factorized A
-    operand would blow VMEM) stays parity-tested even though small
-    trees now route to the factorized path."""
+# levels past the hi-block cap, at CPU sizes: (rows, F, nodes, bins,
+# unit_hess). The cap is set small so these need several hi blocks.
+_DEEP = {
+    "pow2": (1000, 3, 32, 64, False),
+    "full_lanes": (777, 2, 16, 128, False),
+    "odd_bins_17": (513, 2, 64, 17, False),     # n_hi 9: junk slots
+    "odd_bins_20": (1300, 3, 96, 20, False),    # n_hi 15, 2 row tiles
+    "two_channels": (900, 4, 64, 20, True),
+    "one_row_tile_many_nodes": (128, 5, 256, 32, True),
+}
+
+
+@pytest.mark.parametrize("cap", [1, 2, 8])
+@pytest.mark.parametrize("case", sorted(_DEEP))
+def test_hi_blocked_level_matches_segment_and_one_block(
+        monkeypatch, case, cap):
+    """A level with more hi slots than one grid step holds is served in
+    blocks of hi slots. It must (a) match `segment` to 1e-5 and (b) be
+    BITWISE what the same level gives served in one block: every cell
+    sums the same products over the same row tiles in the same order.
+    Rows are not tile-aligned, ~20% are dead and carry NaN gradients,
+    and odd bin counts leave the last block junk slots to slice off."""
     import h2o_kubernetes_tpu.ops.histogram as H
 
-    monkeypatch.setattr(H, "_FACT_MAX_NHI", 0)   # force the fallback
-    binned, rel, g, h, w = _random_case(1000, 3, 4, 64, seed=5)
-    ref = build_histogram(binned, rel, g, h, w, 4, 64, impl="segment")
-    got = build_histogram(binned, rel, g, h, w, 4, 64, impl="pallas")
+    r, F, n_nodes, n_bins, unit = _DEEP[case]
+    binned, rel, g, h, w = _random_case(r, F, n_nodes, n_bins, seed=r)
+    if unit:
+        h = jnp.ones_like(w)
+    args = (binned, rel, g, h, w, n_nodes, n_bins)
+    ref = build_histogram(*args, impl="segment", unit_hess=unit)
+    assert H._hi_blocks(n_nodes * n_bins)[0] == 1
+    one = build_histogram(*args, impl="pallas", unit_hess=unit)
+    monkeypatch.setattr(H, "_FACT_MAX_NHI", cap)
+    n_ht, ht = H._hi_blocks(n_nodes * n_bins)
+    assert n_ht > 1 and ht <= cap
+    got = build_histogram(*args, impl="pallas", unit_hess=unit)
+    assert got.shape == (n_nodes, F, n_bins, 2 if unit else 3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
 
 
-def test_factorized_vs_blocked_agree(monkeypatch):
-    """The two Pallas formulations agree on a shape the blocked kernel
-    actually tiles (n_nodes*n_bins = 2048 = one full bin block)."""
+@pytest.mark.parametrize("cap", [1, 2, 8])
+def test_hi_blocked_class_batch_stores_binned_once(monkeypatch, cap):
+    """The flattened class batch (vmap over K = 3, `binned` unbatched)
+    past the cap: the grid re-reads the one stored copy of `binned` for
+    every class and every hi block. Same two claims as above."""
+    import jax
+
     import h2o_kubernetes_tpu.ops.histogram as H
 
-    binned, rel, g, h, w = _random_case(777, 2, 16, 128, seed=9)
-    live = (np.asarray(rel) >= 0) & (np.asarray(w) > 0)
-    vals = jnp.where(jnp.asarray(live)[:, None],
-                     jnp.stack([g * w, h * w, w], axis=1), 0.0)
-    rel_live = jnp.where(jnp.asarray(live), rel, -1)
-    fact = H._hist_pallas_fact(binned, rel_live, vals, 16, 128)
-    monkeypatch.setattr(H, "_FACT_MAX_NHI", 0)
-    blocked = H._hist_pallas(binned, rel_live, vals, 16, 128)
-    np.testing.assert_allclose(np.asarray(fact), np.asarray(blocked),
+    K, rows, F, n_nodes, n_bins = 3, 1500, 4, 32, 20
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, F, n_nodes, n_bins, seed=23)
+
+    def build(impl):
+        return jax.vmap(lambda rel, g, h: build_histogram(
+            binned, rel, g, h, w, n_nodes, n_bins, impl))(relK, gK, hK)
+
+    one = build("pallas")
+    monkeypatch.setattr(H, "_FACT_MAX_NHI", cap)
+    assert H._hi_blocks(K * n_nodes * n_bins)[0] > 1
+    calls = []
+    real = H._hist_pallas
+
+    def spy(binned_f, *a, **kw):
+        calls.append((binned_f.shape, kw.get("binned_tile")))
+        return real(binned_f, *a, **kw)
+
+    monkeypatch.setattr(H, "_hist_pallas", spy)
+    got = build("pallas")
+    # the batching rule's call: one copy of binned, rows tile-padded
+    assert calls[-1] == ((1024 * 2, F), K)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(build("segment")),
                                rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+
+
+def test_hi_blocked_level_lowers_for_tpu_under_its_own_name(monkeypatch):
+    """AOT-lower a level past the cap for a TPU target from the CPU:
+    Mosaic accepts the 4-D grid with several hi blocks, and the call is
+    named `hist_blocked` (a level within the cap stays `hist_fact`) —
+    the name a profile and the benchmark's readers find it by."""
+    import unittest.mock as mock
+
+    import jax
+
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    rows, F, n_bins = 2048, 3, 64
+    binned, rel, g, h, w = _random_case(rows, F, 16, n_bins, seed=3)
+    monkeypatch.setattr(H, "_FACT_MAX_NHI", 8)
+    with mock.patch("jax.default_backend", lambda: "tpu"):
+        for n_nodes, name, other in ((16, "hist_fact", "hist_blocked"),
+                                     (64, "hist_blocked", "hist_fact")):
+            txt = jax.jit(lambda r: build_histogram(
+                binned, r, g, h, w, n_nodes, n_bins, "pallas")).trace(
+                rel).lower(lowering_platforms=("tpu",)).as_text()
+            assert f'kernel_name = "{name}"' in txt
+            assert other not in txt
 
 
 @pytest.mark.parametrize("impl", ["segment", "pallas"])
@@ -136,15 +223,8 @@ def test_vmapped_batch_matches_loop():
     import jax
 
     K, rows, F, n_nodes, n_bins = 3, 1500, 4, 8, 32
-    rng = np.random.default_rng(21)
-    binned = jnp.asarray(
-        rng.integers(0, n_bins, size=(rows, F)).astype(np.uint8))
-    relK = jnp.asarray(np.where(
-        rng.random((K, rows)) < 0.85,
-        rng.integers(0, n_nodes, size=(K, rows)), -1).astype(np.int32))
-    gK = jnp.asarray(rng.normal(size=(K, rows)).astype(np.float32))
-    hK = jnp.asarray(rng.random((K, rows)).astype(np.float32))
-    w = jnp.asarray((rng.random(rows) < 0.9).astype(np.float32))
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, F, n_nodes, n_bins, seed=21)
 
     for impl in ("segment", "pallas"):
         got = jax.vmap(

@@ -8,8 +8,9 @@ This script does, on a TPU:
 1. pallas-compiles the FACTORIZED histogram kernel (interpret=False is
    automatic on tpu) at a bench-like shape and asserts parity vs the
    segment_sum reference path;
-2. same for the BIN-BLOCKED kernel (deep-tree shape past the
-   factorized VMEM cap) and the TreeSHAP serving kernel
+2. same for levels past the hi-block cap, which the same kernel
+   serves in two and in four blocks of hi slots (`hist_blocked`), and
+   the TreeSHAP serving kernel
    (`ops/shap_kernel.py`, to 1e-5 of the lowered-XLA
    `flat_shap_tab`, bitwise reported);
 3. jit-compiles and runs the fused boost scan (binomial AND
@@ -42,6 +43,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 CHECK_NAMES = [
     "fact_kernel", "fact_kernel_cap", "binblock_kernel",
+    "binblock_kernel_4",
     "leaf_totals_kernel", "unit_hess_kernel", "two_term_kernel",
     "boost_scan_binomial", "boost_scan_multinomial",
     "flat_scorer_parity", "flat_scorer_parity_multinomial",
@@ -87,7 +89,8 @@ def main(argv=None) -> int:
     rng = np.random.default_rng(0)
     checks = []
 
-    def parity(name, rows, F, n_nodes, n_bins, tol=1e-5):
+    def parity(name, rows, F, n_nodes, n_bins, tol=1e-5,
+               unit_hess=False):
         binned = jnp.asarray(
             rng.integers(0, n_bins, size=(rows, F)).astype(np.uint8))
         rel = jnp.asarray(np.where(
@@ -98,13 +101,13 @@ def main(argv=None) -> int:
             np.float32))
         w = jnp.asarray((rng.uniform(size=rows) < 0.95).astype(
             np.float32))
-        got = jax.jit(build_histogram, static_argnums=(5, 6, 7))(
-            binned, rel, g, h, w, n_nodes, n_bins, "pallas")
+        got = jax.jit(build_histogram, static_argnums=(5, 6, 7, 8))(
+            binned, rel, g, h, w, n_nodes, n_bins, "pallas", unit_hess)
         live = (np.asarray(rel) >= 0) & (np.asarray(w) > 0)
-        vals = np.where(live[:, None],
-                        np.stack([np.asarray(g) * np.asarray(w),
-                                  np.asarray(h) * np.asarray(w),
-                                  np.asarray(w)], axis=1), 0.0)
+        gw, hw = np.asarray(g) * np.asarray(w), np.asarray(h) * np.asarray(w)
+        chans = [gw, np.asarray(w)] if unit_hess \
+            else [gw, hw, np.asarray(w)]
+        vals = np.where(live[:, None], np.stack(chans, axis=1), 0.0)
         want = _hist_segment(binned, jnp.where(jnp.asarray(live),
                                                rel, -1),
                              jnp.asarray(vals), n_nodes, n_bins)
@@ -162,22 +165,30 @@ def main(argv=None) -> int:
     # ------------------------- checks --------------------------------
 
     def chk_fact_kernel():
-        # factorized kernel: node·bins within 128·_FACT_MAX_NHI
+        # one hi block: node·bins within 128·_FACT_MAX_NHI
         n_nodes_fact = 16
         assert -(-n_nodes_fact * 256 // 128) <= _FACT_MAX_NHI
         parity("fact_kernel", 100_000, 10, n_nodes_fact, 256)
 
     def chk_fact_kernel_cap():
-        # factorized kernel AT the VMEM cap (n_hi == _FACT_MAX_NHI):
+        # one hi block AT the VMEM cap (n_hi == _FACT_MAX_NHI):
         # validates the [3·C·n_hi, T] stacked-term A fits VMEM on real
-        # Mosaic, where interpret mode can't see allocation failures
+        # Mosaic, where interpret mode can't see allocation failures.
+        # Every block of a deeper level has this shape's working set
         parity("fact_kernel_cap", 50_000, 2,
                _FACT_MAX_NHI * 128 // 256, 256)
 
     def chk_binblock_kernel():
-        # bin-blocked kernel: force past the factorized cap
+        # twice the cap: the level is served in TWO blocks of hi slots
+        # (`hist_blocked`), three channels
         n_nodes_deep = (_FACT_MAX_NHI * 128 // 256) * 2
         parity("binblock_kernel", 50_000, 4, n_nodes_deep, 256)
+
+    def chk_binblock_kernel_4():
+        # four hi blocks, two channels, 64 bins: one level past the
+        # deepest of a depth-12 forest's tree
+        parity("binblock_kernel_4", 50_000, 4,
+               _FACT_MAX_NHI * 128 // 64 * 4, 64, unit_hess=True)
 
     def chk_leaf_totals_kernel():
         # single-bin totals shape (the final-level leaf reduction)
@@ -411,6 +422,7 @@ def main(argv=None) -> int:
         "fact_kernel": chk_fact_kernel,
         "fact_kernel_cap": chk_fact_kernel_cap,
         "binblock_kernel": chk_binblock_kernel,
+        "binblock_kernel_4": chk_binblock_kernel_4,
         "leaf_totals_kernel": chk_leaf_totals_kernel,
         "unit_hess_kernel": chk_unit_hess_kernel,
         "two_term_kernel": chk_two_term_kernel,
