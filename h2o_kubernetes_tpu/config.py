@@ -17,13 +17,11 @@ CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
 | H2O_TPU_COORDINATOR | — | jax.distributed coordinator (runtime/mesh) |
 | H2O_TPU_NUM_PROCESSES | 1 | multi-host process count (runtime/mesh) |
 | H2O_TPU_PROCESS_ID | 0 | this host's process id (runtime/mesh) |
-| H2O_TPU_HIST_TERMS | 3 | bf16 mantissa terms (2 = throughput mode, ~2⁻¹⁶ products; ops/histogram) |
 | H2O_TPU_HIST_BYTES_BUDGET | 2³⁰ | deep-tree level-histogram memory budget (models/gbm validation, the out-of-core trigger) |
 | H2O_TPU_CV_SHAPE_SHARE_ROWS | tpu≤1M | weights-masked CV row threshold; 0 disables, N forces on any backend (models/cv) |
 | H2O_TPU_ARROW_CSV | 1 | 0 disables the pyarrow CSV fast path (frame/parse) |
 | H2O_TPU_INGEST_CHUNK_BYTES | 16 MiB | pyarrow record-batch size for streamed CSV ingest (frame/parse, docs/SCALING.md) |
 | H2O_TPU_DEVICE_GATHER_MIN | 65536 | row threshold for the on-device Vec.select_rows gather; 0 forces it, below it the host path wins (frame/frame) |
-| H2O_TPU_BIN_BLOCK_COLS | derived | columns binned per block in Frame.binned (≤256 MB f32 transient; models/tree/binning) |
 | H2O_TPU_EFB | auto | Exclusive Feature Bundling for wide sparse frames: 0 kill switch, 1 force, auto = plan on >= MIN_F-feature frames, keep when the shrink gate passes (models/tree/efb, docs/SCALING.md) |
 | H2O_TPU_EFB_CONFLICT | 0 | allowed conflict-ROW fraction per bundle (LightGBM max_conflict_rate analog); 0 = exact exclusivity, the parity-gated default |
 | H2O_TPU_EFB_MIN_F | 64 | feature-count floor below which auto mode skips EFB planning entirely (narrow frames keep the fused no-host-sync prologue) |
@@ -53,11 +51,15 @@ CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
 | H2O_TPU_DRAIN_TIMEOUT | 30 | seconds the SIGTERM drain waits for RUNNING jobs / batcher flush before failing them (runtime/lifecycle.py) |
 | H2O_TPU_BREAKER_FAILURES | 5 | consecutive device-dispatch errors that trip the serving circuit breaker open (runtime/lifecycle.py) |
 | H2O_TPU_BREAKER_COOLDOWN | 30 | seconds the breaker stays open before admitting the half-open probe (runtime/lifecycle.py) |
+| H2O_TPU_RETRY_ATTEMPTS | 5 | total attempts of a retried persist/HTTP call (runtime/retry.py; read per call) |
+| H2O_TPU_RETRY_BASE | 0.2 | first retry backoff, seconds; doubles per attempt with jitter |
+| H2O_TPU_RETRY_MAX_DELAY | 10 | per-sleep cap of a retry loop, seconds |
+| H2O_TPU_RETRY_DEADLINE | 120 | total sleep budget of a retry loop, seconds |
+| H2O_TPU_RETRY_DISABLE | — (off) | 1 = single attempt, no sleeps (chaos drills prove a fault reaches the retry path) |
 | H2O_TPU_RETRY_MAX_ELAPSED_S | 0 (off) | hard cap on a retry loop's total elapsed time, attempts included (runtime/retry.py) |
 | H2O_TPU_AUTOML_PIPELINE | 1 | 0 kills the pipelined AutoML executor AND the CV fold pipeline — restores the serial path bit-for-bit (runtime/scheduler.py, docs/SCALING.md) |
 | H2O_TPU_AUTOML_COMPILE_AHEAD | 1 | plan entries whose boost executables are pre-lowered ahead of the training cursor; 0 disables the compile stream (needs the persistent XLA cache to pay — auto-disabled without it) |
 | H2O_TPU_AUTOML_QUEUE_DEPTH | 4 | bound on the scheduler's host/compile queues: completed-but-unapplied models and stale compile requests cannot accumulate (runtime/scheduler.py) |
-| H2O_TPU_FUSED_BINNING | 1 | 0 restores the two-dispatch fit_bins→Frame.binned train prologue instead of the fused single-dispatch fit+apply (models/tree/binning.py) |
 | H2O_TPU_POOL_REPLICA | — | 1 marks this rest.py process an operator-provisioned scorer replica: /readyz additionally requires a pushed+warmed registry artifact (rest.py, docs/OPERATOR.md) |
 | H2O_TPU_POOL_WARM_BUCKETS | 128,1024 | default warm-up ladder: Model.warm_up pre-traces every pow2 batch bucket up to the largest listed, before a replica's readyz flips (models/base.py) |
 | H2O_TPU_POOL_RECONCILE_INTERVAL | 0.5 | seconds between scorer-pool reconcile passes (operator/reconcile.py) |
@@ -84,6 +86,9 @@ CLI flags, and H2O-3 runtime options (`H2O.OptArgs` command line,
 | H2O_TPU_REBALANCE_COOLDOWN | 30 | seconds between moves, fleet-wide: rebalancing converges one tenant at a time instead of thrashing |
 | H2O_TPU_REBALANCE_RETIRE_S | 5 | make-before-break dwell: seconds the move's SOURCE keeps serving after the destination took routing-preference position 0, and only while the destination stays healthy |
 | H2O_TPU_REBALANCE_FAILBACK_S | 30 | failback hygiene for loss-driven re-placements: once every home shard of an overridden tenant has been healthy this long, the override copies age out of the survivor's child spec and the routing table |
+| H2O_TPU_FAULTS | — (off) | fault-injection spec `site:kind[@n][~p];...` armed for the process (runtime/faults.py; chaos drills and tests only) |
+| H2O_TPU_SVMLIGHT_DENSE_BUDGET | 200000000 | cells (rows × max feature index) an SVMLight import may densify to before it is refused (frame/parse.py) |
+| H2O_TPU_WEBHDFS | — | namenode HTTP address (http://namenode:9870) that `hdfs://` URIs are read and written through (persist_cloud.py) |
 | H2O_TPU_METRICS_TOPK | 20 | fleet telemetry: per-metric series cap for tenant-cardinality labels (`model`) — the top-K label values by traffic keep their own series, everything else rolls into `other`, so 1000 tenants cost K+1 series on GET /metrics (runtime/telemetry.py, docs/OBSERVABILITY.md) |
 | H2O_TPU_METRICS_PORT | — (off) | operator.run status listener: bind /metrics + /healthz on this port so the control plane is scrapeable like any replica (0 = ephemeral; `--status-port` overrides) |
 | H2O_TPU_TRACE | 1 | 0 disables request-span recording (trace ring + per-request phase histograms) — the tracing perf kill switch; counters and /metrics stay on (runtime/telemetry.py) |

@@ -72,6 +72,31 @@ def _feature_names(frame: Frame, x: Sequence[str] | None,
     return names
 
 
+def resolve_response(frame: Frame, y: str, distribution: str = "auto"
+                     ) -> tuple[str, int, list[str] | None]:
+    """(distribution, nclasses, response domain) from the response
+    column's METADATA alone (kind, cardinality): no device work, so
+    compile-ahead asks it what `resolve_xy` will answer."""
+    yv = frame.vec(y)
+    nclasses, domain = 1, None
+    if yv.is_enum():
+        domain = yv.domain
+        nclasses = yv.cardinality()
+        if nclasses < 2:
+            raise ValueError(f"response '{y}' has {nclasses} classes")
+    if distribution == "auto":
+        if nclasses == 2:
+            distribution = "bernoulli"
+        elif nclasses > 2:
+            distribution = "multinomial"
+        else:
+            distribution = "gaussian"
+    if distribution in ("bernoulli", "multinomial") and nclasses == 1:
+        raise ValueError(f"{distribution} needs a categorical response; "
+                         f"'{y}' is numeric (use .asfactor()-style enum)")
+    return distribution, nclasses, domain
+
+
 def resolve_xy(frame: Frame, y: str, x: Sequence[str] | None = None,
                ignored: Sequence[str] | None = None,
                weights_column: str | None = None,
@@ -98,26 +123,11 @@ def resolve_xy(frame: Frame, y: str, x: Sequence[str] | None = None,
                 f"offset column '{offset_column}' must be numeric")
         ignored.add(offset_column)
     names = _feature_names(frame, x, ignored)
-    yv = frame.vec(y)
-    nclasses, domain = 1, None
-    if yv.is_enum():
-        domain = yv.domain
-        nclasses = yv.cardinality()
-        if nclasses < 2:
-            raise ValueError(f"response '{y}' has {nclasses} classes")
-    if distribution == "auto":
-        if nclasses == 2:
-            distribution = "bernoulli"
-        elif nclasses > 2:
-            distribution = "multinomial"
-        else:
-            distribution = "gaussian"
-    if distribution in ("bernoulli", "multinomial") and nclasses == 1:
-        raise ValueError(f"{distribution} needs a categorical response; "
-                         f"'{y}' is numeric (use .asfactor()-style enum)")
+    distribution, nclasses, domain = resolve_response(frame, y,
+                                                      distribution)
 
     X = frame.to_matrix(names) if materialize_x else None
-    y_arr = yv.as_float()
+    y_arr = frame.vec(y).as_float()
     w = frame.valid_mask()
     if weights_column:
         w = w * frame.vec(weights_column).as_float()

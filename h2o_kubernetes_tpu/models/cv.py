@@ -162,8 +162,8 @@ def cross_validate(est, y: str, frame: Frame, cv: CVArgs,
     # fold model on the FULL frame with the holdout rows' weights
     # zeroed. All fold fits + the final fit then share one row shape,
     # one binned matrix and one set of XLA executables — the dominant
-    # share of a cold AutoML's compile count (232 → 166 measured,
-    # AUTOML_R04SHAPE_r05.json). Holdout rows still carry zero
+    # share of a cold AutoML's compile count (232 → 166 counted on the
+    # CPU mesh). Holdout rows still carry zero
     # loss/histogram/Gram weight (w=0 is the established dead-row
     # convention); frame-global statistics (quantile bin edges, mean
     # imputation, standardization) see the holdout feature

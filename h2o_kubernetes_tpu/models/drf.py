@@ -30,7 +30,7 @@ from typing import Sequence
 import numpy as np
 
 from ..frame import Frame
-from .base import resolve_xy
+from .base import _feature_names
 from .gbm import GBM, GBMModel, GBMParams
 
 
@@ -69,11 +69,7 @@ class DRF(GBM):
             ignored.add(self.cv_args.fold_column)
         if weights_column:
             ignored.add(weights_column)
-        names = list(x) if x else [
-            n for n in training_frame.names
-            if n not in ignored and
-            training_frame.vec(n).kind in ("numeric", "enum", "time")]
-        F = len(names)
+        F = len(_feature_names(training_frame, x, ignored))
         classification = training_frame.vec(y).is_enum()
         # H2O semantics: -1 → sqrt(F) classification / F/3 regression
         # (the default), -2 → all features, >0 → that many

@@ -18,10 +18,9 @@ Distributions (hex/genmodel DistributionFamily analogs):
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import os
-from dataclasses import dataclass, field
+from dataclasses import dataclass, replace
 from typing import Any, NamedTuple, Sequence
 
 import jax
@@ -31,16 +30,17 @@ from jax import lax
 
 from ..frame import Frame
 from ..runtime.health import device_dispatch, require_healthy
-from ..runtime.mesh import ROWS, global_mesh
+from ..runtime.mesh import ROWS, global_mesh, replicated, row_sharding
 from ..runtime.telemetry import phase_span
-from .base import Model, TrainData, resolve_xy
+from .base import (Model, TrainData, _feature_names, resolve_response,
+                   resolve_xy)
 from .tree.binning import (BinSpec, apply_bins, apply_bins_jit, fit_bins,
-                           fused_binning_enabled, fused_fit_bins)
+                           fused_fit_bins)
 from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
-                        _grad_hess, boost_trees, boost_trees_drf,
-                        boost_trees_multi, descend_tree, flat_margin,
-                        flatten_cover, flatten_trees, goss_round_keys,
-                        predict_tree, round_keys)
+                        _boost_drf_jit, _boost_jit, _boost_multi_jit,
+                        descend_tree, flat_margin, flatten_cover,
+                        flatten_trees, goss_round_keys, level_hist_bytes,
+                        multi_grow_vmapped, predict_tree, round_keys)
 
 
 @dataclass
@@ -78,7 +78,7 @@ _jit_exp = jax.jit(jnp.exp)
 _jit_min_pos = jax.jit(
     lambda y, w: jnp.nanmin(jnp.where(w > 0, y, jnp.inf)))
 # max histogram work units (rows·F·nbins·2^depth summed over a chunk's
-# trees) per compiled dispatch — see the chunking comment in train()
+# trees) per compiled dispatch — see BoostPlan.chunks
 _DISPATCH_BUDGET = 3e12
 
 # h ≡ 1 losses accumulate 2-channel histograms (1/3 fewer MXU passes +
@@ -88,9 +88,8 @@ _UNIT_HESS_DISTS = ("gaussian", "laplace", "quantile", "huber")
 
 def _make_tree_params(p: "GBMParams", distribution: str) -> TreeParams:
     """GBMParams + resolved distribution -> the TreeParams the boost
-    dispatch is traced with — shared by train() and compile-ahead
-    (compile_ahead_lowerings), so a pre-lowered executable's static
-    config cannot drift from the one train() dispatches."""
+    dispatch is traced with (`boost_plan` is the one caller on the
+    pointwise path)."""
     return TreeParams(max_depth=p.max_depth, n_bins=p.nbins,
                       min_rows=p.min_rows, reg_lambda=p.reg_lambda,
                       reg_alpha=p.reg_alpha,
@@ -123,7 +122,7 @@ def goss_params(p: "GBMParams", distribution: str) -> tuple[float, float]:
 
 
 def _make_boost_params(p: "GBMParams", distribution: str) -> BoostParams:
-    """The BoostParams twin of _make_tree_params (same no-drift rule)."""
+    """The BoostParams twin of _make_tree_params."""
     goss_a, goss_b = goss_params(p, distribution)
     return BoostParams(
         distribution=distribution,
@@ -141,26 +140,226 @@ def _draws_from_keys(p: "GBMParams", F: int) -> bool:
         or 0 < p.mtries < F
 
 
-def _chunk_sizes(p: "GBMParams", padded: int, F: int, K: int,
-                 start_t: int = 0) -> list[int]:
-    """Tree counts of the compiled dispatches the in-HBM boost loop
-    will issue — shared by _boost_in_hbm and compile-ahead, so the
-    pre-lowered key shapes match the dispatched ones exactly."""
-    per_round = padded * max(F, 1) * p.nbins * (2 ** p.max_depth) * K
-    budget_chunk = max(1, int(_DISPATCH_BUDGET // per_round))
-    score = p.score_every if (p.score_every and not p._drf_mode) else 0
-    out: list[int] = []
-    t = start_t
-    while t < p.ntrees:
-        n = min(budget_chunk, p.ntrees - t)
-        if score:
-            # stop at score boundaries, but never let the budget
-            # densify the scoring cadence (each scoring event is
-            # a blocking host sync)
-            n = min(n, score - (t - start_t) % score)
-        out.append(n)
-        t += n
-    return out
+# THE table of boosting modes: the jitted program that serves a job.
+# A device trace shows each as module `jit_<__name__>`;
+# telemetry.TRAIN_PROGRAMS["boost"] lists the same three names
+# (tests/test_telemetry.py holds it to this table).
+_BOOST_PROGRAMS = {"forest": _boost_drf_jit,    # single-output DRF
+                   "single": _boost_jit,        # one tree a round
+                   "multi": _boost_multi_jit}   # K class trees a round
+
+
+class BoostPlan(NamedTuple):
+    """What a training job is, decided ONCE from what is known before
+    any device work: which program serves it, with which static
+    arguments and operands, in which dispatches, in HBM or streamed.
+    `_train`, `_boost_in_hbm` and `compile_ahead_lowerings` all execute
+    this plan, so what is lowered ahead is what is dispatched.
+    ``F`` is the HISTOGRAM width (the bundled width under EFB)."""
+
+    p: GBMParams
+    distribution: str
+    K: int                  # class trees a round (1: single output)
+    F: int
+    tp: TreeParams
+    bp: BoostParams
+    hist_bytes: int         # level histograms live at the deepest level
+    budget: float           # H2O_TPU_HIST_BYTES_BUDGET, read once
+    mesh: Any
+
+    @property
+    def mode(self) -> str:
+        """This job's row of `_BOOST_PROGRAMS`."""
+        if self.K > 1:
+            return "multi"      # a multinomial forest grows there too
+        return "forest" if self.bp.drf_mode else "single"
+
+    @property
+    def score_every(self) -> int:
+        """Rounds between scoring events inside the loop (0: none; a
+        forest scores once, at the end)."""
+        return 0 if self.bp.drf_mode else (self.p.score_every or 0)
+
+    @property
+    def device_init(self) -> bool:
+        """A fresh job's prior and margin come from `_init_margin`: a
+        forest starts from zeros, laplace from the host's median."""
+        return not self.bp.drf_mode and self.distribution != "laplace"
+
+    def validate(self) -> None:
+        """Refuse, before the frame is binned, what cannot train."""
+        p = self.p
+        if self.bp.goss_b > 0 and p.sample_rate < 1.0:
+            raise ValueError(
+                "H2O_TPU_GOSS replaces row subsampling — train with "
+                f"sample_rate=1.0 (got {p.sample_rate}) or disable "
+                "the GOSS knob")
+        # deep-tree memory: the dense heap's per-level histogram
+        # working set is O(2^d·F·B·C) — the SAME accounting
+        # (core.level_hist_bytes) the multinomial vmap branch uses, K×
+        # only when the grower really vmaps. ANY depth whose level
+        # histograms fit the budget trains (depth 16 with 4 features ×
+        # 16 bins is ~25 MB); one that cannot fails HERE with sizing
+        # guidance instead of an opaque device OOM mid-boost.
+        if self.hist_bytes > self.budget:
+            raise ValueError(
+                f"max_depth={p.max_depth} with {self.F} histogram "
+                f"columns x {p.nbins} bins needs "
+                f"~{self.hist_bytes / 2 ** 20:.0f} MiB of "
+                f"level histograms (> budget "
+                f"{self.budget / 2 ** 20:.0f} MiB). "
+                "Lower max_depth or nbins, drop features, or raise "
+                "H2O_TPU_HIST_BYTES_BUDGET if the device has room.")
+
+    def chunks(self, padded: int, start_t: int = 0) -> list[int]:
+        """Tree counts of the compiled dispatches of the in-HBM loop.
+        One dispatch's work is capped: the TPU worker (behind its RPC
+        deadline) kills executions that run for minutes. Work/round ~
+        rows·F·nbins·2^depth·K (deepest level dominates with sibling
+        subtraction): 1.9e12 units are 1.0 s on a v5e at depth 6 x 256
+        bins, so `_DISPATCH_BUDGET` keeps a dispatch within seconds and
+        leaves shallow shapes in a single one. A forest's trees go by
+        the same rule, one a scan step."""
+        p = self.p
+        per_round = padded * max(self.F, 1) * p.nbins \
+            * (2 ** p.max_depth) * self.K
+        budget_chunk = max(1, int(_DISPATCH_BUDGET // per_round))
+        score = self.score_every
+        out: list[int] = []
+        t = start_t
+        while t < p.ntrees:
+            n = min(budget_chunk, p.ntrees - t)
+            if score:
+                # stop at score boundaries, but never let the budget
+                # densify the scoring cadence (each scoring event is
+                # a blocking host sync)
+                n = min(n, score - (t - start_t) % score)
+            out.append(n)
+            t += n
+        return out
+
+    def ooc_chunk(self, padded: int, ckpt) -> int | None:
+        """Rows per host-pinned chunk when out-of-core mode engages,
+        None for the in-HBM path.
+
+        Trigger: H2O_TPU_OOC=1 forces it (where eligible), =0 disables;
+        otherwise it engages when the uint8 binned matrix would exceed
+        the headroom the budget leaves after the level histograms.
+        Eligibility is pointwise single-output boosting — multinomial,
+        DRF voting, huber (global residual quantile per round),
+        checkpoint continuation, a scoring cadence (score_every: the
+        stream scores once at the end, and a parameter must never be
+        dropped silently), and row/column/per-node feature sampling
+        (sample_rate / col_sample_rate_per_tree < 1, mtries > 0: the
+        streamed key schedule differs from the fused core's, so the
+        MODEL would depend on the chunk-size perf knob or on which
+        path engaged) stay in-HBM (docs/SCALING.md). Multi-host (DCN)
+        meshes stay in-HBM too: the chunk staging `device_put` cannot
+        target other processes' devices (same guard as
+        Vec.select_rows)."""
+        p = self.p
+        env = os.environ.get("H2O_TPU_OOC", "auto")
+        if env == "0":
+            return None
+        if self.mode != "single" or ckpt is not None or \
+                self.distribution == "huber" or p.score_every or \
+                p.sample_rate < 1.0 or p.col_sample_rate_per_tree < 1.0 \
+                or p.mtries > 0:
+            return None
+        if not row_sharding(self.mesh).is_fully_addressable:
+            return None
+        if env != "1" and \
+                padded * self.F <= max(self.budget - self.hist_bytes, 0):
+            return None
+        from .tree.ooc import chunk_rows_for
+
+        return chunk_rows_for(padded, self.F, self.budget,
+                              self.hist_bytes)
+
+    def operands(self, binned, y, w, margin, keys, goss_keys,
+                 efb=None) -> tuple:
+        """The program's whole argument list — arrays for a dispatch,
+        ShapeDtypeStructs for a lowering. Under GOSS the scan runs
+        over a (round keys, goss keys) pair; with GOSS off the scanned
+        operand is the plain key array, byte-identical to a build
+        without the feature."""
+        if self.bp.goss_b > 0:
+            keys = (keys, goss_keys)
+        statics = (self.tp, self.bp, self.mesh) if self.K == 1 \
+            else (self.tp, self.bp, self.K, self.mesh)
+        return (binned, y, w, margin, keys, efb) + statics
+
+    def dispatch(self, binned, y, w, margin, kc, n: int, efb=None,
+                 goss_keys=None) -> tuple:
+        """``n`` rounds in ONE compiled dispatch → (margin, trees
+        [n·K, N], the rounds' keys [n], the GOSS overflow scalar or
+        None). Every draw a round makes is a pure function of its key
+        (`TreeDraws`)."""
+        keys = round_keys(kc, n)
+        out = _BOOST_PROGRAMS[self.mode](*self.operands(
+            binned, y, w, margin, keys, goss_keys, efb))
+        trees = out[1]
+        if self.K > 1:
+            # [n, K, ...] -> interleaved [n*K, ...] (class fastest),
+            # the layout _margins de-interleaves with a[k::K]
+            trees = jax.tree.map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), trees)
+        return out[0], trees, keys, \
+            (out[2] if self.bp.goss_b > 0 else None)
+
+    def lowerings(self, padded: int) -> list[tuple]:
+        """(jitted program, abstract arguments) of what a fresh in-HBM
+        job of ``padded`` rows dispatches, in its order: `_init_margin`,
+        then the boost program once a distinct dispatch. efb=None: EFB
+        plans are data-dependent, and compile-ahead serves the frames
+        the auto gate keeps unbundled."""
+        rows = row_sharding(self.mesh)
+        row_s = jax.ShapeDtypeStruct((padded,), jnp.float32, sharding=rows)
+        binned_s = jax.ShapeDtypeStruct((padded, self.F), jnp.uint8,
+                                        sharding=rows)
+        out = []
+        if self.device_init:
+            out.append((_init_margin, (row_s, row_s, row_s,
+                                       self.distribution, self.K)))
+        # the first dispatch takes the margin as `_initial_margin`
+        # makes it — row-sharded for one output (made off `data.y`),
+        # `_init_margin`'s replicated broadcast for K classes, an
+        # uncommitted jnp.zeros (no sharding) for a K-class forest —
+        # and every later one the row-sharded output of the one before
+        first = rows if self.K == 1 else None if self.bp.drf_mode \
+            else replicated(self.mesh)
+        keydt = jax.eval_shape(lambda: jax.random.key(0)).dtype
+        seen = set()
+        for i, n in enumerate(self.chunks(padded)):
+            msh = first if i == 0 else rows
+            if (n, msh) in seen:
+                continue
+            seen.add((n, msh))
+            margin_s = jax.ShapeDtypeStruct(
+                (padded,) if self.K == 1 else (padded, self.K),
+                jnp.float32, sharding=msh)
+            keys_s = jax.ShapeDtypeStruct((n,), keydt)
+            out.append((_BOOST_PROGRAMS[self.mode], self.operands(
+                binned_s, row_s, row_s, margin_s, keys_s, keys_s)))
+        return out
+
+
+def boost_plan(p: "GBMParams", distribution: str, nclasses: int, F: int,
+               mesh=None) -> BoostPlan:
+    """The plan of ``p`` on a resolved response and ``F`` histogram
+    columns. Bad GOSS knobs raise here (`goss_params`)."""
+    K = nclasses if nclasses > 2 else 1
+    tp = _make_tree_params(p, distribution)
+    hist_bytes = level_hist_bytes(tp, F)
+    if K > 1 and multi_grow_vmapped(tp, F, K):
+        # the memory that will actually be live: K× only when the
+        # grower really vmaps (past its budget it falls to lax.map
+        # with one class's histograms live)
+        hist_bytes *= K
+    budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET", 2 ** 30))
+    return BoostPlan(p, distribution, K, F, tp,
+                     _make_boost_params(p, distribution), hist_bytes,
+                     budget, mesh or global_mesh())
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
@@ -322,8 +521,8 @@ class GBMModel(Model):
         self.params = params
         self.bin_spec = bin_spec
         # stacked pytree: leaves have leading tree axis [T(*K), N];
-        # accepts an already-stacked Tree (the fused boost_trees /
-        # boost_trees_multi output) or a list of single trees (the
+        # accepts an already-stacked Tree (what BoostPlan.dispatch
+        # hands back) or a list of single trees (the
         # XGBoost lambdarank host loop)
         if isinstance(trees, Tree):
             self.trees = trees
@@ -578,22 +777,6 @@ class GBMModel(Model):
                 for k, val in sorted(v.items(), key=lambda kv: -kv[1])}
 
 
-@contextlib.contextmanager
-def legacy_scoring_path(model: GBMModel):
-    """Route `model.predict()` through the PRE-flattening path —
-    binned heap re-descent, eager op dispatch, no scorer cache — for
-    the duration of the block.  The serving benchmarks (bench.py score
-    mode, bench_suite's gbm_score_rows_per_sec) use this as the ONE
-    definition of the legacy baseline; everything else should never
-    need it."""
-    model._margins = model._margins_binned
-    model._serving_jit = False
-    try:
-        yield model
-    finally:
-        del model._margins, model._serving_jit
-
-
 class GBM:
     """H2OGradientBoostingEstimator analog."""
 
@@ -665,54 +848,13 @@ class GBM:
                 if ymin < 0:
                     raise ValueError(f"{data.distribution} distribution "
                                      "needs a non-negative response")
-            margin_scale = 1.0
             ckpt = p.checkpoint
+            bin_spec = None                  # fit below, fused when it fits
             if ckpt is not None:
-                if self.cv_args.enabled:
-                    # H2O forbids checkpoint+CV: fold models would inherit
-                    # trees that already saw their holdout rows
-                    raise ValueError(
-                        "checkpoint cannot be combined with cross-validation")
-                if ckpt.feature_names != data.feature_names:
-                    raise ValueError(
-                        "checkpoint model was trained on different features "
-                        f"({ckpt.feature_names} vs {data.feature_names})")
-                if ckpt.distribution != data.distribution:
-                    raise ValueError("checkpoint distribution mismatch")
-                if ckpt.nclasses != data.nclasses or \
-                        (ckpt.response_domain or []) != \
-                        (data.response_domain or []):
-                    raise ValueError(
-                        "checkpoint response mismatch: "
-                        f"{ckpt.nclasses} classes {ckpt.response_domain} vs "
-                        f"{data.nclasses} classes {data.response_domain}")
-                K0 = ckpt.nclasses if ckpt.nclasses > 2 else 1
-                if p.ntrees * K0 <= len(ckpt.trees.value):
-                    raise ValueError(
-                        f"ntrees={p.ntrees} must exceed the checkpoint's "
-                        f"{len(ckpt.trees.value) // K0} trees")
+                _check_checkpoint(ckpt, p, data, offset_column,
+                                  self.cv_args.enabled)
                 bin_spec = ckpt.bin_spec     # same binning → trees compose
-            else:
-                bin_spec = None              # fit below, fused when eligible
-
-            K = data.nclasses if data.nclasses > 2 else 1
-            tp = _make_tree_params(p, data.distribution)
             key = jax.random.key(p.seed)
-            F = len(data.feature_names)
-
-            # GOSS (H2O_TPU_GOSS): validated up front so a bad knob or a
-            # conflicting sample_rate fails before any binning work; the
-            # per-round key stream is derived OUTSIDE the dispatch-chunk
-            # key schedule (goss_round_keys) so the fused in-HBM path and
-            # the ooc stream draw identical keep patterns at one seed
-            goss_a, goss_b = goss_params(p, data.distribution)
-            if goss_b > 0 and p.sample_rate < 1.0:
-                raise ValueError(
-                    "H2O_TPU_GOSS replaces row subsampling — train with "
-                    f"sample_rate=1.0 (got {p.sample_rate}) or disable "
-                    "the GOSS knob")
-            goss_keys = goss_round_keys(key, p.ntrees) if goss_b > 0 \
-                else None
 
             # Exclusive Feature Bundling (models/tree/efb.py,
             # docs/SCALING.md "Wide sparse frames"): on wide frames
@@ -726,70 +868,43 @@ class GBM:
             # through to the fused prologue unchanged.
             from .tree import efb as efb_mod
 
-            efb_plan = None
-            efb = None
-            F_eff = F
-            if bin_spec is None and efb_mod.efb_eligible(F, ckpt):
-                spec_efb, efb_plan = efb_mod.fit_plan_cached(
-                    training_frame, data.feature_names, p.nbins)
+            efb_plan = efb = None
+            F = len(data.feature_names)
+            if efb_mod.efb_eligible(F, ckpt):
                 # reuse the fitted spec either way: when the plan is
                 # rejected (shrink gate / no exclusive sets) re-fitting
                 # through the fused prologue would just duplicate the
                 # quantile fit this pass already paid
-                bin_spec = spec_efb
+                bin_spec, efb_plan = efb_mod.fit_plan_cached(
+                    training_frame, data.feature_names, p.nbins)
                 if efb_plan is not None:
                     efb = efb_plan.device_luts()
-                    F_eff = efb_plan.fb
+                    # histograms are accounted at the width they have:
+                    # the memory win is exactly what buys deeper trees
+                    # on wide sparse frames
+                    F = efb_plan.fb
 
-            # deep-tree memory validation: the dense heap's per-level
-            # histogram working set is O(2^d·F·B·C) — the SAME accounting
-            # (core.level_hist_bytes) the multinomial vmap branch uses,
-            # so this validator and the actual branch decision cannot
-            # drift. The reference reaches depth 20
-            # via dynamic row partitions; here ANY depth whose level
-            # histograms fit the budget trains fine (e.g. depth 16 with 4
-            # features × 16 bins is ~25 MB), and one that cannot fit fails
-            # HERE with sizing guidance instead of an opaque device OOM
-            # mid-boost.
-            from .tree.core import level_hist_bytes, multi_grow_vmapped
-
-            # histogram accounting at the width histograms actually have:
-            # the BUNDLED width when EFB engaged (the memory win is exactly
-            # what buys deeper trees on wide sparse frames)
-            hist_bytes = level_hist_bytes(tp, F_eff)
-            if K > 1 and multi_grow_vmapped(tp, F_eff, K):
-                # validate the memory that will actually be live: K× only
-                # when the grower really vmaps (past its budget it falls
-                # to lax.map with one class's histograms live)
-                hist_bytes *= K
-            budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET",
-                                          2 ** 30))
-            if hist_bytes > budget:
-                need_mb = hist_bytes / 2 ** 20
-                raise ValueError(
-                    f"max_depth={p.max_depth} with {F_eff} histogram "
-                    f"columns x {p.nbins} bins needs ~{need_mb:.0f} MiB of "
-                    f"level histograms (> budget "
-                    f"{budget / 2 ** 20:.0f} MiB). "
-                    "Lower max_depth or nbins, drop features, or raise "
-                    "H2O_TPU_HIST_BYTES_BUDGET if the device has room.")
-
-            # out-of-core mode: when the uint8 binned matrix would not fit
-            # the headroom the histogram budget leaves, keep it host-
-            # resident in chunks and stream per boosting iteration
-            # (models/tree/ooc.py). `binned` is only materialized on device
-            # for the in-HBM path.
-            ooc_chunk = _ooc_chunk_rows(p, data, K, F_eff, hist_bytes,
-                                        budget, ckpt)
+            plan = boost_plan(p, data.distribution, data.nclasses, F)
+            plan.validate()
+            # the per-round GOSS key stream is derived OUTSIDE the
+            # dispatch-chunk key schedule (goss_round_keys) so the fused
+            # in-HBM path and the ooc stream draw identical keep patterns
+            # at one seed
+            goss_keys = goss_round_keys(key, p.ntrees) \
+                if plan.bp.goss_b > 0 else None
+            # out-of-core: the binned matrix stays host-resident in
+            # chunks, streamed per boosting iteration (models/tree/ooc.py);
+            # `binned` is on the device for the in-HBM path only
+            ooc_chunk = plan.ooc_chunk(data.y.shape[0], ckpt)
             binned = None
-        root.update(rows=training_frame.nrows, features=F_eff,
-                    chips=global_mesh().size)
+        root.update(rows=training_frame.nrows, features=plan.F,
+                    chips=plan.mesh.size)
         # no span blocks on the device for its own sake (the dispatch
         # pipeline below is the loop's design): `enqueue` spans read the
         # dispatch, the device's side is in the device trace under
         # telemetry.TRAIN_PROGRAMS' names
         with phase_span("train.bin", kind="enqueue",
-                        rows=data.y.shape[0], features=F_eff):
+                        rows=data.y.shape[0], features=plan.F):
             if efb_plan is not None:
                 # bundled training matrix [padded, Fb] (host-built
                 # during planning, device-cached on the plan); the
@@ -798,13 +913,11 @@ class GBM:
                 if ooc_chunk is None:
                     binned = efb_plan.binned_device()
             elif bin_spec is None:
-                # fresh fit: on the in-HBM path the quantile fit and
-                # the bin apply fuse into ONE dispatch with no host
-                # sync in between (binning.fused_fit_bins;
-                # H2O_TPU_FUSED_BINNING=0 restores the two-dispatch
-                # path) — the out-of-core path keeps the classic fit
-                # (its apply streams host chunks)
-                if ooc_chunk is None and fused_binning_enabled():
+                # fresh fit: in HBM the quantile fit and the bin apply
+                # fuse into ONE dispatch with no host sync in between
+                # (binning.fused_fit_bins); the stream keeps the
+                # two-dispatch fit (its apply streams host chunks)
+                if ooc_chunk is None:
                     bin_spec, binned = fused_fit_bins(
                         training_frame, data.feature_names,
                         n_bins=p.nbins)
@@ -816,96 +929,23 @@ class GBM:
                 binned = training_frame.binned(bin_spec)
 
         with phase_span("train.init_margin", kind="enqueue"):
-            off = data.offset if data.offset is not None \
-                else jnp.zeros_like(data.y)
-            if ckpt is not None:
-                if ckpt.params.nbins != p.nbins or \
-                        ckpt.params.max_depth != p.max_depth:
-                    raise ValueError(
-                        "checkpoint nbins/max_depth must match "
-                        f"({ckpt.params.nbins}/{ckpt.params.max_depth} vs "
-                        f"{p.nbins}/{p.max_depth})")
-                if (getattr(ckpt, "offset_column", None) or None) != \
-                        (offset_column or None):
-                    raise ValueError(
-                        "checkpoint offset_column mismatch: "
-                        f"{getattr(ckpt, 'offset_column', None)!r} vs "
-                        f"{offset_column!r}")
-                init = ckpt.init_score
-                if p._drf_mode:
-                    margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
-                        else jnp.zeros_like(data.y)
-                elif K == 1:
-                    margin = init + off + _stack_predict(
-                        ckpt.trees, binned, p.max_depth, p.nbins)
-                else:
-                    outs = [init[k] + _stack_predict(
-                        jax.tree.map(lambda a: a[k::K], ckpt.trees),
-                        binned, p.max_depth, p.nbins) for k in range(K)]
-                    margin = jnp.stack(outs, axis=1)
-            elif p._drf_mode:
-                # DRF: no boosting — leaves are in-leaf target means, init 0
-                init = np.zeros(K, dtype=np.float32) if K > 1 else 0.0
-                margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
-                    else jnp.zeros_like(data.y)
-            elif data.distribution == "laplace":
-                # L1 leaf steps are bounded by learn_rate, so fit in
-                # median/MAD-scaled space: |y-f| is scale-equivariant and
-                # the minimizer is unchanged; predictions rescale on read
-                yv = np.asarray(data.y)[np.asarray(data.w) > 0]
-                init = float(np.median(yv)) if len(yv) else 0.0
-                mad = float(np.median(np.abs(yv - init))) if len(yv) else 1.0
-                # MAD degenerates to 0 on zero-inflated data (>=50% of y at
-                # one value) — only then fall back to the non-robust std,
-                # otherwise keep the outlier-insensitive scale
-                if mad * 1.4826 > 1e-8:
-                    margin_scale = mad * 1.4826
-                else:
-                    std = float(np.std(yv)) if len(yv) else 1.0
-                    margin_scale = max(std, 1e-8)
-                import dataclasses
+            init, margin, margin_scale, data = _initial_margin(
+                plan, data, ckpt, binned)
 
-                data = dataclasses.replace(
-                    data, y=(data.y - init) / margin_scale)
-                margin = jnp.zeros_like(data.y)
-            else:
-                # bernoulli/multinomial/poisson/gamma/tweedie/gaussian:
-                # init + margin in one device dispatch, no host sync before
-                # the first boost chunk (init is read back at model build)
-                init, margin = _init_margin(data.y, data.w, off,
-                                            data.distribution, K)
-
-            if ckpt is not None and data.distribution == "laplace":
-                # continuation must reuse the checkpoint's robust scaling or
-                # the new trees' leaf units would not compose; the working
-                # margin lives in SCALED units (tree leaves), so drop the
-                # init the generic ckpt branch added above
-                init = ckpt.init_score
-                margin_scale = getattr(ckpt, "margin_scale", 1.0)
-                import dataclasses
-
-                data = dataclasses.replace(
-                    data, y=(data.y - init) / margin_scale)
-                margin = margin - init
-
-        start_t = 0
-        if ckpt is not None:
-            start_t = len(ckpt.trees.value) // K
+        start_t = 0 if ckpt is None else len(ckpt.trees.value) // plan.K
         history: list[dict] = []
         key_chunks: list = []
         # fused loop: all boosting rounds of a chunk build inside ONE
         # compiled shard_map (scan over rounds; for K>2 classes the K
         # trees of a round grow via vmap inside the scan) — the margin
         # never leaves the device and the host dispatches once per chunk
-        # instead of >=3 times per tree (VERDICT r1: the per-tree Python
-        # loop dominated wall-clock; r2 left multinomial on it)
-        bp = _make_boost_params(p, data.distribution)
+        # instead of >=3 times per tree
         if ooc_chunk is not None:
             # chunk-streamed boosting: host-pinned binned chunks,
             # double-buffered device_put per level, chunk-accumulated
             # histograms (models/tree/ooc.py). Metrics land once at
             # the end — models with a score_every cadence never reach
-            # this branch (_ooc_chunk_rows gates them in-HBM).
+            # this branch (plan.ooc_chunk gates them in-HBM).
             from ..runtime.mrtask import shard_rows
             from .tree.ooc import boost_trees_chunked, make_chunks
 
@@ -917,7 +957,7 @@ class GBM:
                                   data.w, margin, ooc_chunk,
                                   plan=efb_plan)
                 margin_np, trees, goss_dropped = boost_trees_chunked(
-                    cks, key, p.ntrees, tp, bp, efb=efb,
+                    cks, key, p.ntrees, plan.tp, plan.bp, efb=efb,
                     goss_keys=goss_keys)
             _warn_goss_overflow(goss_dropped)
             margin = shard_rows(margin_np)
@@ -925,9 +965,8 @@ class GBM:
             with phase_span("train.boost", kind="enqueue", mode="in_hbm",
                             trees=p.ntrees):
                 trees, margin, history, key_chunks = self._boost_in_hbm(
-                    p, tp, bp, data, binned, margin, key, K, F_eff,
-                    ckpt, start_t, history, efb=efb,
-                    goss_keys=goss_keys)
+                    plan, data, binned, margin, key, ckpt, start_t,
+                    history, efb=efb, goss_keys=goss_keys)
         with phase_span("train.read_model", kind="wait"):
             if isinstance(init, jax.Array):
                 # read the device init back AFTER the boost chunks are
@@ -949,12 +988,12 @@ class GBM:
                 model.tree_draws = TreeDraws(
                     keys=np.concatenate(
                         [np.asarray(k) for k in key_chunks]).tolist(),
-                    shards=global_mesh().shape[ROWS],
+                    shards=plan.mesh.shape[ROWS],
                     padded=int(data.y.shape[0]), rows=int(data.nrows),
                     sample_rate=p.sample_rate,
                     col_rate=p.col_sample_rate_per_tree, mtries=p.mtries,
                     features=len(data.feature_names),
-                    max_depth=p.max_depth, classes=K)
+                    max_depth=p.max_depth, classes=plan.K)
             model._varimp = _stacked_varimp(model.trees, data.feature_names)
         with phase_span("train.metric", kind="wait"):
             if p._drf_mode:
@@ -990,14 +1029,14 @@ class GBM:
                  "offset_column": offset_column},
                 validation_frame)
 
-    def _boost_in_hbm(self, p, tp, bp, data, binned, margin, key, K, F,
+    def _boost_in_hbm(self, plan: BoostPlan, data, binned, margin, key,
                       ckpt, start_t, history, efb=None, goss_keys=None):
-        """The fused in-HBM boosting loop (all rows device-resident).
-        ``F`` is the HISTOGRAM width (the bundled width under EFB) —
-        it sizes the dispatch-budget chunks to the actual work.
-        ``goss_keys`` ([ntrees] rows, indexed by GLOBAL tree number)
-        is sliced per dispatch chunk so the per-round GOSS draw never
-        depends on the _DISPATCH_BUDGET chunk schedule."""
+        """The fused in-HBM boosting loop (all rows device-resident):
+        the dispatches `plan.chunks` names, each through
+        `plan.dispatch`. ``goss_keys`` ([ntrees] rows, indexed by
+        GLOBAL tree number) is sliced per dispatch so the per-round
+        GOSS draw never depends on the _DISPATCH_BUDGET schedule."""
+        p = plan.p
         chunks: list[Tree] = [] if ckpt is None else [ckpt.trees]
         goss_overflow: list = []      # per-dispatch device scalars
         # the keys of the rounds grown, kept while a round draws
@@ -1011,20 +1050,9 @@ class GBM:
             sampled = prior is not None
             if sampled:
                 key_chunks.append(np.asarray(prior.keys, np.uint32))
-        # cap ONE compiled dispatch's work: the TPU worker (behind
-        # its RPC deadline) kills executions that run for minutes.
-        # Work/round ~ rows·F·nbins·2^depth·K (deepest level
-        # dominates with sibling subtraction): 1.9e12 units are 1.0 s
-        # on a v5e at depth 6 x 256 bins (the factorized kernel), so
-        # the budget keeps a dispatch within seconds and leaves
-        # shallow/bench shapes in a single dispatch. A forest's trees
-        # go by the same rule, one a scan step. The chunk schedule
-        # lives in _chunk_sizes — compile-ahead pre-lowers exactly
-        # these shapes.
-        score = p.score_every if (p.score_every and not p._drf_mode) \
-            else 0
+        score = plan.score_every
         t = start_t
-        for n in _chunk_sizes(p, data.y.shape[0], F, K, start_t):
+        for n in plan.chunks(data.y.shape[0], start_t):
             require_healthy()        # fail fast on a dead mesh (§5.3)
             key, kc = jax.random.split(key)
             # the boost dispatch runs under the device guard: a chip
@@ -1040,35 +1068,12 @@ class GBM:
             with device_dispatch("gbm boost dispatch"), \
                     phase_span("train.dispatch", kind="enqueue",
                                first_tree=t, trees=n):
-                if K == 1 and p._drf_mode:
-                    # a forest's trees are independent: no margin
-                    # update, and the trees' own keys come back
-                    margin, tchunk, kchunk = boost_trees_drf(
-                        binned, data.y, data.w, margin, kc, n, tp, bp,
-                        efb=efb)
-                elif K == 1:
-                    out = boost_trees(
-                        binned, data.y, data.w, margin, kc, n, tp, bp,
-                        efb=efb, goss_keys=gk)
-                    margin, tchunk = out[0], out[1]
-                    if gk is not None:
-                        goss_overflow.append(out[2])
-                else:
-                    out = boost_trees_multi(
-                        binned, data.y, data.w, margin, kc, n, K, tp,
-                        bp, efb=efb, goss_keys=gk)
-                    margin, tchunk = out[0], out[1]
-                    if gk is not None:
-                        goss_overflow.append(out[2])
-                    # [n, K, ...] -> interleaved [n*K, ...] (class
-                    # fastest), the layout _margins de-interleaves with
-                    # a[k::K]
-                    tchunk = jax.tree.map(
-                        lambda a: a.reshape((-1,) + a.shape[2:]), tchunk)
+                margin, tchunk, kchunk, overflow = plan.dispatch(
+                    binned, data.y, data.w, margin, kc, n, efb, gk)
             chunks.append(tchunk)
+            if overflow is not None:
+                goss_overflow.append(overflow)
             if sampled:
-                if not (K == 1 and p._drf_mode):
-                    kchunk = round_keys(kc, n)
                 key_chunks.append(jax.random.key_data(kchunk))
             t += n
             if score and (t - start_t) % score == 0:
@@ -1086,84 +1091,45 @@ class GBM:
 
     def compile_ahead_lowerings(self, y: str, frame: Frame,
                                 x: Sequence[str] | None = None) -> list:
-        """Zero-arg thunks that AOT-lower+compile the fused boost
-        programs ``train(y, frame, x)`` will dispatch — run on the
-        compile-ahead stream while the device token is busy with an
-        earlier model, so the device stream's later dispatch is a
-        compile-cache hit (in-process executable cache + the
+        """Zero-arg thunks that AOT-lower+compile the programs
+        ``train(y, frame, x)`` will dispatch (`BoostPlan.lowerings`) —
+        run on the compile-ahead stream while the device token is busy
+        with an earlier model, so the device stream's later dispatch
+        is a compile-cache hit (in-process executable cache + the
         persistent XLA cache: a fill on a cold run, a no-op warm).
 
-        Shape reconstruction mirrors train() from column METADATA only
-        (padded_len, kinds, cardinality — no device dispatch, the
-        compile stream never touches the device token). Coverage is
-        the in-HBM pointwise tree path: the final fit's full-frame
-        shape plus, under modulo CV (AutoML's fold assignment), the
-        fold shapes — identical to the full shape in weights-masked
-        share mode, the complement sizes in sliced mode. Ineligible
-        configs (checkpoint continuation, out-of-core engagement,
-        offset/weights columns, non-modulo folds) return [] and train
-        compiles on-demand exactly as before.  Drift between this
-        mirror and train() is pinned by tests/test_scheduler.py."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from ..runtime import mesh as meshlib
+        The plan is built from column METADATA only (padded_len,
+        kinds, cardinality — no device dispatch, the compile stream
+        never touches the device token). Coverage is the in-HBM
+        pointwise tree path: the final fit's full-frame shape plus,
+        under modulo CV (AutoML's fold assignment), the fold shapes —
+        identical to the full shape in weights-masked share mode, the
+        complement sizes in sliced mode. What it alone rules out
+        (checkpoint continuation, a fold column, a missing response, a
+        width EFB may rebundle, the lambdarank host loop), what the
+        plan refuses and what streams out of core return no thunks,
+        and train compiles on demand. tests/test_scheduler.py holds
+        what is lowered to what is dispatched, mode by mode."""
         from ..runtime.mrtask import _padded_len
-        from .tree import core as _core
-        from .tree.core import level_hist_bytes, multi_grow_vmapped
-
-        p = self.params
-        if p.checkpoint is not None or self.cv_args.fold_column:
-            return []
-        if y not in frame:
-            return []
-        ignored = {y}
-        names = list(x) if x else [
-            n for n in frame.names if n not in ignored and
-            frame.vec(n).kind in ("numeric", "enum", "time")]
-        if not names or ignored.intersection(names):
-            return []
         from .tree import efb as efb_mod
 
-        if efb_mod.efb_eligible(len(names), None):
-            # EFB may rebundle this frame to a DATA-dependent width —
-            # pre-lowering F-width executables would be dead compile
-            # work burning the compile stream while train() compiles
-            # the bundled shapes on demand anyway
+        p = self.params
+        if p.checkpoint is not None or self.cv_args.fold_column or \
+                y not in frame:
             return []
-        for n in names:
-            if n not in frame or frame.vec(n).kind not in (
-                    "numeric", "enum", "time"):
-                return []
-        yv = frame.vec(y)
-        nclasses = yv.cardinality() if yv.is_enum() else 1
-        dist = p.distribution
-        if dist == "auto":
-            dist = "bernoulli" if nclasses == 2 else \
-                "multinomial" if nclasses > 2 else "gaussian"
-        if dist.startswith("rank:"):
-            return []       # the lambdarank host loop, not this path
-        K = nclasses if nclasses > 2 else 1
-        tp = _make_tree_params(p, dist)
         try:
-            bp = _make_boost_params(p, dist)
+            names = _feature_names(frame, x, {y})
+            dist, nclasses, _ = resolve_response(frame, y, p.distribution)
+            # EFB may rebundle the frame to a DATA-dependent width:
+            # F-width executables would be dead compile work
+            if not names or efb_mod.efb_eligible(len(names), None) or \
+                    dist.startswith("rank:"):
+                return []
+            plan = boost_plan(p, dist, nclasses, len(names))
+            plan.validate()
         except ValueError:
-            return []       # bad GOSS knobs: train() raises, on the
-            #                 driver thread with the real message
-        if bp.goss_b > 0 and p.sample_rate < 1.0:
-            return []       # train() rejects the combination up front
-        hist_bytes = level_hist_bytes(tp, len(names))
-        if K > 1 and multi_grow_vmapped(tp, len(names), K):
-            hist_bytes *= K
-        budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET",
-                                      2 ** 30))
-        if hist_bytes > budget:
-            return []                       # train() raises up front
-        mesh = meshlib.global_mesh()
-        shards = mesh.shape[meshlib.ROWS]
-        rows_shard = NamedSharding(mesh, P(meshlib.ROWS))
-        F = len(names)
+            return []       # train() raises it, on the driver thread
         n = frame.nrows
-
         # the shapes train() will see: the final fit's padded length,
         # plus the modulo-CV fold lengths — full-frame in share mode
         # (models/cv.py weights-masked folds), complement sizes sliced
@@ -1179,66 +1145,116 @@ class GBM:
             if "_cv_mask_w_" in frame.names:
                 share = False
             if not share:
+                shards = plan.mesh.shape[ROWS]
                 for k in range(cv.nfolds):
                     hold = n // cv.nfolds + (1 if k < n % cv.nfolds
                                              else 0)
                     padded_sizes.add(_padded_len(n - hold, shards))
+        return [functools.partial(_aot, fn, *args)
+                for padded in sorted(padded_sizes)
+                if plan.ooc_chunk(padded, None) is None
+                for fn, args in plan.lowerings(padded)]
 
-        # mirror the out-of-core gate per shape (ooc streams its own
-        # per-level programs; the fused boost lowering would be wasted)
-        class _Shim:           # just .y.shape[0] / .distribution for
-            pass               # _ooc_chunk_rows — zero logic duplicated
 
-        keydt = jax.eval_shape(lambda: jax.random.key(0)).dtype
-        thunks: list = []
-        for padded in sorted(padded_sizes):
-            shim = _Shim()
-            shim.distribution = dist
-            shim.y = jax.ShapeDtypeStruct((padded,), jnp.float32)
-            if _ooc_chunk_rows(p, shim, K, F, hist_bytes, budget,
-                               None) is not None:
-                continue
-            binned_s = jax.ShapeDtypeStruct((padded, F), jnp.uint8,
-                                            sharding=rows_shard)
-            row_s = jax.ShapeDtypeStruct((padded,), jnp.float32,
-                                         sharding=rows_shard)
-            if p._drf_mode:
-                # train()'s DRF margin is an eager jnp.zeros
-                # (uncommitted) — mirror its unspecified sharding or
-                # the executable key misses
-                margin_s = jax.ShapeDtypeStruct(
-                    (padded,) if K == 1 else (padded, K), jnp.float32)
-            else:
-                margin_s = row_s if K == 1 else jax.ShapeDtypeStruct(
-                    (padded, K), jnp.float32, sharding=rows_shard)
-            if not p._drf_mode and dist != "laplace":
-                thunks.append(functools.partial(
-                    _aot, _init_margin, row_s, row_s, row_s, dist, K))
-            for nt in sorted(set(_chunk_sizes(p, padded, F, K))):
-                # efb=None mirrors train(): compile-ahead covers the
-                # unbundled dispatch shapes (EFB plans are
-                # data-dependent, and the auto gate keeps narrow
-                # frames — everything this mirror serves — unbundled)
-                keys_s = jax.ShapeDtypeStruct((nt,), keydt)
-                if K == 1 and p._drf_mode:
-                    thunks.append(functools.partial(
-                        _aot, _core._boost_drf_jit, binned_s, row_s,
-                        row_s, margin_s, keys_s, None, tp, bp, mesh))
-                    continue
-                if bp.goss_b > 0:
-                    # GOSS scans a (round keys, goss keys) pair —
-                    # mirror boost_trees' operand structure exactly
-                    keys_s = (keys_s,
-                              jax.ShapeDtypeStruct((nt,), keydt))
-                if K == 1:
-                    thunks.append(functools.partial(
-                        _aot, _core._boost_jit, binned_s, row_s, row_s,
-                        margin_s, keys_s, None, tp, bp, mesh))
-                else:
-                    thunks.append(functools.partial(
-                        _aot, _core._boost_multi_jit, binned_s, row_s,
-                        row_s, margin_s, keys_s, None, tp, bp, K, mesh))
-        return thunks
+def _check_checkpoint(ckpt, p: GBMParams, data: TrainData, offset_column,
+                      cv_enabled: bool) -> None:
+    """Refuse a checkpoint whose trees the new rounds would not compose
+    with (reference SharedTree checkpoint semantics, SURVEY.md §5.4)."""
+    if cv_enabled:
+        # H2O forbids checkpoint+CV: fold models would inherit
+        # trees that already saw their holdout rows
+        raise ValueError(
+            "checkpoint cannot be combined with cross-validation")
+    if ckpt.feature_names != data.feature_names:
+        raise ValueError(
+            "checkpoint model was trained on different features "
+            f"({ckpt.feature_names} vs {data.feature_names})")
+    if ckpt.distribution != data.distribution:
+        raise ValueError("checkpoint distribution mismatch")
+    if ckpt.nclasses != data.nclasses or \
+            (ckpt.response_domain or []) != (data.response_domain or []):
+        raise ValueError(
+            "checkpoint response mismatch: "
+            f"{ckpt.nclasses} classes {ckpt.response_domain} vs "
+            f"{data.nclasses} classes {data.response_domain}")
+    K0 = ckpt.nclasses if ckpt.nclasses > 2 else 1
+    if p.ntrees * K0 <= len(ckpt.trees.value):
+        raise ValueError(
+            f"ntrees={p.ntrees} must exceed the checkpoint's "
+            f"{len(ckpt.trees.value) // K0} trees")
+    if ckpt.params.nbins != p.nbins or \
+            ckpt.params.max_depth != p.max_depth:
+        raise ValueError(
+            "checkpoint nbins/max_depth must match "
+            f"({ckpt.params.nbins}/{ckpt.params.max_depth} vs "
+            f"{p.nbins}/{p.max_depth})")
+    if (getattr(ckpt, "offset_column", None) or None) != \
+            (offset_column or None):
+        raise ValueError(
+            "checkpoint offset_column mismatch: "
+            f"{getattr(ckpt, 'offset_column', None)!r} vs "
+            f"{offset_column!r}")
+
+
+def _initial_margin(plan: BoostPlan, data: TrainData, ckpt, binned):
+    """(init score, starting margin, margin_scale, data) of a job: a
+    checkpoint's trees scored over the binned matrix, a forest's zeros,
+    laplace's robust scaling (which rescales ``data.y``), or the prior
+    on the device."""
+    p, K = plan.p, plan.K
+    margin_scale = 1.0
+    off = data.offset if data.offset is not None \
+        else jnp.zeros_like(data.y)
+    laplace = data.distribution == "laplace"
+    if ckpt is not None:
+        init = ckpt.init_score
+        if p._drf_mode:
+            margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
+                else jnp.zeros_like(data.y)
+        elif K == 1:
+            margin = init + off + _stack_predict(
+                ckpt.trees, binned, p.max_depth, p.nbins)
+        else:
+            outs = [init[k] + _stack_predict(
+                jax.tree.map(lambda a: a[k::K], ckpt.trees),
+                binned, p.max_depth, p.nbins) for k in range(K)]
+            margin = jnp.stack(outs, axis=1)
+        if laplace:
+            # continuation must reuse the checkpoint's robust scaling or
+            # the new trees' leaf units would not compose; the working
+            # margin lives in SCALED units (tree leaves), so the init
+            # added above is dropped below
+            margin_scale = getattr(ckpt, "margin_scale", 1.0)
+    elif plan.device_init:
+        # bernoulli/multinomial/poisson/gamma/tweedie/gaussian:
+        # init + margin in one device dispatch, no host sync before
+        # the first boost chunk (init is read back at model build)
+        init, margin = _init_margin(data.y, data.w, off,
+                                    data.distribution, K)
+    elif p._drf_mode:
+        # DRF: no boosting — leaves are in-leaf target means, init 0
+        init = np.zeros(K, dtype=np.float32) if K > 1 else 0.0
+        margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
+            else jnp.zeros_like(data.y)
+    else:
+        # laplace: L1 leaf steps are bounded by learn_rate, so fit in
+        # median/MAD-scaled space: |y-f| is scale-equivariant and
+        # the minimizer is unchanged; predictions rescale on read
+        yv = np.asarray(data.y)[np.asarray(data.w) > 0]
+        init = float(np.median(yv)) if len(yv) else 0.0
+        mad = float(np.median(np.abs(yv - init))) if len(yv) else 1.0
+        # MAD degenerates to 0 on zero-inflated data (>=50% of y at
+        # one value) — only then fall back to the non-robust std,
+        # otherwise keep the outlier-insensitive scale
+        if mad * 1.4826 > 1e-8:
+            margin_scale = mad * 1.4826
+        else:
+            std = float(np.std(yv)) if len(yv) else 1.0
+            margin_scale = max(std, 1e-8)
+    if laplace:
+        data = replace(data, y=(data.y - init) / margin_scale)
+        margin = jnp.zeros_like(data.y) if ckpt is None else margin - init
+    return init, margin, margin_scale, data
 
 
 def _warn_goss_overflow(dropped: int) -> None:
@@ -1271,51 +1287,6 @@ def _aot(jitted, *args) -> None:
     persistent XLA cache), so the training-time dispatch of the same
     (program, shapes, statics) is a cache hit instead of a compile."""
     jitted.lower(*args).compile()
-
-
-def _ooc_chunk_rows(p: GBMParams, data: TrainData, K: int, F: int,
-                    hist_bytes: int, budget: float,
-                    ckpt) -> int | None:
-    """Rows per host-pinned chunk when out-of-core mode engages, None
-    for the in-HBM path.
-
-    Trigger: H2O_TPU_OOC=1 forces it (where eligible), =0 disables;
-    otherwise it engages when the uint8 binned matrix would exceed the
-    headroom H2O_TPU_HIST_BYTES_BUDGET leaves after the level
-    histograms. Eligibility is pointwise single-output boosting —
-    multinomial, DRF voting, huber (global residual quantile per
-    round), checkpoint continuation, a scoring cadence
-    (score_every: the stream scores once at the end, and a parameter
-    must never be dropped silently), and row/column/per-node feature
-    sampling (sample_rate / col_sample_rate_per_tree < 1, mtries > 0:
-    the streamed key schedule differs from the fused core's, so the
-    MODEL would depend on the chunk-size perf knob or on which path
-    engaged) stay in-HBM
-    (docs/SCALING.md). Multi-host (DCN) meshes stay in-HBM too:
-    the chunk staging `device_put` cannot target other processes'
-    devices (same guard as Vec.select_rows).
-    """
-    env = os.environ.get("H2O_TPU_OOC", "auto")
-    if env == "0":
-        return None
-    if K != 1 or p._drf_mode or ckpt is not None or \
-            data.distribution == "huber" or p.score_every or \
-            p.sample_rate < 1.0 or p.col_sample_rate_per_tree < 1.0 \
-            or p.mtries > 0:
-        return None
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..runtime import mesh as meshlib
-
-    sharding = NamedSharding(meshlib.global_mesh(), P(meshlib.ROWS))
-    if not sharding.is_fully_addressable:
-        return None
-    binned_bytes = data.y.shape[0] * F
-    if env != "1" and binned_bytes <= max(budget - hist_bytes, 0):
-        return None
-    from .tree.ooc import chunk_rows_for
-
-    return chunk_rows_for(data.y.shape[0], F, budget, hist_bytes)
 
 
 def _heap_path(i: int) -> str:

@@ -248,16 +248,11 @@ apply_bins_jit = jax.jit(apply_bins, static_argnums=3)
 # per-feature independent (vmap over columns), so blocking the column
 # axis cannot change a single bin code.
 
-import os as _os
-
 # f32 bytes one column block may occupy while being binned
 _BIN_BLOCK_BYTES = 256 << 20
 
 
 def _bin_block_cols(padded_rows: int, F: int) -> int:
-    env = _os.environ.get("H2O_TPU_BIN_BLOCK_COLS")
-    if env:
-        return max(1, min(int(env), F))
     return max(1, min(F, _BIN_BLOCK_BYTES // max(padded_rows * 4, 1)))
 
 
@@ -300,8 +295,7 @@ def bin_frame(frame, bin_spec: BinSpec) -> jax.Array:
 # blocking host round trip: Frame.binned fingerprints the EDGE BYTES
 # for its cache key, so `np.asarray(edges)` must wait out the quantile
 # computation and transfer it to the host before the bin apply can even
-# dispatch — ~100 ms per train() on the round-4 chip (PROFILE.md
-# "What's next" #2), paid once per AutoML candidate and per CV fold.
+# dispatch, paid once per AutoML candidate and per CV fold.
 # `fused_fit_bins` folds both halves into the frame's first training
 # dispatch: one jitted program computes the quantile edges AND the
 # first column block's codes, nothing touches the host, and the binned
@@ -310,11 +304,6 @@ def bin_frame(frame, bin_spec: BinSpec) -> jax.Array:
 # version counter bumps on Frame.__setitem__).  Bit-parity with the
 # two-dispatch path (same sample gather, same quantile program, same
 # apply_bins) is asserted by tests/test_scheduler.py.
-
-
-def fused_binning_enabled() -> bool:
-    """H2O_TPU_FUSED_BINNING != "0" (the two-dispatch escape hatch)."""
-    return _os.environ.get("H2O_TPU_FUSED_BINNING", "1") != "0"
 
 
 @functools.partial(jax.jit, static_argnums=(5,))

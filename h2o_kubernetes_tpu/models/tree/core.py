@@ -752,8 +752,10 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, *,
     (SharedTree.Driver.computeImpl's outer loop, SURVEY.md §3.4) with a
     single compiled program — the margin never leaves the device and
     the host dispatches once per chunk of trees instead of ≥3 times per
-    tree.
+    tree. A single-output forest has a scan of its own
+    (``_boost_shard_drf``: no margin to carry).
     """
+    assert not bp.drf_mode, "a forest grows in _boost_shard_drf"
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     goss = bp.goss_b > 0.0
 
@@ -764,10 +766,7 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, *,
         with jax.named_scope("sample"):
             w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
         with jax.named_scope("grad_hess"):
-            if bp.drf_mode:
-                g, h = -y, jnp.ones_like(y)
-            else:
-                g, h = _boost_grad_hess(bp, margin, y, w)
+            g, h = _boost_grad_hess(bp, margin, y, w)
         if goss:
             # GOSS: amplified weights → static-cap compaction → the
             # grower streams only the sampled rows. The margin update
@@ -783,19 +782,17 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, *,
                                        k_tree, p, efb)
             with jax.named_scope("margin"):
                 tree = tree._replace(value=bp.learn_rate * tree.value)
-                if not bp.drf_mode:
-                    margin = margin + tree.value[descend_tree(
-                        tree, binned, p.max_depth, p.n_bins, efb)]
+                margin = margin + tree.value[descend_tree(
+                    tree, binned, p.max_depth, p.n_bins, efb)]
             return margin, (tree, lax.psum(dropped, ROWS))
         tree, leaf = _grow_tree_shard(binned, g, h, w_t, col_mask,
                                       k_tree, p, efb)
         with jax.named_scope("margin"):
             tree = tree._replace(value=bp.learn_rate * tree.value)
-            if not bp.drf_mode:
-                # the grower already walked each row to its leaf: one
-                # gather replaces a full predict_tree heap re-descent
-                # per tree
-                margin = margin + tree.value[leaf]
+            # the grower already walked each row to its leaf: one
+            # gather replaces a full predict_tree heap re-descent
+            # per tree
+            margin = margin + tree.value[leaf]
         return margin, tree
 
     if goss:
@@ -834,10 +831,9 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
     batch across classes), inside the same scan-over-rounds shard_map.
 
     Replaces the round-2 host loop (K ``grow_tree`` + K predict
-    dispatches per iteration — the exact dispatch-latency failure mode
-    PROFILE.md documents for round-1 binomial). Margin is [rows, K] and
-    never leaves the device; one dispatch covers a whole chunk of
-    boosting rounds. Reference: hex/tree/gbm/GBM.java grows the K class
+    dispatches per iteration: dispatch latency dominated). Margin is
+    [rows, K] and never leaves the device; one dispatch covers a whole
+    chunk of boosting rounds. Reference: hex/tree/gbm/GBM.java grows the K class
     trees of an iteration from shared softmax probs (SURVEY.md §3.4).
     """
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
@@ -924,6 +920,7 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
     level stays within one hi block of the histogram kernel, a loss
     once one reached the bin-blocked kernel of the time, at G times
     the temporaries), so there is one path. keys: [n_trees]."""
+    assert bp.drf_mode
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     g0 = -y
     h0 = jnp.ones_like(y)
@@ -943,6 +940,8 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
 @functools.partial(jax.jit, static_argnums=(6, 7, 8))
 def _boost_drf_jit(binned, y, w, margin, keys, efb, p: TreeParams,
                    bp: BoostParams, mesh):
+    """A forest's trees in ONE dispatch, one a scan step, each from its
+    own key of ``keys`` → (margin unchanged, trees [T, N])."""
     fn = jax.shard_map(
         functools.partial(_boost_shard_drf, p=p, bp=bp),
         mesh=mesh,
@@ -952,23 +951,12 @@ def _boost_drf_jit(binned, y, w, margin, keys, efb, p: TreeParams,
     return fn(binned, y, w, margin, keys, efb)
 
 
-def boost_trees_drf(binned, y, w, margin, key, n_trees: int,
-                    p: TreeParams, bp: BoostParams, mesh=None,
-                    efb=None):
-    """A forest's trees in ONE dispatch, one a scan step. Returns
-    (margin unchanged, trees [n_trees, N], the trees' keys [n_trees]):
-    every draw a tree makes — its bag, its nodes' candidate features —
-    is a pure function of its key (`tree_bag`, `tree_candidates`)."""
-    assert bp.drf_mode
-    keys = round_keys(key, n_trees)
-    margin, trees = _boost_drf_jit(binned, y, w, margin, keys, efb,
-                                   p, bp, mesh or global_mesh())
-    return margin, trees, keys
-
-
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
 def _boost_multi_jit(binned, y, w, margin, keys, efb, p: TreeParams,
                      bp: BoostParams, K: int, mesh):
+    """Fused multinomial boosting: len(keys) rounds × K class trees in
+    ONE dispatch → (margin [rows, K], trees [T, K, N]), plus the GOSS
+    overflow scalar when sampling is active (see `_boost_jit`)."""
     out_specs = (P(ROWS), P(), P()) if bp.goss_b > 0 \
         else (P(ROWS), P())
     fn = jax.shard_map(
@@ -980,25 +968,14 @@ def _boost_multi_jit(binned, y, w, margin, keys, efb, p: TreeParams,
     return fn(binned, y, w, margin, keys, efb)
 
 
-def boost_trees_multi(binned, y, w, margin, key, n_trees: int, K: int,
-                      p: TreeParams, bp: BoostParams, mesh=None,
-                      efb=None, goss_keys=None):
-    """Fused multinomial boosting: n_trees rounds × K class trees in ONE
-    compiled dispatch. Returns (margin [rows, K], trees [T, K, N]) —
-    plus the GOSS overflow scalar when sampling is active (see
-    boost_trees)."""
-    keys = round_keys(key, n_trees)
-    if bp.goss_b > 0.0:
-        if goss_keys is None:
-            goss_keys = goss_round_keys(key, n_trees)
-        keys = (keys, goss_keys)
-    return _boost_multi_jit(binned, y, w, margin, keys, efb, p, bp, K,
-                            mesh or global_mesh())
-
-
 @functools.partial(jax.jit, static_argnums=(6, 7, 8))
 def _boost_jit(binned, y, w, margin, keys, efb, p: TreeParams,
                bp: BoostParams, mesh):
+    """Fused boosting: len(keys) rounds in ONE dispatch → (margin,
+    trees [T, N]). With GOSS (``bp.goss_b > 0``) ``keys`` is the pair
+    (round keys, rows of the path-invariant `goss_round_keys` stream)
+    and a third output counts the rows compaction dropped
+    (`goss_compact`). models/gbm.BoostPlan builds the operands."""
     out_specs = (P(ROWS), P(), P()) if bp.goss_b > 0 \
         else (P(ROWS), P())
     fn = jax.shard_map(
@@ -1008,27 +985,6 @@ def _boost_jit(binned, y, w, margin, keys, efb, p: TreeParams,
         out_specs=out_specs,
         check_vma=_resolve_impl(p.hist_impl) == "segment")
     return fn(binned, y, w, margin, keys, efb)
-
-
-def boost_trees(binned, y, w, margin, key, n_trees: int, p: TreeParams,
-                bp: BoostParams, mesh=None, efb=None, goss_keys=None):
-    """Fused boosting: n_trees rounds in ONE compiled dispatch.
-
-    Returns (margin, trees) with trees a stacked Tree pytree [T, N] —
-    plus a third ``overflow`` device scalar (total compaction-dropped
-    row count, see goss_compact) when GOSS is active. ``goss_keys``
-    ([n_trees] key rows of the path-invariant goss_round_keys stream)
-    rides along as a second scanned key array when GOSS is active;
-    with GOSS off the scanned operand is the plain key array,
-    byte-identical to a build without the feature.
-    """
-    keys = round_keys(key, n_trees)
-    if bp.goss_b > 0.0:
-        if goss_keys is None:
-            goss_keys = goss_round_keys(key, n_trees)
-        keys = (keys, goss_keys)
-    return _boost_jit(binned, y, w, margin, keys, efb, p, bp,
-                      mesh or global_mesh())
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8))

@@ -3,7 +3,7 @@
 When the uint8 binned matrix itself exceeds the device-memory headroom
 left by `H2O_TPU_HIST_BYTES_BUDGET` (models/gbm.py derives the
 trigger), training switches from the fused all-rows-resident
-`core.boost_trees` scan to this driver: the binned matrix lives as
+`core._boost_jit` scan to this driver: the binned matrix lives as
 HOST-resident row chunks and is streamed to device per tree level with
 double-buffered `device_put` (the upload of chunk c+1 overlaps the
 histogram build of chunk c), exactly the compressed-stream design of
@@ -41,7 +41,7 @@ end — a requested cadence must not be dropped silently), row/column
 subsampling (the streamed key schedule differs from the fused
 core's, so sampled models would depend on which path engaged or on
 the chunk-size knob) and multi-host meshes stay on the in-HBM path —
-models/gbm._ooc_chunk_rows is the single gate; docs/SCALING.md
+models/gbm.BoostPlan.ooc_chunk is the single gate; docs/SCALING.md
 documents the matrix.
 """
 
@@ -216,7 +216,7 @@ def _shard_hist(binned, rel, g, h, w, n_nodes, p: TreeParams, mesh):
 def _chunk_grads_jit(margin, y, w, bp: BoostParams):
     """Per-chunk (g, h) for one boosting round. No row sampling here:
     sample_rate < 1 is OOC-ineligible (a per-chunk keep-draw would tie
-    the model to the chunk grid — models/gbm._ooc_chunk_rows)."""
+    the model to the chunk grid — models/gbm.BoostPlan.ooc_chunk)."""
     return _boost_grad_hess(bp, margin, y, w)
 
 
@@ -596,13 +596,13 @@ def boost_trees_chunked(chunks: BinnedChunks, key, n_trees: int,
     F = efb.feat_col.shape[0] if efb is not None else chunks.n_features
     trees: list[Tree] = []
     # every stochastic option (sample_rate, col_sample_rate_per_tree,
-    # mtries) is gated OFF this path in models/gbm._ooc_chunk_rows —
+    # mtries) is gated OFF this path in models/gbm.BoostPlan.ooc_chunk —
     # the key below is plumbed only for _splits_with_mask's signature
     col_mask = jnp.ones(F, dtype=bool)
     goss = bp.goss_b > 0.0
     goss_dropped = None
     if goss:
-        if goss_keys is None:       # same fallback as core.boost_trees
+        if goss_keys is None:
             goss_keys = goss_round_keys(key, n_trees)
         shards = mesh.shape[ROWS]
         cap_local = goss_cap_rows(chunks.chunk_rows // shards,

@@ -161,7 +161,7 @@ def _rank_round(binned, margin, y_dense, maxdcg, idx, pos, mask, w, key,
     occasionally deadlocks XLA:CPU's collective rendezvous. Keeping the
     whole round inside one jit removes every eager sharded dispatch
     from the hot path (the fused GBM loop got the same treatment via
-    boost_trees)."""
+    core._boost_jit)."""
     from .tree.core import _grow_tree_jit, predict_tree
 
     g, h = _lambda_grads(margin, idx, pos, mask, use_ndcg, batch,

@@ -29,7 +29,6 @@ result across the ROWS mesh axis.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +41,11 @@ from jax.experimental.pallas import tpu as pltpu
 # the kernel reproduces f32 products with THREE explicit bf16 mantissa
 # terms of the values against the exactly-representable 0/1 one-hot —
 # the same arithmetic HIGHEST would emulate, minus the wasted passes on
-# the one-hot operand (it is already bf16-exact).
+# the one-hot operand (it is already bf16-exact). Two terms (~2^-16)
+# were measured on a v5e and were no faster (PERF.md §6, PR 25), so
+# every caller gets the stated float32. `terms` stays a static argument
+# of the kernel: a caller-stated contract like `unit_hess` (exact
+# small-integer values need one term) would pass it from there.
 
 
 def _interpret() -> bool:
@@ -65,18 +68,6 @@ def _fact_row_tile(ht: int, rows: int) -> int:
 # processed in groups of `fg` per grid step so [fg, C·n_hi, 128] f32
 # stays resident; past this budget F is split into 8-aligned groups
 _OUT_BUDGET = 3 << 20
-
-# mantissa terms for the f32-precision bf16 emulation. 3 (default)
-# reproduces f32 products to ~2^-24 (parity-gated at 1e-6 vs the
-# segment path). 2 is the throughput mode (~2^-16 product precision —
-# the single-precision-histogram regime LightGBM ships): the stacked
-# A operand drops from 3·C·n_hi to 2·C·n_hi MXU rows, which at the
-# bench shape's deepest level means ONE 128-row M-tile instead of two,
-# and the A-build VPU cost falls by a third. Gain argmaxes are robust
-# at 2^-16 relative noise; the kernel gate checks the 2-term path at
-# its own looser tolerance.
-_TERMS = 2 if os.environ.get("H2O_TPU_HIST_TERMS", "3") == "2" else 3
-
 
 def _dimsem(*sems):
     return pltpu.CompilerParams(dimension_semantics=sems)
@@ -268,7 +259,7 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
     vma = jax.typeof(vals).vma
     out = pl.pallas_call(
         functools.partial(_hist_fact_kernel, n_bins=n_bins, ht=ht,
-                          n_ht=n_ht, n_ch=C, fg=fg, terms=_TERMS),
+                          n_ht=n_ht, n_ch=C, fg=fg, terms=3),
         # one (fg, C·ht, 128) block per (feature group, hi block),
         # contiguous
         out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),

@@ -116,8 +116,8 @@ def enable_persistent_compile_cache(
 
 def require_tpu(what: str) -> None:
     """Fail unless this process runs on a TPU — for the scripts whose
-    numbers only mean something on the chip (bench.py, the kernel
-    gate, the profilers). There is no CPU fallback: a run that wanted
+    numbers only mean something on the chip (bench/run.py, the kernel
+    gate, chip_smoke.py). There is no CPU fallback: a run that wanted
     a chip and found none is an error, not a slower run."""
     import jax
 

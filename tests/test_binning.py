@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import h2o_kubernetes_tpu as h2o
+from h2o_kubernetes_tpu.models.tree import binning
 from h2o_kubernetes_tpu.models.tree.binning import (_bin_block_jit,
                                                     apply_bins,
                                                     apply_bins_jit,
@@ -159,7 +160,9 @@ def test_frame_binning_is_searchsorted_right_to_the_bit(mesh8, n_bins,
     range-grouped enum (700 levels past every n_bins here)."""
     fr = _frame(np.random.default_rng(n_bins), 3000)
     names = ["x", "ties", "gone", "c", "hc"]
-    monkeypatch.setenv("H2O_TPU_BIN_BLOCK_COLS", "2")   # three blocks
+    # two columns a block: three blocks
+    monkeypatch.setattr(binning, "_BIN_BLOCK_BYTES",
+                        2 * 4 * fr.vec("x").padded_len)
     spec = fit_bins(fr, names, n_bins=n_bins)
     assert spec.is_enum == [False, False, False, True, False]
     E = np.asarray(spec.edges_matrix())

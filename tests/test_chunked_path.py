@@ -22,6 +22,7 @@ import h2o_kubernetes_tpu as h2o
 from h2o_kubernetes_tpu.frame import Frame
 from h2o_kubernetes_tpu.frame.parse import import_file
 from h2o_kubernetes_tpu.models import GBM
+from h2o_kubernetes_tpu.models.tree import binning
 from h2o_kubernetes_tpu.models.tree.binning import (apply_bins_jit,
                                                     bin_frame_host_chunks,
                                                     fit_bins)
@@ -135,8 +136,9 @@ def test_frame_binned_matches_apply_bins_bitwise(mesh8, monkeypatch):
     fr = _mixed_frame()
     names = ["x1", "x2", "c", "hc"]
     spec = fit_bins(fr, names, n_bins=64)
-    # force several column blocks so the block seam is exercised
-    monkeypatch.setenv("H2O_TPU_BIN_BLOCK_COLS", "2")
+    # two columns a block, so the block seam is exercised
+    monkeypatch.setattr(binning, "_BIN_BLOCK_BYTES",
+                        2 * 4 * fr.vec("x1").padded_len)
     got = np.asarray(fr.binned(spec))
     import jax.numpy as jnp
 

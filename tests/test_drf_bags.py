@@ -6,7 +6,7 @@ kept, with the functions the grower itself calls); a plain float64
 forest (`bench/reference/drf_plain.py`, which imports nothing of the
 program) follows the system's trees over those bags: covers exactly,
 values and gains to float32 rounding, every split the best among its
-candidates. A forest grows one tree a scan step, and `_chunk_sizes` is
+candidates. A forest grows one tree a scan step, and `BoostPlan.chunks` is
 the one sizing of its dispatches, which `train()` and compile-ahead
 share with boosted trees.
 """
@@ -213,27 +213,29 @@ def test_model_with_its_draws_goes_through_save_and_load(mesh8, tmp_path):
 # -- the one sizing ---------------------------------------------------------
 
 
-def _forest_params(depth, bins, ntrees=50):
-    return DRF(ntrees=ntrees, max_depth=depth, nbins=bins).params
+def _forest_plan(depth, bins, ntrees=50):
+    return gbm_mod.boost_plan(
+        DRF(ntrees=ntrees, max_depth=depth, nbins=bins).params,
+        "bernoulli", 2, 28)
 
 
 @pytest.mark.parametrize("rows", [65_536, 1_048_576, 4_194_304, 8_388_608])
 @pytest.mark.parametrize("depth,bins", [(6, 64), (8, 256), (12, 64)])
 def test_a_forest_dispatches_by_the_boosted_trees_rule(rows, depth, bins):
-    """One sizing: a forest's dispatches are what `_chunk_sizes` gives
-    boosted trees of the same shape — all the trees, as many a dispatch
-    as `_DISPATCH_BUDGET` holds and never less than one — and nothing
-    sizes a group (one tree a scan step: a dispatch's temporaries are
-    one tree's, tests/test_chip_compile.py)."""
-    p = _forest_params(depth, bins)
-    chunks = gbm_mod._chunk_sizes(p, rows, 28, 1)
+    """One sizing: a forest's dispatches are what `BoostPlan.chunks`
+    gives boosted trees of the same shape — all the trees, as many a
+    dispatch as `_DISPATCH_BUDGET` holds and never less than one — and
+    nothing sizes a group (one tree a scan step: a dispatch's
+    temporaries are one tree's, tests/test_chip_compile.py)."""
+    chunks = _forest_plan(depth, bins).chunks(rows)
     assert sum(chunks) == 50 and min(chunks) >= 1
     per_tree = rows * 28 * bins * 2 ** depth
     assert all(n == 1 or n * per_tree <= gbm_mod._DISPATCH_BUDGET
                for n in chunks)
-    boosted = GBM(ntrees=50, max_depth=depth, nbins=bins,
-                  score_every=0).params
-    assert chunks == gbm_mod._chunk_sizes(boosted, rows, 28, 1)
+    boosted = gbm_mod.boost_plan(
+        GBM(ntrees=50, max_depth=depth, nbins=bins, score_every=0).params,
+        "bernoulli", 2, 28)
+    assert chunks == boosted.chunks(rows)
 
 
 def test_the_forests_the_old_sizing_refused():
@@ -241,15 +243,37 @@ def test_the_forests_the_old_sizing_refused():
     were one dispatch AND one vmapped group of six (25 G of temporaries
     on a 16 G chip). They are still one dispatch; the deep forest of
     `drf-higgs.train` goes a tree a dispatch."""
-    assert gbm_mod._chunk_sizes(_forest_params(6, 64, 6),
-                                4_194_304, 28, 1) == [6]
-    assert gbm_mod._chunk_sizes(_forest_params(12, 64, 3),
-                                4_194_304, 28, 1) == [1, 1, 1]
+    assert _forest_plan(6, 64, 6).chunks(4_194_304) == [6]
+    assert _forest_plan(12, 64, 3).chunks(4_194_304) == [1, 1, 1]
+
+
+def test_the_boosting_scan_refuses_a_forest(mesh8):
+    """A single-output forest grows in `_boost_drf_jit` (no margin to
+    carry through the scan). `_boost_shard` kept `drf_mode` branches
+    that nothing reached; they are gone, and the boosting program
+    refuses a forest's parameters when it is traced."""
+    _, _, cols = _table(seed=3, rows=256)
+    fr = h2o.Frame.from_arrays(cols)
+    plan = gbm_mod.boost_plan(
+        DRF(ntrees=2, max_depth=3, nbins=NBINS).params, "bernoulli", 2, F)
+    assert plan.mode == "forest" and plan.bp.drf_mode
+    from h2o_kubernetes_tpu.models.base import resolve_xy
+    from h2o_kubernetes_tpu.models.tree.binning import fused_fit_bins
+
+    data = resolve_xy(fr, "y", materialize_x=False)
+    _, binned = fused_fit_bins(fr, data.feature_names, NBINS)
+    keys = core.round_keys(jax.random.key(0), 2)
+    args = plan.operands(binned, data.y, data.w, jnp.zeros_like(data.y),
+                         keys, None)
+    with pytest.raises(AssertionError, match="_boost_shard_drf"):
+        core._boost_jit(*args)
+    margin, trees = core._boost_drf_jit(*args)
+    assert trees.value.shape[0] == 2
 
 
 def test_train_and_compile_ahead_agree_on_the_dispatches(mesh8,
                                                          monkeypatch):
-    """Both take the trees of every dispatch from `_chunk_sizes`: with
+    """Both take the trees of every dispatch from `BoostPlan.chunks`: with
     the budget steered to four trees a dispatch, train() sends 4 + 2
     and compile-ahead lowers the same two key shapes."""
     _, _, cols = _table(seed=6, rows=2048)

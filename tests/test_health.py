@@ -98,7 +98,7 @@ def test_gbm_fails_fast_mid_train(mesh8, monkeypatch):
     fr = h2o.Frame.from_arrays({"x": x, "y": y})
     # force one tree per dispatch so the loop has chunk boundaries
     monkeypatch.setattr(gbm_mod, "_DISPATCH_BUDGET", 1)
-    orig = gbm_mod.boost_trees
+    orig = gbm_mod.BoostPlan.dispatch
     calls = {"n": 0}
 
     def dying_boost(*a, **kw):
@@ -108,7 +108,7 @@ def test_gbm_fails_fast_mid_train(mesh8, monkeypatch):
             health.mark_unhealthy("ICI link down (test)")
         return out
 
-    monkeypatch.setattr(gbm_mod, "boost_trees", dying_boost)
+    monkeypatch.setattr(gbm_mod.BoostPlan, "dispatch", dying_boost)
     try:
         with pytest.raises(health.ClusterHealthError):
             GBM(ntrees=6, max_depth=3, seed=0).train(

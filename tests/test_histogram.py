@@ -384,22 +384,3 @@ def test_histogram_auc_inf_scores_pinned():
     hist = M.roc_auc(y, s_inf, exact=False)
     # one +inf / one -inf row must not collapse the binning
     assert abs(exact - hist) < 5e-3, (exact, hist)
-
-
-def test_two_term_mode_close_to_segment(monkeypatch):
-    """H2O_TPU_HIST_TERMS=2 (throughput mode): products carry ~2^-16
-    relative error — the histogram must match segment to single-
-    precision-histogram tolerance, far inside split-decision noise."""
-    import h2o_kubernetes_tpu.ops.histogram as H
-
-    monkeypatch.setattr(H, "_TERMS", 2)
-    binned, rel, g, h, w = _random_case(2000, 4, 8, 64, seed=7)
-    ref = build_histogram(binned, rel, g, h, w, 8, 64, impl="segment")
-    got = build_histogram(binned, rel, g, h, w, 8, 64, impl="pallas")
-    ref_np, got_np = np.asarray(ref), np.asarray(got)
-    # near-zero cells make pointwise relative error meaningless —
-    # normalize by the histogram's scale (what split gains compare
-    # against); 2-term products are ~2^-16, so scale-relative error
-    # stays well under 1e-5
-    scale = np.abs(ref_np).max()
-    assert np.max(np.abs(got_np - ref_np)) < 1e-4 * scale

@@ -146,6 +146,37 @@ def test_env_config_validation(monkeypatch):
     C.CONFIG["nbins"] = 256          # restore the default for the suite
 
 
+def test_config_table_and_package_agree():
+    """`config.py`'s table is the one list of `H2O_TPU_*` names: every
+    name the package's code mentions has a row, and every row is a name
+    some module other than the table mentions. A name that ends in `_`
+    is a prefix (a docstring's `H2O_TPU_RETRY_*`, or one built at run
+    time): it needs rows that start with it."""
+    import pathlib
+    import re
+
+    import h2o_kubernetes_tpu
+    from h2o_kubernetes_tpu import config as C
+
+    rows = set(re.findall(r"^\| (H2O_TPU_[A-Z0-9_]+) \|", C.__doc__,
+                          re.M))
+    assert len(rows) > 70
+    read = set()
+    pkg = pathlib.Path(h2o_kubernetes_tpu.__file__).parent
+    for f in pkg.rglob("*.py"):
+        src = f.read_text()
+        if f.name == "config.py":
+            src = src.replace(C.__doc__, "")
+        read |= set(re.findall(r"H2O_TPU_[A-Z0-9_]+", src))
+    prefixes = {n for n in read if n.endswith("_")}
+    for pre in prefixes:
+        assert any(r.startswith(pre) for r in rows), pre
+    missing = read - prefixes - rows
+    assert not missing, f"read by the package, no row: {sorted(missing)}"
+    dead = rows - read
+    assert not dead, f"rows nothing reads: {sorted(dead)}"
+
+
 def test_doall_cache_key_reuses_jit(mesh8):
     """cache_key makes repeated same-computation doall calls reuse one
     jitted callable — rollups across CV fold frames must not recompile
@@ -185,7 +216,7 @@ def test_doall_cache_key_reuses_jit(mesh8):
 def test_host_features_fingerprint(tmp_path):
     """The persistent-XLA-cache dir is keyed by a host CPU feature
     fingerprint: a cache copied from an +amx/+avx512 build host can
-    never serve a mismatched AOT binary (SIGILL class, BENCH_r05)."""
+    never serve a mismatched AOT binary (SIGILL class)."""
     from h2o_kubernetes_tpu.runtime.backend import (
         host_features_fingerprint)
 
@@ -241,7 +272,7 @@ def test_compile_cache_dir_placed_from_outside(monkeypatch, tmp_path):
 
 
 def test_require_tpu_refuses_cpu():
-    """The on-chip scripts (bench.py, kernel_gate, boost_profile) have
+    """The on-chip scripts (bench/run.py, kernel_gate, chip_smoke) have
     no CPU fallback: without a TPU they exit, naming what they found."""
     from h2o_kubernetes_tpu.runtime.backend import require_tpu
 

@@ -216,21 +216,6 @@ class TestFusedBinning:
         spec_f3, _ = fused_fit_bins(fr, names, 64)
         assert spec_f3 is not spec_f
 
-    def test_kill_switch_trains_identically(self, mesh8):
-        from h2o_kubernetes_tpu.models import GBM
-
-        fr = _frame(300, seed=5)
-        m_fused = GBM(ntrees=4, max_depth=3, seed=0).train(
-            y="y", training_frame=fr)
-        os.environ["H2O_TPU_FUSED_BINNING"] = "0"
-        try:
-            m_classic = GBM(ntrees=4, max_depth=3, seed=0).train(
-                y="y", training_frame=fr)
-        finally:
-            os.environ.pop("H2O_TPU_FUSED_BINNING", None)
-        assert np.array_equal(np.asarray(m_fused.trees.value),
-                              np.asarray(m_classic.trees.value))
-
 
 # ---------------------------------------------------------------------------
 # compile-ahead: cache-hit accounting against the real train path
@@ -314,6 +299,96 @@ class TestCompileAhead:
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", prev_min)
             _cc.reset_cache()
+
+    @pytest.mark.parametrize("case", ["single", "forest", "multi",
+                                      "multi_forest"])
+    def test_lowered_is_what_is_dispatched(self, mesh8, monkeypatch,
+                                           case):
+        """The drift pin, mode by mode (bernoulli GBM, single-output
+        DRF with `mtries`, three-class GBM, three-class DRF): every
+        program `compile_ahead_lowerings` lowers is one `train()`
+        really dispatches and the other way round — the same jitted function,
+        the same shapes, dtypes and shardings (none where the array is
+        uncommitted) and the same static arguments, so the lowered
+        executable is the one the dispatch looks up."""
+        import jax
+
+        from h2o_kubernetes_tpu.models import DRF, GBM
+        from h2o_kubernetes_tpu.models import gbm as gbm_mod
+
+        rng = np.random.default_rng(11)
+        n = 1000                        # padded to 8 shards
+        cols = {f"x{i}": rng.normal(size=n).astype(np.float32)
+                for i in range(5)}
+        score = cols["x0"] + 0.5 * cols["x1"]
+        mode = case.split("_")[0]       # the row of _BOOST_PROGRAMS
+        if mode == "multi":
+            cols["y"] = np.array(["a", "b", "c"])[
+                np.digitize(score, [-0.5, 0.5])]
+        else:
+            cols["y"] = np.where(score > 0, "p", "n")
+        est = DRF(ntrees=5, max_depth=3, nbins=16, mtries=2, seed=1) \
+            if "forest" in case else GBM(ntrees=5, max_depth=3, seed=1)
+        fr = h2o.Frame.from_arrays(cols)
+        # three dispatches of two sizes: 2 + 2 + 1 trees
+        monkeypatch.setattr(gbm_mod, "_DISPATCH_BUDGET",
+                            2 * n * 5 * est.params.nbins * 2 ** 3
+                            * (3 if mode == "multi" else 1))
+
+        lowered, sent = [], []
+        monkeypatch.setattr(gbm_mod, "_aot",
+                            lambda fn, *a: lowered.append((fn, a)))
+        for thunk in est.compile_ahead_lowerings("y", fr):
+            thunk()
+
+        def recording(fn):
+            def call(*a):
+                sent.append((fn, a))
+                return fn(*a)
+            return call
+
+        monkeypatch.setitem(gbm_mod._BOOST_PROGRAMS, mode,
+                            recording(gbm_mod._BOOST_PROGRAMS[mode]))
+        monkeypatch.setattr(gbm_mod, "_init_margin",
+                            recording(gbm_mod._init_margin))
+        m = est.train(y="y", training_frame=fr)
+        assert m.ntrees == (15 if mode == "multi" else 5)
+
+        def leaf(x):
+            if not hasattr(x, "dtype"):
+                return x                # a static argument
+            sh = x.sharding
+            if isinstance(x, jax.Array) and not x.committed:
+                sh = None
+            return (x.shape, str(x.dtype), sh)
+
+        def same(a, b):
+            (fa, la), (fb, lb) = a, b
+            la, ta = jax.tree.flatten(la)
+            lb, tb = jax.tree.flatten(lb)
+            if fa is not fb or ta != tb:
+                return False
+            for x, y in zip(map(leaf, la), map(leaf, lb)):
+                if isinstance(x, tuple) and isinstance(y, tuple) \
+                        and x[2] is not None and y[2] is not None:
+                    if x[:2] != y[:2] or not x[2].is_equivalent_to(
+                            y[2], len(x[0])):
+                        return False
+                elif x != y:
+                    return False
+            return True
+
+        programs = {fn.__name__ for fn, _ in sent}
+        assert programs == {
+            "single": {"_init_margin", "_boost_jit"},
+            "forest": {"_boost_drf_jit"},
+            "multi": {"_init_margin", "_boost_multi_jit"},
+            "multi_forest": {"_boost_multi_jit"}}[case]
+        assert len(sent) == (3 if "forest" in case else 4)
+        for call in sent:
+            assert any(same(call, low) for low in lowered), call
+        for low in lowered:
+            assert any(same(call, low) for call in sent), low
 
     def test_unsupported_and_dedupe_accounting(self, mesh8):
         cs = sched.CompileStream(name="t-compile-acct", max_queue=2)

@@ -587,6 +587,10 @@ def test_train_programs_name_jitted_functions():
     assert len(listed) == len(set(listed))
     assert set(listed) <= jitted, set(listed) - jitted
     assert "<lambda>" not in listed
+    # the boost phase is the table `train()` and compile-ahead pick
+    # their program from (gbm.BoostPlan)
+    assert set(telemetry.TRAIN_PROGRAMS["boost"]) == {
+        fn.__name__ for fn in gbm._BOOST_PROGRAMS.values()}
     # the programs a one-chip GBM job runs, by the recorded trace
     for phase, name in (("bin", "_fused_fit_bin_jit"),
                         ("bin", "_bin_block_jit"),

@@ -44,7 +44,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 CHECK_NAMES = [
     "fact_kernel", "fact_kernel_cap", "binblock_kernel",
     "binblock_kernel_4",
-    "leaf_totals_kernel", "unit_hess_kernel", "two_term_kernel",
+    "leaf_totals_kernel", "unit_hess_kernel",
     "boost_scan_binomial", "boost_scan_multinomial",
     "flat_scorer_parity", "flat_scorer_parity_multinomial",
     "shap_parity", "shap_kernel_parity", "efb_parity", "goss_parity",
@@ -217,23 +217,6 @@ def main(argv=None) -> int:
                       (jnp.max(jnp.abs(want_u)) + 1e-30))
         checks.append({"check": "unit_hess_kernel",
                        "ok": err_u < 1e-5, "rel_err": err_u})
-
-    def chk_two_term_kernel():
-        # 2-term mantissa throughput mode (H2O_TPU_HIST_TERMS=2): the
-        # stacked A drops a third of its M rows; parity is checked
-        # against the SEGMENT reference (so the check stays meaningful
-        # whatever mode the gate itself runs under) at
-        # single-precision-histogram tolerance (products ~2^-16)
-        import h2o_kubernetes_tpu.ops.histogram as H
-
-        orig_terms = H._TERMS
-        H._TERMS = 2
-        jax.clear_caches()  # _TERMS is not a trace key: force retrace
-        try:
-            parity("two_term_kernel", 100_000, 10, 16, 256, tol=1e-4)
-        finally:
-            H._TERMS = orig_terms
-            jax.clear_caches()
 
     def chk_boost_scan_binomial():
         _, m2 = fix_binomial()
@@ -425,7 +408,6 @@ def main(argv=None) -> int:
         "binblock_kernel_4": chk_binblock_kernel_4,
         "leaf_totals_kernel": chk_leaf_totals_kernel,
         "unit_hess_kernel": chk_unit_hess_kernel,
-        "two_term_kernel": chk_two_term_kernel,
         "boost_scan_binomial": chk_boost_scan_binomial,
         "boost_scan_multinomial": chk_boost_scan_multinomial,
         "flat_scorer_parity": chk_flat_scorer_parity,

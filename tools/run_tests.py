@@ -30,10 +30,10 @@ module passed). Same tests, same markers; only the wall-cap
 granularity changed.
 
 A preflight scan warns (or, with ``--strict-preflight`` /
-``H2O_TPU_PREFLIGHT_STRICT=1``, fails) when orphaned bench/AutoML
-processes are still running on the box — a leftover
-``automl_scale_10m.py`` once starved tier-1 into rendezvous stalls,
-and nothing timed on a contended core is trustworthy.
+``H2O_TPU_PREFLIGHT_STRICT=1``, fails) when orphaned load-drill or
+scorer-pod processes are still running on the box — a leftover
+AutoML run once starved tier-1 into rendezvous stalls, and nothing
+timed on a contended core is trustworthy.
 
 Prints one status line per module and a final JSON summary; exit 0
 only if every module passed.
@@ -52,14 +52,12 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# cmdline fragments that mark a bench/AutoML workload: one such process
-# left over from an earlier round starves the shared core and turns
-# tier-1's collective rendezvous into timeouts (the stale
-# automl_scale_10m.py found at 72% CPU during the PR-4 round did
-# exactly that — CHANGES.md PR 4 ops note)
-_ORPHAN_PATTERNS = ("automl_scale", "bench_suite", "bench.py",
-                    "boost_profile", "score_load",
-                    "automl_wall", "operator.pod")
+# cmdline fragments that mark a load drill or a scorer pod: one such
+# process left over from an earlier round starves the shared core and
+# turns tier-1's collective rendezvous into timeouts (a stale AutoML
+# run found at 72% CPU during the PR-4 round did exactly that —
+# CHANGES.md PR 4 ops note)
+_ORPHAN_PATTERNS = ("score_load", "operator.pod")
 
 # operator scorer-pool pods are REAPED (SIGKILL), not just reported —
 # but ONLY when their parent reconciler is gone (the pod has been
@@ -111,7 +109,7 @@ def find_orphan_processes() -> list[tuple[int, str]]:
                 cmd = b" ".join(argv).decode(errors="replace").strip()
         except OSError:
             continue
-        # only interpreter processes count: 'vim tools/bench.py' or a
+        # only interpreter processes count: 'vim tools/score_load.py' or a
         # grep mentioning the name is not a workload
         if not argv or b"python" not in argv[0].lower():
             continue

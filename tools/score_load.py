@@ -30,9 +30,9 @@ like a pod pulled from a Service's endpoints. ``--assert-zero-5xx``
 makes the run fail loudly (rc 1) on ANY 5xx response — the
 rolling-update acceptance bar (docs/OPERATOR.md).
 
-The gain this measures is recorded by ``bench_suite``'s
-``gbm_score_rows_per_sec`` config; this tool is the REST-level
-closed-loop view of the same fast path (request coalescing included).
+This is the REST-level closed-loop view of the serving fast path
+(request coalescing included). No benchmark cell times serving yet
+(PERF.md §7 row 4).
 """
 
 from __future__ import annotations
@@ -351,8 +351,7 @@ def run_load_multi(targets, model_key: str, columns: list[str],
 # plus popularity-DECILE percentiles (the tail decile is the fairness
 # contract's needle) and a /3/Stats scrape of the byte-budgeted scorer
 # cache (resident bytes vs budget, evictions, promotions, compile
-# watch).  ``run_zipf_bench`` is the bench_suite entry: residency
-# sweep + the hot-model storm legs (fairness on vs off).
+# watch).
 
 
 def _self_server_tenants(n_models: int, seed: int = 0,
@@ -654,327 +653,6 @@ def run_load_zipf(targets, model_keys: list[str], columns: list[str],
                   for k, r in per_model.items()},
         deciles=_popularity_deciles(model_keys, per_model),
         residency=residency)
-
-
-def _storm_leg(url: str, hot_key: str, tail_key: str,
-               columns: list[str], fair: bool,
-               hot_workers: int = 16, hot_rows: int = 256,
-               tail_rows: int = 8, seconds: float = 6.0,
-               queue_max: int = 8, tail_deadline_ms: float = 500.0,
-               seed: int = 0) -> dict:
-    """One hot-model storm leg: ``hot_workers`` closed-loop threads
-    flood ``hot_key`` (standard class) while ONE tail worker sends
-    small ``interactive``-class requests to ``tail_key``. The tail's
-    SLO is met iff it was never shed and never 5xx'd/504'd: the
-    interactive class carries an IMPLICIT server-side deadline
-    (rest.SLO_CLASSES), so every 200 response proves its result was
-    ready inside that deadline — zero 504s IS the server-side p99 ≤
-    deadline proof, immune to the load generator's own scheduling
-    noise (client-observed p99 is recorded alongside, informational:
-    on a 1-core box it includes generator GIL/scheduler time). With
-    fairness ON the hot model sheds against its own queue share and
-    the tail is admitted + dispatched first by construction; with
-    fairness OFF the hot flood owns the whole queue and the tail
-    provably misses (shed and/or 504)."""
-    import urllib.error
-
-    os.environ["H2O_TPU_SCORE_FAIRNESS"] = "1" if fair else "0"
-    os.environ["H2O_TPU_SCORE_QUEUE_MAX"] = str(queue_max)
-    # a wide batch window makes the storm's queue dynamics structural
-    # instead of timing-dependent: while the dispatcher collects, the
-    # closed-loop hot flood refills the queue to its cap — unfair, the
-    # tail then finds it FULL (shed/504, the provable miss); fair, the
-    # hot model's share cap leaves tail room by construction
-    os.environ["H2O_TPU_SCORE_BATCH_US"] = "20000"
-    hot_bodies = _make_bodies(columns, hot_rows, seed, pool=4)
-    tail_bodies = _make_bodies(columns, tail_rows, seed + 1, pool=4)
-    stop = threading.Event()
-    lock = threading.Lock()
-    hot = {"requests": 0, "shed": 0, "fivexx": 0}
-    tail = {"requests": 0, "shed": 0, "fivexx": 0, "deadline_504": 0,
-            "fourxx": 0, "lat": []}
-
-    def hot_worker(wid: int) -> None:
-        i = wid
-        route = f"{url}/3/Predictions/models/{hot_key}"
-        while not stop.is_set():
-            body = hot_bodies[i % len(hot_bodies)]
-            i += 1
-            try:
-                _post_json(route, body, timeout=30.0)
-                with lock:
-                    hot["requests"] += 1
-            except urllib.error.HTTPError as e:
-                with lock:
-                    hot["requests"] += 1
-                    if e.code == 429:
-                        hot["shed"] += 1
-                    elif e.code >= 500:
-                        hot["fivexx"] += 1
-                e.read()
-                if e.code == 429:
-                    time.sleep(0.01)    # shed backoff: don't spin
-            except Exception:  # noqa: BLE001 — the leg keeps driving
-                pass
-
-    def tail_worker() -> None:
-        i = 0
-        route = f"{url}/3/Predictions/models/{tail_key}"
-        while not stop.is_set():
-            body = tail_bodies[i % len(tail_bodies)]
-            i += 1
-            t0 = time.perf_counter()
-            try:
-                _post_json(route, body, timeout=30.0,
-                           headers={"X-H2O-SLO": "interactive"})
-                with lock:
-                    tail["requests"] += 1
-                    tail["lat"].append(time.perf_counter() - t0)
-            except urllib.error.HTTPError as e:
-                with lock:
-                    tail["requests"] += 1
-                    if e.code == 429:
-                        tail["shed"] += 1
-                    elif e.code == 504:
-                        tail["deadline_504"] += 1
-                    elif e.code >= 500:
-                        tail["fivexx"] += 1
-                    else:
-                        # residual 4xx (bad key/payload): counted, so
-                        # an all-errors leg cannot read as SLO-met
-                        tail["fourxx"] += 1
-                e.read()
-                time.sleep(0.005)
-            except Exception:  # noqa: BLE001
-                pass
-            time.sleep(0.01)    # ~100 rps offered tail rate
-
-    # warm both request shapes before the clock starts: the leg
-    # measures fairness under load, not a first-dispatch compile
-    # (hot_rows may pad to a bucket warm-up never traced)
-    try:
-        _post_json(f"{url}/3/Predictions/models/{hot_key}",
-                   hot_bodies[0], timeout=120.0)
-        _post_json(f"{url}/3/Predictions/models/{tail_key}",
-                   tail_bodies[0], timeout=120.0)
-    except Exception:  # noqa: BLE001 — the leg's own counters judge
-        pass
-    threads = [threading.Thread(target=hot_worker, args=(w,),
-                                daemon=True)
-               for w in range(hot_workers)]
-    threads.append(threading.Thread(target=tail_worker, daemon=True))
-    for t in threads:
-        t.start()
-    time.sleep(seconds)
-    stop.set()
-    for t in threads:
-        t.join(timeout=30.0)
-    p99 = _percentile_ms(tail["lat"], 0.99)
-    # zero shed + zero 504 + zero 5xx/4xx AND at least one SUCCESSFUL
-    # score == the SLO held: every admitted tail request produced its
-    # result inside the interactive class's server-enforced deadline
-    # (a late result would have 504'd). len(lat) > 0, not requests >
-    # 0: a leg that only ever errored (bad key, unloaded artifact)
-    # must never read as a passing fairness proof.
-    slo_met = (tail["shed"] == 0 and tail["fivexx"] == 0
-               and tail["deadline_504"] == 0 and tail["fourxx"] == 0
-               and len(tail["lat"]) > 0)
-    return {"fair": fair, "seconds": seconds,
-            "queue_max": queue_max, "hot_workers": hot_workers,
-            "hot_rows": hot_rows, "tail_rows": tail_rows,
-            "hot": dict(hot),
-            "tail": {**{k: v for k, v in tail.items() if k != "lat"},
-                     "p50_ms": _percentile_ms(tail["lat"], 0.50),
-                     "p99_ms": p99,
-                     "deadline_ms": tail_deadline_ms},
-            "tail_slo_met": slo_met}
-
-
-def _metrics_scrape(url: str) -> dict:
-    """Time one GET /metrics against a serving target: the bench
-    records exposition cost alongside the serving p99 so the artifact
-    can state what a Prometheus scrape adds at the measured shape
-    (acceptance note: < 1% of the storm-shape p99)."""
-    import time as _time
-    import urllib.request
-
-    t0 = _time.monotonic()
-    try:
-        with urllib.request.urlopen(url.rstrip("/") + "/metrics",
-                                    timeout=10) as r:
-            body = r.read()
-        return {"ok": True, "ms": round(
-            (_time.monotonic() - t0) * 1000.0, 3),
-            "bytes": len(body)}
-    except Exception as e:  # noqa: BLE001 — the bench must not die
-        return {"ok": False, "error": repr(e)[:120],
-                "ms": round((_time.monotonic() - t0) * 1000.0, 3)}
-
-
-def run_zipf_bench(n_models: int = 100, seconds: float = 15.0,
-                   zipf_s: float = 1.1, budget_mb: float = 4.0,
-                   concurrency: int = 6, rows_per_request: int = 16,
-                   storm_seconds: float = 6.0, seed: int = 0) -> dict:
-    """The BENCH_SUITE multi-tenant leg (one self-contained record):
-
-    1. **Residency sweep** — ``n_models`` registry-pushed tenants
-       under a ``budget_mb`` byte budget, Zipf(s) traffic: resident
-       bytes must never exceed the budget, evictions/promotions churn,
-       and every compile during the sweep is a persistent-cache HIT
-       (promotion re-traces recompile known HLO — the "eviction costs
-       a pcache hit, never a cold compile" contract).
-    2. **Evict→promote parity** — one tenant force-evicted and
-       re-scored: output must be bitwise-identical.
-    3. **Hot-model storm** — fairness ON vs OFF: the tail tenant's
-       interactive SLO must hold under fairness and provably miss
-       without it."""
-    import numpy as np
-
-    saved = {k: os.environ.get(k) for k in
-             ("H2O_TPU_SCORER_CACHE_BYTES", "H2O_TPU_SCORE_FAIRNESS",
-              "H2O_TPU_SCORE_QUEUE_MAX", "H2O_TPU_SCORE_BATCH_US")}
-    os.environ["H2O_TPU_SCORER_CACHE_BYTES"] = \
-        str(int(budget_mb * 2 ** 20))
-    srv = None
-    try:
-        srv, url, keys, columns = _self_server_tenants(
-            n_models, seed=seed)
-        scrape_before = _metrics_scrape(url)
-        sweep = run_load_zipf(
-            url, keys, columns, concurrency=concurrency,
-            rows_per_request=rows_per_request, seconds=seconds,
-            zipf_s=zipf_s, seed=seed)
-        # /metrics AFTER the sweep: the exposition now carries the
-        # full tenant series set — this is the scrape cost a live
-        # fleet pays per Prometheus interval
-        scrape_after = _metrics_scrape(url)
-
-        # 2. evict→promote bitwise parity on a live tenant
-        from h2o_kubernetes_tpu import rest
-        from h2o_kubernetes_tpu.models.base import evict_scorer_cache
-
-        probe = rest.MODELS[keys[-1]]
-        rng = np.random.default_rng(seed + 7)
-        Xp = rng.normal(size=(64, len(columns))).astype(np.float32)
-        before = probe.score_numpy(Xp)
-        evict_scorer_cache(probe)
-        after = probe.score_numpy(Xp)
-        bitwise = bool(np.array_equal(before, after))
-
-        storm_fair = _storm_leg(url, keys[0], keys[-1], columns,
-                                fair=True, seconds=storm_seconds,
-                                seed=seed)
-        storm_unfair = _storm_leg(url, keys[0], keys[-1], columns,
-                                  fair=False, seconds=storm_seconds,
-                                  seed=seed)
-        final = _get_json(url + "/3/Stats") or {}
-        return {
-            "metric": "multitenant_zipf_p99",
-            "models": n_models,
-            "zipf_s": zipf_s,
-            "budget_mb": budget_mb,
-            "sweep": {k: sweep[k] for k in
-                      ("value", "requests", "p50_ms", "p99_ms",
-                       "fivexx", "shed", "deciles", "residency")},
-            "evict_promote_bitwise": bitwise,
-            "storm_fair": storm_fair,
-            "storm_unfair": storm_unfair,
-            "scorer_cache_final": final.get("scorer_cache"),
-            "compiles_final": final.get("compiles"),
-            "metrics_scrape": {"before": scrape_before,
-                               "after": scrape_after},
-        }
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        if srv is not None:
-            srv.shutdown()
-
-
-def run_router_bench(tenants: int = 120, shards: int = 3,
-                     head: int = 8, budget_bytes: int = 2_000_000,
-                     seconds: float = 15.0, zipf_s: float = 1.1,
-                     concurrency: int = 6, rows_per_request: int = 16,
-                     seed: int = 0) -> dict:
-    """The BENCH_SUITE ``router_zipf_p99`` leg: the SAME Zipf tenant
-    storm driven two ways at EQUAL total cache budget —
-
-    1. **router + sharded catalog**: ``shards`` shard groups of one
-       replica each, the catalog rendezvous-placed (head replicated,
-       tail on one shard), traffic through the device-free front-door
-       router;
-    2. **direct everyone-has-everything pool** (the PR-7 baseline):
-       the same replica count, every replica holding the FULL catalog
-       under the same per-replica byte budget, traffic round-robined
-       straight at the replicas.
-
-    Records aggregate rows/s, head-decile and tail-decile p99 for
-    both; the acceptance bar is router head p99 within 1.3x of the
-    direct baseline (the router hop + health indirection must be
-    cheap), with the sharded fleet's per-replica catalog share —
-    not the router — absorbing the cache churn the baseline pays."""
-    from tools.chaos import _ShardedFixture
-
-    def leg(shard_count: int, use_router: bool, tag: str) -> dict:
-        fx = _ShardedFixture(tag, tenants=tenants, shards=shard_count,
-                             head=head if shard_count > 1 else 1,
-                             replicas_per_shard=1 if shard_count > 1
-                             else shards,
-                             budget_bytes=budget_bytes,
-                             with_router=use_router)
-        try:
-            targets = [fx.router_url] if use_router else \
-                fx.pool.endpoints
-            scrape_target = fx.router_url if use_router else \
-                (fx.pool.endpoints()[0] if callable(fx.pool.endpoints)
-                 else fx.pool.endpoints[0])
-            scrape_before = _metrics_scrape(scrape_target)
-            out = run_load_zipf(
-                targets, fx.tenant_keys, fx.feature_cols,
-                concurrency=concurrency,
-                rows_per_request=rows_per_request, seconds=seconds,
-                zipf_s=zipf_s, seed=seed, router=use_router)
-            scrape_after = _metrics_scrape(scrape_target)
-            deciles = out.get("deciles") or []
-            return {
-                "metrics_scrape": {"before": scrape_before,
-                                   "after": scrape_after},
-                "rows_per_s": out["value"],
-                "requests": out["requests"],
-                "p50_ms": out["p50_ms"],
-                "p99_ms": out["p99_ms"],
-                "fivexx": out["fivexx"],
-                "errors": out["errors"],
-                "degraded": out.get("degraded", 0),
-                "head_p99_ms": deciles[0]["p99_ms"] if deciles
-                else None,
-                "tail_p99_ms": deciles[-1]["p99_ms"] if deciles
-                else None,
-                "router_stats": fx.router.snapshot()["stats"]
-                if use_router else None,
-            }
-        finally:
-            fx.close()
-
-    routed = leg(shards, True, "rtbench")
-    direct = leg(1, False, "rtbase")
-    ratio = None
-    if routed["head_p99_ms"] and direct["head_p99_ms"]:
-        ratio = round(routed["head_p99_ms"] / direct["head_p99_ms"], 3)
-    return {
-        "metric": "router_zipf_p99",
-        "tenants": tenants, "shards": shards, "head": head,
-        "budget_bytes": budget_bytes, "zipf_s": zipf_s,
-        "seconds": seconds,
-        "router": routed,
-        "direct": direct,
-        "head_p99_ratio": ratio,
-        "head_p99_within_1_3x": bool(ratio is not None
-                                     and ratio <= 1.3),
-    }
 
 
 def main(argv: list[str]) -> int:
