@@ -235,8 +235,9 @@ def _chunk_root_hist_jit(binned, g, h, w, rel0, n_bins_full: bool,
 def _descend(binned, rel, absn, feat, bin_, nal, can, d: int,
              n_bins: int, efb=None):
     """Move every row from level ``d`` to ``d+1`` given level-``d``
-    splits — the exact row-walk of core._grow_tree_shard (bundle slots
-    decoded through the shared core.row_orig_bins LUT gather)."""
+    splits — the exact row-walk of core._grow_tree_shard (the row's
+    bin comes from the shared core.row_orig_bins: a select over the
+    chunk's columns, bundle slots decoded through its LUTs)."""
     live = rel >= 0
     safe_rel = jnp.where(live, rel, 0)
     f = feat[safe_rel]

@@ -152,6 +152,19 @@ def test_boost_scan_compiles(boost_scan, n_dev):
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
+def test_boost_scan_descends_without_a_gather(boost_scan, n_dev):
+    """Row descent selects each row's split column in one loop fusion
+    (PR 31). The `take_along_axis` it replaced compiled to a `kCustom`
+    gather fusion with a `u8[rows]` result, one a level: 72-88 ms a
+    call on the chip at 4,194,304 x 28, 47% of a GBM job (PERF.md
+    section 6)."""
+    txt = boost_scan(n_dev).as_text()
+    assert not re.findall(
+        rf"= u8\[{ROWS_N}\]\S* fusion\([^\n]*kind=kCustom", txt)
+    assert "descend" in _scopes(txt)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
 def test_boost_scan_names_its_kernel_and_scopes(boost_scan, n_dev):
     """What a profile of the chip shows for the boost program: the
     histogram kernel's instruction is `hist_fact.N` (it was the name

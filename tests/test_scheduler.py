@@ -300,19 +300,12 @@ class TestCompileAhead:
                 "jax_persistent_cache_min_compile_time_secs", prev_min)
             _cc.reset_cache()
 
-    @pytest.mark.parametrize("case", ["single", "forest", "multi",
-                                      "multi_forest"])
-    def test_lowered_is_what_is_dispatched(self, mesh8, monkeypatch,
-                                           case):
-        """The drift pin, mode by mode (bernoulli GBM, single-output
-        DRF with `mtries`, three-class GBM, three-class DRF): every
-        program `compile_ahead_lowerings` lowers is one `train()`
-        really dispatches and the other way round — the same jitted function,
-        the same shapes, dtypes and shardings (none where the array is
-        uncommitted) and the same static arguments, so the lowered
-        executable is the one the dispatch looks up."""
-        import jax
-
+    @staticmethod
+    def _small_job(case, monkeypatch):
+        """(estimator, frame, row of `_BOOST_PROGRAMS`) of a small job
+        in one mode: bernoulli GBM, single-output DRF with `mtries`,
+        three-class GBM, three-class DRF; three dispatches of two
+        sizes, 2 + 2 + 1 trees."""
         from h2o_kubernetes_tpu.models import DRF, GBM
         from h2o_kubernetes_tpu.models import gbm as gbm_mod
 
@@ -330,27 +323,44 @@ class TestCompileAhead:
         est = DRF(ntrees=5, max_depth=3, nbins=16, mtries=2, seed=1) \
             if "forest" in case else GBM(ntrees=5, max_depth=3, seed=1)
         fr = h2o.Frame.from_arrays(cols)
-        # three dispatches of two sizes: 2 + 2 + 1 trees
         monkeypatch.setattr(gbm_mod, "_DISPATCH_BUDGET",
                             2 * n * 5 * est.params.nbins * 2 ** 3
                             * (3 if mode == "multi" else 1))
+        return est, fr, mode
 
+    @staticmethod
+    def _recording(sent, fn):
+        """``fn``, noting each call's (function, arguments) in ``sent``."""
+        def call(*a):
+            sent.append((fn, a))
+            return fn(*a)
+        return call
+
+    @pytest.mark.parametrize("case", ["single", "forest", "multi",
+                                      "multi_forest"])
+    def test_lowered_is_what_is_dispatched(self, mesh8, monkeypatch,
+                                           case):
+        """The drift pin, mode by mode (`_small_job`): every
+        program `compile_ahead_lowerings` lowers is one `train()`
+        really dispatches and the other way round — the same jitted function,
+        the same shapes, dtypes and shardings (none where the array is
+        uncommitted) and the same static arguments, so the lowered
+        executable is the one the dispatch looks up."""
+        import jax
+
+        from h2o_kubernetes_tpu.models import gbm as gbm_mod
+
+        est, fr, mode = self._small_job(case, monkeypatch)
         lowered, sent = [], []
         monkeypatch.setattr(gbm_mod, "_aot",
                             lambda fn, *a: lowered.append((fn, a)))
         for thunk in est.compile_ahead_lowerings("y", fr):
             thunk()
 
-        def recording(fn):
-            def call(*a):
-                sent.append((fn, a))
-                return fn(*a)
-            return call
-
-        monkeypatch.setitem(gbm_mod._BOOST_PROGRAMS, mode,
-                            recording(gbm_mod._BOOST_PROGRAMS[mode]))
+        monkeypatch.setitem(gbm_mod._BOOST_PROGRAMS, mode, self._recording(
+            sent, gbm_mod._BOOST_PROGRAMS[mode]))
         monkeypatch.setattr(gbm_mod, "_init_margin",
-                            recording(gbm_mod._init_margin))
+                            self._recording(sent, gbm_mod._init_margin))
         m = est.train(y="y", training_frame=fr)
         assert m.ntrees == (15 if mode == "multi" else 5)
 
@@ -389,6 +399,43 @@ class TestCompileAhead:
             assert any(same(call, low) for low in lowered), call
         for low in lowered:
             assert any(same(call, low) for call in sent), low
+
+    @pytest.mark.parametrize("case", ["single", "forest", "multi",
+                                      "multi_forest"])
+    def test_no_program_gathers_from_the_binned_matrix(
+            self, mesh8, monkeypatch, case):
+        """Row descent selects each row's split column while the binned
+        matrix streams (`core.row_orig_bins`, PR 31): no program
+        `train()` dispatches over the binned matrix — the boost program
+        of each mode, and `_stack_predict`, the heap walk a forest's
+        train metric is read through — lowers to a `gather` whose
+        operand is the `[rows, F]` `uint8` matrix. On the chip each such
+        gather cost ~500 whole reads of what it indexed."""
+        import re
+
+        from h2o_kubernetes_tpu.models import gbm as gbm_mod
+
+        est, fr, mode = self._small_job(case, monkeypatch)
+        sent = []
+        monkeypatch.setitem(gbm_mod._BOOST_PROGRAMS, mode, self._recording(
+            sent, gbm_mod._BOOST_PROGRAMS[mode]))
+        monkeypatch.setattr(gbm_mod, "_stack_predict", self._recording(
+            sent, gbm_mod._stack_predict))
+        est.train(y="y", training_frame=fr)
+        # a program's dispatches differ in their trees, not their body
+        programs = {fn.__name__: (fn, a) for fn, a in sent}
+        assert set(programs) == {
+            "single": {"_boost_jit"},
+            "forest": {"_boost_drf_jit", "_stack_predict"},
+            "multi": {"_boost_multi_jit"},
+            "multi_forest": {"_boost_multi_jit", "_stack_predict"}}[case]
+        # `"stablehlo.gather"(%binned, %idx) ... : (tensor<125x5xui8>,`
+        from_binned = re.compile(
+            r"stablehlo\.gather[^\n]*: \(tensor<\d+x\d+xui8>")
+        for name, (fn, a) in programs.items():
+            text = fn.lower(*a).as_text()
+            assert "xui8>" in text          # the matrix is in there
+            assert not from_binned.search(text), name
 
     def test_unsupported_and_dedupe_accounting(self, mesh8):
         cs = sched.CompileStream(name="t-compile-acct", max_queue=2)
