@@ -34,13 +34,15 @@ from ..runtime.mesh import ROWS, global_mesh, replicated, row_sharding
 from ..runtime.telemetry import phase_span
 from .base import (Model, TrainData, _feature_names, resolve_response,
                    resolve_xy)
-from .tree.binning import (BinSpec, apply_bins, apply_bins_jit, fit_bins,
-                           fused_fit_bins)
+from .tree.binning import (BinSpec, apply_bins, apply_bins_jit,
+                           bin_code_dtype, fit_bins, fused_fit_bins,
+                           matrix_bins, resolve_encoding, set_features)
 from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
                         _boost_drf_jit, _boost_jit, _boost_multi_jit,
                         descend_tree, flat_margin, flatten_cover,
                         flatten_trees, goss_round_keys, level_hist_bytes,
-                        multi_grow_vmapped, predict_tree, round_keys)
+                        multi_grow_vmapped, predict_tree, round_keys,
+                        set_split_reason)
 
 
 @dataclass
@@ -50,6 +52,16 @@ class GBMParams:
     learn_rate: float = 0.1
     min_rows: float = 10.0
     nbins: int = 256
+    # H2O-3's own: the levels an enum keeps a bin each for (past them
+    # contiguous code ranges share bins), and how an enum is split.
+    # "label_encoder": ordinally on its code, in a matrix of `nbins`
+    # bins (so past nbins-1 levels code ranges share bins whatever
+    # nbins_cats says). "enum": one bin a level up to nbins_cats, a
+    # split sends a SET of levels left — H2O-3's AUTO for GBM. "AUTO"
+    # here is still label_encoder: not every serving format carries a
+    # set yet (`refuse_set_splits`, `core.require_ordinal`).
+    nbins_cats: int = 1024
+    categorical_encoding: str = "AUTO"
     sample_rate: float = 1.0
     col_sample_rate_per_tree: float = 1.0
     mtries: int = -1                     # per-node feature sampling (DRF)
@@ -86,11 +98,15 @@ _DISPATCH_BUDGET = 3e12
 _UNIT_HESS_DISTS = ("gaussian", "laplace", "quantile", "huber")
 
 
-def _make_tree_params(p: "GBMParams", distribution: str) -> TreeParams:
+def _make_tree_params(p: "GBMParams", distribution: str,
+                      n_bins: int | None = None,
+                      set_feats: tuple = ()) -> TreeParams:
     """GBMParams + resolved distribution -> the TreeParams the boost
     dispatch is traced with (`boost_plan` is the one caller on the
-    pointwise path)."""
-    return TreeParams(max_depth=p.max_depth, n_bins=p.nbins,
+    pointwise path). ``n_bins``: the binned matrix's bins where they
+    are not ``p.nbins`` (a job with ``set_feats``: `matrix_bins`)."""
+    return TreeParams(max_depth=p.max_depth, n_bins=n_bins or p.nbins,
+                      set_feats=set_feats,
                       min_rows=p.min_rows, reg_lambda=p.reg_lambda,
                       reg_alpha=p.reg_alpha,
                       gamma=p.min_split_improvement, mtries=p.mtries,
@@ -140,6 +156,33 @@ def _draws_from_keys(p: "GBMParams", F: int) -> bool:
         or 0 < p.mtries < F
 
 
+# What cannot carry a set split yet (categorical_encoding="enum"), by
+# the fact that says a job would reach it and the name the error gives
+# it. The scoring side's list is `core.require_ordinal`'s callers.
+_NO_SET_SPLITS_YET = (
+    ("xgboost", "the XGBoost facade"),
+    ("drf", "DRF"),
+    ("multinomial", "the multinomial grower"),
+    ("checkpoint", "checkpoint restart"),
+    ("efb", "an EFB-bundled frame"),
+    ("goss", "GOSS (H2O_TPU_GOSS)"),
+    ("ooc", "the out-of-core path"),
+)
+
+
+def refuse_set_splits(**facts) -> None:
+    """THE one check of what trains with set splits: raise, naming it,
+    for the first true fact of `_NO_SET_SPLITS_YET`."""
+    for fact, name in _NO_SET_SPLITS_YET:
+        if facts.get(fact):
+            raise ValueError(
+                f"categorical_encoding='enum': {name} cannot carry a "
+                "set split yet (a split that sends a set of an enum's "
+                "levels left); train with "
+                "categorical_encoding='label_encoder', or a bernoulli / "
+                "regression GBM in device memory")
+
+
 # THE table of boosting modes: the jitted program that serves a job.
 # A device trace shows each as module `jit_<__name__>`;
 # telemetry.TRAIN_PROGRAMS["boost"] lists the same three names
@@ -186,9 +229,20 @@ class BoostPlan(NamedTuple):
         forest starts from zeros, laplace from the host's median."""
         return not self.bp.drf_mode and self.distribution != "laplace"
 
-    def validate(self) -> None:
-        """Refuse, before the frame is binned, what cannot train."""
+    def validate(self, algo: str = "gbm", ckpt=None, efb: bool = False,
+                 padded: int | None = None) -> None:
+        """Refuse, before the frame is binned, what cannot train. The
+        arguments are what `_train` knows beside the plan, and matter
+        to a job with set splits alone (`refuse_set_splits`)."""
         p = self.p
+        if any(self.tp.set_feats):
+            refuse_set_splits(
+                xgboost=algo == "xgboost", drf=self.bp.drf_mode,
+                multinomial=self.K > 1,
+                checkpoint=ckpt is not None, efb=efb,
+                goss=self.bp.goss_b > 0,
+                ooc=padded is not None
+                and self.ooc_chunk(padded, ckpt) is not None)
         if self.bp.goss_b > 0 and p.sample_rate < 1.0:
             raise ValueError(
                 "H2O_TPU_GOSS replaces row subsampling — train with "
@@ -204,7 +258,7 @@ class BoostPlan(NamedTuple):
         if self.hist_bytes > self.budget:
             raise ValueError(
                 f"max_depth={p.max_depth} with {self.F} histogram "
-                f"columns x {p.nbins} bins needs "
+                f"columns x {self.tp.n_bins} bins needs "
                 f"~{self.hist_bytes / 2 ** 20:.0f} MiB of "
                 f"level histograms (> budget "
                 f"{self.budget / 2 ** 20:.0f} MiB). "
@@ -221,7 +275,7 @@ class BoostPlan(NamedTuple):
         leaves shallow shapes in a single one. A forest's trees go by
         the same rule, one a scan step."""
         p = self.p
-        per_round = padded * max(self.F, 1) * p.nbins \
+        per_round = padded * max(self.F, 1) * self.tp.n_bins \
             * (2 ** p.max_depth) * self.K
         budget_chunk = max(1, int(_DISPATCH_BUDGET // per_round))
         score = self.score_every
@@ -315,8 +369,9 @@ class BoostPlan(NamedTuple):
         the auto gate keeps unbundled."""
         rows = row_sharding(self.mesh)
         row_s = jax.ShapeDtypeStruct((padded,), jnp.float32, sharding=rows)
-        binned_s = jax.ShapeDtypeStruct((padded, self.F), jnp.uint8,
-                                        sharding=rows)
+        binned_s = jax.ShapeDtypeStruct(
+            (padded, self.F), bin_code_dtype(self.tp.n_bins, self.p.nbins),
+            sharding=rows)
         out = []
         if self.device_init:
             out.append((_init_margin, (row_s, row_s, row_s,
@@ -345,11 +400,13 @@ class BoostPlan(NamedTuple):
 
 
 def boost_plan(p: "GBMParams", distribution: str, nclasses: int, F: int,
-               mesh=None) -> BoostPlan:
+               mesh=None, n_bins: int | None = None,
+               set_feats: tuple = ()) -> BoostPlan:
     """The plan of ``p`` on a resolved response and ``F`` histogram
-    columns. Bad GOSS knobs raise here (`goss_params`)."""
+    columns; ``n_bins`` / ``set_feats`` as `_make_tree_params` takes
+    them. Bad GOSS knobs raise here (`goss_params`)."""
     K = nclasses if nclasses > 2 else 1
-    tp = _make_tree_params(p, distribution)
+    tp = _make_tree_params(p, distribution, n_bins, set_feats)
     hist_bytes = level_hist_bytes(tp, F)
     if K > 1 and multi_grow_vmapped(tp, F, K):
         # the memory that will actually be live: K× only when the
@@ -564,6 +621,13 @@ class GBMModel(Model):
         tree ``t`` was offered when it looked for its split."""
         return self._draws().tree_candidates(t)
 
+    @property
+    def _set_splits(self) -> bool:
+        """This ensemble's splits send sets of levels left
+        (`Tree.left_bins`): it is scored by the heap descent over bin
+        codes and by nothing that reads `split_bin` as a threshold."""
+        return self.trees.left_bins is not None
+
     def _flat(self) -> FlatTrees:
         """The ONE flattening of this ensemble (serving scorer + MOJO
         export share it): compact reachable-node arrays with raw-
@@ -577,14 +641,19 @@ class GBMModel(Model):
             self._flat_trees = ft
         return ft
 
-    # base._cached_score calls this before tracing the jitted scorer
-    _serving_prepare = _flat
+    def _serving_prepare(self):
+        """base._cached_score calls this before tracing the jitted
+        scorer: the flat ensemble, which a model with set splits does
+        not have (its `_margins` descends the heap)."""
+        return None if self._set_splits else self._flat()
 
     def _margins(self, X: jax.Array,
                  offset: jax.Array | None = None) -> jax.Array:
         """Raw boosting margins via the flattened serving scorer — no
         re-binning at score time; bitwise-equal to `_margins_binned`
         (the heap re-descent kept as the parity reference)."""
+        if self._set_splits:
+            return self._margins_binned(X, offset)
         K = self.nclasses if self.nclasses > 2 else 1
         p = self.params
         lv = flat_margin(self._flat(), X, self._enum_mask, p.max_depth,
@@ -619,8 +688,9 @@ class GBMModel(Model):
         not compile a new scorer."""
         K = self.nclasses if self.nclasses > 2 else 1
         p = self.params
+        n_bins = self.bin_spec.n_bins
         if K == 1:
-            m = _stack_predict(self.trees, binned, p.max_depth, p.nbins)
+            m = _stack_predict(self.trees, binned, p.max_depth, n_bins)
             if p._drf_mode:
                 m = m / self.ntrees
             base = self.init_score if offset is None \
@@ -630,7 +700,7 @@ class GBMModel(Model):
         outs = []
         for k in range(K):
             tk = jax.tree.map(lambda a: a[k::K], self.trees)
-            mk = _stack_predict(tk, binned, p.max_depth, p.nbins)
+            mk = _stack_predict(tk, binned, p.max_depth, n_bins)
             if p._drf_mode:
                 mk = mk / (self.ntrees // K)
             outs.append(self.init_score[k] + mk)
@@ -673,7 +743,8 @@ class GBMModel(Model):
                                 self.bin_spec.na_bin)
         p = self.params
         nodes = np.asarray(_stack_leaf_nodes(
-            self.trees, binned, p.max_depth, p.nbins))[:, : frame.nrows]
+            self.trees, binned, p.max_depth,
+            self.bin_spec.n_bins))[:, : frame.nrows]
         K = self.nclasses if self.nclasses > 2 else 1
         out = Frame()
         for t in range(nodes.shape[0]):
@@ -702,6 +773,9 @@ class GBMModel(Model):
         if self.nclasses > 2:
             return ("predict_contributions supports binomial "
                     "and regression models only")
+        if self._set_splits:
+            return set_split_reason(self.trees,
+                                    "TreeSHAP (predict_contributions)")
         if getattr(self, "offset_column", None):
             # a per-row offset is not attributable to any feature, so
             # SHAP columns could not sum to the margin
@@ -814,11 +888,9 @@ class GBM:
         with phase_span("train.prepare"):
             if p.ntrees < 1:
                 raise ValueError(f"ntrees must be >= 1, got {p.ntrees}")
-            if not 4 <= p.nbins <= 256:
-                # fit_bins validates this too; checking up front keeps the
-                # error first whichever binning path (classic/fused) runs
-                raise ValueError(f"n_bins must be in [4, 256] (uint8 bin "
-                                 f"codes), got {p.nbins}")
+            # the binning paths ask the same function; asking up front
+            # keeps the error first whichever of them runs
+            bin_code_dtype(p.nbins)
             if offset_column and p._drf_mode:
                 # the reference rejects offsets for DRF too (trees vote —
                 # there is no additive margin for an offset to join)
@@ -868,6 +940,11 @@ class GBM:
             # through to the fused prologue unchanged.
             from .tree import efb as efb_mod
 
+            encoding, set_feats, nbins_cats, n_bins = _bin_layout(
+                p, training_frame, data.feature_names)
+            if ckpt is not None and ckpt.bin_spec.set_feats:
+                refuse_set_splits(checkpoint=True)
+
             efb_plan = efb = None
             F = len(data.feature_names)
             if efb_mod.efb_eligible(F, ckpt):
@@ -883,9 +960,13 @@ class GBM:
                     # the memory win is exactly what buys deeper trees
                     # on wide sparse frames
                     F = efb_plan.fb
+                elif set_feats:
+                    bin_spec = None     # that fit was label_encoder's
 
-            plan = boost_plan(p, data.distribution, data.nclasses, F)
-            plan.validate()
+            plan = boost_plan(p, data.distribution, data.nclasses, F,
+                              n_bins=n_bins, set_feats=set_feats)
+            plan.validate(self.model_cls.algo, ckpt, efb_plan is not None,
+                          data.y.shape[0])
             # the per-round GOSS key stream is derived OUTSIDE the
             # dispatch-chunk key schedule (goss_round_keys) so the fused
             # in-HBM path and the ooc stream draw identical keep patterns
@@ -898,7 +979,10 @@ class GBM:
             ooc_chunk = plan.ooc_chunk(data.y.shape[0], ckpt)
             binned = None
         root.update(rows=training_frame.nrows, features=plan.F,
-                    chips=plan.mesh.size)
+                    chips=plan.mesh.size, encoding=encoding,
+                    bins=n_bins, enum_features=sum(
+                        training_frame.vec(n).is_enum()
+                        for n in data.feature_names))
         # no span blocks on the device for its own sake (the dispatch
         # pipeline below is the loop's design): `enqueue` spans read the
         # dispatch, the device's side is in the device trace under
@@ -920,11 +1004,12 @@ class GBM:
                 if ooc_chunk is None:
                     bin_spec, binned = fused_fit_bins(
                         training_frame, data.feature_names,
-                        n_bins=p.nbins)
+                        n_bins=p.nbins, nbins_cats=nbins_cats)
                 else:
                     bin_spec = fit_bins(training_frame,
                                         data.feature_names,
-                                        n_bins=p.nbins)
+                                        n_bins=p.nbins,
+                                        nbins_cats=nbins_cats)
             if ooc_chunk is None and binned is None:
                 binned = training_frame.binned(bin_spec)
 
@@ -995,6 +1080,7 @@ class GBM:
                     features=len(data.feature_names),
                     max_depth=p.max_depth, classes=plan.K)
             model._varimp = _stacked_varimp(model.trees, data.feature_names)
+            _count_splits(model.trees, set_feats)
         with phase_span("train.metric", kind="wait"):
             if p._drf_mode:
                 if binned is not None and efb is None:
@@ -1125,8 +1211,10 @@ class GBM:
             if not names or efb_mod.efb_eligible(len(names), None) or \
                     dist.startswith("rank:"):
                 return []
-            plan = boost_plan(p, dist, nclasses, len(names))
-            plan.validate()
+            _, set_feats, _, n_bins = _bin_layout(p, frame, names)
+            plan = boost_plan(p, dist, nclasses, len(names),
+                              n_bins=n_bins, set_feats=set_feats)
+            plan.validate(self.model_cls.algo)
         except ValueError:
             return []       # train() raises it, on the driver thread
         n = frame.nrows
@@ -1154,6 +1242,23 @@ class GBM:
                 for padded in sorted(padded_sizes)
                 if plan.ooc_chunk(padded, None) is None
                 for fn, args in plan.lowerings(padded)]
+
+
+def _bin_layout(p: GBMParams, frame, names: list[str]) -> tuple:
+    """(encoding, set_feats, nbins_cats, n_bins) of a job: what
+    `categorical_encoding` resolves to, the features that take set
+    splits, the `nbins_cats` the binning is told (None: the matrix is
+    label_encoder's) and the binned matrix's bins a feature — static
+    facts, from column metadata. Without a set feature
+    (``label_encoder``, or no enum column within `nbins_cats`) the job
+    is label_encoder's, to the program's last byte."""
+    encoding = resolve_encoding(p.categorical_encoding)
+    set_feats = set_features(frame, names, p.nbins_cats) \
+        if encoding == "enum" else ()
+    nbins_cats = p.nbins_cats if set_feats else None
+    n_bins = matrix_bins(frame, names, p.nbins, nbins_cats)
+    bin_code_dtype(n_bins, p.nbins)
+    return encoding, set_feats, nbins_cats, n_bins
 
 
 def _check_checkpoint(ckpt, p: GBMParams, data: TrainData, offset_column,
@@ -1213,11 +1318,11 @@ def _initial_margin(plan: BoostPlan, data: TrainData, ckpt, binned):
                 else jnp.zeros_like(data.y)
         elif K == 1:
             margin = init + off + _stack_predict(
-                ckpt.trees, binned, p.max_depth, p.nbins)
+                ckpt.trees, binned, p.max_depth, plan.tp.n_bins)
         else:
             outs = [init[k] + _stack_predict(
                 jax.tree.map(lambda a: a[k::K], ckpt.trees),
-                binned, p.max_depth, p.nbins) for k in range(K)]
+                binned, p.max_depth, plan.tp.n_bins) for k in range(K)]
             margin = jnp.stack(outs, axis=1)
         if laplace:
             # continuation must reuse the checkpoint's robust scaling or
@@ -1312,5 +1417,25 @@ def _stacked_varimp(trees: Tree, names: list[str]) -> dict[str, float]:
     a per-tree np.asarray would force a device sync every boosting
     iteration. The ravel happens host-side (np) — an eager jnp op
     on the committed tree arrays is a multi-device dispatch."""
-    flat = Tree(*(np.asarray(x).ravel() for x in trees))
+    flat = trees._replace(split_feat=np.asarray(trees.split_feat).ravel(),
+                          gain=np.asarray(trees.gain).ravel())
     return dict(zip(names, _gain_by_feat(flat, len(names))))
+
+
+def _count_splits(trees: Tree, set_feats: tuple) -> None:
+    """`h2o_train_splits_total{kind}`: the splits of a model just read
+    back, by whether they send a set of levels left or cut a numeric
+    (or label-encoded) feature at a threshold."""
+    from ..runtime.telemetry import REGISTRY
+
+    feat = np.asarray(trees.split_feat).ravel()
+    feat = feat[feat >= 0]
+    n_set = int(np.asarray(set_feats, dtype=bool)[feat].sum()) \
+        if set_feats else 0
+    ctr = REGISTRY.counter(
+        "h2o_train_splits_total",
+        "splits of the tree models trained, by kind: set (a set of an "
+        "enum's levels goes left) or numeric (a threshold)",
+        label="kind")
+    ctr.inc(n_set, label_value="set")
+    ctr.inc(len(feat) - n_set, label_value="numeric")

@@ -11,8 +11,14 @@ literature (PAPERS.md: XGBoost GPU, Booster) rather than the Java design.
 
 Layout: B total bins per feature. Bin B-1 is reserved for NA. Numeric
 features use quantile edges (≤ B-2 finite bins); categorical features
-use their codes directly; past B-1 levels, contiguous code ranges share
-bins (the reference's DHistogram grouping past nbins_cats [U3]).
+use their codes directly. What happens to an enum with many levels is
+the job's ``categorical_encoding`` (`_classify_features`):
+``label_encoder`` keeps B = nbins ≤ 256 and, past B-1 levels, lets
+contiguous code ranges share bins (NOT what H2O-3 does below its
+`nbins_cats`); ``enum`` gives every level of an enum with at most
+`nbins_cats` levels its own bin, in a matrix wide enough to hold them
+(B a power of two, 16-bit codes past 256), which is H2O-3's own
+DHistogram layout for categoricals [U3].
 
 Wide sparse frames additionally go through Exclusive Feature Bundling
 at bin time (models/tree/efb.py, docs/SCALING.md "Wide sparse
@@ -49,10 +55,25 @@ class BinSpec:
     is_enum: list[bool]
     n_bins: int = 256                # total incl. NA bin
     edges_dev: object = None         # [F, B-2] device matrix (+inf pad)
+    # "enum": every `is_enum` feature keeps one bin a level and splits
+    # on a SET of levels (core._find_splits); "label_encoder": enums
+    # split ordinally on their code. Class default, so specs pickled
+    # before the field existed read as what they were.
+    encoding: str = "label_encoder"
 
     @property
     def na_bin(self) -> int:
         return self.n_bins - 1
+
+    @property
+    def set_feats(self) -> tuple:
+        """Per feature, whether it splits on a set of levels — the
+        static `TreeParams.set_feats` of a job on this spec; () when
+        none does (every `label_encoder` spec, every all-numeric
+        frame)."""
+        if self.encoding != "enum" or not any(self.is_enum):
+            return ()
+        return tuple(bool(e) for e in self.is_enum)
 
     def edges_matrix(self):
         """[F, B-2] edge matrix padded with +inf (for device binning)."""
@@ -122,36 +143,115 @@ def _sampled_feature_matrix(num_cols: list) -> jax.Array:
     return jnp.stack(num_cols, axis=1)
 
 
-def _classify_features(frame, feature_names: list[str], n_bins: int
+def bin_code_dtype(n_bins: int, nbins: int | None = None):
+    """dtype of the codes of a matrix with ``n_bins`` bins a feature,
+    NA bin included — THE one check of a job's bin counts (`fit_bins`,
+    `fused_fit_bins` and `GBM.train` all ask here). ``nbins``, the
+    numeric quantile bins a user asks for, stays within [4, 256]; the
+    matrix itself may be wider where an enum keeps a bin a level
+    (``categorical_encoding="enum"``): 8-bit codes to 256 bins, 16-bit
+    codes to 65,536."""
+    nbins = n_bins if nbins is None else nbins
+    if not 4 <= nbins <= 256:
+        raise ValueError(f"n_bins must be in [4, 256] (numeric quantile "
+                         f"bins), got {nbins}")
+    if not nbins <= n_bins <= 1 << 16:
+        raise ValueError(f"a binned matrix holds at most 65536 bins a "
+                         f"feature (16-bit bin codes), got {n_bins}")
+    return jnp.uint8 if n_bins <= 256 else jnp.uint16
+
+
+def resolve_encoding(categorical_encoding: str) -> str:
+    """H2O-3's `categorical_encoding` as this program runs it:
+    ``label_encoder`` (an enum splits ordinally on its code) or
+    ``enum`` (one bin a level, splits on a set of levels). ``AUTO``
+    still means ``label_encoder`` here — H2O-3's AUTO means ``enum``
+    for GBM and DRF — until every serving format carries a set."""
+    enc = str(categorical_encoding).lower()
+    if enc in ("auto", "label_encoder", "labelencoder"):
+        return "label_encoder"
+    if enc == "enum":
+        return "enum"
+    raise ValueError(
+        f"categorical_encoding must be one of AUTO, label_encoder, enum "
+        f"(got {categorical_encoding!r})")
+
+
+def set_features(frame, feature_names: list[str],
+                 nbins_cats: int) -> tuple:
+    """Per feature, whether ``categorical_encoding="enum"`` gives it
+    set splits on this frame: an enum with at most ``nbins_cats``
+    levels. () when no feature does — the job is then the one
+    ``label_encoder`` runs. Column metadata only."""
+    out = tuple(v.is_enum() and v.cardinality() <= nbins_cats
+                for v in (frame.vec(n) for n in feature_names))
+    return out if any(out) else ()
+
+
+def matrix_bins(frame, feature_names: list[str], n_bins: int,
+                nbins_cats: int | None) -> int:
+    """Bins a feature (NA bin included) of the matrix a job bins to:
+    ``n_bins`` as it always was without ``nbins_cats`` or without a
+    set feature; else the least power of two that holds the widest
+    feature — ``n_bins`` numeric bins, or an enum's levels up to
+    ``nbins_cats`` — and the NA bin."""
+    if nbins_cats is None or \
+            not set_features(frame, feature_names, nbins_cats):
+        return n_bins
+    widest = n_bins
+    for name in feature_names:
+        v = frame.vec(name)
+        if v.is_enum():
+            widest = max(widest, min(v.cardinality(), nbins_cats))
+    return 1 << widest.bit_length()         # least 2^k >= widest + 1
+
+
+def _classify_features(frame, feature_names: list[str], n_bins: int,
+                       nbins_cats: int | None = None
                        ) -> tuple[list[bool], list[int], list, np.ndarray]:
     """(is_enum, num_idx, num_cols, base_M) — the ONE feature-kind
     classification shared by fit_bins and the fused path.
 
-    ``base_M`` is the host [F, B-2] +inf edge matrix with the
-    high-cardinality range-grouping edges already filled: past B-1
-    levels, contiguous CODE RANGES share bins — the same range grouping
-    the reference's DHistogram applies to categoricals past nbins_cats
-    ([U3] hex/tree/DHistogram). Expressed through the numeric
-    edge-count path (is_enum=False + synthetic edges between ranges);
-    NA codes arrive as NaN from as_float and land in the NA bin as
-    usual.  Enum rows never consult edges (apply_bins clips the code),
-    so their rows stay at the +inf padding."""
+    ``base_M`` is the host [F, B-2] +inf edge matrix (B the matrix's
+    bins, `matrix_bins`) with the range-grouping edges of an enum that
+    has more levels than bins of its own already filled. Enum rows
+    never consult edges (apply_bins clips the code), so theirs stay at
+    the +inf padding; a numeric feature's ``n_bins - 3`` quantile edges
+    are filled by the caller whatever B is, so it bins as a job of
+    ``n_bins`` bins always did.
+
+    ``nbins_cats=None`` (``label_encoder``): B = ``n_bins``, and an enum
+    past B-1 levels is folded into B-2 contiguous CODE RANGES, expressed
+    through the numeric edge-count path (is_enum=False + synthetic edges
+    between ranges; airlines Origin/Dest is ~300 levels). H2O-3 does
+    this only past its `nbins_cats` (default 1024), so at 300 levels it
+    is this program's own departure. With ``nbins_cats`` (``enum``):
+    every enum of at most ``nbins_cats`` levels keeps one bin a level,
+    code = bin, and only one past it is folded, into ``nbins_cats``
+    ranges — H2O-3's DHistogram layout for categoricals ([U3]
+    hex/tree/DHistogram). NA codes arrive as NaN from as_float and land
+    in the NA bin either way."""
+    B = matrix_bins(frame, feature_names, n_bins, nbins_cats)
+    # ranges an enum with too many levels is folded into, and the most
+    # levels one keeps without folding (the matrix is wider than
+    # ``n_bins`` exactly where some feature takes set splits)
+    groups, keep = (nbins_cats, nbins_cats) if B > n_bins \
+        else (n_bins - 2, n_bins - 1)
     is_enum: list[bool] = []
     num_idx: list[int] = []
     num_cols = []
-    base = np.full((len(feature_names), n_bins - 2), np.inf,
-                   dtype=np.float32)
+    base = np.full((len(feature_names), B - 2), np.inf, dtype=np.float32)
     for name in feature_names:
         v = frame.vec(name)
         if v.is_enum():
             card = v.cardinality()
-            if card > n_bins - 1:
-                # n_bins-3 edges split the code space [0, card) into
-                # n_bins-2 near-equal ranges; the -0.5 puts each edge
-                # BETWEEN codes (airlines Origin/Dest is ~300 levels)
-                e = (np.arange(1, n_bins - 2, dtype=np.float32)
-                     * card / (n_bins - 2)) - 0.5
-                base[len(is_enum), : n_bins - 3] = e
+            if card > keep:
+                # groups-1 edges split the code space [0, card) into
+                # `groups` near-equal ranges; the -0.5 puts each edge
+                # BETWEEN codes
+                e = (np.arange(1, groups, dtype=np.float32)
+                     * card / groups) - 0.5
+                base[len(is_enum), : groups - 1] = e
                 is_enum.append(False)
                 continue
             is_enum.append(True)
@@ -162,9 +262,12 @@ def _classify_features(frame, feature_names: list[str], n_bins: int
     return is_enum, num_idx, num_cols, base
 
 
-def fit_bins(frame, feature_names: list[str],
-             n_bins: int = 256) -> BinSpec:
+def fit_bins(frame, feature_names: list[str], n_bins: int = 256,
+             nbins_cats: int | None = None) -> BinSpec:
     """Compute quantile edges per numeric feature, fully device-side.
+    ``nbins_cats``: the job's `categorical_encoding` is ``enum``
+    (`_classify_features`); the spec's ``n_bins`` is then the matrix's
+    (`matrix_bins`), not the ``n_bins`` numeric bins asked for.
 
     The edge matrix never visits the host: NaN quantiles (all-NA
     columns) become +inf on device, and duplicate quantiles (heavily
@@ -172,11 +275,9 @@ def fit_bins(frame, feature_names: list[str],
     which is semantically identical to the round-2 host-side
     `np.unique` dedup (bin ids are labels; MOJO scoring uses the SAME
     matrix, so artifacts stay consistent)."""
-    if not 4 <= n_bins <= 256:
-        raise ValueError(f"n_bins must be in [4, 256] (uint8 bin codes), "
-                         f"got {n_bins}")
     is_enum, num_idx, num_cols, base = _classify_features(
-        frame, feature_names, n_bins)
+        frame, feature_names, n_bins, nbins_cats)
+    bin_code_dtype(base.shape[1] + 2, n_bins)
     M = jnp.asarray(base)
     if num_cols:
         Q = _device_quantiles(_sampled_feature_matrix(num_cols),
@@ -184,8 +285,17 @@ def fit_bins(frame, feature_names: list[str],
         Q = jnp.where(jnp.isnan(Q), jnp.inf, Q.astype(jnp.float32))
         M = M.at[jnp.asarray(num_idx, dtype=jnp.int32),
                  : n_bins - 3].set(Q)
+    return _spec(feature_names, is_enum, M, n_bins)
+
+
+def _spec(feature_names, is_enum, M, n_bins: int) -> BinSpec:
+    """The BinSpec of a fit: the matrix's bins are the edge matrix's
+    width and two (`_classify_features`), and a matrix wider than the
+    ``n_bins`` asked for is an ``enum`` job's (`matrix_bins`)."""
+    B = M.shape[1] + 2
     return BinSpec(names=list(feature_names), edges=None,
-                   is_enum=is_enum, n_bins=n_bins, edges_dev=M)
+                   is_enum=is_enum, n_bins=B, edges_dev=M,
+                   encoding="enum" if B != n_bins else "label_encoder")
 
 
 # edges counted in one pass over the values: unrolled, a trip's compares
@@ -201,7 +311,8 @@ _EDGE_UNROLL = 32
 @jax.named_scope("apply_bins")
 def apply_bins(X: jax.Array, edges_matrix: jax.Array, enum_mask: jax.Array,
                na_bin: int) -> jax.Array:
-    """Bin a [rows, F] float matrix → [rows, F] uint8 codes (jittable).
+    """Bin a [rows, F] float matrix → [rows, F] bin codes (jittable):
+    uint8 up to 256 bins, uint16 past them (`bin_code_dtype`).
 
     Numeric: the number of that feature's quantile edges at or below
     the value — `searchsorted(edges, x, side="right")` to the bit (a
@@ -225,7 +336,7 @@ def apply_bins(X: jax.Array, edges_matrix: jax.Array, enum_mask: jax.Array,
     cat = jnp.clip(X, 0, na_bin - 1).astype(jnp.int32)
     b = jnp.where(enum_mask, cat, num)
     b = jnp.where(jnp.isnan(X) | (X < 0) & enum_mask, na_bin, b)
-    return b.astype(jnp.uint8)
+    return b.astype(jnp.uint8 if na_bin < 256 else jnp.uint16)
 
 
 # module-level jitted form: a fresh jax.jit per train() call would
@@ -306,16 +417,19 @@ def bin_frame(frame, bin_spec: BinSpec) -> jax.Array:
 # apply_bins) is asserted by tests/test_scheduler.py.
 
 
-@functools.partial(jax.jit, static_argnums=(5,))
+@functools.partial(jax.jit, static_argnums=(5, 6))
 def _fused_fit_bin_jit(base_M, num_idx, sample, cols: tuple,
-                       enum_block, na_bin: int):
-    """ONE dispatch: quantile edges from the sampled matrix + the bin
-    codes of the first column block.  ``sample=None`` (no numeric
-    features) skips the quantile half at trace time."""
+                       enum_block, na_bin: int, n_q: int | None = None):
+    """ONE dispatch: ``n_q`` quantile edges a numeric feature (all the
+    edge matrix holds but its last, unless given: an ``enum`` job's
+    matrix is wider than its numeric bins) from the sampled matrix +
+    the bin codes of the first column block.  ``sample=None`` (no
+    numeric features) skips the quantile half at trace time."""
     M = base_M
     if sample is not None:
         with jax.named_scope("fit_quantiles"):
-            n_q = M.shape[1] - 1                  # n_bins - 3
+            if n_q is None:
+                n_q = M.shape[1] - 1              # n_bins - 3
             qs = jnp.linspace(0.0, 1.0, n_q + 2)[1:-1]
             Q = jax.vmap(lambda c: jnp.nanquantile(c, qs))(sample.T)
             Q = jnp.where(jnp.isnan(Q), jnp.inf, Q.astype(jnp.float32))
@@ -325,9 +439,11 @@ def _fused_fit_bin_jit(base_M, num_idx, sample, cols: tuple,
     return M, binned
 
 
-def fused_fit_bins(frame, feature_names: list[str],
-                   n_bins: int = 256) -> tuple[BinSpec, jax.Array]:
-    """(BinSpec, [padded, F] uint8 codes) in one fused first dispatch.
+def fused_fit_bins(frame, feature_names: list[str], n_bins: int = 256,
+                   nbins_cats: int | None = None
+                   ) -> tuple[BinSpec, jax.Array]:
+    """(BinSpec, [padded, F] bin codes) in one fused first dispatch;
+    ``nbins_cats`` as `fit_bins` takes it.
 
     Cache: hits the owning frame's ``_binned_cache`` under a
     content-version fit key WITHOUT any device sync, so a second model
@@ -335,18 +451,17 @@ def fused_fit_bins(frame, feature_names: list[str],
     pays neither the quantile fit nor the bin apply.  The classic
     fingerprint path (Frame.binned) remains for specs that did not come
     from fitting THIS frame (checkpoint continuation)."""
-    if not 4 <= n_bins <= 256:
-        raise ValueError(f"n_bins must be in [4, 256] (uint8 bin codes), "
-                         f"got {n_bins}")
     cache = frame.__dict__.setdefault("_binned_cache", {})
-    key = ("fitbin", tuple(feature_names), n_bins,
+    key = ("fitbin", tuple(feature_names), n_bins, nbins_cats,
            frame.__dict__.get("_version", 0))
     hit = cache.pop(key, None)
     if hit is not None:
         cache[key] = hit              # true LRU: a hit refreshes recency
         return hit
     is_enum, num_idx, num_cols, base = _classify_features(
-        frame, feature_names, n_bins)
+        frame, feature_names, n_bins, nbins_cats)
+    na_bin = base.shape[1] + 1
+    bin_code_dtype(na_bin + 1, n_bins)
     F = len(feature_names)
     padded = frame.vec(feature_names[0]).padded_len
     sample = _sampled_feature_matrix(num_cols) if num_cols else None
@@ -356,17 +471,17 @@ def fused_fit_bins(frame, feature_names: list[str],
                   for nm in feature_names[:block])
     M, first = _fused_fit_bin_jit(
         jnp.asarray(base), jnp.asarray(num_idx, dtype=jnp.int32),
-        sample, cols0, jnp.asarray(enum_arr[:block]), n_bins - 1)
+        sample, cols0, jnp.asarray(enum_arr[:block]), na_bin,
+        None if na_bin == n_bins - 1 else n_bins - 3)
     outs = [first]
     for lo in range(block, F, block):
         hi = min(lo + block, F)
         cols = tuple(frame.vec(nm).as_float()
                      for nm in feature_names[lo:hi])
-        outs.append(_bin_block_jit(cols, M[lo:hi], n_bins - 1,
+        outs.append(_bin_block_jit(cols, M[lo:hi], na_bin,
                                    jnp.asarray(enum_arr[lo:hi])))
     binned = outs[0] if len(outs) == 1 else _concat_blocks(*outs)
-    spec = BinSpec(names=list(feature_names), edges=None,
-                   is_enum=is_enum, n_bins=n_bins, edges_dev=M)
+    spec = _spec(feature_names, is_enum, M, n_bins)
     while len(cache) >= 2:                  # tiny LRU: drop oldest
         cache.pop(next(iter(cache)))
     cache[key] = (spec, binned)

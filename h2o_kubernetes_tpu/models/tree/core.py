@@ -18,7 +18,10 @@ TPU-first choices (SURVEY.md §7 "hard parts"):
 
 Split semantics: `bin <= split_bin` goes left. The NA bin is the last
 bin; `na_left` per node records the learned NA direction (both
-directions are scored, XGBoost-style).
+directions are scored, XGBoost-style). A job with set features
+(`TreeParams.set_feats`: H2O-3's ``categorical_encoding="enum"``) keeps
+`Tree.left_bins` beside them, the bins that go left at every node, and
+every descent of such a tree reads that table alone (`_goes_right`).
 """
 
 from __future__ import annotations
@@ -47,6 +50,10 @@ class TreeParams(NamedTuple):
     min_child_weight: float = 0.0   # min hessian mass per child (XGBoost)
     hist_impl: str = "auto"         # auto | segment | pallas (ops/histogram)
     unit_hess: bool = False         # h ≡ 1 loss: 2-channel histograms
+    # per feature, whether it splits on a SET of its bins (an enum under
+    # categorical_encoding="enum": BinSpec.set_feats); () = none does,
+    # and the program is the one it was before sets existed
+    set_feats: tuple = ()
 
 
 class Tree(NamedTuple):
@@ -63,6 +70,33 @@ class Tree(NamedTuple):
     # field existed (6-tuple Trees) still unpickle; load_model backfills
     # the None (persist.py) and predict_contributions rejects it.
     cover: jax.Array = None
+    # bool [2^max_depth - 1, B]: at inner node i, the bins that go LEFT
+    # — THE description of a split in a job with set features (numeric
+    # cuts and the NA bin are written into it too: `bin <= split_bin`,
+    # `na_left`), read by the grower, `descend_tree` and so by every
+    # scorer of such a tree. None where no feature takes set splits:
+    # `split_bin` / `na_left` then say it all.
+    left_bins: jax.Array = None
+
+
+def set_split_reason(trees: "Tree", what: str) -> str | None:
+    """Why ``what`` cannot take ``trees``, if they hold set splits:
+    ``what`` reads `split_bin` as a threshold, and a split that sends
+    a set of levels left has none. None for every other ensemble."""
+    if getattr(trees, "left_bins", None) is None:
+        return None
+    return (f"{what} cannot carry a set split yet: this model was "
+            "trained with categorical_encoding='enum' (a split sends a "
+            "SET of an enum's levels left). Score it with predict(), "
+            "or train with categorical_encoding='label_encoder'.")
+
+
+def require_ordinal(trees: "Tree", what: str) -> None:
+    """Raise `set_split_reason`, if there is one: a model trained with
+    ``categorical_encoding="enum"`` must never be scored ordinally."""
+    reason = set_split_reason(trees, what)
+    if reason:
+        raise ValueError(reason)
 
 
 def _soft_thresh(g, alpha):
@@ -115,16 +149,31 @@ def _find_splits(hist, p: TreeParams, feat_ok=None, efb=None):
     (per-tree column sampling and DRF per-node mtries) — always in
     ORIGINAL feature space, whatever the histogram width.
     Returns (feat, bin, na_left, can_split, node_value, best_gain,
-    cover, left, right) per node — cover is the node's total weight
-    mass (TreeSHAP's r_j); left/right are the chosen split's side
+    cover, left, right, left_bins) per node — cover is the node's total
+    weight mass (TreeSHAP's r_j); left/right are the chosen split's side
     totals [n, 3] (== the children's node totals, NA side applied),
     which the grower uses as the final level's leaf stats.
+
+    Set features (``p.set_feats``; Fisher 1958, CART §4.2.2: for a
+    convex loss the best two-way partition of the levels is a prefix of
+    their order by mean response): in every node the bins of a set
+    feature are put in the order of `_set_order` before the prefixes
+    are scanned, so candidate ``bin`` k of such a feature is "the first
+    k+1 bins of that order go left"; a numeric feature's order is its
+    codes' and nothing changes for it. ``left_bins`` [n, B] then says,
+    for the winner of each node, which bins go left (the NA bin by
+    ``na_left``); it is None without set features.
     """
     if efb is not None:
-        return _find_splits_efb(hist, p, efb, feat_ok)
+        return _find_splits_efb(hist, p, efb, feat_ok) + (None,)
     nb = hist.shape[2]
     na = hist[:, :, nb - 1, :]                 # [n, F, 3]
     body = hist[:, :, : nb - 1, :]
+    order = None
+    if any(p.set_feats):
+        with jax.named_scope("set_order"):
+            order = _set_order(body, p)
+            body = jnp.take_along_axis(body, order[..., None], axis=2)
     cum = jnp.cumsum(body, axis=2)             # left stats, NA excluded
     tot = cum[:, :, -1, :] + na                # [n, F, 3] node totals
     totn = tot[:, 0:1, :]                      # same for every feature
@@ -163,8 +212,49 @@ def _find_splits(hist, p: TreeParams, feat_ok=None, efb=None):
     can_split = (best_gain > p.gamma) & (C >= 2 * p.min_rows) & \
         jnp.isfinite(best_gain)
     value = _leaf_value(G, H, p)
+    left_bins = None
+    if order is not None:
+        with jax.named_scope("set_order"):
+            # the winner's order, inverted: a bin's place in it
+            mine = jnp.take_along_axis(
+                order, feat[:, None, None], axis=1)[:, 0]     # [n, B-1]
+            place = jnp.argsort(mine, axis=1)
+            left_bins = jnp.concatenate(
+                [place <= bin_[:, None], na_l[:, None]], axis=1)
     return (feat, bin_, na_l, can_split, value, best_gain, C,
-            left, right)
+            left, right, left_bins)
+
+
+def _set_order(body, p: TreeParams):
+    """int32 [n, F, B-1]: for every node and feature, the body bins in
+    the order their prefixes are scanned. A set feature's bins that
+    hold rows (count > 0) come first, by G / (H + lambda) ascending —
+    the Newton step's order, which for squared error is the order by
+    mean response H2O-3 sorts by — equal ratios by code; its bins
+    without rows in the node (levels absent from it, and the bins past
+    its last level) come after them, by code, so a scanned prefix ends
+    before them wherever it can and an absent level goes RIGHT. A
+    numeric feature keeps the order of its codes."""
+    G, H, C = body[..., 0], body[..., 1], body[..., 2]
+    codes = jnp.arange(body.shape[2], dtype=jnp.float32)
+    ratio = jnp.where(C > 0, G / (H + p.reg_lambda + 1e-10), jnp.inf)
+    is_set = jnp.asarray(p.set_feats, dtype=bool)[None, :, None]
+    return jnp.argsort(jnp.where(is_set, ratio, codes), axis=2,
+                       stable=True).astype(jnp.int32)
+
+
+def _goes_right(rowbin, b, nl, n_bins: int, left_bins=None, node=None):
+    """Per row, whether it goes to the right child — THE one reading of
+    a split, shared by the grower's descent and `descend_tree`. Without
+    set features: the NA bin by ``nl``, else `rowbin > b`. With them:
+    the row's bin is looked up among its node's ``left_bins`` ([nodes,
+    B]; ``node`` is each row's row of that table), which holds numeric
+    cuts and the NA direction as well."""
+    if left_bins is None:
+        is_na = rowbin == n_bins - 1
+        return jnp.where(is_na, ~nl, rowbin > b)
+    with jax.named_scope("set_descend"):
+        return ~left_bins.reshape(-1)[node * n_bins + rowbin]
 
 
 def _find_splits_efb(hist, p: TreeParams, efb, feat_ok):
@@ -336,6 +426,8 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
     value = jnp.zeros(N, dtype=jnp.float32)
     gain = jnp.zeros(N, dtype=jnp.float32)
     cover = jnp.zeros(N, dtype=jnp.float32)
+    left_bins = jnp.zeros((2 ** p.max_depth - 1, p.n_bins), dtype=bool) \
+        if any(p.set_feats) else None
 
     rel = jnp.zeros(binned.shape[0], dtype=jnp.int32)   # relative node @ lvl
     abs_node = jnp.zeros(binned.shape[0], dtype=jnp.int32)
@@ -415,8 +507,10 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
         with jax.named_scope("split_find"):
             feat_ok = level_candidates(key, d, col_mask, p.mtries)
             (feat, bin_, na_l, can, val, g_best, cov, left_ch,
-             right_ch) = _find_splits(hist, p, feat_ok, efb)
+             right_ch, lb) = _find_splits(hist, p, feat_ok, efb)
             idx = off + jnp.arange(n_nodes)
+            if lb is not None:
+                left_bins = left_bins.at[idx].set(lb)
             split_feat = split_feat.at[idx].set(jnp.where(can, feat, -1))
             split_bin = split_bin.at[idx].set(bin_)
             na_left = na_left.at[idx].set(na_l)
@@ -434,8 +528,7 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
             b = bin_[safe_rel]
             nl = na_l[safe_rel]
             rowbin = row_orig_bins(binned, f, efb)
-            is_na = rowbin == p.n_bins - 1
-            go_right = jnp.where(is_na, ~nl, rowbin > b)
+            go_right = _goes_right(rowbin, b, nl, p.n_bins, lb, safe_rel)
             child = 2 * rel + go_right.astype(jnp.int32)  # rel at d+1
             moved = live & can[safe_rel]
             rel = jnp.where(moved, child, -1)
@@ -443,7 +536,7 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
                                  abs_node)
 
     return Tree(split_feat, split_bin, na_left, is_split, value, gain,
-                cover), abs_node
+                cover, left_bins), abs_node
 
 
 def grow_tree(binned, g, h, w, p: TreeParams, col_mask=None, key=None,
@@ -1020,7 +1113,8 @@ def descend_tree(tree: Tree, binned, max_depth: int, n_bins: int,
                  efb=None):
     """Per-row resting heap node by iterative descent (jittable) — the
     ONE implementation of split semantics at scoring time (NA bin
-    routing via na_left, `bin > split_bin` goes right). With ``efb``
+    routing via na_left, `bin > split_bin` goes right; a tree with
+    set splits by its `left_bins`: `_goes_right`). With ``efb``
     the binned matrix is in BUNDLED column space and per-row bins
     decode through the shared row_orig_bins (a select over the
     columns, then the bundle's LUTs)."""
@@ -1031,8 +1125,8 @@ def descend_tree(tree: Tree, binned, max_depth: int, n_bins: int,
         nl = tree.na_left[node]
         sp = tree.is_split[node]
         rowbin = row_orig_bins(binned, jnp.maximum(f, 0), efb)
-        is_na = rowbin == n_bins - 1
-        go_right = jnp.where(is_na, ~nl, rowbin > b)
+        go_right = _goes_right(rowbin, b, nl, n_bins, tree.left_bins,
+                               node)
         child = 2 * node + 1 + go_right.astype(jnp.int32)
         node = jnp.where(sp, child, node)
     return node
@@ -1126,6 +1220,7 @@ def flatten_trees(trees: Tree, edges_matrix: np.ndarray,
     NA routing stays explicit via na_left (callers canonicalize
     negative enum codes to NaN before descending — apply_bins sends
     those to the NA bin)."""
+    require_ordinal(trees, "The flat scorer (flatten_trees / flat_margin)")
     sf = np.asarray(trees.split_feat)
     sb = np.asarray(trees.split_bin)
     nl = np.asarray(trees.na_left).astype(bool)

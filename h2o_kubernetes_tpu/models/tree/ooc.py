@@ -307,7 +307,9 @@ def _splits_with_mask(hist, col_key, p: TreeParams, d: int, efb=None):
         r = jnp.where(feat_ok, r, jnp.inf)
         kth = jnp.sort(r, axis=1)[:, p.mtries - 1: p.mtries]
         feat_ok = feat_ok & (r <= kth)
-    return _find_splits(hist, p, feat_ok, efb)
+    # the stream takes no set splits (BoostPlan.validate refuses them
+    # before it engages): the finder's tenth value, `left_bins`, is None
+    return _find_splits(hist, p, feat_ok, efb)[:9]
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
@@ -557,7 +559,7 @@ def _goss_round_chunked(chunks: BinnedChunks, gs, hs, wts, kg, col_key,
     scaled = (tree.value
               * np.float32(bp.learn_rate)).astype(np.float32)
     tree = tree._replace(value=scaled)
-    tree_dev = Tree(*(jnp.asarray(x) for x in tree))
+    tree_dev = jax.tree.map(jnp.asarray, tree)
     for ci, bc in enumerate(_stream(chunks, mesh)):
         chunks.margin[ci] = _chunk_goss_margin_jit(
             bc, chunks.margin[ci], tree_dev, p, efb)

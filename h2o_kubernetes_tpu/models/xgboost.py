@@ -35,8 +35,8 @@ from .. import metrics as M
 from ..frame import Frame
 from ..runtime.health import require_healthy
 from .base import resolve_xy
-from .gbm import GBM, GBMModel, _stacked_varimp
-from .tree.binning import fit_bins
+from .gbm import GBM, GBMModel, _stacked_varimp, refuse_set_splits
+from .tree.binning import fit_bins, resolve_encoding, set_features
 from .tree.core import TreeParams
 
 _OBJECTIVE_ALIASES = {
@@ -315,6 +315,9 @@ class XGBoost(GBM):
         gfull[frame.nrows:] = top + np.arange(padded - frame.nrows)
         layout = _GroupLayout(gfull, padded)
 
+        if resolve_encoding(p.categorical_encoding) == "enum" and \
+                set_features(frame, data.feature_names, p.nbins_cats):
+            refuse_set_splits(xgboost=True)
         bin_spec = fit_bins(frame, data.feature_names, n_bins=p.nbins)
         binned = frame.binned(bin_spec)
 

@@ -76,6 +76,9 @@ def export_mojo(model, path) -> str:
     }
     arrays: dict[str, np.ndarray] = {}
     if algo in ("gbm", "drf", "xgboost"):
+        from .models.tree.core import require_ordinal
+
+        require_ordinal(model.trees, "MOJO export")
         meta["max_depth"] = model.params.max_depth
         meta["nbins"] = model.params.nbins
         meta["drf_mode"] = bool(model.params._drf_mode)

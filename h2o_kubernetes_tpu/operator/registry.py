@@ -307,6 +307,11 @@ class ModelRegistry:
                 f"cannot publish algo '{getattr(model, 'algo', '?')}' "
                 f"to a scorer pool (supported: "
                 f"{', '.join(SERVABLE_ALGOS)})")
+        if getattr(model, "trees", None) is not None:
+            from ..models.tree.core import require_ordinal
+
+            require_ordinal(model.trees, "The registry's scorer "
+                            "(ModelRegistry.publish)")
         if hasattr(model, "export_artifact"):
             # re-publishing a loaded FlatTreeScorer (replica-to-replica
             # promotion): it has no heap trees for export_mojo to walk,

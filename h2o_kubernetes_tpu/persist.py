@@ -284,6 +284,9 @@ def load_model(path: str):
         # detects the all-NaN cover and asks for a re-train
         model.trees = trees._replace(
             cover=np.full_like(np.asarray(trees.value), np.nan))
+    # a Tree pickled before it had `left_bins` (seven fields) loads
+    # with the field's default, None: no set splits, which is what
+    # every such model has (tests/test_set_splits.py)
     return model
 
 

@@ -198,7 +198,7 @@ def test_amplified_gain_unbiasedness_exact(mesh8, monkeypatch):
         np.add.at(hist[0, f], binned[:, f],
                   np.stack([g * w_amp, w_amp, w_amp], axis=1))
     tp = _make_tree_params(m.params, "gaussian")
-    feat, bin_, _, can, _, gain, cover, _, _ = C._find_splits(
+    feat, bin_, _, can, _, gain, cover, _, _, _ = C._find_splits(
         jnp.asarray(hist), tp)
     assert bool(can[0])
     assert int(m.trees.split_feat[0, 0]) == int(feat[0])
