@@ -377,10 +377,12 @@ class BoostPlan(NamedTuple):
             out.append((_init_margin, (row_s, row_s, row_s,
                                        self.distribution, self.K)))
         # the first dispatch takes the margin as `_initial_margin`
-        # makes it — row-sharded for one output (made off `data.y`),
+        # makes it — row-sharded for one output (made off `data.y`; a
+        # forest's are the zeros its sum of leaf values starts from),
         # `_init_margin`'s replicated broadcast for K classes, an
-        # uncommitted jnp.zeros (no sharding) for a K-class forest —
-        # and every later one the row-sharded output of the one before
+        # uncommitted jnp.zeros (no sharding) where a K-class forest's
+        # sums start — and every later one the row-sharded output of
+        # the one before
         first = rows if self.K == 1 else None if self.bp.drf_mode \
             else replicated(self.mesh)
         keydt = jax.eval_shape(lambda: jax.random.key(0)).dtype
@@ -497,6 +499,17 @@ def _stack_predict(trees: Tree, binned, max_depth: int, n_bins: int):
     init = jnp.zeros(binned.shape[0], dtype=jnp.float32)
     total, _ = lax.scan(body, init, trees)
     return total
+
+
+def _leaf_sums(trees: Tree, binned, K: int, max_depth: int, n_bins: int):
+    """`_stack_predict` of an ensemble of K class trees a round: [rows],
+    or [rows, K] with the interleaved trees (class fastest) taken apart
+    by class. What a boost scan's carry holds less the prior."""
+    if K == 1:
+        return _stack_predict(trees, binned, max_depth, n_bins)
+    return jnp.stack(
+        [_stack_predict(jax.tree.map(lambda a: a[k::K], trees), binned,
+                        max_depth, n_bins) for k in range(K)], axis=1)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -680,31 +693,33 @@ class GBMModel(Model):
 
     def _margins_of_binned(self, binned: jax.Array,
                            offset: jax.Array | None = None) -> jax.Array:
-        """`_margins_binned` from the bin codes themselves. Its program
-        depends on the ensemble's dense shape [T, N] alone, where the
-        flat scorer's width is the model's own (its reachable nodes):
-        training reads a forest's train metric through this one, from
-        the binned matrix it already holds, so that a new forest does
-        not compile a new scorer."""
+        """`_margins_binned` from the bin codes themselves: every tree's
+        heap walked over them. Its program depends on the ensemble's
+        dense shape [T, N] alone, where the flat scorer's width is the
+        model's own (its reachable nodes). Training needs it only where
+        a job holds no sum of its own (a checkpoint's trees at a
+        restart): a forest's train metric is `_margins_of_sums` of what
+        its scan carried."""
         K = self.nclasses if self.nclasses > 2 else 1
-        p = self.params
-        n_bins = self.bin_spec.n_bins
+        return self._margins_of_sums(
+            _leaf_sums(self.trees, binned, K, self.params.max_depth,
+                       self.bin_spec.n_bins), offset)
+
+    def _margins_of_sums(self, sums: jax.Array,
+                         offset: jax.Array | None = None) -> jax.Array:
+        """Margins from the sum of the trees' leaf values a row ([rows],
+        or [rows, K]): a forest's mean over its trees a class, a
+        boosted ensemble's prior, offset and scale. THE arithmetic
+        between a sum and a margin, for the walk (`_stack_predict`) and
+        for the sum a forest's scan carried."""
+        K = self.nclasses if self.nclasses > 2 else 1
+        if self.params._drf_mode:
+            sums = sums / (self.ntrees // K)
         if K == 1:
-            m = _stack_predict(self.trees, binned, p.max_depth, n_bins)
-            if p._drf_mode:
-                m = m / self.ntrees
             base = self.init_score if offset is None \
                 else self.init_score + offset
-            return base + getattr(self, "margin_scale", 1.0) * m
-        # multinomial: trees interleaved [T*K]; de-interleave per class
-        outs = []
-        for k in range(K):
-            tk = jax.tree.map(lambda a: a[k::K], self.trees)
-            mk = _stack_predict(tk, binned, p.max_depth, n_bins)
-            if p._drf_mode:
-                mk = mk / (self.ntrees // K)
-            outs.append(self.init_score[k] + mk)
-        return jnp.stack(outs, axis=1)
+            return base + getattr(self, "margin_scale", 1.0) * sums
+        return jnp.asarray(self.init_score)[None, :] + sums
 
     def _score_matrix(self, X: jax.Array,
                       offset: jax.Array | None = None) -> jax.Array:
@@ -1081,17 +1096,21 @@ class GBM:
                     max_depth=p.max_depth, classes=plan.K)
             model._varimp = _stacked_varimp(model.trees, data.feature_names)
             _count_splits(model.trees, set_feats)
-        with phase_span("train.metric", kind="wait"):
+        # which way the metric is read: off the boosting margin, off
+        # the sum of leaf values a forest's scan carried (every tree
+        # over every row, bitwise what `_margins_of_binned` walks
+        # to), or by scoring the frame where a forest holds no such
+        # sum (none today: `ooc_chunk` keeps every forest in HBM)
+        source = "margin" if not p._drf_mode \
+            else "walk" if binned is None else "carried"
+        with phase_span("train.metric", kind="wait", source=source):
             if p._drf_mode:
-                if binned is not None and efb is None:
-                    # the forest over the binned matrix training holds
-                    # (bitwise what scoring the frame gives)
-                    perf = model.performance_of(
-                        training_frame, y,
-                        np.asarray(model._response(
-                            model._margins_of_binned(binned))))
-                else:
+                if source == "walk":
                     perf = model.model_performance(training_frame, y)
+                else:
+                    perf = model.performance_of(
+                        training_frame, y, np.asarray(model._response(
+                            model._margins_of_sums(margin))))
                 history.append({"ntrees": p.ntrees,
                                 **{f"train_{k}": v for k, v in perf.items()}})
             elif not (history and history[-1].get("ntrees") == p.ntrees):
@@ -1303,9 +1322,9 @@ def _check_checkpoint(ckpt, p: GBMParams, data: TrainData, offset_column,
 
 def _initial_margin(plan: BoostPlan, data: TrainData, ckpt, binned):
     """(init score, starting margin, margin_scale, data) of a job: a
-    checkpoint's trees scored over the binned matrix, a forest's zeros,
-    laplace's robust scaling (which rescales ``data.y``), or the prior
-    on the device."""
+    checkpoint's trees scored over the binned matrix, a fresh forest's
+    zeros, laplace's robust scaling (which rescales ``data.y``), or the
+    prior on the device."""
     p, K = plan.p, plan.K
     margin_scale = 1.0
     off = data.offset if data.offset is not None \
@@ -1313,17 +1332,13 @@ def _initial_margin(plan: BoostPlan, data: TrainData, ckpt, binned):
     laplace = data.distribution == "laplace"
     if ckpt is not None:
         init = ckpt.init_score
-        if p._drf_mode:
-            margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
-                else jnp.zeros_like(data.y)
-        elif K == 1:
-            margin = init + off + _stack_predict(
-                ckpt.trees, binned, p.max_depth, plan.tp.n_bins)
-        else:
-            outs = [init[k] + _stack_predict(
-                jax.tree.map(lambda a: a[k::K], ckpt.trees),
-                binned, p.max_depth, plan.tp.n_bins) for k in range(K)]
-            margin = jnp.stack(outs, axis=1)
+        # the scan's carry goes on from the checkpoint's trees, walked
+        # once: a forest's sum of leaf values, a boosted margin
+        margin = _leaf_sums(ckpt.trees, binned, K, p.max_depth,
+                            plan.tp.n_bins)
+        if not p._drf_mode:
+            margin = init + off + margin if K == 1 \
+                else jnp.asarray(init)[None, :] + margin
         if laplace:
             # continuation must reuse the checkpoint's robust scaling or
             # the new trees' leaf units would not compose; the working

@@ -856,7 +856,7 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, *,
     single compiled program — the margin never leaves the device and
     the host dispatches once per chunk of trees instead of ≥3 times per
     tree. A single-output forest has a scan of its own
-    (``_boost_shard_drf``: no margin to carry).
+    (``_boost_shard_drf``: no gradients to take off the margin).
     """
     assert not bp.drf_mode, "a forest grows in _boost_shard_drf"
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
@@ -938,6 +938,9 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
     [rows, K] and never leaves the device; one dispatch covers a whole
     chunk of boosting rounds. Reference: hex/tree/gbm/GBM.java grows the K class
     trees of an iteration from shared softmax probs (SURVEY.md §3.4).
+    A K-class forest (``bp.drf_mode``) grows here too: its gradients
+    are the class indicators, and the margin it carries is the sum of
+    its trees' leaf values a class, as ``_boost_shard_drf``'s.
     """
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     goss = bp.goss_b > 0.0
@@ -993,16 +996,16 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
             trees, leaf = lax.map(lambda a: grow_one(*a),
                                   (gC, hC, keys_k))
         with jax.named_scope("margin"):
+            # a K-class forest carries its sums here too (learn_rate
+            # 1; its gradients above never read the margin)
             trees = trees._replace(value=bp.learn_rate * trees.value)
-            if not bp.drf_mode:
-                if goss:
-                    # sampled grow → full-row leaf values by re-descent
-                    upd = jax.vmap(lambda tr: tr.value[descend_tree(
-                        tr, binned, p.max_depth, p.n_bins, efb)])(trees)
-                else:
-                    upd = jax.vmap(lambda v, lf: v[lf])(trees.value,
-                                                        leaf)
-                margin = margin + upd.T
+            if goss:
+                # sampled grow → full-row leaf values by re-descent
+                upd = jax.vmap(lambda tr: tr.value[descend_tree(
+                    tr, binned, p.max_depth, p.n_bins, efb)])(trees)
+            else:
+                upd = jax.vmap(lambda v, lf: v[lf])(trees.value, leaf)
+            margin = margin + upd.T
         if goss:
             return margin, (trees, lax.psum(dropped, ROWS))
         return margin, trees
@@ -1016,35 +1019,43 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
 
 def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
                      p: TreeParams, bp: BoostParams):
-    """Forest growth: the trees are INDEPENDENT (no margin coupling),
-    one a scan step, each on the bag and the candidate features its
-    own key draws. Growing several a step under vmap was measured on a
-    v5e and never won (PERF.md §6, PR 28: a tie while every merged
-    level stays within one hi block of the histogram kernel, a loss
-    once one reached the bin-blocked kernel of the time, at G times
-    the temporaries), so there is one path. keys: [n_trees]."""
+    """Forest growth: the trees are INDEPENDENT (their gradients never
+    read the carry), one a scan step, each on the bag and the candidate
+    features its own key draws. Growing several a step under vmap was
+    measured on a v5e and never won (PERF.md §6, PR 28: a tie while
+    every merged level stays within one hi block of the histogram
+    kernel, a loss once one reached the bin-blocked kernel of the
+    time, at G times the temporaries), so there is one path.
+
+    The scan carries what ``_boost_shard`` carries: ``margin`` plus the
+    leaf value of every tree so far, for EVERY row — the bag is a
+    weight, and a row of weight 0 descends with the rest — so the
+    forest's train metric is read off it (``learn_rate`` is 1) and no
+    tree is walked again. keys: [n_trees]."""
     assert bp.drf_mode
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     g0 = -y
     h0 = jnp.ones_like(y)
 
-    def body(carry, kt):
+    def body(margin, kt):
         k_row, k_col, k_tree = jax.random.split(kt, 3)
         with jax.named_scope("sample"):
             w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
-        tree, _ = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
-                                   k_tree, p, efb)
-        return carry, tree
+        tree, leaf = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
+                                      k_tree, p, efb)
+        with jax.named_scope("margin"):
+            margin = margin + tree.value[leaf]
+        return margin, tree
 
-    _, trees = lax.scan(body, 0, keys)
-    return margin, trees
+    return lax.scan(body, margin, keys)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8))
 def _boost_drf_jit(binned, y, w, margin, keys, efb, p: TreeParams,
                    bp: BoostParams, mesh):
     """A forest's trees in ONE dispatch, one a scan step, each from its
-    own key of ``keys`` → (margin unchanged, trees [T, N])."""
+    own key of ``keys`` → (margin + the sum of the trees' leaf values a
+    row, trees [T, N])."""
     fn = jax.shard_map(
         functools.partial(_boost_shard_drf, p=p, bp=bp),
         mesh=mesh,

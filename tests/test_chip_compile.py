@@ -197,7 +197,9 @@ def test_forest_scan_holds_one_trees_temporaries(topo, depth, kernels):
     bins the deepest histogram level (1,024 left children) is past what
     one block of hi slots holds and the kernel serves it in two, under
     the name `hist_blocked`: the one path of `drf-higgs.train` that no
-    other cell runs."""
+    other cell runs. The scan carries the sum of its trees' leaf values
+    (PR 33: the forest's train metric is read off it), so the `margin`
+    scope names operations of this program too."""
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
     args = _boost_args(mesh, ROWS_N, ntrees=1)
     tp = args[6]._replace(max_depth=depth, n_bins=64, min_rows=1.0,
@@ -209,6 +211,7 @@ def test_forest_scan_holds_one_trees_temporaries(topo, depth, kernels):
         a = _boost_args(mesh, ROWS_N, ntrees)
         c = core._boost_drf_jit.lower(*a[:6], tp, bp, mesh).compile()
         assert _kernels(c) == depth and _kernel_names(c) == kernels
+        assert "margin" in _scopes(c.as_text())
         temp[ntrees] = c.memory_analysis().temp_size_in_bytes
     # the trees stacked for the way out are the difference
     assert temp[6] < 1.5 * temp[1]

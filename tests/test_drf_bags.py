@@ -8,7 +8,9 @@ program) follows the system's trees over those bags: covers exactly,
 values and gains to float32 rounding, every split the best among its
 candidates. A forest grows one tree a scan step, and `BoostPlan.chunks` is
 the one sizing of its dispatches, which `train()` and compile-ahead
-share with boosted trees.
+share with boosted trees. Its scan carries the sum of its trees' leaf
+values, every row's, and the train metric is read off that sum: bitwise
+what walking every tree again gives (PR 33).
 """
 
 import os
@@ -248,8 +250,8 @@ def test_the_forests_the_old_sizing_refused():
 
 
 def test_the_boosting_scan_refuses_a_forest(mesh8):
-    """A single-output forest grows in `_boost_drf_jit` (no margin to
-    carry through the scan). `_boost_shard` kept `drf_mode` branches
+    """A single-output forest grows in `_boost_drf_jit` (no gradients
+    to take off the margin). `_boost_shard` kept `drf_mode` branches
     that nothing reached; they are gone, and the boosting program
     refuses a forest's parameters when it is traced."""
     _, _, cols = _table(seed=3, rows=256)
@@ -323,3 +325,196 @@ def test_a_multinomial_rounds_class_trees_share_one_bag(mesh8):
         assert cand[np.flatnonzero(split), feat[split]].all()
     assert (bags[0] != bags[3]).any()
     assert (m.tree_candidates(0) != m.tree_candidates(1)).any()
+
+
+# ---------------------------------------------------------------------------
+# The forest's train metric off the leaves the grower found (PR 33)
+# ---------------------------------------------------------------------------
+
+def _response_table(kind, rows=1500, na=False, seed=4):
+    """A frame's columns with a two-class, numeric or three-class
+    response; ``na`` blanks a twentieth of every feature."""
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((rows, F)).astype(np.float32)
+    score = X[:, 0] + X[:, 1] * X[:, 2] + 0.3 * rng.standard_normal(rows)
+    if na:
+        X[rng.random(X.shape) < 0.05] = np.nan
+    cols = {f"f{j}": X[:, j] for j in range(F)}
+    if kind == "binomial":
+        cols["y"] = np.where(score > 0, "s", "b")
+    elif kind == "multinomial":
+        cols["y"] = np.array(["a", "b", "c"])[np.digitize(score,
+                                                          [-0.5, 0.5])]
+    else:
+        cols["y"] = score.astype(np.float32)
+    return cols
+
+
+def _walked_metric(m, fr):
+    """(binned matrix, the train metric by the walk): every tree of the
+    finished model descended again over every row."""
+    binned = fr.binned(m.bin_spec)
+    raw = np.asarray(m._response(m._margins_of_binned(binned)))
+    return binned, {f"train_{k}": v
+                    for k, v in m.performance_of(fr, "y", raw).items()}
+
+
+def _metric_span():
+    """The newest job's `train.metric` span."""
+    (span,) = [s for s in TRACER.by_root("train")[-1]["spans"]
+               if s["name"] == "train.metric"]
+    return span
+
+
+def _grown_again(m, fr, binned):
+    """(trees, leaf [T, rows], bag weight [rounds, rows]) of the model's
+    forest grown again from the keys it kept, round by round as the
+    scans' bodies grow it (`_boost_shard_drf`; K class trees a round
+    from one bag as `_boost_shard_multi`), keeping what the grower
+    returns beside a tree: every row's resting heap node."""
+    from jax import lax
+
+    from h2o_kubernetes_tpu.models.base import resolve_xy
+    from h2o_kubernetes_tpu.runtime.mesh import global_mesh
+
+    data = resolve_xy(fr, "y", materialize_x=False)
+    plan = gbm_mod.boost_plan(m.params, data.distribution, data.nclasses,
+                              F)
+    tp, bp, K = plan.tp, plan.bp, plan.K
+    keys = jax.random.wrap_key_data(
+        jnp.asarray(m.tree_draws.keys, jnp.uint32))
+
+    def shard(binned, y, w, keys):
+        def body(_, kt):
+            k_row, k_col, k_tree = jax.random.split(kt, 3)
+            w_t, col_mask = core._round_sampling(bp, w, F, k_row, k_col)
+            if K == 1:
+                tree, leaf = core._grow_tree_shard(
+                    binned, -y, jnp.ones_like(y), w_t, col_mask, k_tree, tp)
+                return 0, (jax.tree.map(lambda a: a[None], tree),
+                           leaf[None], w_t)
+            g = -(y[:, None] == jnp.arange(K, dtype=y.dtype)[None, :]
+                  ).astype(jnp.float32).T
+            trees, leaf = jax.vmap(lambda gk, kk: core._grow_tree_shard(
+                binned, gk, jnp.ones_like(gk), w_t, col_mask, kk, tp))(
+                    g, jax.random.split(k_tree, K))
+            return 0, (trees, leaf, w_t)
+
+        _, (trees, leaf, w_t) = lax.scan(body, 0, keys)
+        # [rounds, K, ...] -> [rounds * K, ...], class fastest
+        flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731
+        return jax.tree.map(flat, trees), flat(leaf), w_t
+
+    return jax.jit(jax.shard_map(
+        shard, mesh=global_mesh(),
+        in_specs=(P(ROWS), P(ROWS), P(ROWS), P()),
+        out_specs=(P(), P(None, ROWS), P(None, ROWS)),
+        check_vma=False))(binned, data.y, data.w, keys)
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("sampling", ["bagged", "every_row"])
+@pytest.mark.parametrize("kind,extra", [
+    pytest.param("binomial", {}, id="binomial"),
+    pytest.param("regression", {}, id="regression"),
+    pytest.param("multinomial", {}, id="multinomial"),
+    # nodes that stop above the last level: rows rest at inner nodes
+    pytest.param("binomial", {"min_rows": 120.0}, id="binomial-early_stop"),
+    # rows at the NA bin, routed by `na_left`
+    pytest.param("binomial", {"na": True}, id="binomial-na_bins"),
+])
+def test_the_train_metric_is_read_off_the_leaves_the_grower_found(
+        kind, extra, sampling, devices):
+    """(a) the grower's resting node of every row of every tree is where
+    `descend_tree` walks that row to — rows out of the bag (weight 0)
+    and the padding included; (b) so the metric read off the sum the
+    scan carried is BITWISE the one the walk gives."""
+    extra = dict(extra)
+    cols = _response_table(kind, na=extra.pop("na", False))
+    kw = {"bagged": dict(sample_rate=0.632, mtries=-1),
+          "every_row": dict(sample_rate=1.0)}[sampling]
+    with _on(devices):
+        fr = h2o.Frame.from_arrays(cols)
+        m = DRF(ntrees=3, max_depth=4, nbins=NBINS, seed=7, **kw,
+                **extra).train(y="y", training_frame=fr)
+        assert _metric_span()["source"] == "carried"
+        binned, walked = _walked_metric(m, fr)
+        assert m.scoring_history[-1] == {"ntrees": 3, **walked}      # (b)
+        trees, leaf, w_t = _grown_again(m, fr, binned)
+        walked_to = gbm_mod._stack_leaf_nodes(m.trees, binned, 4, NBINS)
+    for a, b in zip(trees[:-1], m.trees[:-1]):     # the same forest
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    leaf, w_t = np.asarray(leaf), np.asarray(w_t)
+    np.testing.assert_array_equal(leaf, np.asarray(walked_to))       # (a)
+    out_of_bag = (w_t == 0)[:, : fr.nrows].mean()
+    if sampling == "bagged":
+        assert 0.3 < out_of_bag < 0.45
+    else:
+        assert out_of_bag == 0
+    if devices == 8:
+        assert leaf.shape[1] > fr.nrows            # padding rows descend too
+    if "min_rows" in extra:
+        # some rows rest above the last level (heap nodes under 15)
+        assert (leaf < 2 ** 4 - 1).any() and (leaf >= 2 ** 4 - 1).any()
+
+
+def test_a_scan_goes_on_from_the_sum_it_is_given(mesh8):
+    """Two trees and then two more, the second dispatch starting from
+    the first's sum or from a walk of the first's trees (what a
+    restart does), carry what four trees at once carry: the same sums
+    in the same order, bitwise `_stack_predict` of the four."""
+    from h2o_kubernetes_tpu.models.base import resolve_xy
+    from h2o_kubernetes_tpu.models.tree.binning import fused_fit_bins
+
+    fr = h2o.Frame.from_arrays(_response_table("binomial", rows=1000))
+    plan = gbm_mod.boost_plan(
+        DRF(ntrees=4, max_depth=4, nbins=NBINS, mtries=2).params,
+        "bernoulli", 2, F)
+    data = resolve_xy(fr, "y", materialize_x=False)
+    _, binned = fused_fit_bins(fr, data.feature_names, NBINS)
+    keys = core.round_keys(jax.random.key(2), 4)
+
+    def grow(margin, keys):
+        return core._boost_drf_jit(*plan.operands(
+            binned, data.y, data.w, margin, keys, None))
+
+    zeros = jnp.zeros_like(data.y)
+    at_once, trees = grow(zeros, keys)
+    first, two = grow(zeros, keys[:2])
+    walked = gbm_mod._stack_predict(two, binned, 4, NBINS)
+    for start in (first, walked):
+        after, more = grow(start, keys[2:])
+        np.testing.assert_array_equal(np.asarray(after),
+                                      np.asarray(at_once))
+        np.testing.assert_array_equal(np.asarray(more.value),
+                                      np.asarray(trees.value)[2:])
+    np.testing.assert_array_equal(
+        np.asarray(at_once),
+        np.asarray(gbm_mod._stack_predict(trees, binned, 4, NBINS)))
+    assert np.asarray(at_once).any()
+
+
+@pytest.mark.parametrize("kind", ["binomial", "multinomial"])
+def test_a_continued_forests_metric_covers_all_its_trees(mesh8, kind):
+    """A forest continued from a checkpoint starts its carry at a walk
+    of the checkpoint's trees, so its metric row is the whole forest's:
+    bitwise the walk over all four trees, and read off the carry."""
+    fr = h2o.Frame.from_arrays(_response_table(kind, rows=1200))
+    kw = dict(max_depth=4, nbins=NBINS)
+    # another seed for the continuation: with the first job's it draws
+    # the first job's keys again (`test_checkpoint_carries_the_keys_on`
+    # pins only the kept ones), and a forest's trees hang on their keys
+    # alone (ROADMAP.md Queue 3)
+    first = DRF(ntrees=2, seed=5, **kw).train(y="y", training_frame=fr)
+    more = DRF(ntrees=4, seed=6, checkpoint=first, **kw).train(
+        y="y", training_frame=fr)
+    assert _metric_span()["source"] == "carried"
+    K = 3 if kind == "multinomial" else 1
+    assert more.ntrees == 4 * K
+    np.testing.assert_array_equal(np.asarray(more.trees.value)[:2 * K],
+                                  np.asarray(first.trees.value))
+    _, walked = _walked_metric(more, fr)
+    assert more.scoring_history[-1] == {"ntrees": 4, **walked}
+    # and not the last two trees' alone
+    _, of_first = _walked_metric(first, fr)
+    assert of_first["train_logloss"] != walked["train_logloss"]

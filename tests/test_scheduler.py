@@ -305,7 +305,8 @@ class TestCompileAhead:
         """(estimator, frame, row of `_BOOST_PROGRAMS`) of a small job
         in one mode: bernoulli GBM, single-output DRF with `mtries`,
         three-class GBM, three-class DRF; three dispatches of two
-        sizes, 2 + 2 + 1 trees."""
+        sizes, 2 + 2 + 1 trees (`forest_restart`: the last three of
+        five trees, on a checkpoint of the first two)."""
         from h2o_kubernetes_tpu.models import DRF, GBM
         from h2o_kubernetes_tpu.models import gbm as gbm_mod
 
@@ -320,9 +321,13 @@ class TestCompileAhead:
                 np.digitize(score, [-0.5, 0.5])]
         else:
             cols["y"] = np.where(score > 0, "p", "n")
-        est = DRF(ntrees=5, max_depth=3, nbins=16, mtries=2, seed=1) \
-            if "forest" in case else GBM(ntrees=5, max_depth=3, seed=1)
         fr = h2o.Frame.from_arrays(cols)
+        kw = dict(max_depth=3, nbins=16, mtries=2, seed=1)
+        if case == "forest_restart":    # two trees, then three more
+            kw["checkpoint"] = DRF(ntrees=2, **kw).train(
+                y="y", training_frame=fr)
+        est = DRF(ntrees=5, **kw) \
+            if "forest" in case else GBM(ntrees=5, max_depth=3, seed=1)
         monkeypatch.setattr(gbm_mod, "_DISPATCH_BUDGET",
                             2 * n * 5 * est.params.nbins * 2 ** 3
                             * (3 if mode == "multi" else 1))
@@ -401,16 +406,18 @@ class TestCompileAhead:
             assert any(same(call, low) for call in sent), low
 
     @pytest.mark.parametrize("case", ["single", "forest", "multi",
-                                      "multi_forest"])
+                                      "multi_forest", "forest_restart"])
     def test_no_program_gathers_from_the_binned_matrix(
             self, mesh8, monkeypatch, case):
         """Row descent selects each row's split column while the binned
         matrix streams (`core.row_orig_bins`, PR 31): no program
         `train()` dispatches over the binned matrix — the boost program
-        of each mode, and `_stack_predict`, the heap walk a forest's
-        train metric is read through — lowers to a `gather` whose
-        operand is the `[rows, F]` `uint8` matrix. On the chip each such
-        gather cost ~500 whole reads of what it indexed."""
+        of each mode, and `_stack_predict`, the heap walk a job
+        continued from a checkpoint starts its carry with — lowers to a
+        `gather` whose operand is the `[rows, F]` `uint8` matrix. On
+        the chip each such gather cost ~500 whole reads of what it
+        indexed. A fresh forest sends its boost program alone: its
+        train metric is read off what the scan carried (PR 33)."""
         import re
 
         from h2o_kubernetes_tpu.models import gbm as gbm_mod
@@ -426,9 +433,10 @@ class TestCompileAhead:
         programs = {fn.__name__: (fn, a) for fn, a in sent}
         assert set(programs) == {
             "single": {"_boost_jit"},
-            "forest": {"_boost_drf_jit", "_stack_predict"},
+            "forest": {"_boost_drf_jit"},
             "multi": {"_boost_multi_jit"},
-            "multi_forest": {"_boost_multi_jit", "_stack_predict"}}[case]
+            "multi_forest": {"_boost_multi_jit"},
+            "forest_restart": {"_boost_drf_jit", "_stack_predict"}}[case]
         # `"stablehlo.gather"(%binned, %idx) ... : (tensor<125x5xui8>,`
         from_binned = re.compile(
             r"stablehlo\.gather[^\n]*: \(tensor<\d+x\d+xui8>")

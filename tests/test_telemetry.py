@@ -504,6 +504,33 @@ def test_train_leaves_one_span_tree(stats_server):
     assert fr["spans"][0]["t1_ns"] <= root["t0_ns"]
 
 
+@pytest.mark.parametrize("job,source", [
+    ("boosted", "margin"), ("forest", "carried"), ("out_of_core", "margin")])
+def test_train_metric_says_how_it_was_read(mesh8, monkeypatch, job, source):
+    """`train.metric`'s `source`: a boosted job reads its train metric
+    off the margin (streamed out of core too), a forest off the sum of
+    leaf values its scan carried; the third value, `walk`, is left for
+    a forest that holds no such sum (no plan makes one today)."""
+    from h2o_kubernetes_tpu.models import DRF
+
+    rng = np.random.default_rng(3)
+    cols = {f"x{i}": rng.normal(size=600).astype(np.float32)
+            for i in range(4)}
+    cols["y"] = np.where(cols["x0"] - cols["x1"] > 0, "late", "ontime")
+    fr = h2o.Frame.from_arrays(cols)
+    if job == "out_of_core":
+        monkeypatch.setenv("H2O_TPU_OOC", "1")
+        monkeypatch.setenv("H2O_TPU_OOC_CHUNK_ROWS", "256")
+    est = DRF(ntrees=2, max_depth=3, nbins=16, seed=1) if job == "forest" \
+        else GBM(ntrees=2, max_depth=2, seed=1)
+    est.train(y="y", training_frame=fr)
+    spans = telemetry.TRACER.by_root("train")[-1]["spans"]
+    boost = next(s for s in spans if s["name"] == "train.boost")
+    assert boost["mode"] == ("ooc" if job == "out_of_core" else "in_hbm")
+    (metric,) = [s for s in spans if s["name"] == "train.metric"]
+    assert metric["source"] == source
+
+
 def test_trace_off_leaves_no_spans_and_the_same_model(mesh8, monkeypatch):
     on = _train_tiny(seed=11)
     telemetry.TRACER.clear()
