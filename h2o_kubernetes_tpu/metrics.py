@@ -376,24 +376,23 @@ def ndcg(y_true, score, group, k: int = 10) -> float:
     y = np.asarray(y_true).ravel().astype(np.float64)
     s = np.asarray(score).ravel().astype(np.float64)
     g = np.asarray(group).ravel()
-    # one argsort by group, then contiguous slices — O(n log n), not O(n·G)
-    order = np.argsort(g, kind="stable")
-    y, s, g = y[order], s[order], g[order]
-    _, starts = np.unique(g, return_index=True)
-    bounds = np.append(starts, len(g))
-    total, n = 0.0, 0
-    for a, b in zip(bounds[:-1], bounds[1:]):
-        yy, ss = y[a:b], s[a:b]
-        kk = min(k, b - a)
-        disc = 1.0 / np.log2(np.arange(2, kk + 2))
-        top = np.argsort(-ss, kind="stable")[:kk]
-        dcg = ((2.0 ** yy[top] - 1.0) * disc).sum()
-        ideal = np.sort(2.0 ** yy - 1.0)[::-1]
-        idcg = (ideal[:kk] * disc).sum()
-        if idcg > 0:
-            total += dcg / idcg
-            n += 1
-    return total / max(n, 1)
+    if not len(g):
+        return 0.0
+    # every query at once: rows by query, then by score descending, ties
+    # in row order (lexsort is stable); a row's place in its query is
+    # its index less its query's first
+    by_score = np.lexsort((-s, g))
+    gs = g[by_score]
+    first = np.concatenate([[True], gs[1:] != gs[:-1]])
+    q = np.cumsum(first) - 1
+    at = np.arange(len(g)) - np.flatnonzero(first)[q]
+    disc = np.where(at < k, 1.0 / np.log2(at + 2.0), 0.0)
+    dcg = np.bincount(q, weights=(2.0 ** y[by_score] - 1.0) * disc)
+    # the ideal list has the same queries in the same places
+    ideal = np.bincount(
+        q, weights=(2.0 ** y[np.lexsort((-y, g))] - 1.0) * disc)
+    ok = ideal > 0
+    return float(np.sum(dcg[ok] / ideal[ok]) / max(int(ok.sum()), 1))
 
 
 def r2(y_true, pred) -> float:

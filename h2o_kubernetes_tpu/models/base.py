@@ -79,6 +79,10 @@ def resolve_response(frame: Frame, y: str, distribution: str = "auto"
     compile-ahead asks it what `resolve_xy` will answer."""
     yv = frame.vec(y)
     nclasses, domain = 1, None
+    if distribution.startswith("rank:"):
+        # graded relevance stored as an enum: its codes ARE the grades
+        # — one output, never the multinomial path
+        return distribution, 1, None
     if yv.is_enum():
         domain = yv.domain
         nclasses = yv.cardinality()

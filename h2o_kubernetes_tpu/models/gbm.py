@@ -43,6 +43,8 @@ from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
                         flatten_trees, goss_round_keys, level_hist_bytes,
                         multi_grow_vmapped, predict_tree, round_keys,
                         set_split_reason)
+from .tree.rank import (RankLayout, grouped, groups_abstract, ndcg_at,
+                        rank_layout)
 
 
 @dataclass
@@ -120,10 +122,11 @@ def goss_params(p: "GBMParams", distribution: str) -> tuple[float, float]:
     """(top_a, rand_b) of GOSS gradient-based one-side sampling
     (arXiv:1809.04559) — (0.0, 0.0) when off. THE one env reader:
     H2O_TPU_GOSS=1 activates it for the boosted-tree growers (GBM +
-    XGBoost-hist pointwise objectives); DRF stays bagged/unsampled and
-    the lambdarank host loop is excluded. Knobs are read at train
-    time, so AutoML plan entries and CV folds inherit them uniformly."""
-    if p._drf_mode or distribution.startswith("rank:"):
+    XGBoost-hist); DRF stays bagged/unsampled, and a grouped objective
+    under it is refused by name (`refuse_grouped`). Knobs are read at
+    train time, so AutoML plan entries and CV folds inherit them
+    uniformly."""
+    if p._drf_mode:
         return 0.0, 0.0
     if os.environ.get("H2O_TPU_GOSS", "0") != "1":
         return 0.0, 0.0
@@ -183,6 +186,31 @@ def refuse_set_splits(**facts) -> None:
                 "regression GBM in device memory")
 
 
+# What cannot carry a GROUPED objective yet (rank:pairwise / rank:ndcg:
+# a gradient that hangs on a row's query, `rank.grouped`), as
+# `_NO_SET_SPLITS_YET` above: the fact and the name the error gives it.
+_NO_GROUPED_YET = (
+    ("efb", "an EFB-bundled frame"),
+    ("goss", "GOSS (H2O_TPU_GOSS)"),
+    ("ooc", "the out-of-core path"),
+    ("checkpoint", "checkpoint restart"),
+    ("offset", "offset_column"),
+    ("cv", "cross-validation (its folds are not group-aware)"),
+)
+
+
+def refuse_grouped(distribution: str, **facts) -> None:
+    """THE one check of what trains a grouped objective: raise, naming
+    it, for the first true fact of `_NO_GROUPED_YET`."""
+    for fact, name in _NO_GROUPED_YET:
+        if facts.get(fact):
+            raise ValueError(
+                f"{distribution}: {name} cannot carry a grouped "
+                "objective yet (pairwise gradients over the rows of a "
+                "query); train it in device memory, unbundled, from "
+                "scratch, without an offset or cross-validation")
+
+
 # THE table of boosting modes: the jitted program that serves a job.
 # A device trace shows each as module `jit_<__name__>`;
 # telemetry.TRAIN_PROGRAMS["boost"] lists the same three names
@@ -209,6 +237,9 @@ class BoostPlan(NamedTuple):
     hist_bytes: int         # level histograms live at the deepest level
     budget: float           # H2O_TPU_HIST_BYTES_BUDGET, read once
     mesh: Any
+    # the query layout of a grouped objective (`rank.RankLayout`: the
+    # last operand of `_boost_jit`), None for a pointwise one
+    rank: RankLayout | None = None
 
     @property
     def mode(self) -> str:
@@ -216,6 +247,14 @@ class BoostPlan(NamedTuple):
         if self.K > 1:
             return "multi"      # a multinomial forest grows there too
         return "forest" if self.bp.drf_mode else "single"
+
+    @property
+    def grouped(self) -> bool:
+        """The gradients hang on a row's query (rank:*): the job takes
+        a query layout, skips the EFB planning pass whatever the
+        frame's width, and is refused by name wherever a layout cannot
+        be carried (`refuse_grouped`)."""
+        return grouped(self.distribution)
 
     @property
     def score_every(self) -> int:
@@ -226,15 +265,25 @@ class BoostPlan(NamedTuple):
     @property
     def device_init(self) -> bool:
         """A fresh job's prior and margin come from `_init_margin`: a
-        forest starts from zeros, laplace from the host's median."""
-        return not self.bp.drf_mode and self.distribution != "laplace"
+        forest and a ranker start from zeros, laplace from the host's
+        median."""
+        return not self.bp.drf_mode and not self.grouped \
+            and self.distribution != "laplace"
 
     def validate(self, algo: str = "gbm", ckpt=None, efb: bool = False,
-                 padded: int | None = None) -> None:
+                 padded: int | None = None, offset: bool = False,
+                 cv: bool = False) -> None:
         """Refuse, before the frame is binned, what cannot train. The
         arguments are what `_train` knows beside the plan, and matter
-        to a job with set splits alone (`refuse_set_splits`)."""
+        to a job with set splits (`refuse_set_splits`) or a grouped
+        objective (`refuse_grouped`) alone."""
         p = self.p
+        if self.grouped:
+            refuse_grouped(
+                self.distribution, efb=efb, goss=self.bp.goss_b > 0,
+                ooc=padded is not None
+                and self.ooc_chunk(padded, ckpt) is not None,
+                checkpoint=ckpt is not None, offset=offset, cv=cv)
         if any(self.tp.set_feats):
             refuse_set_splits(
                 xgboost=algo == "xgboost", drf=self.bp.drf_mode,
@@ -341,6 +390,8 @@ class BoostPlan(NamedTuple):
             keys = (keys, goss_keys)
         statics = (self.tp, self.bp, self.mesh) if self.K == 1 \
             else (self.tp, self.bp, self.K, self.mesh)
+        if self.rank is not None:
+            statics += (self.rank.groups,)
         return (binned, y, w, margin, keys, efb) + statics
 
     def dispatch(self, binned, y, w, margin, kc, n: int, efb=None,
@@ -396,17 +447,22 @@ class BoostPlan(NamedTuple):
                 (padded,) if self.K == 1 else (padded, self.K),
                 jnp.float32, sharding=msh)
             keys_s = jax.ShapeDtypeStruct((n,), keydt)
-            out.append((_BOOST_PROGRAMS[self.mode], self.operands(
-                binned_s, row_s, row_s, margin_s, keys_s, keys_s)))
+            args = self.operands(binned_s, row_s, row_s, margin_s,
+                                 keys_s, keys_s)
+            if self.rank is not None:
+                args = args[:-1] + (groups_abstract(args[-1]),)
+            out.append((_BOOST_PROGRAMS[self.mode], args))
         return out
 
 
 def boost_plan(p: "GBMParams", distribution: str, nclasses: int, F: int,
                mesh=None, n_bins: int | None = None,
-               set_feats: tuple = ()) -> BoostPlan:
+               set_feats: tuple = (), rank: RankLayout | None = None
+               ) -> BoostPlan:
     """The plan of ``p`` on a resolved response and ``F`` histogram
     columns; ``n_bins`` / ``set_feats`` as `_make_tree_params` takes
-    them. Bad GOSS knobs raise here (`goss_params`)."""
+    them, ``rank`` the query layout of a grouped objective. Bad GOSS
+    knobs raise here (`goss_params`)."""
     K = nclasses if nclasses > 2 else 1
     tp = _make_tree_params(p, distribution, n_bins, set_feats)
     hist_bytes = level_hist_bytes(tp, F)
@@ -418,7 +474,7 @@ def boost_plan(p: "GBMParams", distribution: str, nclasses: int, F: int,
     budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET", 2 ** 30))
     return BoostPlan(p, distribution, K, F, tp,
                      _make_boost_params(p, distribution), hist_bytes,
-                     budget, mesh or global_mesh())
+                     budget, mesh or global_mesh(), rank)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
@@ -469,14 +525,19 @@ def _init_margin(y, w, off, dist: str, K: int):
     return init, init + off
 
 
-def _margin_metrics(dist: str, margin, y, w, model=None) -> dict:
+def _margin_metrics(dist: str, margin, y, w, model=None,
+                    rank: RankLayout | None = None) -> dict:
     """Training metrics from the CURRENT boosting margin (no re-predict).
 
     Fully device-side with w-masking (pads/holdouts carry w=0): the
     round-1 version round-tripped the 1M-row margin through the host,
-    which cost multiple seconds per call."""
+    which cost multiple seconds per call. A grouped objective's
+    NDCG@10 is ranked on the device too, over the job's query layout
+    (`rank.ndcg_at`)."""
     from .. import metrics as M
 
+    if grouped(dist):
+        return {"train_ndcg@10": ndcg_at(margin, rank.groups, 10)}
     if dist == "bernoulli":
         p1 = _jit_sigmoid(margin)
         return {"train_logloss": M.logloss(y, p1, w=w),
@@ -592,8 +653,7 @@ class GBMModel(Model):
         self.bin_spec = bin_spec
         # stacked pytree: leaves have leading tree axis [T(*K), N];
         # accepts an already-stacked Tree (what BoostPlan.dispatch
-        # hands back) or a list of single trees (the
-        # XGBoost lambdarank host loop)
+        # hands back) or a list of single trees
         if isinstance(trees, Tree):
             self.trees = trees
             self.ntrees = int(trees.value.shape[0])
@@ -886,19 +946,27 @@ class GBM:
               ignored_columns: Sequence[str] | None = None,
               weights_column: str | None = None,
               validation_frame: Frame | None = None,
-              offset_column: str | None = None) -> GBMModel:
+              offset_column: str | None = None,
+              group_column: str | None = None) -> GBMModel:
         # one `train` root span a job (runtime/telemetry.phase_span:
         # histogram, /3/Timeline, GET /3/Trace/{id}, the profiler's
-        # trace); `_train` opens one child per phase
+        # trace); `_train` opens one child per phase.
+        # ``group_column``: the query of every row, for a grouped
+        # objective (rank:*, through the XGBoost estimator); never a
+        # feature
         p = self.params
+        if group_column:
+            ignored_columns = list(ignored_columns or []) + [group_column]
         with phase_span("train", estimator=type(self).__name__,
                         ntrees=p.ntrees, max_depth=p.max_depth) as root:
             return self._train(root, y, training_frame, x,
                                ignored_columns, weights_column,
-                               validation_frame, offset_column)
+                               validation_frame, offset_column,
+                               group_column)
 
     def _train(self, root, y, training_frame, x, ignored_columns,
-               weights_column, validation_frame, offset_column):
+               weights_column, validation_frame, offset_column,
+               group_column=None):
         p = self.params
         with phase_span("train.prepare"):
             if p.ntrees < 1:
@@ -960,9 +1028,21 @@ class GBM:
             if ckpt is not None and ckpt.bin_spec.set_feats:
                 refuse_set_splits(checkpoint=True)
 
+            # a grouped objective's query layout, built once a job
+            rank = None
+            if grouped(data.distribution):
+                if not group_column:
+                    raise ValueError(
+                        f"{data.distribution} needs group_column: the "
+                        "query of every row")
+                with phase_span("train.group_layout"):
+                    rank = _frame_rank_layout(training_frame, y,
+                                              group_column)
+
             efb_plan = efb = None
             F = len(data.feature_names)
-            if efb_mod.efb_eligible(F, ckpt):
+            # (a grouped job skips the planning pass: `BoostPlan.grouped`)
+            if rank is None and efb_mod.efb_eligible(F, ckpt):
                 # reuse the fitted spec either way: when the plan is
                 # rejected (shrink gate / no exclusive sets) re-fitting
                 # through the fused prologue would just duplicate the
@@ -979,9 +1059,11 @@ class GBM:
                     bin_spec = None     # that fit was label_encoder's
 
             plan = boost_plan(p, data.distribution, data.nclasses, F,
-                              n_bins=n_bins, set_feats=set_feats)
+                              n_bins=n_bins, set_feats=set_feats,
+                              rank=rank)
             plan.validate(self.model_cls.algo, ckpt, efb_plan is not None,
-                          data.y.shape[0])
+                          data.y.shape[0], offset=bool(offset_column),
+                          cv=self.cv_args.enabled)
             # the per-round GOSS key stream is derived OUTSIDE the
             # dispatch-chunk key schedule (goss_round_keys) so the fused
             # in-HBM path and the ooc stream draw identical keep patterns
@@ -997,7 +1079,10 @@ class GBM:
                     chips=plan.mesh.size, encoding=encoding,
                     bins=n_bins, enum_features=sum(
                         training_frame.vec(n).is_enum()
-                        for n in data.feature_names))
+                        for n in data.feature_names),
+                    objective=data.distribution)
+        if rank is not None:
+            root.update(queries=rank.queries, max_query=rank.max_query)
         # no span blocks on the device for its own sake (the dispatch
         # pipeline below is the loop's design): `enqueue` spans read the
         # dispatch, the device's side is in the device trace under
@@ -1096,6 +1181,10 @@ class GBM:
                     max_depth=p.max_depth, classes=plan.K)
             model._varimp = _stacked_varimp(model.trees, data.feature_names)
             _count_splits(model.trees, set_feats)
+            if group_column:
+                model._group_column = group_column
+            if rank is not None:
+                _count_pairs(rank, p.ntrees - start_t)
         # which way the metric is read: off the boosting margin, off
         # the sum of leaf values a forest's scan carried (every tree
         # over every row, bitwise what `_margins_of_binned` walks
@@ -1117,7 +1206,8 @@ class GBM:
                 # (when score_every divides ntrees the loop already scored
                 # the final round — don't duplicate the row)
                 history.append({"ntrees": p.ntrees, **_margin_metrics(
-                    data.distribution, margin, data.y, data.w)})
+                    data.distribution, margin, data.y, data.w,
+                    rank=rank)})
             if margin_scale != 1.0 and history:
                 # report rmse in ORIGINAL units, not MAD units
                 for hrow in history:
@@ -1183,7 +1273,8 @@ class GBM:
             t += n
             if score and (t - start_t) % score == 0:
                 history.append({"ntrees": t, **_margin_metrics(
-                    data.distribution, margin, data.y, data.w)})
+                    data.distribution, margin, data.y, data.w,
+                    rank=plan.rank)})
         trees = jax.tree.map(
             lambda *xs: jnp.concatenate(xs), *chunks) \
             if len(chunks) > 1 else chunks[0]
@@ -1195,7 +1286,8 @@ class GBM:
     # -- compile-ahead (runtime/scheduler.py) ---------------------------
 
     def compile_ahead_lowerings(self, y: str, frame: Frame,
-                                x: Sequence[str] | None = None) -> list:
+                                x: Sequence[str] | None = None,
+                                group_column: str | None = None) -> list:
         """Zero-arg thunks that AOT-lower+compile the programs
         ``train(y, frame, x)`` will dispatch (`BoostPlan.lowerings`) —
         run on the compile-ahead stream while the device token is busy
@@ -1209,11 +1301,13 @@ class GBM:
         pointwise tree path: the final fit's full-frame shape plus,
         under modulo CV (AutoML's fold assignment), the fold shapes —
         identical to the full shape in weights-masked share mode, the
-        complement sizes in sliced mode. What it alone rules out
-        (checkpoint continuation, a fold column, a missing response, a
-        width EFB may rebundle, the lambdarank host loop), what the
-        plan refuses and what streams out of core return no thunks,
-        and train compiles on demand. tests/test_scheduler.py holds
+        complement sizes in sliced mode. A grouped objective's job is
+        lowered with the layout of ``group_column`` (its shapes hang
+        on the multiset of query sizes; without the column: no
+        thunks). What it alone rules out (checkpoint continuation, a
+        fold column, a missing response, a width EFB may rebundle),
+        what the plan refuses and what streams out of core return no
+        thunks, and train compiles on demand. tests/test_scheduler.py holds
         what is lowered to what is dispatched, mode by mode."""
         from ..runtime.mrtask import _padded_len
         from .tree import efb as efb_mod
@@ -1223,17 +1317,22 @@ class GBM:
                 y not in frame:
             return []
         try:
-            names = _feature_names(frame, x, {y})
+            names = _feature_names(
+                frame, x, {y, group_column} if group_column else {y})
             dist, nclasses, _ = resolve_response(frame, y, p.distribution)
             # EFB may rebundle the frame to a DATA-dependent width:
             # F-width executables would be dead compile work
-            if not names or efb_mod.efb_eligible(len(names), None) or \
-                    dist.startswith("rank:"):
+            if not names or (grouped(dist) and not group_column) or \
+                    (not grouped(dist)
+                     and efb_mod.efb_eligible(len(names), None)):
                 return []
             _, set_feats, _, n_bins = _bin_layout(p, frame, names)
-            plan = boost_plan(p, dist, nclasses, len(names),
-                              n_bins=n_bins, set_feats=set_feats)
-            plan.validate(self.model_cls.algo)
+            plan = boost_plan(
+                p, dist, nclasses, len(names), n_bins=n_bins,
+                set_feats=set_feats,
+                rank=_frame_rank_layout(frame, y, group_column)
+                if grouped(dist) else None)
+            plan.validate(self.model_cls.algo, cv=self.cv_args.enabled)
         except ValueError:
             return []       # train() raises it, on the driver thread
         n = frame.nrows
@@ -1351,8 +1450,9 @@ def _initial_margin(plan: BoostPlan, data: TrainData, ckpt, binned):
         # the first boost chunk (init is read back at model build)
         init, margin = _init_margin(data.y, data.w, off,
                                     data.distribution, K)
-    elif p._drf_mode:
-        # DRF: no boosting — leaves are in-leaf target means, init 0
+    elif p._drf_mode or plan.grouped:
+        # DRF: no boosting — leaves are in-leaf target means, init 0;
+        # a ranker's scores start from 0 (only their differences count)
         init = np.zeros(K, dtype=np.float32) if K > 1 else 0.0
         margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
             else jnp.zeros_like(data.y)
@@ -1435,6 +1535,33 @@ def _stacked_varimp(trees: Tree, names: list[str]) -> dict[str, float]:
     flat = trees._replace(split_feat=np.asarray(trees.split_feat).ravel(),
                           gain=np.asarray(trees.gain).ravel())
     return dict(zip(names, _gain_by_feat(flat, len(names))))
+
+
+def _frame_rank_layout(frame: Frame, y: str, group_column: str
+                       ) -> RankLayout:
+    """The query layout of ``frame`` (`rank.rank_layout`), from its
+    group column and its labels as the job reads them (an enum's codes
+    are its grades; a missing label is NaN)."""
+    yv = frame.vec(y)
+    return rank_layout(
+        frame.vec(group_column).to_numpy(),
+        np.asarray(yv.as_float())[: frame.nrows], yv.padded_len)
+
+
+def _count_pairs(rank: RankLayout, rounds: int) -> None:
+    """`h2o_train_rank_pairs_total{kind}`, added up once a job: over
+    its rounds, the pairs that exist (sum of n_q^2 over the queries:
+    ``real``) and the pair slots the layout's size classes computed
+    for them (``slots``)."""
+    from ..runtime.telemetry import REGISTRY
+
+    ctr = REGISTRY.counter(
+        "h2o_train_rank_pairs_total",
+        "document pairs of the ranking jobs trained, over their "
+        "rounds: real (sum of n_q^2 over the queries) and slots (pair "
+        "slots the query layout computed)", label="kind")
+    ctr.inc(rank.pairs_real * rounds, label_value="real")
+    ctr.inc(rank.pairs_slots * rounds, label_value="slots")
 
 
 def _count_splits(trees: Tree, set_feats: tuple) -> None:

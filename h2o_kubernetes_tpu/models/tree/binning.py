@@ -105,6 +105,25 @@ import functools
 _QUANTILE_SAMPLE = 1 << 16
 
 
+def _column_quantiles(cols_t: jax.Array, n_q: int) -> jax.Array:
+    """[Fn, n] columns → [Fn, n_q] quantile edges, NON-DECREASING along
+    a feature. `nanquantile` interpolates lo·(1−t) + hi·t, which for
+    lo = hi = 3.0 reads 2.9999998 or 3.0000002 wherever the backend
+    rounds the two products apart (a v5e does; XLA:CPU contracts them):
+    on a column of few distinct values — counts, ratios, 0/1 — the run
+    of equal edges then comes out UNSORTED by an ulp, and `apply_bins`'
+    count of the edges at or below a value is no longer
+    `searchsorted`, so a split at bin b is no longer `x < edges[b]` and
+    every reader of a tree in value space (the flat scorer, MOJO, the
+    benchmark's `cover_gap`) routes the rows that sit on that value
+    apart from the grower (PERF.md section 6, PR 34: 1,984 rows of a
+    node on the chip). The running maximum restores the order and
+    moves no edge that was in order."""
+    qs = jnp.linspace(0.0, 1.0, n_q + 2)[1:-1]
+    Q = jax.vmap(lambda c: jnp.nanquantile(c, qs))(cols_t)
+    return jax.lax.cummax(Q, axis=1)
+
+
 @functools.partial(jax.jit, static_argnums=(1,))
 @jax.named_scope("fit_quantiles")
 def _device_quantiles(Xn: jax.Array, n_q: int) -> jax.Array:
@@ -114,8 +133,7 @@ def _device_quantiles(Xn: jax.Array, n_q: int) -> jax.Array:
     dispatch). Sampling is the CALLER's job: fit_bins feeds this the
     `_sampled_feature_matrix` gather (≤ _QUANTILE_SAMPLE rows), the
     one place the fixed-key sample draw lives."""
-    qs = jnp.linspace(0.0, 1.0, n_q + 2)[1:-1]
-    return jax.vmap(lambda c: jnp.nanquantile(c, qs))(Xn.T)
+    return _column_quantiles(Xn.T, n_q)
 
 
 # per-column sample gather for the sketch path: fit_bins used to stack
@@ -430,8 +448,7 @@ def _fused_fit_bin_jit(base_M, num_idx, sample, cols: tuple,
         with jax.named_scope("fit_quantiles"):
             if n_q is None:
                 n_q = M.shape[1] - 1              # n_bins - 3
-            qs = jnp.linspace(0.0, 1.0, n_q + 2)[1:-1]
-            Q = jax.vmap(lambda c: jnp.nanquantile(c, qs))(sample.T)
+            Q = _column_quantiles(sample.T, n_q)
             Q = jnp.where(jnp.isnan(Q), jnp.inf, Q.astype(jnp.float32))
             M = M.at[num_idx, : n_q].set(Q)
     binned = apply_bins(jnp.stack(cols, axis=1), M[: len(cols)],

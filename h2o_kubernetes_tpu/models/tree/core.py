@@ -116,6 +116,7 @@ def _gain_term(G, H, p: TreeParams):
 from ...ops.histogram import build_histogram as _build_histogram_op
 from ...ops.histogram import expand_unit_hess as _expand_unit_hess
 from ...ops.histogram import resolve_impl as _resolve_impl
+from .rank import groups_specs, rank_grad_hess
 
 
 def _split_gains(left, tot4, p: TreeParams):
@@ -625,10 +626,9 @@ def _boost_grad_hess(bp: BoostParams, margin, y, w):
 
 def _round_sampling(bp: BoostParams, w, F: int, k_row, k_col):
     """Shard-level row/column sampling for one boosting round →
-    (w_t, col_mask). Shared by ``_boost_shard`` and
-    ``_boost_shard_multi``; ``models/xgboost.py::_rank_round`` applies
-    the same scheme host-side (outside shard_map) — keep the semantics
-    in sync."""
+    (w_t, col_mask): THE one sampling scheme, for every boost scan
+    (``_boost_shard``, a ranking job's among them, ``_boost_shard_multi``
+    and ``_boost_shard_drf``)."""
     w_t = w
     if bp.sample_rate < 1.0:
         w_t = w * row_keep(k_row, lax.axis_index(ROWS), w.shape[0],
@@ -846,10 +846,12 @@ def round_keys(key, n_rounds: int):
     return jax.random.split(key, n_rounds)
 
 
-def _boost_shard(binned, y, w, margin, keys, efb=None, *,
+def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
                  p: TreeParams, bp: BoostParams):
     """Scan over trees INSIDE one shard_map: grad/hess → grow → local
-    margin update, with histograms psum'd per level.
+    margin update, with histograms psum'd per level. ``groups``: the
+    query layout of a grouped objective (tree/rank.py), whose gradients
+    hang on a row's query; None for a pointwise one.
 
     This replaces the reference's per-tree driver round trips
     (SharedTree.Driver.computeImpl's outer loop, SURVEY.md §3.4) with a
@@ -869,7 +871,10 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, *,
         with jax.named_scope("sample"):
             w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
         with jax.named_scope("grad_hess"):
-            g, h = _boost_grad_hess(bp, margin, y, w)
+            if groups is not None:
+                g, h = rank_grad_hess(bp.distribution, margin, groups)
+            else:
+                g, h = _boost_grad_hess(bp, margin, y, w)
         if goss:
             # GOSS: amplified weights → static-cap compaction → the
             # grower streams only the sampled rows. The margin update
@@ -1084,21 +1089,29 @@ def _boost_multi_jit(binned, y, w, margin, keys, efb, p: TreeParams,
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8))
 def _boost_jit(binned, y, w, margin, keys, efb, p: TreeParams,
-               bp: BoostParams, mesh):
+               bp: BoostParams, mesh, groups=None):
     """Fused boosting: len(keys) rounds in ONE dispatch → (margin,
     trees [T, N]). With GOSS (``bp.goss_b > 0``) ``keys`` is the pair
     (round keys, rows of the path-invariant `goss_round_keys` stream)
     and a third output counts the rows compaction dropped
-    (`goss_compact`). models/gbm.BoostPlan builds the operands."""
+    (`goss_compact`). A grouped objective's query layout is the last
+    operand (``groups``: `rank.RankGroups`), its classes replicated
+    and its rows' slots sharded as the rows are.
+    models/gbm.BoostPlan builds the operands."""
     out_specs = (P(ROWS), P(), P()) if bp.goss_b > 0 \
         else (P(ROWS), P())
+    in_specs = (P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P())
+    args = (binned, y, w, margin, keys, efb)
+    if groups is not None:
+        in_specs += (groups_specs(groups),)
+        args += (groups,)
     fn = jax.shard_map(
         functools.partial(_boost_shard, p=p, bp=bp),
         mesh=mesh,
-        in_specs=(P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P()),
+        in_specs=in_specs,
         out_specs=out_specs,
         check_vma=_resolve_impl(p.hist_impl) == "segment")
-    return fn(binned, y, w, margin, keys, efb)
+    return fn(*args)
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8))

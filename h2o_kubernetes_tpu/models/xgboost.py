@@ -15,29 +15,28 @@ XGBoost-specific semantics implemented here, distinct from H2O GBM:
   count:poisson) and base_score-style flat init;
 - learning-to-rank: rank:pairwise and rank:ndcg (LambdaMART) over a
   query `group_column`, the reference's MSLR-WEB30K lambdarank config
-  (BASELINE.json:9). Pairwise lambda gradients are computed in a dense
-  [groups, max_docs] layout in fixed-size group batches (lax.map), so
-  the whole objective stays jittable with static shapes.
+  (BASELINE.json:9). They are distributions of the boost plan like any
+  other (`GBM.train` → `BoostPlan`, mode ``single``): the query layout
+  is an operand of `core._boost_jit` and the pairwise gradients are
+  taken inside its scan (models/tree/rank.py has the semantics and the
+  layout). EVERY pair (i, j) of a query with y_i > y_j is taken, the
+  rank by a stable sort of the margins, maxDCG over the whole list —
+  where XGBoost samples or truncates a query's pairs
+  (`lambdarank_pair_method`, `lambdarank_num_pair_per_sample`) and
+  normalises at its truncation level: deterministic, so that a plain
+  reference can hold it (bench/reference/lambdamart_plain.py).
 """
 
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax import lax
 
 from .. import metrics as M
 from ..frame import Frame
-from ..runtime.health import require_healthy
-from .base import resolve_xy
-from .gbm import GBM, GBMModel, _stacked_varimp, refuse_set_splits
-from .tree.binning import fit_bins, resolve_encoding, set_features
-from .tree.core import TreeParams
+from .gbm import GBM, GBMModel
+from .tree.rank import grouped
 
 _OBJECTIVE_ALIASES = {
     "reg:squarederror": "gaussian",
@@ -57,157 +56,20 @@ class XGBoostModel(GBMModel):
 
     def _score_matrix(self, X: jax.Array,
                       offset: jax.Array | None = None) -> jax.Array:
-        if self.distribution.startswith("rank:"):
+        if grouped(self.distribution):
             return self._margins(X, offset)  # raw ranking scores
         return super()._score_matrix(X, offset)
 
     def model_performance(self, frame: Frame, y: str,
                           group_column: str | None = None,
                           k: int = 10) -> dict[str, float]:
-        if self.distribution.startswith("rank:"):
+        if grouped(self.distribution):
             gcol = group_column or self._group_column
             score = self.predict_raw(frame)
             yv = frame.vec(y).to_numpy()
             g = frame.vec(gcol).to_numpy()
             return {f"ndcg@{k}": M.ndcg(yv, score, g, k=k)}
         return super().model_performance(frame, y)
-
-
-# ---------------------------------------------------------------------------
-# LambdaMART gradients
-# ---------------------------------------------------------------------------
-
-class _GroupLayout:
-    """Host-side query-group layout: row-order ↔ dense [G, M] mapping."""
-
-    def __init__(self, group_ids: np.ndarray, padded_len: int):
-        uniq, inv = np.unique(group_ids, return_inverse=True)
-        self.n_groups = len(uniq)
-        sizes = np.bincount(inv, minlength=self.n_groups)
-        self.max_docs = int(sizes.max()) if len(sizes) else 1
-        G, Mx = self.n_groups, self.max_docs
-        order = np.argsort(inv, kind="stable")       # rows grouped together
-        starts = np.concatenate([[0], np.cumsum(sizes)[:-1]])
-        slot = np.arange(len(inv)) - starts[inv[order]]  # within-group slot
-        idx = np.full(G * Mx, -1, dtype=np.int32)
-        pos = np.full(padded_len, -1, dtype=np.int32)
-        flat = inv[order] * Mx + slot
-        idx[flat] = order.astype(np.int32)
-        pos[order] = flat.astype(np.int32)
-        idx = idx.reshape(G, Mx)
-        self.idx = jnp.asarray(idx)          # [G, M] row index or -1
-        self.pos = jnp.asarray(pos)          # [padded] flat dense pos or -1
-        self.mask = jnp.asarray(idx >= 0)    # [G, M]
-
-
-def _dense_layout(y, idx, mask):
-    """Row-sharded y → [G, M] dense group layout + ideal DCG, in one
-    compiled program (no eager sharded gathers on the hot setup path)."""
-    y_dense = jnp.where(mask, y[jnp.maximum(idx, 0)], 0.0)
-    return y_dense, _ideal_dcg(y_dense, mask)
-
-
-_dense_layout_jit = jax.jit(_dense_layout)
-
-
-def _ideal_dcg(y_dense: jax.Array, mask: jax.Array) -> jax.Array:
-    """Max DCG per group over the full list (LambdaMART normalizer)."""
-    gains = jnp.where(mask, 2.0 ** y_dense - 1.0, 0.0)
-    srt = jnp.sort(gains, axis=1)[:, ::-1]
-    disc = 1.0 / jnp.log2(jnp.arange(2, gains.shape[1] + 2))
-    return jnp.sum(srt * disc[None, :], axis=1)
-
-
-def _lambda_grads_batch(f, y, mask, maxdcg, use_ndcg: bool):
-    """Pairwise lambda gradients for one batch of groups.
-
-    f, y, mask: [B, M]; maxdcg: [B]. Returns (g, h): [B, M] each.
-    For each in-group pair with y_i > y_j: cross-entropy on the score
-    difference, weighted by |ΔNDCG| when use_ndcg (Burges LambdaRank).
-    """
-    fm = jnp.where(mask, f, -jnp.inf)
-    # current 1-based rank of each doc within its group (desc by score)
-    order = jnp.argsort(-fm, axis=1, stable=True)
-    rank = jnp.argsort(order, axis=1) + 1
-    diff = f[:, :, None] - f[:, None, :]               # [B, M, M]
-    rho = jax.nn.sigmoid(-diff)
-    pair = ((y[:, :, None] - y[:, None, :]) > 0) \
-        & mask[:, :, None] & mask[:, None, :]
-    if use_ndcg:
-        gain = 2.0 ** y - 1.0
-        disc = 1.0 / jnp.log2(1.0 + rank.astype(jnp.float32))
-        dgain = jnp.abs(gain[:, :, None] - gain[:, None, :])
-        ddisc = jnp.abs(disc[:, :, None] - disc[:, None, :])
-        w = dgain * ddisc / jnp.maximum(maxdcg, 1e-10)[:, None, None]
-    else:
-        w = 1.0
-    A = jnp.where(pair, w * rho, 0.0)
-    Hh = jnp.where(pair, w * rho * (1.0 - rho), 0.0)
-    g = -jnp.sum(A, axis=2) + jnp.sum(A, axis=1)
-    h = jnp.sum(Hh, axis=2) + jnp.sum(Hh, axis=1)
-    return g, h
-
-
-@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12, 13, 14, 15))
-def _rank_round(binned, margin, y_dense, maxdcg, idx, pos, mask, w, key,
-                tp: TreeParams, use_ndcg: bool, batch: int, lr: float,
-                sample_rate: float, col_rate: float, mesh=None):
-    """ONE compiled program per boosting round: lambda gradients → row
-    sampling → tree growth → margin update.
-
-    The round-1/round-2 suite hangs (and the SIGABRTs before the
-    rendezvous timeout was raised) were all in EAGER multi-device
-    dispatch inside this loop — an eager op on sharded arrays
-    occasionally deadlocks XLA:CPU's collective rendezvous. Keeping the
-    whole round inside one jit removes every eager sharded dispatch
-    from the hot path (the fused GBM loop got the same treatment via
-    core._boost_jit)."""
-    from .tree.core import _grow_tree_jit, predict_tree
-
-    g, h = _lambda_grads(margin, idx, pos, mask, use_ndcg, batch,
-                         y_dense=y_dense, maxdcg=maxdcg)
-    k_row, k_col, k_tree = jax.random.split(key, 3)
-    w_t = w
-    if sample_rate < 1.0:
-        w_t = w * (jax.random.uniform(k_row, w.shape) < sample_rate)
-    F = binned.shape[1]
-    col_mask = jnp.ones(F, dtype=bool)
-    if col_rate < 1.0:
-        col_mask = jax.random.uniform(k_col, (F,)) < col_rate
-    # lambdarank stays on the ORIGINAL-space binned matrix (efb=None):
-    # its margin update re-descends `binned` via predict_tree, which
-    # reads original (feature, bin) splits
-    tree = _grow_tree_jit(binned, g, h, w_t, col_mask, k_tree, None,
-                          tp, mesh)
-    tree = tree._replace(value=lr * tree.value)
-    margin = margin + predict_tree(tree, binned, tp.max_depth, tp.n_bins)
-    return margin, tree
-
-
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _lambda_grads(margin, layout_idx, layout_pos, layout_mask,
-                  use_ndcg: bool, batch: int, y_dense=None, maxdcg=None):
-    """Row-layout margins → row-layout (g, h) via the dense group layout."""
-    G, Mx = layout_idx.shape
-    f_dense = jnp.where(layout_mask, margin[jnp.maximum(layout_idx, 0)], 0.0)
-    nb = -(-G // batch)
-    pad = nb * batch - G
-
-    def pad_g(a, fill=0.0):
-        return jnp.concatenate(
-            [a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)]) \
-            if pad else a
-
-    fb = pad_g(f_dense).reshape(nb, batch, Mx)
-    yb = pad_g(y_dense).reshape(nb, batch, Mx)
-    mb = pad_g(layout_mask, False).reshape(nb, batch, Mx)
-    db = pad_g(maxdcg).reshape(nb, batch)
-    g, h = lax.map(lambda t: _lambda_grads_batch(*t, use_ndcg), (fb, yb, mb, db))
-    g = g.reshape(-1, Mx).reshape(-1)[: G * Mx]
-    h = h.reshape(-1, Mx).reshape(-1)[: G * Mx]
-    ok = layout_pos >= 0
-    safe = jnp.maximum(layout_pos, 0)
-    return jnp.where(ok, g[safe], 0.0), jnp.where(ok, h[safe], 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -231,7 +93,7 @@ class XGBoost(GBM):
                  colsample_bytree: float = 1.0,
                  nbins: int = 256, objective: str | None = None,
                  booster: str = "gbtree", tree_method: str = "hist",
-                 ndcg_group_batch: int = 16, **kw):
+                 **kw):
         if booster != "gbtree":
             raise ValueError(f"only booster=gbtree is supported: {booster}")
         if tree_method not in ("hist", "gpu_hist", "approx", "auto"):
@@ -259,112 +121,11 @@ class XGBoost(GBM):
             col_sample_rate_per_tree=colsample_bytree,
             nbins=nbins, min_rows=1.0,
             distribution=dist, **kw)
-        self._ndcg_group_batch = ndcg_group_batch
 
     def train(self, y: str, training_frame: Frame,
               x: Sequence[str] | None = None,
               group_column: str | None = None, **kw) -> XGBoostModel:
-        if self.params.distribution.startswith("rank:"):
-            if group_column is None:
-                raise ValueError("ranking objectives need group_column")
-            if self.cv_args.enabled:
-                raise ValueError(
-                    "cross-validation with rank:* objectives needs "
-                    "group-aware folds; not supported yet")
-            return self._train_rank(y, training_frame, x, group_column, **kw)
-        ignored = list(kw.pop("ignored_columns", None) or [])
-        if group_column:
-            ignored.append(group_column)
-        model = super().train(y=y, training_frame=training_frame, x=x,
-                              ignored_columns=ignored, **kw)
-        model._group_column = group_column
-        return model
-
-    def _train_rank(self, y: str, frame: Frame, x, group_column: str,
-                    ignored_columns: Sequence[str] | None = None,
-                    weights_column: str | None = None,
-                    validation_frame: Frame | None = None,
-                    offset_column: str | None = None) -> XGBoostModel:
-        p = self.params
-        if offset_column:
-            # a base margin is meaningful for pointwise objectives only;
-            # LambdaMART gradients come from pairwise score differences
-            raise ValueError(
-                "offset_column is not supported for rank:* objectives")
-        ignored = list(ignored_columns or []) + [group_column]
-        # no full f32 design matrix: the ranker bins straight from the
-        # Frame columns like the pointwise tree paths (Frame.binned)
-        data = resolve_xy(frame, y, x, ignored, weights_column,
-                          distribution="gaussian", materialize_x=False)
-        data.distribution = p.distribution   # rank:* carried through
-        # graded relevance stored as an enum: codes ARE the grades —
-        # score as a single-output ranker, never the multinomial path
-        data.nclasses = 1
-        data.response_domain = None
-        use_ndcg = p.distribution == "rank:ndcg"
-
-        gv = frame.vec(group_column)
-        gids = gv.to_numpy()
-        # padded rows get fresh singleton group ids → they pair with
-        # nothing and receive zero gradients
-        padded = data.y.shape[0]
-        real = np.asarray(gids).astype(np.int64)
-        gfull = np.empty(padded, dtype=np.int64)
-        gfull[: frame.nrows] = real
-        top = int(real.max()) + 1 if len(real) else 0
-        gfull[frame.nrows:] = top + np.arange(padded - frame.nrows)
-        layout = _GroupLayout(gfull, padded)
-
-        if resolve_encoding(p.categorical_encoding) == "enum" and \
-                set_features(frame, data.feature_names, p.nbins_cats):
-            refuse_set_splits(xgboost=True)
-        bin_spec = fit_bins(frame, data.feature_names, n_bins=p.nbins)
-        binned = frame.binned(bin_spec)
-
-        y_dense, maxdcg = _dense_layout_jit(data.y, layout.idx,
-                                            layout.mask)
-
-        tp = TreeParams(max_depth=p.max_depth, n_bins=p.nbins,
-                        min_rows=p.min_rows, reg_lambda=p.reg_lambda,
-                        reg_alpha=p.reg_alpha,
-                        gamma=p.min_split_improvement, mtries=p.mtries,
-                        min_child_weight=p.min_child_weight)
-        key = jax.random.key(p.seed)
-        F = len(data.feature_names)
-        margin = jnp.zeros_like(data.y)
-        trees, history = [], []
-        batch = min(self._ndcg_group_batch, layout.n_groups)
-        from ..runtime.mesh import global_mesh
-
-        mesh = global_mesh()
-        for t in range(p.ntrees):
-            require_healthy()        # fail fast on a dead mesh (§5.3)
-            key, kt = jax.random.split(key)
-            margin, tree = _rank_round(
-                binned, margin, y_dense, maxdcg, layout.idx, layout.pos,
-                layout.mask, data.w, kt, tp, use_ndcg, batch,
-                p.learn_rate, p.sample_rate, p.col_sample_rate_per_tree,
-                mesh)
-            trees.append(tree)
-            if p.score_every and (t + 1) % p.score_every == 0:
-                sc = np.asarray(margin)[: frame.nrows]
-                yt = np.asarray(data.y)[: frame.nrows]
-                history.append({"ntrees": t + 1,
-                                "train_ndcg@10": M.ndcg(yt, sc, gids, k=10)})
-
-        model = self.model_cls(data, p, bin_spec, trees, init_score=0.0,
-                               varimp=None)
-        model._varimp = _stacked_varimp(model.trees, data.feature_names)
-        model._group_column = group_column
-        sc = np.asarray(margin)[: frame.nrows]
-        yt = np.asarray(data.y)[: frame.nrows]
-        history.append({"ntrees": p.ntrees,
-                        "train_ndcg@10": M.ndcg(yt, sc, gids, k=10)})
-        model.scoring_history = history
-        if validation_frame is not None:
-            vy = validation_frame.vec(y)
-            vscore = model.predict_raw(validation_frame)
-            vg = validation_frame.vec(group_column).to_numpy()
-            model.validation_metrics = {
-                "ndcg@10": M.ndcg(vy.to_numpy(), vscore, vg, k=10)}
-        return model
+        if grouped(self.params.distribution) and group_column is None:
+            raise ValueError("ranking objectives need group_column")
+        return super().train(y=y, training_frame=training_frame, x=x,
+                             group_column=group_column, **kw)

@@ -205,6 +205,24 @@ def _hi_blocks(n_cells: int) -> tuple:
     return n_ht, -(-n_hi // n_ht)
 
 
+def _feature_groups(F: int, C: int, ht: int) -> tuple[int, int]:
+    """(fg, F_pad): the features one grid step of the kernel holds and
+    the width the frame is padded to, a multiple of it. Each step keeps
+    [fg, C·ht, 128] f32 of output resident (`_OUT_BUDGET`), and fg is
+    capped at 64 outright. A frame within the caps goes as one group,
+    unpadded; one past them in 8-aligned groups — the widest the caps
+    allow while it has at most 64 columns, and past 64 the width that
+    pads least (the widest of those): 136 columns go as 17 groups of 8,
+    where three groups of 64 would histogram 192."""
+    fg_cap = min(F, 64, max(1, _OUT_BUDGET // (C * ht * 128 * 4)))
+    if fg_cap >= F:
+        return F, F
+    fg = max(8, fg_cap // 8 * 8)
+    if F > 64:
+        fg = min(range(fg, 7, -8), key=lambda w: -(-F // w) * w)
+    return fg, -(-F // fg) * fg
+
+
 def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
                  binned_tile: int = 1, row_tile: int | None = None):
     """``binned_tile`` > 1: rel/vals carry ``binned_tile`` consecutive
@@ -233,13 +251,8 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
     # saturates long before that, and the resident out block is the
     # only cost that grows with fg (the kernel's fori_loop reuses one
     # iteration's buffers)
-    per_f = C * ht * 128 * 4
-    fg_cap = min(F, 64, max(1, _OUT_BUDGET // per_f))
-    if fg_cap >= F:
-        fg, F_pad = F, F
-    else:
-        fg = max(8, fg_cap // 8 * 8)
-        F_pad = -(-F // fg) * fg
+    fg, F_pad = _feature_groups(F, C, ht)
+    if F_pad > F:
         binned = jnp.pad(binned, ((0, 0), (0, F_pad - F)))
     n_fg = F_pad // fg
     # [rp, F_pad] -> [F_pad, row_block, 1, rt]: a (fg, 1, 1, rt) block

@@ -173,3 +173,41 @@ def test_frame_binning_is_searchsorted_right_to_the_bit(mesh8, n_bins,
     spec2, fused = fused_fit_bins(fr, names, n_bins=n_bins)
     np.testing.assert_array_equal(np.asarray(spec2.edges_matrix()), E)
     np.testing.assert_array_equal(np.asarray(fused), want)
+
+
+def test_quantile_edges_stay_in_order_where_a_backend_rounds_them_apart(
+        monkeypatch):
+    """`nanquantile` interpolates lo·(1−t) + hi·t; a backend that rounds
+    the two products apart (a v5e; XLA:CPU contracts them) reads
+    2.9999998 or 3.0000002 for lo = hi = 3, so a column of few distinct
+    values got a run of equal edges UNSORTED by an ulp, and the count
+    of edges at or below a value (`apply_bins`) stopped being
+    `searchsorted`: a split at bin b was no longer `x < edges[b]`
+    (ISSUE 34: 1,984 rows of a node routed apart on the chip).
+    `_column_quantiles` keeps the edges non-decreasing."""
+    import jax
+
+    real = jnp.nanquantile
+
+    def rounded_apart(c, qs):
+        q = real(c, qs)
+        ulp = ((jnp.arange(q.shape[0]) * 7) % 3 - 1).astype(q.dtype)
+        return q * (1 + 1.1920929e-07 * ulp)
+
+    rng = np.random.default_rng(0)
+    X = np.stack([rng.integers(0, 7, 4000), rng.integers(0, 2, 4000),
+                  rng.integers(1, 7, 4000) / 3.0,
+                  rng.normal(size=4000)], axis=1).astype(np.float32)
+    monkeypatch.setattr(binning.jnp, "nanquantile", rounded_apart)
+    broken = np.asarray(jax.vmap(
+        lambda c: rounded_apart(c, jnp.linspace(0, 1, 32)[1:-1]))(X.T))
+    assert (np.diff(broken, axis=1) < 0).any()        # the fault, emulated
+    Q = np.asarray(binning._column_quantiles(jnp.asarray(X.T), 30))
+    assert (np.diff(Q, axis=1) >= 0).all()
+    codes = np.asarray(binning.apply_bins(
+        jnp.asarray(X), jnp.asarray(Q), jnp.zeros(4, bool), 255))
+    for f in range(4):
+        assert (codes[:, f] == np.searchsorted(Q[f], X[:, f],
+                                               side="right")).all()
+        for b in (0, 7, 15, 29):
+            assert ((codes[:, f] <= b) == (X[:, f] < Q[f, b])).all()

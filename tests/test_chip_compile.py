@@ -344,3 +344,48 @@ def test_kernel_fits_refuses_what_cannot_fit():
 
     ct = jax.ShapeDtypeStruct((4, 32, 12, 1 << 12), jnp.float32)
     assert not shap_kernel.kernel_fits(G, ct, 512)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_ranking_boost_scan_compiles(topo, n_dev):
+    """`_boost_jit` with a query layout for its last operand — what
+    `XGBoost(objective="rank:ndcg").train()` dispatches (ISSUE 34) — at
+    MSLR-WEB30K's width: 136 columns at depth 8 x 256 bins, ragged
+    queries of 1 to 1,251 rows. One kernel a level, the frame's
+    transposition 136 columns wide and not padded to 192 (the groups of
+    `histogram._feature_groups`), the pairwise gradients under
+    `grad_hess/rank_sort` and `grad_hess/rank_pairs`; on four chips the
+    margin is gathered (a query may straddle a shard's edge)."""
+    from h2o_kubernetes_tpu.models.tree import rank
+
+    mesh = Mesh(np.array(topo.devices[:n_dev]).reshape(n_dev, 1),
+                (ROWS, COLS))
+    rs, rep = NamedSharding(mesh, P(ROWS)), NamedSharding(mesh, P())
+    rng = np.random.default_rng(0)
+    sizes = np.concatenate([[1, 1251, 300], rng.integers(2, 260, 500)])
+    rows = -(-int(sizes.sum()) // (1024 * n_dev)) * 1024 * n_dev
+    host = Mesh(np.array(jax.devices("cpu")[:1]).reshape(1, 1),
+                (ROWS, COLS))
+    lay = rank.rank_layout(
+        np.repeat(np.arange(len(sizes)), sizes),
+        rng.integers(0, 5, int(sizes.sum())).astype(np.float32), rows,
+        host)
+    groups = rank.RankGroups(
+        tuple(rank.RankClass(*(_s(a.shape, a.dtype, rep) for a in c))
+              for c in lay.groups.classes),
+        _s((rows,), jnp.int32, rs))
+    args = _boost_args(mesh, rows, ntrees=2)
+    tp = args[6]._replace(max_depth=8, min_rows=1.0, reg_lambda=1.0,
+                          gamma=0.0, min_child_weight=100.0)
+    bp = args[7]._replace(distribution="rank:ndcg")
+    c = core._boost_jit.lower(
+        _s((rows, 136), jnp.uint8, rs), *args[1:6], tp, bp, mesh,
+        groups).compile()
+    txt = c.as_text()
+    assert txt.count("tpu_custom_call") == 8
+    assert _kernel_names(c) == {"hist_fact"}
+    widths = {int(m) for m in re.findall(r"s32\[(\d+),\d+,1,\d+\]", txt)}
+    # (a 34-wide form is the same 136 byte columns, four to a word)
+    assert max(widths) == 136, widths
+    assert {"grad_hess", "rank_sort", "rank_pairs"} <= _scopes(txt)
+    assert ("all-gather" in txt) == (n_dev > 1)
