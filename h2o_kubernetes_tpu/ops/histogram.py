@@ -13,13 +13,27 @@ Two implementations:
   A row's cell seg = node·B + bin is factorized as seg = hi·128 + lo;
   for a row tile the value channels are packed against the hi one-hot
   (A[c·ht + hi, t], ht hi slots a block) and multiplied with the exact
-  lo one-hot [T, 128], so the whole histogram build rides the systolic
+  lo one-hot, so the whole histogram build rides the systolic
   array with full MXU rows and one lane pass (the GPU literature's
   shared-memory atomics have no TPU analog; matmul inflation is the
   right trade — see PAPERS.md GBDT-on-accelerator entries). A level
   with more than `_FACT_MAX_NHI` hi slots is served in blocks of hi
   slots along one grid axis: a row whose slot lies in another block
   matches nothing there, as a dead row does.
+
+  BOTH one-hots are built rows-on-lanes — `iota[·, T] == x[None, :]`, a
+  sublane broadcast of a lane vector, the layout the bin codes arrive
+  in — and the product contracts the row axis of both operands. Until
+  PR 35 the lo one-hot was `[T, 128]`, `iota == lo[:, None]`: laying
+  each row's `lo` across a sublane row cost 896 lane permutes a
+  (column, 4,096-row tile), and THOSE bound every shallow call — 2.4 µs
+  a (column, tile) whatever the level computed, against 0.7 µs without
+  them, bitwise the same sums (PERF.md section 3 has the op-alone
+  table; `tools/hist_forms.py` reruns it, with the older form and a
+  node-stationary one that lost beside the shipped kernel). What binds
+  a call now: up to 8 hi slots the 256 weight pushes and the compares a
+  (column, tile); past that the A operand's build and the MXU, as at
+  the deep levels.
 
 `build_histogram(..., impl="auto")` picks pallas on TPU, segment
 elsewhere. Both run under shard_map (per-shard rows); callers psum the
@@ -56,17 +70,21 @@ def _interpret() -> bool:
 
 
 def _fact_row_tile(ht: int, rows: int) -> int:
-    """Row tile for a hi block of ``ht`` slots. Wider tiles amortize
-    per-grid-step overhead (the bench shape runs ~250 steps/level at
-    4096 instead of ~1000), but the [3·C·ht, T] A operand scales with
-    T — stay at 1024 when the block is large (VMEM ~16 MB/core) or the
-    rows wouldn't fill a wide tile anyway."""
+    """Row tile for a hi block of ``ht`` slots. A wider tile shares the
+    row-stream operands (rel, vals, the mantissa split) and the grid
+    step's sequencing among four times the rows — what a (column, tile)
+    costs on top is in the module docstring — but the [3·C·ht, T] A
+    operand scales with T: stay at 1024 when the block is large (VMEM
+    ~16 MB/core) or the rows wouldn't fill a wide tile anyway."""
     return 4096 if ht <= 64 and rows >= 8192 else 1024
 
 
 # out-block VMEM budget for the fused-feature kernel: features are
 # processed in groups of `fg` per grid step so [fg, C·n_hi, 128] f32
-# stays resident; past this budget F is split into 8-aligned groups
+# stays resident; past this budget F is split into 8-aligned groups.
+# A wider group shares the row-stream operands among more columns and
+# wins nothing else: a column's own work (its two one-hots, its A
+# operand, its 256 weight pushes a 4,096-row tile) is what a step costs
 _OUT_BUDGET = 3 << 20
 
 def _dimsem(*sems):
@@ -110,19 +128,19 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
     seg = rel·B + bin is split as seg = hi·128 + lo.  The LHS packs the
     weighted value channels against the hi one-hot —
     A[c·ht + hi, t] = v_c[t]·1[hi_t = hi] — and the RHS is the exact
-    lo one-hot [T, 128], so hist[c, seg] = (A @ B)[c·ht + hi, lo]: the
-    MXU sees [3·C·ht, T]x[T, 128] (full rows for ht ≥ 43, ONE lane
-    pass), where a one-hot over the cells themselves would fill C of
-    its 128 rows.  A is split into three bf16 terms (hi/mid/lo mantissa)
-    so the f32 products match the segment path to ~2^-24; B is 0/1 and
-    thus exact in bf16.
+    lo one-hot, held transposed: Bt[lo, t] = 1[lo_t = lo], so
+    hist[c, seg] = (A @ Btᵀ)[c·ht + hi, lo]: the MXU sees
+    [3·C·ht, T]x[T, 128] (full rows for ht ≥ 43, ONE lane pass), where
+    a one-hot over the cells themselves would fill C of its 128 rows.
+    A is split into three bf16 terms (hi/mid/lo mantissa) so the f32
+    products match the segment path to ~2^-24; B is 0/1 and thus exact
+    in bf16.
     """
     # grid (feature_groups, hi_blocks, n_copies, row_blocks): one step
     # covers a whole FEATURE GROUP of fg features for its row block —
     # the row-stream operands (rel, vals, mantissa split) load and
     # compute ONCE per row block instead of once per (feature, row
-    # block), and the grid shrinks F× (per-step sequencing overhead,
-    # not FLOPs, was the round-2/3 bench bottleneck).
+    # block), and the grid shrinks F×.
     first = (pl.program_id(2) == 0) & (pl.program_id(3) == 0)
 
     @pl.when(first)
@@ -145,8 +163,11 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
     # f32-width VPU passes to `terms` bf16-width multiplies.
     V = _mantissa_terms(vals_t, terms)               # [terms·n_ch, T]
     iota_hi = lax.broadcasted_iota(jnp.int32, (ht, T), 0)
-    iota_lo = lax.broadcasted_iota(jnp.int32, (T, 128), 1)
-    dn = (((1,), (0,)), ((), ()))
+    iota_lo = lax.broadcasted_iota(jnp.int32, (128, T), 0)
+    # contract the ROW axis of both operands: `lo` stays on the lanes
+    # it arrives on (`lo[:, None]` against a [T, 128] iota relaid every
+    # row's value across a sublane row: what bound a shallow call)
+    dn = (((1,), (1,)), ((), ()))
 
     # REAL loop over the feature group, not a static unroll: Mosaic
     # stack-allocates every unrolled iteration's [3·n_ch·ht, T] A
@@ -165,7 +186,7 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
         # lies in another hi block (hi < 0 or hi >= ht); dead rows'
         # vals are zeroed upstream.
         oh_hi = (iota_hi == hi[None, :]).astype(jnp.bfloat16)
-        B = (iota_lo == lo[:, None]).astype(jnp.bfloat16)
+        Bt = (iota_lo == lo[None, :]).astype(jnp.bfloat16)
         # ONE matmul with all mantissa terms stacked into M — the
         # MXU's row occupancy multiplies (terms·n_ch·ht rows instead
         # of `terms` passes of n_ch·ht); the per-term partial sums
@@ -174,7 +195,7 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
         a = jnp.concatenate(
             [oh_hi * V[k][None, :] for k in range(terms * n_ch)],
             axis=0)                             # [terms·n_ch·ht, T]
-        acc = lax.dot_general(a, B, dimension_numbers=dn,
+        acc = lax.dot_general(a, Bt, dimension_numbers=dn,
                               preferred_element_type=jnp.float32)
         acc = acc.reshape(terms, n_ch * ht, 128)
         out_ref[0, 0, j] += acc.sum(axis=0)          # [n_ch·ht, 128]
@@ -187,7 +208,7 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
 # working set, not its reach. With the stacked-term matmul the peak is
 # the bf16 A [3·n_ch·ht, T] (4.7 MB at ht=256, C=3, T=1024 —
 # _fact_row_tile drops to 1024 past ht=64) plus the [ht, T] hi one-hot,
-# the [T, 128] lo one-hot, the f32 [3·n_ch·ht, 128] dot result (1.2 MB)
+# the [128, T] lo one-hot, the f32 [3·n_ch·ht, 128] dot result (1.2 MB)
 # and the resident out block (_OUT_BUDGET) — ~10 MB worst case against
 # ~16 MB/core VMEM. TIGHT: the on-chip kernel gate compiles exactly
 # this cap shape as `fact_kernel_cap`; if it fails there, lower this

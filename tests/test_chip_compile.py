@@ -90,12 +90,14 @@ def _scopes(text: str) -> set:
 
 
 @pytest.mark.parametrize("n_nodes,unit_hess", [
+    (1, False), (8, False),         # the shallow levels (row tile 4,096),
+    (16, True),                     # whose lo one-hot PR 35 transposed
     (32, False), (32, True),        # factorized kernel, 3 and 2 channels
     (512, False), (512, True),      # deep level: four hi blocks
 ])
 def test_histogram_kernels_compile(one_chip, n_nodes, unit_hess):
     assert (-(-n_nodes * BINS // 128) <= histogram._FACT_MAX_NHI) == \
-        (n_nodes == 32)             # one hi block, and several
+        (n_nodes <= 32)             # one hi block, and several
     fn = jax.jit(lambda b, r, g, h, w: histogram.build_histogram(
         b, r, g, h, w, n_nodes, BINS, "pallas", unit_hess=unit_hess))
     f32 = _s((ROWS_N,), jnp.float32, one_chip)
@@ -104,7 +106,24 @@ def test_histogram_kernels_compile(one_chip, n_nodes, unit_hess):
                  f32).compile()
     assert _kernels(c) == 1
     assert _kernel_names(c) == {
-        "hist_fact" if n_nodes == 32 else "hist_blocked"}
+        "hist_fact" if n_nodes <= 32 else "hist_blocked"}
+
+
+@pytest.mark.parametrize("F,n_nodes,bins,C,dtype", [
+    (136, 8, 256, 3, jnp.uint8),    # MSLR's width: 17 groups of 8
+    (28, 64, 64, 2, jnp.uint8),     # a forest's level of 32 hi slots
+    (8, 8, 512, 3, jnp.uint16),     # the airline's 16-bit codes
+])
+def test_shallow_levels_compile_at_the_other_cells_shapes(
+        one_chip, F, n_nodes, bins, C, dtype):
+    """The row-axis contraction of the lo one-hot (PR 35) at the wide
+    row tile and the widths of the cells that are not HIGGS'."""
+    fn = jax.jit(lambda b, r, v: histogram._hist_pallas(
+        b, r, v, n_nodes, bins))
+    c = fn.lower(_s((ROWS_N, F), dtype, one_chip),
+                 _s((ROWS_N,), jnp.int32, one_chip),
+                 _s((ROWS_N, C), jnp.float32, one_chip)).compile()
+    assert _kernels(c) == 1 and _kernel_names(c) == {"hist_fact"}
 
 
 def _boost_args(mesh, rows, ntrees):

@@ -135,28 +135,155 @@ def test_hi_blocked_class_batch_stores_binned_once(monkeypatch, cap):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
 
 
+def _kernel_names_for_tpu(fn, *args):
+    import re
+    import unittest.mock as mock
+
+    import jax
+
+    with mock.patch("jax.default_backend", lambda: "tpu"):
+        txt = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    return set(re.findall(r'kernel_name = "(\w+)"', txt))
+
+
 def test_hi_blocked_level_lowers_for_tpu_under_its_own_name(monkeypatch):
     """AOT-lower a level past the cap for a TPU target from the CPU:
     Mosaic accepts the 4-D grid with several hi blocks, and the call is
     named `hist_blocked` (a level within the cap stays `hist_fact`) —
     the name a profile and the benchmark's readers find it by."""
-    import unittest.mock as mock
-
-    import jax
-
     import h2o_kubernetes_tpu.ops.histogram as H
 
     rows, F, n_bins = 2048, 3, 64
     binned, rel, g, h, w = _random_case(rows, F, 16, n_bins, seed=3)
     monkeypatch.setattr(H, "_FACT_MAX_NHI", 8)
-    with mock.patch("jax.default_backend", lambda: "tpu"):
-        for n_nodes, name, other in ((16, "hist_fact", "hist_blocked"),
-                                     (64, "hist_blocked", "hist_fact")):
-            txt = jax.jit(lambda r: build_histogram(
-                binned, r, g, h, w, n_nodes, n_bins, "pallas")).trace(
-                rel).lower(lowering_platforms=("tpu",)).as_text()
-            assert f'kernel_name = "{name}"' in txt
-            assert other not in txt
+    for n_nodes, name in ((16, "hist_fact"), (64, "hist_blocked")):
+        assert _kernel_names_for_tpu(lambda r: build_histogram(
+            binned, r, g, h, w, n_nodes, n_bins, "pallas"),
+            rel) == {name}
+
+
+# the shallow levels — a handful of histogrammed nodes at the cells'
+# widths, where the lo one-hot's layout bound the call until PR 35:
+# (rows, F, nodes, bins, unit_hess, 16-bit codes)
+_SHALLOW = {
+    "root_256": (1300, 28, 1, 256, False, False),
+    "two_nodes_256": (1100, 5, 2, 256, False, False),
+    "eight_nodes_256_wide_tile": (9000, 28, 8, 256, False, False),
+    "fourteen_nodes_256": (2100, 3, 14, 256, False, False),
+    "fifteen_nodes_256": (2100, 3, 15, 256, False, False),
+    "sixteen_nodes_256_two_channels": (1500, 4, 16, 256, True, False),
+    "forest_64_bins_32_nodes": (1800, 28, 32, 64, True, False),
+    "forest_64_bins_root_wide_tile": (8300, 6, 1, 64, True, False),
+    "three_channels_64_bins": (1500, 5, 15, 64, False, False),
+    "wide_136_columns": (700, 136, 8, 256, False, False),
+    "odd_bins_17": (900, 3, 4, 17, False, False),
+    "airline_512_bins_16_bit": (1200, 8, 1, 512, False, True),
+    "airline_512_bins_8_nodes": (1000, 8, 8, 512, False, True),
+    "wide_codes_256_bins": (1200, 8, 2, 256, False, True),
+    "leaf_totals_one_bin": (700, 1, 32, 1, False, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SHALLOW))
+def test_shallow_levels_match_segment(case):
+    """Rows not a multiple of the tile (both tiles: 1,024 and, from
+    8,192 rows, 4,096), ~20% dead rows carrying NaN gradients, ~10% of
+    weight 0, 8- and 16-bit codes, 2 and 3 channels, 28 and 136
+    columns (17 groups of 8), to 1e-5 of `segment`."""
+    r, F, n_nodes, n_bins, unit, wide = _SHALLOW[case]
+    binned, rel, g, h, w = _random_case(r, F, n_nodes, n_bins, seed=r + F)
+    if wide:
+        binned = binned.astype(jnp.uint16)
+    if unit:
+        h = jnp.ones_like(w)
+    args = (binned, rel, g, h, w, n_nodes, n_bins)
+    ref = build_histogram(*args, impl="segment", unit_hess=unit)
+    got = build_histogram(*args, impl="pallas", unit_hess=unit)
+    assert got.shape == (n_nodes, F, n_bins, 2 if unit else 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n_nodes,n_bins,unit", [
+    (1, 256, False), (8, 256, False), (16, 64, True)])
+def test_shallow_levels_under_the_mesh_match_one_shard(mesh8, n_nodes,
+                                                       n_bins, unit):
+    """Under shard_map over the 8-device mesh (per-shard rows, psum):
+    the sharded Pallas build equals one shard's over all rows."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from h2o_kubernetes_tpu.runtime.mesh import ROWS
+
+    binned, rel, g, h, w = _random_case(8 * 300, 6, n_nodes, n_bins,
+                                        seed=n_nodes)
+    g = jnp.nan_to_num(g)
+
+    def shard(b, r, gg, hh, ww):
+        return jax.lax.psum(build_histogram(
+            b, r, gg, hh, ww, n_nodes, n_bins, "pallas",
+            unit_hess=unit), ROWS)
+
+    got = jax.jit(jax.shard_map(
+        shard, mesh=mesh8, in_specs=(P(ROWS),) * 5, out_specs=P(),
+        check_vma=False))(binned, rel, g, h, w)
+    want = build_histogram(binned, rel, g, h, w, n_nodes, n_bins,
+                           "segment", unit_hess=unit)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("rows,n_nodes,n_bins,T", [
+    (8192, 1, 256, 4096), (8192, 16, 256, 4096), (2048, 128, 256, 1024)])
+def test_both_one_hots_are_built_rows_on_lanes(rows, n_nodes, n_bins, T):
+    """The kernel holds no `[T, 128]` operand: the lo one-hot is
+    `iota[128, T] == lo[None, :]`, contracted over the row axis of both
+    operands. Built as `iota[T, 128] == lo[:, None]` it cost 896 lane
+    permutes a (column, 4,096-row tile) and bound every shallow call at
+    2.4 µs a (column, tile), 3x what the call costs without them
+    (PERF.md section 3, PR 35)."""
+    import jax
+
+    from h2o_kubernetes_tpu.ops.histogram import _hist_pallas
+
+    jaxpr = str(jax.make_jaxpr(
+        lambda b, r, v: _hist_pallas(b, r, v, n_nodes, n_bins))(
+        jnp.zeros((rows, 3), jnp.uint8), jnp.zeros(rows, jnp.int32),
+        jnp.zeros((rows, 3), jnp.float32)))
+    assert f"bf16[128,{T}]" in jaxpr
+    assert f"[{T},128]" not in jaxpr
+
+
+def test_class_batch_lowers_to_one_hist_fact_call():
+    """The K-class grower's vmapped build is lowered into ONE flat call
+    of the factorized kernel over the merged node axis, `binned` stored
+    once."""
+    import jax
+
+    K, rows, F, n_nodes, n_bins = 3, 2048, 4, 2, 64
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, F, n_nodes, n_bins, seed=5)
+    assert _kernel_names_for_tpu(
+        jax.vmap(lambda rel, g, h: build_histogram(
+            binned, rel, g, h, w, n_nodes, n_bins, "pallas")),
+        relK, gK, hK) == {"hist_fact"}
+
+
+def test_auc_histogram_lowers_to_hist_fact():
+    """The AUC's one column of `_AUC_BINS` bins (32 hi slots) goes
+    through the same kernel under the same name."""
+    import h2o_kubernetes_tpu as h2o
+    from h2o_kubernetes_tpu import metrics as M
+
+    col = jnp.zeros(8192, jnp.float32)
+    prev = h2o.get_config("hist_impl")
+    h2o.set_config("hist_impl", "pallas")
+    try:
+        names = _kernel_names_for_tpu(M._score_hist_shard, col, col, col)
+    finally:
+        h2o.set_config("hist_impl", prev)
+    assert names == {"hist_fact"}
 
 
 @pytest.mark.parametrize("impl", ["segment", "pallas"])

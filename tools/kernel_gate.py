@@ -7,7 +7,9 @@ This script does, on a TPU:
 
 1. pallas-compiles the FACTORIZED histogram kernel (interpret=False is
    automatic on tpu) at a bench-like shape and asserts parity vs the
-   segment_sum reference path;
+   segment_sum reference path, at the shallow levels too (1 to 14
+   nodes x 256 bins at 28 columns and the 4,096-row tile; the op-alone
+   timings of the kernel's forms are `tools/hist_forms.py`'s);
 2. same for levels past the hi-block cap, which the same kernel
    serves in two and in four blocks of hi slots (`hist_blocked`), and
    the TreeSHAP serving kernel
@@ -42,7 +44,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 CHECK_NAMES = [
-    "fact_kernel", "fact_kernel_cap", "binblock_kernel",
+    "fact_kernel", "fact_kernel_cap", "fact_kernel_shallow",
+    "binblock_kernel",
     "binblock_kernel_4",
     "leaf_totals_kernel", "unit_hess_kernel",
     "boost_scan_binomial", "boost_scan_multinomial",
@@ -177,6 +180,20 @@ def main(argv=None) -> int:
         # Every block of a deeper level has this shape's working set
         parity("fact_kernel_cap", 50_000, 2,
                _FACT_MAX_NHI * 128 // 256, 256)
+
+    def chk_fact_kernel_shallow():
+        # the shallow levels at the bench's width and row tile (4,096):
+        # the root and 8 nodes x 256 bins, 14 (28 hi slots: not a power
+        # of two), and 64 nodes x 64 bins x 2 channels (a forest's
+        # level of 32 hi slots). Since PR 35 the lo one-hot
+        # is held transposed and the product contracts the row axis of
+        # both operands: Mosaic's lowering of that contraction, which
+        # interpret mode does not run (`tools/hist_forms.py` times it
+        # beside the form it replaced)
+        for n_nodes, n_bins, unit in ((1, 256, False), (8, 256, False),
+                                      (14, 256, False), (64, 64, True)):
+            parity(f"fact_kernel_shallow[{n_nodes}x{n_bins}]", 100_000,
+                   28, n_nodes, n_bins, unit_hess=unit)
 
     def chk_binblock_kernel():
         # twice the cap: the level is served in TWO blocks of hi slots
@@ -404,6 +421,7 @@ def main(argv=None) -> int:
     registry = {
         "fact_kernel": chk_fact_kernel,
         "fact_kernel_cap": chk_fact_kernel_cap,
+        "fact_kernel_shallow": chk_fact_kernel_shallow,
         "binblock_kernel": chk_binblock_kernel,
         "binblock_kernel_4": chk_binblock_kernel_4,
         "leaf_totals_kernel": chk_leaf_totals_kernel,
