@@ -8,6 +8,15 @@ tpuk CLI + h2o-tpu-operator reconciling the H2OTpu CRD).
 See SURVEY.md for the reference blueprint this is built against.
 """
 
+# the `import` span's start: stamped before anything else is imported,
+# filed at this file's last line (`sys` and `time` the interpreter has
+# loaded already)
+import sys as _sys
+import time as _time
+
+_IMPORT_STARTED = (_time.perf_counter_ns(), _time.thread_time_ns(),
+                   len(_sys.modules))
+
 from .automl import AutoML, Job, Leaderboard, jobs
 from .config import get_config, set_config
 from .grid import GridSearch, H2OGridSearch
@@ -37,11 +46,22 @@ def init(coordinator: str | None = None, **kw) -> None:
     compiles — the disk cache keys on hardware+HLO, so a SECOND
     process pays none of them.
     """
-    from .runtime.backend import enable_persistent_compile_cache
+    from .runtime.backend import (enable_persistent_compile_cache,
+                                  start_compile_watch)
+    from .runtime.telemetry import phase_span
 
-    enable_persistent_compile_cache()
-    initialize_distributed(coordinator, **kw)
-    global_mesh()
+    # the compile watch first (idempotent): what the spans of this
+    # process pay in jax's trace / lower / compile stages is theirs
+    start_compile_watch()
+    with phase_span("init"):
+        with phase_span("init.cache"):
+            enable_persistent_compile_cache()
+        with phase_span("init.distributed"):
+            initialize_distributed(coordinator, **kw)
+        # the first `jax.devices()` of a process that has not called
+        # it (the backend's start) lands here
+        with phase_span("init.mesh") as mesh:
+            mesh["devices"] = len(global_mesh().devices.flat)
 
 
 def cluster_status() -> dict:
@@ -59,3 +79,19 @@ def cluster_status() -> dict:
         "process_count": jax.process_count(),
         "devices": [str(d) for d in mesh.devices.flat],
     }
+
+
+def _file_import_span() -> None:
+    """The `import` root span: this package's import from its first
+    line to here, by the stamps above; `modules` is how many of
+    `sys.modules` it added, `cpu_ms` the importing thread's CPU."""
+    from .runtime.telemetry import record_root_span
+
+    t0, cpu0, modules0 = _IMPORT_STARTED
+    record_root_span(
+        "import", t0, _time.perf_counter_ns(),
+        modules=len(_sys.modules) - modules0,
+        cpu_ms=round((_time.thread_time_ns() - cpu0) / 1e6, 3))
+
+
+_file_import_span()

@@ -457,8 +457,8 @@ class Frame:
         # the transfer it queues (`frame.put`)
         with phase_span("frame.from_arrays", columns=len(cols)) as root:
             for name, col in cols.items():
-                with phase_span("frame.encode") as enc:
-                    arr = np.asarray(col)
+                with phase_span("frame.encode", column=name) as enc:
+                    arr = arrived = np.asarray(col)
                     domain = domains.get(name)
                     if arr.dtype.kind in "OUS":
                         # strings -> enum codes, against the given
@@ -468,9 +468,9 @@ class Frame:
                         arr = arr.astype(np.float32)
                     host, kind, origin, pad = Vec._host_rows(arr, domain)
                     host = pad_rows(host, pad_value=pad)
-                    enc["bytes"] = host.nbytes
-                with phase_span("frame.put", kind="enqueue",
-                                bytes=host.nbytes):
+                    enc.update(_encoded(arrived.dtype, domains.get(name), host))
+                with phase_span("frame.put", kind="enqueue", bytes=host.nbytes,
+                                shards=meshlib.n_row_shards()):
                     vecs[name] = Vec(put_rows(host), nrows=len(arr),
                                      kind=kind, domain=domain, name=name,
                                      origin=origin)
@@ -737,3 +737,16 @@ def _factorize(arr: np.ndarray,
         codes = np.array([lookup.get(x, NA_ENUM) for x in s], dtype=np.int32)
         codes[isna] = NA_ENUM
     return codes, domain
+
+
+def _encoded(arrived: np.dtype, given_domain, host: np.ndarray) -> dict:
+    """What a `frame.encode` span says of its column: the dtype it
+    arrived in, the path it took to its storage dtype, the bytes that
+    go to the device. (Down here, and called from one line of
+    `Frame.from_arrays`, so that no line of a traced operation of this
+    file moves: a program's cache key holds its operations' lines.)"""
+    if arrived.kind in "OUS":
+        path = "factorize" if given_domain is None else "factorize_domain"
+    else:
+        path = "as_is" if host.dtype == arrived else "cast"
+    return {"dtype": str(arrived), "path": path, "bytes": host.nbytes}

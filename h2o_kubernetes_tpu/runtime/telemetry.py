@@ -47,11 +47,18 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import functools
+import gc
 import os
 import sys
 import threading
 import time
 import uuid
+
+try:                                    # Unix only
+    import resource
+except ImportError:                     # pragma: no cover
+    resource = None
 
 from .retry import _env_float
 
@@ -59,7 +66,8 @@ __all__ = [
     "REGISTRY", "TRACER", "MetricsRegistry", "TraceRing",
     "register_group", "group_snapshot", "prometheus_text",
     "parse_prometheus_text", "build_info", "phase_span",
-    "SPAN_KINDS", "TRAIN_PROGRAMS",
+    "record_root_span", "credit_open_span", "process_start_ns",
+    "SPAN_KINDS", "HOST_FIELDS", "TRAIN_PROGRAMS",
     "record_request_phases", "new_trace_id", "trace_id_from",
     "count_event", "ooc_stream_account", "start_status_listener",
     "metric_name", "CONTENT_TYPE", "write_metrics",
@@ -731,13 +739,21 @@ class TraceRing:
     # a single record — a client reusing one (valid-looking) trace id
     # for every request must not grow one record without limit
     MAX_SPANS = 256
+    # ... and a span tree that `phase_span` files whole, under an id it
+    # minted itself: nobody can add to it, so its bound only stops a
+    # runaway loop of spans. (A 138-column frame's tree is 277 spans:
+    # under MAX_SPANS its last columns' spans were dropped, the string
+    # response's among them.)
+    MAX_TREE_SPANS = 4096
 
-    def record(self, trace_id: str, spans, **meta) -> None:
+    def record(self, trace_id: str, spans, limit: int | None = None,
+               **meta) -> None:
         """Append spans under ``trace_id`` (merging with an existing
         record — a hedged request's two legs land on one trace).
-        Past MAX_SPANS per record, further spans are dropped and the
-        record is flagged ``truncated`` (a reused id is a client bug
-        or an attack, never a reason for unbounded memory)."""
+        Past ``limit`` (MAX_SPANS) per record, further spans are
+        dropped and the record is flagged ``truncated`` (a reused id
+        is a client bug or an attack, never a reason for unbounded
+        memory)."""
         if not _trace_on():
             return
         with self._lock:
@@ -748,7 +764,7 @@ class TraceRing:
                 self._ring[trace_id] = rec
                 while len(self._ring) > self._capacity():
                     self._ring.popitem(last=False)
-            room = self.MAX_SPANS - len(rec["spans"])
+            room = (limit or self.MAX_SPANS) - len(rec["spans"])
             if room <= 0:
                 rec["truncated"] = True
             else:
@@ -847,7 +863,7 @@ def record_request_phases(trace_id: str | None, marks: dict,
 #   host     the block computes on the host
 #   enqueue  the block only queues device work: its seconds are the
 #            dispatch, not the work (a dispatch can itself block while
-#            the runtime's queue is full: an upper bound on host work)
+#            the runtime's queue is full: the record's `cpu_ms` says so)
 #   wait     the block reads a device result back, so it also waits
 #            for whatever was queued before it
 SPAN_KINDS = ("host", "enqueue", "wait")
@@ -875,8 +891,40 @@ TRAIN_PROGRAMS = {
                "_auc_of_score_hist", "_rmse_w", "_rmse_unw"),
 }
 
+# What the host did under a span, on the ring's record beside its
+# times (docs/OBSERVABILITY.md "Training spans" has the table). All
+# inclusive, as `ms` is: a reader takes a span's own part as the span
+# less its children.
+#   cpu_ms                the span's own thread on the CPU, user +
+#                         system (the thread's CPU clock at the span's
+#                         two ends): `ms - cpu_ms` is the time the
+#                         thread was not running (blocked in a dispatch
+#                         or a read-back, or switched out)
+#   sys_ms                the system part, by getrusage(RUSAGE_THREAD)
+#                         at the two ends. Both always there where the
+#                         platform can say, zero or not
+#   faults, switched      page faults (minor + major) and involuntary
+#                         context switches of that thread, by the same
+#                         two calls; where non-zero
+#   proc_cpu_ms           root spans: every thread of the process
+#                         (RUSAGE_SELF), the compiler's pool among them
+#   gc_ms                 collector pauses inside the span (`_on_gc`)
+#   trace_ms, lower_ms,   jax's trace / lower / backend-compile stages
+#   compile_ms,           under the span, each second under the innermost
+#   cache_load_ms,        stage (cache loads lie inside compile_ms); how
+#   traces, programs      many programs were traced, and the names of
+#                         those that paid: the compile watch
+#                         (runtime/backend.py) credits them
+_CREDITED = ("gc_ms", "trace_ms", "lower_ms", "compile_ms",
+             "cache_load_ms", "traces")
+HOST_FIELDS = ("cpu_ms", "sys_ms", "faults", "switched", "proc_cpu_ms",
+               *_CREDITED, "programs")
+MAX_PROGRAMS = 8        # names a span keeps; then `programs_more` counts
+
+# the record's own fields: kept out of the TimeLine's copy of a span
 _SPAN_FIELDS = frozenset(
-    {"name", "id", "parent", "kind", "t0_ns", "t1_ns", "ms"})
+    {"name", "id", "parent", "kind", "t0_ns", "t1_ns", "ms",
+     *HOST_FIELDS, "programs_more"})
 
 # the open span of this thread of control: (trace id, the root's span
 # list, this span's id)
@@ -896,6 +944,99 @@ def train_phase_histogram() -> Histogram:
                  300.0))
 
 
+# getrusage's selectors; RUSAGE_THREAD is Linux's. Where either is
+# missing the fields it gives are absent from a record, never zero
+_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+_PROCESS = getattr(resource, "RUSAGE_SELF", None)
+_thread_cpu_ns = getattr(time, "thread_time_ns", None)
+
+_gc_hook_lock = threading.Lock()
+_gc_hooked = False
+_gc_started = threading.local()
+
+
+def _cpu_ms(before, after) -> float:
+    return round((after.ru_utime - before.ru_utime
+                  + after.ru_stime - before.ru_stime) * 1e3, 3)
+
+
+def _thread_now() -> tuple:
+    """(the calling thread's CPU clock, its getrusage): one end of a
+    span; None for what the platform lacks."""
+    return (_thread_cpu_ns() if _thread_cpu_ns is not None else None,
+            resource.getrusage(_THREAD) if _THREAD is not None else None)
+
+
+def _thread_usage(rec: dict, before: tuple, after: tuple) -> None:
+    """What the thread used between two `_thread_now()`s, as a
+    record's fields. (`cpu_ms` is the thread's clock and not
+    getrusage's sum: the kernel adds a running thread's time up at its
+    tick, so over a span of a few milliseconds getrusage can say more
+    CPU than wall time. The split into user and system is sampled at
+    the tick too: `sys_ms` is good over many spans or a long one.)"""
+    (cpu0, ru0), (cpu1, ru1) = before, after
+    if cpu0 is not None:
+        rec["cpu_ms"] = round((cpu1 - cpu0) / 1e6, 3)
+    if ru0 is None:
+        return
+    rec["sys_ms"] = round((ru1.ru_stime - ru0.ru_stime) * 1e3, 3)
+    for name, n in (
+            ("faults", ru1.ru_minflt - ru0.ru_minflt
+             + ru1.ru_majflt - ru0.ru_majflt),
+            ("switched", ru1.ru_nivcsw - ru0.ru_nivcsw)):
+        if n:
+            rec[name] = n
+
+
+def _add_programs(rec: dict, names, more: int = 0) -> None:
+    have = rec.setdefault("programs", [])
+    for name in names:
+        if name in have:
+            continue
+        if len(have) < MAX_PROGRAMS:
+            have.append(name)
+        else:
+            more += 1
+    if more:
+        rec["programs_more"] = rec.get("programs_more", 0) + more
+
+
+def credit_open_span(programs=(), **credit) -> None:
+    """Add to the credited fields (``gc_ms=``, ``trace_ms=``,
+    ``traces=``, ...) and the program names of the innermost span open
+    on the calling thread; nothing where none is open. The span hands
+    them on to its parent when it ends, so the fields are inclusive as
+    its times are."""
+    here = _OPEN_SPAN.get()
+    if here is None:
+        return
+    rec = here[1][here[2]]
+    for field, v in credit.items():
+        rec[field] = rec.get(field, 0) + v
+    if programs:
+        _add_programs(rec, programs)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks`: a collection runs on the thread that set it off,
+    between a `start` and a `stop` call."""
+    if phase == "start":
+        _gc_started.ns = time.perf_counter_ns()
+        return
+    t0 = getattr(_gc_started, "ns", None)
+    if t0 is not None:
+        _gc_started.ns = None
+        credit_open_span(gc_ms=(time.perf_counter_ns() - t0) / 1e6)
+
+
+def _hook_gc() -> None:
+    global _gc_hooked
+    with _gc_hook_lock:
+        if not _gc_hooked:
+            gc.callbacks.append(_on_gc)
+            _gc_hooked = True
+
+
 @contextlib.contextmanager
 def phase_span(phase: str, kind: str = "host", **data):
     """THE span of the training path: times a block on
@@ -908,17 +1049,21 @@ def phase_span(phase: str, kind: str = "host", **data):
     `TRACER` when it ends, so ``GET /3/Trace/{id}`` serves a training
     job as it serves a request. A span record holds ``name``, ``id``
     and ``parent`` (ids count from 0 within the trace), ``kind`` (see
-    SPAN_KINDS), ``t0_ns``/``t1_ns``, ``ms`` and the attributes; the
-    block may add attributes to the dict it is given. The same span
-    lies in any profile taken meanwhile (`diagnostics.profile()`) as
+    SPAN_KINDS), ``t0_ns``/``t1_ns``, ``ms``, what the host did
+    meanwhile (HOST_FIELDS) and the attributes; the block may add
+    attributes to the dict it is given. The same span lies in any
+    profile taken meanwhile (`diagnostics.profile()`) as
     ``h2o.<name>``, on the profiler's clock beside the device
-    operations. ``H2O_TPU_TRACE=0`` switches the ring and the
+    operations, with the attributes it was opened with.
+    ``H2O_TPU_TRACE=0`` switches the ring, the host's readings and the
     annotation off; histogram and TimeLine stay."""
     if kind not in SPAN_KINDS:
         raise ValueError(f"span kind {kind!r} not in {SPAN_KINDS}")
     rec = dict(data)
-    token = note = None
+    token = note = used0 = proc0 = None
     if _trace_on():
+        if not _gc_hooked:
+            _hook_gc()
         tid, spans, parent = _OPEN_SPAN.get() or (new_trace_id(), [], None)
         ident = len(spans)
         spans.append(rec)               # in the order the spans opened
@@ -931,10 +1076,15 @@ def phase_span(phase: str, kind: str = "host", **data):
                 "h2o." + phase,
                 **{k: v for k, v in data.items() if v is not None})
             note.__enter__()
+        if parent is None and _PROCESS is not None:
+            proc0 = resource.getrusage(_PROCESS)
     t0 = time.perf_counter_ns()
+    if token is not None:
+        used0 = _thread_now()
     try:
         yield rec
     finally:
+        used1 = _thread_now() if used0 is not None else None
         t1 = time.perf_counter_ns()
         dur = (t1 - t0) / 1e9
         if token is not None:
@@ -943,8 +1093,26 @@ def phase_span(phase: str, kind: str = "host", **data):
             _OPEN_SPAN.reset(token)
             rec.update(name=phase, id=ident, parent=parent, kind=kind,
                        t0_ns=t0, t1_ns=t1, ms=round(dur * 1000.0, 3))
-            if parent is None:
-                TRACER.record(tid, spans, root=phase)
+            _thread_usage(rec, used0, used1)
+            # what was credited to this span goes on to its parent
+            up = None if parent is None else spans[parent]
+            for field in _CREDITED:
+                v = rec.get(field)
+                if v is None:
+                    continue
+                if isinstance(v, float):
+                    v = rec[field] = round(v, 3)
+                if up is not None:
+                    up[field] = up.get(field, 0) + v
+            if up is None:
+                if proc0 is not None:
+                    rec["proc_cpu_ms"] = _cpu_ms(
+                        proc0, resource.getrusage(_PROCESS))
+                TRACER.record(tid, spans, limit=TraceRing.MAX_TREE_SPANS,
+                              root=phase)
+            elif "programs" in rec:
+                _add_programs(up, rec["programs"],
+                              rec.get("programs_more", 0))
         train_phase_histogram().observe(dur, label_value=phase)
         try:
             from ..diagnostics import timeline
@@ -956,6 +1124,39 @@ def phase_span(phase: str, kind: str = "host", **data):
                    if k not in _SPAN_FIELDS})
         except Exception:  # noqa: BLE001 — accounting only
             pass
+
+
+def record_root_span(phase: str, t0_ns: int, t1_ns: int, **data) -> None:
+    """File a root span after the fact, from two stamps of
+    ``time.perf_counter_ns()``: for a block that starts before this
+    module can be imported (the package's own `import`). Ring and
+    histogram only: no host readings were taken at its ends, and the
+    profiler's annotation cannot be written late."""
+    dur = (t1_ns - t0_ns) / 1e9
+    if _trace_on():
+        rec = dict(data, name=phase, id=0, parent=None, kind="host",
+                   t0_ns=t0_ns, t1_ns=t1_ns, ms=round(dur * 1000.0, 3))
+        TRACER.record(new_trace_id(), [rec], root=phase)
+    train_phase_histogram().observe(dur, label_value=phase)
+
+
+@functools.cache
+def process_start_ns() -> int | None:
+    """The process's start on ``time.perf_counter_ns()``'s clock, so
+    that a reader can lay the ring's first records out from the
+    process's own zero: `/proc/self/stat`'s start time (field 22, in
+    ticks since boot) against CLOCK_BOOTTIME, to the tick. None where
+    that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the second field is the command in brackets, and may
+            # hold spaces: count from its closing bracket
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_ns = time.clock_gettime_ns(time.CLOCK_BOOTTIME) \
+            - ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        return time.perf_counter_ns() - age_ns
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
 
 
 # -- out-of-core stream overlap accounting ----------------------------------

@@ -568,15 +568,12 @@ def test_span_kinds_nest_and_list_by_root():
                 r["spans"][1]["depth"]) == ("level_hist", 0, 0)
 
 
-def test_telemetry_module_needs_no_jax():
-    """The router serves this module without a device runtime: loaded
-    beside empty parent packages it imports no jax, and a span works
-    without the profiler's annotation."""
-    import subprocess
-    import sys
-
+def _bare_telemetry():
+    """A child's first lines: the telemetry module as `t`, loaded
+    beside empty parent packages, so that nothing else of the package
+    (and no jax) is imported."""
     pkg = os.path.dirname(os.path.abspath(h2o.__file__))
-    code = (
+    return (
         "import sys, types, importlib\n"
         f"pkg = {pkg!r}\n"
         "for name, path in (('h2o_kubernetes_tpu', pkg),\n"
@@ -585,7 +582,254 @@ def test_telemetry_module_needs_no_jax():
         "    m = types.ModuleType(name); m.__path__ = [path]\n"
         "    sys.modules[name] = m\n"
         "t = importlib.import_module(\n"
-        "    'h2o_kubernetes_tpu.runtime.telemetry')\n"
+        "    'h2o_kubernetes_tpu.runtime.telemetry')\n")
+
+
+def _one_root(name):
+    return telemetry.TRACER.by_root(name)[-1]["spans"]
+
+
+def _busy(seconds):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+@pytest.mark.parametrize("what", ["busy", "asleep"])
+def test_span_reads_its_threads_cpu(what):
+    """`cpu_ms` is the span's own thread on the CPU, so `ms - cpu_ms`
+    is the time it was not running: a busy loop reads within 20% of
+    its wall time, a sleep near none. Beside other workers the machine
+    may take the busy thread off the CPU, and the span then has to say
+    that too: it is held to the thread's own CPU clock around the same
+    block every time, and to its wall time whenever that clock shows
+    the thread was left to run (five tries)."""
+    for _ in range(5):
+        cpu0, wall0 = time.thread_time(), time.perf_counter()
+        with telemetry.phase_span("unit_cpu"):
+            _busy(0.1) if what == "busy" else time.sleep(0.05)
+        cpu, wall = time.thread_time() - cpu0, time.perf_counter() - wall0
+        (root,) = _one_root("unit_cpu")
+        assert {"cpu_ms", "sys_ms", "proc_cpu_ms"} <= set(root)
+        assert 0 <= root["cpu_ms"] <= root["ms"] + 0.01
+        if what == "asleep":
+            assert root["cpu_ms"] < 5 and root["ms"] - root["cpu_ms"] > 40
+            return
+        assert root["cpu_ms"] / 1e3 == pytest.approx(cpu, rel=0.2)
+        if cpu >= 0.9 * wall:
+            assert root["cpu_ms"] >= 0.8 * root["ms"]
+            return
+
+
+def test_a_childs_readings_lie_inside_its_parents():
+    import gc
+
+    with telemetry.phase_span("unit_family"):
+        _busy(0.02)
+        with telemetry.phase_span("first"):
+            _busy(0.03)
+            gc.collect()
+        with telemetry.phase_span("second"):
+            time.sleep(0.01)
+    root, first, second = _one_root("unit_family")
+    # only a root says what the whole process used
+    assert "proc_cpu_ms" in root and "proc_cpu_ms" not in first
+    assert root["cpu_ms"] >= first["cpu_ms"] + second["cpu_ms"]
+    assert first["cpu_ms"] >= 20 > second["cpu_ms"]
+    # the collection ran under `first`: on it and on the root (the
+    # fields are inclusive, as `ms` is), not on its sibling
+    assert 0 < first["gc_ms"] <= first["ms"]
+    assert root["gc_ms"] >= first["gc_ms"] and "gc_ms" not in second
+    # counts are left out where they are zero, times never
+    assert all(v > 0 for s in (root, first, second)
+               for k, v in s.items() if k in ("faults", "switched"))
+    # and none of it goes to the TimeLine's copy of the span
+    from h2o_kubernetes_tpu.diagnostics import timeline
+
+    ev = [e for e in timeline.events("phase")
+          if e.get("phase") == "unit_family"][-1]
+    assert not set(ev) & set(telemetry.HOST_FIELDS)
+
+
+def test_compile_stages_are_credited_to_the_span_that_paid(mesh8):
+    """A jitted function's first call under a span leaves the seconds
+    of jax's three stages and its name on the innermost open span (and
+    up the tree) and in the watch's `by_program`; its second call
+    leaves nothing: any `trace_ms` on a later record is a re-trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from h2o_kubernetes_tpu.runtime.backend import (compile_watch_snapshot,
+                                                    start_compile_watch)
+
+    start_compile_watch()
+
+    @jax.jit
+    def unit_fresh_program(x):
+        return jnp.tanh(x) * 3.0 + jnp.cumsum(x)
+
+    x = jnp.ones(7)
+    x.block_until_ready()
+    before = compile_watch_snapshot()
+    with telemetry.phase_span("unit_compile"):
+        with telemetry.phase_span("first_call", kind="enqueue"):
+            unit_fresh_program(x)
+        with telemetry.phase_span("second_call", kind="enqueue"):
+            unit_fresh_program(x)
+    root, first, second = _one_root("unit_compile")
+    for field in ("trace_ms", "lower_ms", "compile_ms"):
+        assert 0 < first[field] <= root[field] <= root["ms"]
+    assert first["trace_ms"] + first["lower_ms"] + first["compile_ms"] \
+        <= first["ms"]
+    assert "unit_fresh_program" in first["programs"]
+    assert "unit_fresh_program" in root["programs"]
+    assert not set(second) & {"trace_ms", "lower_ms", "compile_ms",
+                              "cache_load_ms", "programs"}
+    after = compile_watch_snapshot()
+    mine = after["by_program"]["unit_fresh_program"]
+    assert mine["traces"] == 1 and mine["compiles"] == 1
+    assert mine["trace_s"] > 0 and mine["lower_s"] > 0
+    assert mine["compile_s"] >= mine["cache_load_s"] >= 0
+    assert after["traces"] - before["traces"] >= 1
+    for key, field in (("trace_s", "trace_ms"), ("lower_s", "lower_ms"),
+                       ("compile_s", "compile_ms")):
+        assert (after[key] - before[key]) * 1e3 == pytest.approx(
+            root[field], abs=0.01)
+    # the stat group lists the programs (a list: the exposition's
+    # flattener passes over it, so a program's name mints no series)
+    group = telemetry.group_snapshot(["compiles"])["compiles"]
+    assert "unit_fresh_program" in [r["program"]
+                                    for r in group["by_program"]]
+    assert "unit_fresh_program" not in telemetry.prometheus_text()
+
+
+def test_import_and_init_are_spans_from_the_process_own_zero():
+    """A fresh process: the package's import is one `import` root,
+    `h2o.init()` one `init` root with its three children and the
+    compile watch installed, and the process's start precedes both on
+    the same clock."""
+    import subprocess
+    import sys
+
+    code = (
+        "import json, time\n"
+        "import h2o_kubernetes_tpu as h2o\n"
+        "from h2o_kubernetes_tpu.runtime import backend, telemetry\n"
+        "assert not backend._watch_installed\n"
+        "h2o.init()\n"
+        "print(json.dumps({\n"
+        "    'import': telemetry.TRACER.by_root('import'),\n"
+        "    'init': telemetry.TRACER.by_root('init'),\n"
+        "    'start': telemetry.process_start_ns(),\n"
+        "    'now': time.perf_counter_ns(),\n"
+        "    'watch': backend._watch_installed}))\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, r.stderr
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    (imp,), (init,) = got["import"], got["init"]
+    (root,) = imp["spans"]
+    assert root["name"] == "import" and root["parent"] is None
+    assert root["modules"] > 100 and 0 < root["cpu_ms"] <= root["ms"] + 1
+    assert got["start"] < root["t0_ns"] < root["t1_ns"] <= got["now"]
+    # to the tick, and a Python process is up within seconds
+    assert root["t0_ns"] - got["start"] < 60e9
+    names = [s["name"] for s in init["spans"]]
+    assert names == ["init", "init.cache", "init.distributed", "init.mesh"]
+    _check_tree(init["spans"])
+    assert init["spans"][0]["t0_ns"] >= root["t1_ns"]
+    assert init["spans"][3]["devices"] >= 1
+    assert all("cpu_ms" in s for s in init["spans"])
+    assert got["watch"] is True
+
+
+def test_frame_encode_says_what_it_encoded(mesh8):
+    cols = {"s": np.array(["a", "b", "a", "c"]),
+            "f": np.arange(4, dtype=np.float32),
+            "d": np.arange(4, dtype=np.float64),
+            "b": np.array([True, False, True, True]),
+            "g": np.array(["x", "y", "x", "x"], dtype=object)}
+    h2o.Frame.from_arrays(cols, domains={"g": ["x", "y"]})
+    spans = _one_root("frame.from_arrays")
+    enc = {s["column"]: s for s in spans if s["name"] == "frame.encode"}
+    assert {c: (s["path"], s["dtype"]) for c, s in enc.items()} == {
+        "s": ("factorize", "<U1"), "f": ("as_is", "float32"),
+        "d": ("cast", "float64"), "b": ("cast", "bool"),
+        "g": ("factorize_domain", "object")}
+    assert all(s["bytes"] == 8 * 4 and "cpu_ms" in s for s in enc.values())
+    puts = [s for s in spans if s["name"] == "frame.put"]
+    assert len(puts) == 5 and all(s["shards"] == 8 for s in puts)
+
+
+def test_a_wide_frames_tree_is_filed_whole(mesh8):
+    """A span tree goes to the ring whole: 138 columns are 277 spans,
+    over a request record's bound (which dropped the last columns'
+    spans, `y` among them), under a tree's."""
+    cols = {f"f{i}": np.zeros(16, np.float32) for i in range(137)}
+    cols["y"] = np.array(["a", "b"] * 8)
+    h2o.Frame.from_arrays(cols)
+    rec = telemetry.TRACER.by_root("frame.from_arrays")[-1]
+    assert len(rec["spans"]) == 277 > telemetry.TraceRing.MAX_SPANS
+    assert "truncated" not in rec
+    assert rec["spans"][-2]["column"] == "y"
+    # a record that requests merge into keeps its bound
+    telemetry.TRACER.record("unit_reused_id", [{"name": "x"}] * 300)
+    assert len(telemetry.TRACER.get("unit_reused_id")["spans"]) == 256
+
+
+def test_trace_off_makes_no_host_reading(mesh8, monkeypatch):
+    """With `H2O_TPU_TRACE=0` a span asks the kernel nothing: getrusage
+    raises here, and the job trains, bitwise as with the ring on."""
+    on = _train_tiny(seed=13)
+    assert "cpu_ms" in _one_root("train")[0]
+
+    def refuse(who):
+        raise AssertionError("getrusage called with the ring off")
+
+    monkeypatch.setenv("H2O_TPU_TRACE", "0")
+    monkeypatch.setattr(telemetry.resource, "getrusage", refuse)
+    telemetry.TRACER.clear()
+    off = _train_tiny(seed=13)
+    assert telemetry.TRACER.by_root("train") == []
+    for a, b in zip(on.trees, off.trees):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert on.scoring_history == off.scoring_history
+
+
+def test_host_readings_need_no_jax():
+    """As `test_telemetry_module_needs_no_jax`, for what a span reads
+    of the host and for the spans filed after the fact."""
+    import subprocess
+    import sys
+
+    code = _bare_telemetry() + (
+        "import gc, time\n"
+        "with t.phase_span('r'):\n"
+        "    gc.collect()\n"
+        "    t.credit_open_span(programs=('p',), trace_ms=1.5)\n"
+        "t.record_root_span('late', 5, 2_000_005, modules=3)\n"
+        "(rec,) = t.TRACER.by_root('r')\n"
+        "(r,) = rec['spans']\n"
+        "assert r['cpu_ms'] >= 0 and r['gc_ms'] > 0, r\n"
+        "assert r['trace_ms'] == 1.5 and r['programs'] == ['p'], r\n"
+        "(late,) = t.TRACER.by_root('late')[0]['spans']\n"
+        "assert late['ms'] == 2.0 and late['modules'] == 3\n"
+        "assert t.process_start_ns() < time.perf_counter_ns()\n"
+        "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def test_telemetry_module_needs_no_jax():
+    """The router serves this module without a device runtime: loaded
+    beside empty parent packages it imports no jax, and a span works
+    without the profiler's annotation."""
+    import subprocess
+    import sys
+
+    code = _bare_telemetry() + (
         "with t.phase_span('r'):\n"
         "    with t.phase_span('c', kind='wait'):\n"
         "        pass\n"
