@@ -105,7 +105,7 @@ class Vec:
         if kind == "enum":
             if x.dtype.kind == "f":  # pre-encoded float codes: NaN is NA
                 x = np.where(np.isnan(x), NA_ENUM, x)
-            return x.astype(np.int32), kind, 0.0, NA_ENUM
+            return x.astype(np.int32, copy=_put_aliases()), kind, 0.0, NA_ENUM
         if kind == "time":
             if x.dtype.kind == "M":
                 ms = x.astype("datetime64[ms]").astype(np.float64)
@@ -114,14 +114,14 @@ class Vec:
                 ms = x.astype(np.float64)
             origin = float(np.nanmin(ms)) if len(ms) else 0.0
             return (ms - origin).astype(np.float32), kind, origin, np.nan
-        return x.astype(np.float32), kind, 0.0, np.nan
+        return x.astype(np.float32, copy=_put_aliases()), kind, 0.0, np.nan
 
     @staticmethod
     def from_numpy(x: np.ndarray, name: str = "", domain=None,
                    kind: str | None = None) -> "Vec":
         x = np.asarray(x)
         arr, kind, origin, pad = Vec._host_rows(x, domain, kind)
-        return Vec(shard_rows(arr, pad_value=pad), nrows=len(x), kind=kind,
+        return Vec(_shard_settled(arr, pad, x), nrows=len(x), kind=kind,
                    domain=domain, name=name, origin=origin)
 
     # -- basics -------------------------------------------------------------
@@ -452,28 +452,28 @@ class Frame:
         """Build from {name: array-like}. Object/str columns become enums."""
         domains = dict(domains or {})
         vecs: dict[str, Vec] = {}
-        # spans (runtime/telemetry.phase_span): per column the host's
-        # part (`frame.encode`: factorize, casts, padding) apart from
-        # the transfer it queues (`frame.put`)
+        # spans (telemetry.phase_span), per column: the host's part
+        # (`frame.encode`), its transfer queued and the column before it
+        # waited for (`frame.put`); then the last one (`frame.settle`)
         with phase_span("frame.from_arrays", columns=len(cols)) as root:
             for name, col in cols.items():
                 with phase_span("frame.encode", column=name) as enc:
                     arr = arrived = np.asarray(col)
-                    domain = domains.get(name)
-                    if arr.dtype.kind in "OUS":
-                        # strings -> enum codes, against the given
-                        # domain or a built vocab
-                        arr, domain = _factorize(arr, domain=domain)
+                    domain, path = domains.get(name), None
+                    if arr.dtype.kind in "OUS":   # strings -> enum codes
+                        arr, domain, path = _factorize(arr, domain=domain)
                     elif arr.dtype.kind == "b" and domain is None:
                         arr = arr.astype(np.float32)
                     host, kind, origin, pad = Vec._host_rows(arr, domain)
                     host = pad_rows(host, pad_value=pad)
-                    enc.update(_encoded(arrived.dtype, domains.get(name), host))
+                    enc.update(_encoded(arrived.dtype, path, host))
                 with phase_span("frame.put", kind="enqueue", bytes=host.nbytes,
                                 shards=meshlib.n_row_shards()):
-                    vecs[name] = Vec(put_rows(host), nrows=len(arr),
+                    vecs[name] = Vec(_put_after(host, vecs), nrows=len(arr),
                                      kind=kind, domain=domain, name=name,
                                      origin=origin)
+            with phase_span("frame.settle", kind="wait"):
+                _settle(vecs)
             root["rows"] = len(arr) if cols else 0
         return Frame(vecs)
 
@@ -713,14 +713,26 @@ class Frame:
         return Frame(out)
 
 
-def _factorize(arr: np.ndarray,
-               domain: list[str] | None = None) -> tuple[np.ndarray, list[str]]:
-    """String column → (int32 codes, sorted vocab).
+def _factorize(arr: np.ndarray, domain: list[str] | None = None
+               ) -> tuple[np.ndarray, list[str], str]:
+    """String column → (int32 codes, sorted vocab, the path taken, as
+    the `frame.encode` span says it).
 
     NA is only true missingness: None / float NaN cells in object arrays
     and empty strings. Literal tokens like "NA" or "nan" stay categories —
     parse-time NA-token handling is the CSV reader's job, not ours.
+
+    Fixed-width strings with no given domain go through a table over
+    their code points (`encode.factorize_table`: no sort, no copy of the
+    strings) wherever it serves; what follows is the general case.
     """
+    given = domain is not None
+    if not given and arr.dtype.kind in "US":
+        from .encode import factorize_table
+
+        served = factorize_table(arr)
+        if served is not None:
+            return *served, "factorize_table"
     if arr.dtype.kind == "O":
         isna = np.array([x is None or x != x for x in arr], dtype=bool)
     else:
@@ -736,17 +748,55 @@ def _factorize(arr: np.ndarray,
         lookup = {d: i for i, d in enumerate(domain)}
         codes = np.array([lookup.get(x, NA_ENUM) for x in s], dtype=np.int32)
         codes[isna] = NA_ENUM
-    return codes, domain
+    return codes, domain, "factorize_domain" if given else "factorize"
 
 
-def _encoded(arrived: np.dtype, given_domain, host: np.ndarray) -> dict:
+def _encoded(arrived: np.dtype, factorized: str | None,
+             host: np.ndarray) -> dict:
     """What a `frame.encode` span says of its column: the dtype it
-    arrived in, the path it took to its storage dtype, the bytes that
-    go to the device. (Down here, and called from one line of
-    `Frame.from_arrays`, so that no line of a traced operation of this
-    file moves: a program's cache key holds its operations' lines.)"""
-    if arrived.kind in "OUS":
-        path = "factorize" if given_domain is None else "factorize_domain"
-    else:
-        path = "as_is" if host.dtype == arrived else "cast"
+    arrived in, the path it took to its storage dtype (`_factorize`'s
+    for a string column), the bytes that go to the device. (Down here,
+    and called from one line of `Frame.from_arrays`, so that no line of
+    a traced operation of this file moves: a program's cache key holds
+    its operations' lines.)"""
+    path = factorized or ("as_is" if host.dtype == arrived else "cast")
     return {"dtype": str(arrived), "path": path, "bytes": host.nbytes}
+
+
+def _put_aliases() -> bool:
+    """Whether the device array `put_rows` makes goes on reading the
+    host array it was given for as long as it lives. The CPU backend's
+    `jnp.asarray` keeps an aligned numpy buffer as it is, so there
+    `Vec._host_rows` copies a column that needs no conversion, as it did
+    everywhere; an accelerator reads the array until its transfer is
+    done (after `device_put` has returned: seen on the chip), so there
+    the column goes as it arrived and whoever put it waits: `_settle`,
+    `_shard_settled`."""
+    return jax.default_backend() == "cpu"
+
+
+def _settle(sent: Mapping[str, Vec]) -> None:
+    """Wait until the last column of `sent` is on the device: its host
+    array has been read, and the whole-column copy `put_rows` stages on
+    the default device of a sharded frame is gone."""
+    if sent:
+        jax.block_until_ready(next(reversed(sent.values())).data)
+
+
+def _put_after(host: np.ndarray, sent: Mapping[str, Vec]) -> jax.Array:
+    """`put_rows(host)`, then wait for the column queued before it: a
+    transfer overlaps the next column's queuing and no more, so a frame
+    of any width stages two columns at most (the host's own copy of a
+    column used to pace the transfers; with no copy nothing else does)."""
+    data = put_rows(host)
+    _settle(sent)
+    return data
+
+
+def _shard_settled(host: np.ndarray, pad, given: np.ndarray) -> jax.Array:
+    """`shard_rows(host)`, waited for where `host` is the caller's own
+    array and not a conversion of it."""
+    data = shard_rows(host, pad_value=pad)
+    if np.may_share_memory(host, given):
+        jax.block_until_ready(data)
+    return data

@@ -307,7 +307,7 @@ def _import_arrow(files: list[str], fmt: str,
                 pa.types.is_binary(t):
             arr = np.asarray(col.to_pylist(), dtype=object)
             from .frame import _factorize
-            codes, dom = _factorize(arr)
+            codes, dom, _ = _factorize(arr)
             v = Vec.from_numpy(codes, name, domain=dom)
         else:
             a = col.to_numpy(zero_copy_only=False).astype(np.float64)

@@ -497,10 +497,10 @@ def test_train_leaves_one_span_tree(stats_server):
     _check_tree(fr["spans"])
     assert fr["spans"][0]["columns"] == 5 and fr["spans"][0]["rows"] == 300
     kinds = [(s["name"], s["kind"]) for s in fr["spans"][1:]]
-    assert kinds == [("frame.encode", "host"),
-                     ("frame.put", "enqueue")] * 5
+    assert kinds == [("frame.encode", "host"), ("frame.put", "enqueue")
+                     ] * 5 + [("frame.settle", "wait")]
     # 300 rows pad to 304 over the 8 shards, 4 bytes a cell
-    assert all(s["bytes"] == 304 * 4 for s in fr["spans"][1:])
+    assert all(s["bytes"] == 304 * 4 for s in fr["spans"][1:-1])
     assert fr["spans"][0]["t1_ns"] <= root["t0_ns"]
 
 
@@ -749,30 +749,32 @@ def test_frame_encode_says_what_it_encoded(mesh8):
             "f": np.arange(4, dtype=np.float32),
             "d": np.arange(4, dtype=np.float64),
             "b": np.array([True, False, True, True]),
-            "g": np.array(["x", "y", "x", "x"], dtype=object)}
+            "g": np.array(["x", "y", "x", "x"], dtype=object),
+            "o": np.array(["x", None, "x", "y"], dtype=object)}
     h2o.Frame.from_arrays(cols, domains={"g": ["x", "y"]})
     spans = _one_root("frame.from_arrays")
     enc = {s["column"]: s for s in spans if s["name"] == "frame.encode"}
     assert {c: (s["path"], s["dtype"]) for c, s in enc.items()} == {
-        "s": ("factorize", "<U1"), "f": ("as_is", "float32"),
+        "s": ("factorize_table", "<U1"), "f": ("as_is", "float32"),
         "d": ("cast", "float64"), "b": ("cast", "bool"),
-        "g": ("factorize_domain", "object")}
+        "g": ("factorize_domain", "object"), "o": ("factorize", "object")}
     assert all(s["bytes"] == 8 * 4 and "cpu_ms" in s for s in enc.values())
     puts = [s for s in spans if s["name"] == "frame.put"]
-    assert len(puts) == 5 and all(s["shards"] == 8 for s in puts)
+    assert len(puts) == 6 and all(s["shards"] == 8 for s in puts)
 
 
 def test_a_wide_frames_tree_is_filed_whole(mesh8):
-    """A span tree goes to the ring whole: 138 columns are 277 spans,
+    """A span tree goes to the ring whole: 138 columns are 278 spans,
     over a request record's bound (which dropped the last columns'
     spans, `y` among them), under a tree's."""
     cols = {f"f{i}": np.zeros(16, np.float32) for i in range(137)}
     cols["y"] = np.array(["a", "b"] * 8)
     h2o.Frame.from_arrays(cols)
     rec = telemetry.TRACER.by_root("frame.from_arrays")[-1]
-    assert len(rec["spans"]) == 277 > telemetry.TraceRing.MAX_SPANS
+    assert len(rec["spans"]) == 278 > telemetry.TraceRing.MAX_SPANS
     assert "truncated" not in rec
-    assert rec["spans"][-2]["column"] == "y"
+    assert rec["spans"][-3]["column"] == "y"
+    assert rec["spans"][-1]["name"] == "frame.settle"
     # a record that requests merge into keeps its bound
     telemetry.TRACER.record("unit_reused_id", [{"name": "x"}] * 300)
     assert len(telemetry.TRACER.get("unit_reused_id")["spans"]) == 256
