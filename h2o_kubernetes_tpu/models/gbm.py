@@ -30,7 +30,7 @@ from jax import lax
 
 from ..frame import Frame
 from ..runtime.health import device_dispatch, require_healthy
-from ..runtime.mesh import ROWS, global_mesh, replicated, row_sharding
+from ..runtime.mesh import ROWS, global_mesh, row_sharding
 from ..runtime.telemetry import phase_span
 from .base import (Model, TrainData, _feature_names, resolve_response,
                    resolve_xy)
@@ -240,6 +240,10 @@ class BoostPlan(NamedTuple):
     # the query layout of a grouped objective (`rank.RankLayout`: the
     # last operand of `_boost_jit`), None for a pointwise one
     rank: RankLayout | None = None
+    # how a round's K class trees are grown: "vmap" (one batched
+    # histogram call a level) or "map" (a class at a time, past
+    # `core._MULTI_HIST_BUDGET`); None for one tree a round
+    class_batch: str | None = None
 
     @property
     def mode(self) -> str:
@@ -426,26 +430,18 @@ class BoostPlan(NamedTuple):
         out = []
         if self.device_init:
             out.append((_init_margin, (row_s, row_s, row_s,
-                                       self.distribution, self.K)))
-        # the first dispatch takes the margin as `_initial_margin`
-        # makes it — row-sharded for one output (made off `data.y`; a
-        # forest's are the zeros its sum of leaf values starts from),
-        # `_init_margin`'s replicated broadcast for K classes, an
-        # uncommitted jnp.zeros (no sharding) where a K-class forest's
-        # sums start — and every later one the row-sharded output of
-        # the one before
-        first = rows if self.K == 1 else None if self.bp.drf_mode \
-            else replicated(self.mesh)
+                                       self.distribution, self.K,
+                                       self.mesh)))
+        # every dispatch takes its margin sharded by rows: the first as
+        # `_initial_margin` makes it (`_init_margin`'s prior, [rows, K]
+        # for K classes; the zeros a forest's sum of leaf values starts
+        # from), every later one the output of the one before — so a
+        # job's dispatches of one length are ONE executable
+        margin_s = jax.ShapeDtypeStruct(
+            (padded,) if self.K == 1 else (padded, self.K),
+            jnp.float32, sharding=rows)
         keydt = jax.eval_shape(lambda: jax.random.key(0)).dtype
-        seen = set()
-        for i, n in enumerate(self.chunks(padded)):
-            msh = first if i == 0 else rows
-            if (n, msh) in seen:
-                continue
-            seen.add((n, msh))
-            margin_s = jax.ShapeDtypeStruct(
-                (padded,) if self.K == 1 else (padded, self.K),
-                jnp.float32, sharding=msh)
+        for n in dict.fromkeys(self.chunks(padded)):
             keys_s = jax.ShapeDtypeStruct((n,), keydt)
             args = self.operands(binned_s, row_s, row_s, margin_s,
                                  keys_s, keys_s)
@@ -466,19 +462,22 @@ def boost_plan(p: "GBMParams", distribution: str, nclasses: int, F: int,
     K = nclasses if nclasses > 2 else 1
     tp = _make_tree_params(p, distribution, n_bins, set_feats)
     hist_bytes = level_hist_bytes(tp, F)
-    if K > 1 and multi_grow_vmapped(tp, F, K):
+    class_batch = None
+    if K > 1:
         # the memory that will actually be live: K× only when the
         # grower really vmaps (past its budget it falls to lax.map
         # with one class's histograms live)
-        hist_bytes *= K
+        class_batch = "vmap" if multi_grow_vmapped(tp, F, K) else "map"
+        if class_batch == "vmap":
+            hist_bytes *= K
     budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET", 2 ** 30))
     return BoostPlan(p, distribution, K, F, tp,
                      _make_boost_params(p, distribution), hist_bytes,
-                     budget, mesh or global_mesh(), rank)
+                     budget, mesh or global_mesh(), rank, class_batch)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _init_margin(y, w, off, dist: str, K: int):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _init_margin(y, w, off, dist: str, K: int, mesh):
     """(init score, starting margin) fully ON DEVICE — the round-2 path
     transferred the prior sums to the host before the first boost
     dispatch, a blocking host round trip per train() that AutoML pays
@@ -489,7 +488,10 @@ def _init_margin(y, w, off, dist: str, K: int):
     starts at init + off and the init prior is the intercept MLE GIVEN
     the offset (hex/tree/gbm GBM getInitialValue solves the same
     offset-aware prior [U3]) — closed form for gaussian/poisson/gamma,
-    3 Newton steps from logit(ȳ) for bernoulli."""
+    3 Newton steps from logit(ȳ) for bernoulli. A K-class margin
+    [rows, K] lies as the rows of ``mesh`` do (a broadcast of the class
+    priors would come out replicated: a first boost dispatch unlike
+    every later one, and a second executable of the same program)."""
     w_sum = jnp.sum(w)
     if dist == "bernoulli":
         p1 = jnp.clip(jnp.sum(y * w) / w_sum, 1e-6, 1 - 1e-6)
@@ -509,7 +511,9 @@ def _init_margin(y, w, off, dist: str, K: int):
             num_segments=K + 1)[:K]
         init = jnp.log(jnp.clip(cls_w / w_sum, 1e-8, None)).astype(
             jnp.float32)
-        return init, jnp.broadcast_to(init[None, :], (y.shape[0], K))
+        return init, lax.with_sharding_constraint(
+            jnp.broadcast_to(init[None, :], (y.shape[0], K)),
+            row_sharding(mesh))
     if dist in ("poisson", "tweedie"):
         # intercept MLE with log link + offset: e^b = Σwy / Σw·e^off
         init = jnp.log(jnp.clip(
@@ -1118,6 +1122,9 @@ class GBM:
                 plan, data, ckpt, binned)
 
         start_t = 0 if ckpt is None else len(ckpt.trees.value) // plan.K
+        if plan.K > 1:
+            root.update(classes=plan.K, rounds=p.ntrees - start_t,
+                        class_batch=plan.class_batch)
         history: list[dict] = []
         key_chunks: list = []
         # fused loop: all boosting rounds of a chunk build inside ONE
@@ -1185,6 +1192,8 @@ class GBM:
                 model._group_column = group_column
             if rank is not None:
                 _count_pairs(rank, p.ntrees - start_t)
+            if plan.K > 1:
+                _count_class_trees(plan, p.ntrees - start_t)
         # which way the metric is read: off the boosting margin, off
         # the sum of leaf values a forest's scan carried (every tree
         # over every row, bitwise what `_margins_of_binned` walks
@@ -1449,12 +1458,13 @@ def _initial_margin(plan: BoostPlan, data: TrainData, ckpt, binned):
         # init + margin in one device dispatch, no host sync before
         # the first boost chunk (init is read back at model build)
         init, margin = _init_margin(data.y, data.w, off,
-                                    data.distribution, K)
+                                    data.distribution, K, plan.mesh)
     elif p._drf_mode or plan.grouped:
         # DRF: no boosting — leaves are in-leaf target means, init 0;
         # a ranker's scores start from 0 (only their differences count)
         init = np.zeros(K, dtype=np.float32) if K > 1 else 0.0
-        margin = jnp.zeros((data.y.shape[0], K)) if K > 1 \
+        margin = jnp.zeros((data.y.shape[0], K),
+                           device=row_sharding(plan.mesh)) if K > 1 \
             else jnp.zeros_like(data.y)
     else:
         # laplace: L1 leaf steps are bounded by learn_rate, so fit in
@@ -1562,6 +1572,23 @@ def _count_pairs(rank: RankLayout, rounds: int) -> None:
         "slots the query layout computed)", label="kind")
     ctr.inc(rank.pairs_real * rounds, label_value="real")
     ctr.inc(rank.pairs_slots * rounds, label_value="slots")
+
+
+def _count_class_trees(plan: BoostPlan, rounds: int) -> None:
+    """`h2o_train_class_trees_total{kind}`, added up once a job: the
+    class trees of its rounds (rounds x K), by how a round's K were
+    grown — ``batched`` (one vmapped grow: a level's histograms in one
+    kernel call, the bin codes read once for the K trees) or ``mapped``
+    (a class at a time under `lax.map`)."""
+    from ..runtime.telemetry import REGISTRY
+
+    REGISTRY.counter(
+        "h2o_train_class_trees_total",
+        "class trees of the K-class jobs trained (rounds x K), by how "
+        "a round's K trees were grown: batched (one vmapped grow) or "
+        "mapped (a class at a time)", label="kind").inc(
+            rounds * plan.K, label_value="batched"
+            if plan.class_batch == "vmap" else "mapped")
 
 
 def _count_splits(trees: Tree, set_feats: tuple) -> None:

@@ -408,3 +408,52 @@ def test_ranking_boost_scan_compiles(topo, n_dev):
     assert max(widths) == 136, widths
     assert {"grad_hess", "rank_sort", "rank_pairs"} <= _scopes(txt)
     assert ("all-gather" in txt) == (n_dev > 1)
+
+
+def test_k_class_boost_scan_compiles(topo):
+    """`_boost_multi_jit` — what `XGBoost(objective="multi:softprob")
+    .train()` dispatches on a 7-level response (ISSUE 38) — at the
+    widths of the `xgb-covtype.train` cell: K 7, 54 `uint8` columns,
+    depth 6, 256 bins, one round, the `[rows, 7]` margin sharded by
+    rows. The seven class trees of a round grow under `vmap`, whose
+    batching rule folds the class batch into the kernel's node axis:
+    ONE `hist_fact` call a level for the seven (6 in all, the deepest
+    over 7 x 32 node slots in one hi block), never seven calls and
+    never the bin-blocked kernel. The temporaries: the value stack
+    `f32[rows, C]` is lane-padded to 128 a row and now there is one a
+    class, so a row costs seven times `_boost_jit`'s 1.16 KB — 8.1 KB
+    at this size (PERF.md section 7); under 10 KB a row is what keeps
+    581,632 rows inside a third of a chip. Every phase of the step
+    names its operations for K > 1 as it does for one tree a round."""
+    K, F_COV = 7, 54
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
+    rs = NamedSharding(mesh, P(ROWS))
+    args = _boost_args(mesh, ROWS_N, ntrees=1)
+    tp = args[6]._replace(min_rows=1.0, reg_lambda=1.0, gamma=0.0,
+                          min_child_weight=1.0)
+    bp = args[7]._replace(distribution="multinomial", learn_rate=0.3)
+    assert core.multi_grow_vmapped(tp, F_COV, K)
+    lowered = core._boost_multi_jit.lower(
+        _s((ROWS_N, F_COV), jnp.uint8, rs), *args[1:3],
+        _s((ROWS_N, K), jnp.float32, rs), *args[4:6], tp, bp, K, mesh)
+    c = lowered.compile()
+    txt = c.as_text()
+    assert _kernels(c) == DEPTH
+    assert _kernel_names(c) == {"hist_fact"}
+    # the deepest level's call: 7 feature groups of 8 (54 padded to
+    # 56), 7 x 32 left children x 3 channels = 672 rows, one hi block
+    assert re.search(r"f32\[7,1,8,672,128\]", txt)
+    assert c.memory_analysis().temp_size_in_bytes < 10_000 * ROWS_N
+    # (the grower's scopes come out as `vmap(level_hist)`: a reader that
+    # takes a name stack apart by "/" alone files them under no scope)
+    scopes = _scopes(txt)
+    scopes |= {m for s in scopes for m in re.findall(r"vmap\((\w+)\)", s)}
+    want = {"grad_hess", "margin", "level_hist", "sibling", "split_find",
+            "descend", "leaves", "hist_fact"}
+    assert want <= scopes, want - scopes
+    assert "vmap(level_hist)" in scopes and "level_hist" not in _scopes(txt)
+    # (`sample` names nothing at sample_rate 1: the compiler folds it)
+    traced = set(re.findall(r"\w+", " ".join(re.findall(
+        r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))))
+    assert {"sample", "grad_hess", "margin", "level_hist", "split_find",
+            "descend"} <= traced
