@@ -1030,7 +1030,14 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
     measured on a v5e and never won (PERF.md §6, PR 28: a tie while
     every merged level stays within one hi block of the histogram
     kernel, a loss once one reached the bin-blocked kernel of the
-    time, at G times the temporaries), so there is one path.
+    time, at G times the temporaries), so there is one path. (Why it
+    could not win then: the histogram's batching rule of the time
+    folded the G trees into the kernel's node axis, every tree's rows
+    multiplied against all G trees' slots — G² products for G. A
+    tree's rows now meet its own slots only (at a forest's 64 bins, a
+    tree a call: PERF.md §6, PR 39); the G-fold temporaries, and the
+    by-row lookups that `vmap` turns back into gathers, are what a
+    second try would have to beat.)
 
     The scan carries what ``_boost_shard`` carries: ``margin`` plus the
     leaf value of every tree so far, for EVERY row — the bag is a

@@ -35,6 +35,17 @@ Two implementations:
   (column, tile); past that the A operand's build and the MXU, as at
   the deep levels.
 
+  K trees grown at once over one stored frame (the K class trees of a
+  boosting round, under `vmap`) go through ONE call a level whose row
+  tile carries the K classes' node ids and values
+  (`_hist_class_kernel`): a column's codes are read once, each class's
+  rows meet that class's hi slots only, and the K classes are packed
+  on the sublanes of ONE A operand for one product against the
+  column's lo one-hot. That takes a node of whole 128-lane rows
+  (lo = bin mod 128 whatever the node); where a node takes part of
+  one, the classes go a class at a time through the unbatched call
+  (PERF.md section 6, PR 39).
+
 `build_histogram(..., impl="auto")` picks pallas on TPU, segment
 elsewhere. Both run under shard_map (per-shard rows); callers psum the
 result across the ROWS mesh axis.
@@ -121,6 +132,23 @@ def _mantissa_terms(vals_t, terms: int):
     return jnp.concatenate([v1, v2, v3], axis=0)
 
 
+# contract the ROW axis of both operands: `lo` stays on the lanes it
+# arrives on (`lo[:, None]` against a [T, 128] iota relaid every row's
+# value across a sublane row: what bound a shallow call)
+_ROW_AXES = (((1,), (1,)), ((), ()))
+
+
+def _term_products(a, Bt, terms: int):
+    """ONE matmul with all mantissa terms stacked into M — the MXU's
+    row occupancy multiplies (terms·n_ch·ht rows instead of `terms`
+    passes of n_ch·ht); the per-term partial sums `[terms, M/terms,
+    128]` recombine with one cheap VPU add. Same bf16 products, same
+    f32 accumulation."""
+    acc = lax.dot_general(a, Bt, dimension_numbers=_ROW_AXES,
+                          preferred_element_type=jnp.float32)
+    return acc.reshape(terms, a.shape[0] // terms, 128)
+
+
 def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
                       ht, n_ht, n_ch, fg, terms):
     """Factorized one-hot histogram matmul, one block of ``ht`` hi slots.
@@ -136,7 +164,7 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
     products match the segment path to ~2^-24; B is 0/1 and thus exact
     in bf16.
     """
-    # grid (feature_groups, hi_blocks, n_copies, row_blocks): one step
+    # grid (feature_groups, hi_blocks, 1, row_blocks): one step
     # covers a whole FEATURE GROUP of fg features for its row block —
     # the row-stream operands (rel, vals, mantissa split) load and
     # compute ONCE per row block instead of once per (feature, row
@@ -153,7 +181,6 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
         # this step's hi block starts at slot program_id·ht: shift the
         # cell index so the block's slots read 0..ht-1 below
         rel_base = rel_base - pl.program_id(1) * (ht * 128)
-    T = rel.shape[0]
     vals_t = vals_ref[:].T                           # [n_ch, T]
     # f32-precision via `terms` bf16 mantissa terms, split on the TINY
     # [n_ch, T] values and masked by the 0/1 one-hot IN bf16 —
@@ -162,12 +189,9 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
     # plus two subtract passes over it: the A-build drops from ~6
     # f32-width VPU passes to `terms` bf16-width multiplies.
     V = _mantissa_terms(vals_t, terms)               # [terms·n_ch, T]
+    T = rel.shape[0]
     iota_hi = lax.broadcasted_iota(jnp.int32, (ht, T), 0)
     iota_lo = lax.broadcasted_iota(jnp.int32, (128, T), 0)
-    # contract the ROW axis of both operands: `lo` stays on the lanes
-    # it arrives on (`lo[:, None]` against a [T, 128] iota relaid every
-    # row's value across a sublane row: what bound a shallow call)
-    dn = (((1,), (1,)), ((), ()))
 
     # REAL loop over the feature group, not a static unroll: Mosaic
     # stack-allocates every unrolled iteration's [3·n_ch·ht, T] A
@@ -187,18 +211,64 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
         # vals are zeroed upstream.
         oh_hi = (iota_hi == hi[None, :]).astype(jnp.bfloat16)
         Bt = (iota_lo == lo[None, :]).astype(jnp.bfloat16)
-        # ONE matmul with all mantissa terms stacked into M — the
-        # MXU's row occupancy multiplies (terms·n_ch·ht rows instead
-        # of `terms` passes of n_ch·ht); the per-term partial sums
-        # recombine with one cheap VPU add over [n_ch·ht, 128]. Same
-        # bf16 products, same f32 accumulation.
         a = jnp.concatenate(
             [oh_hi * V[k][None, :] for k in range(terms * n_ch)],
             axis=0)                             # [terms·n_ch·ht, T]
-        acc = lax.dot_general(a, Bt, dimension_numbers=dn,
-                              preferred_element_type=jnp.float32)
-        acc = acc.reshape(terms, n_ch * ht, 128)
+        acc = _term_products(a, Bt, terms)
         out_ref[0, 0, j] += acc.sum(axis=0)          # [n_ch·ht, 128]
+        return carry
+
+    lax.fori_loop(0, fg, _feature, 0)
+
+
+def _hist_class_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
+                       ht, n_ht, n_ch, fg, terms):
+    """`_hist_fact_kernel` for a block of classes that share one stored
+    `binned` (the K class trees of a boosting round): ``rel_ref``
+    `[classes, T]`, ``vals_ref`` `[classes, n_ch, T]` (rows on lanes
+    already: a `[T, n_ch]` block a class would pad each to 128 lanes).
+    A node takes whole 128-lane rows (``n_bins`` a multiple of 128), so
+    lo = bin mod 128 whatever the class and the node: a column's codes
+    are read once, and ONE lo one-hot, one set of weight pushes and ONE
+    product serve the block's classes, each class's hi one-hot over ITS
+    ``ht`` slots. The classes are packed on the sublanes — row (class,
+    slot) of the operands below, built ONCE a row tile — so a column
+    costs one add, one shift and one compare over `[classes·ht, T]` and
+    `terms·n_ch` multiplies, where a class at a time spends a whole
+    vreg row on each of its 2- or 4-row pieces (25.8 against 10.1 ms
+    for the root of seven class trees: PERF.md section 3). The same
+    bf16 products and K-tiles a cell as `_hist_fact_kernel`'s: bitwise
+    its sums. Out rows `[channel][class][slot]`."""
+    # grid (feature_groups, hi_blocks, class_blocks, row_blocks)
+    @pl.when(pl.program_id(3) == 0)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    classes, T = rel_ref.shape
+    rel_bases = [rel_ref[k, :] * n_bins for k in range(classes)]
+    if n_ht > 1:
+        # this step's hi block starts at slot program_id·ht
+        rel_bases = [b - pl.program_id(1) * (ht * 128) for b in rel_bases]
+    Vs = [_mantissa_terms(vals_ref[k], terms) for k in range(classes)]
+    first_cell = lax.broadcasted_iota(jnp.int32, (ht, T), 0) * 128
+    # a class's cell base less its slot's first cell: the row's one-hot
+    # is `0 <= base + bin < 128` (dead rows and rows of another hi
+    # block fall outside, as in `_hist_fact_kernel`)
+    base = jnp.concatenate([b[None, :] - first_cell for b in rel_bases],
+                           axis=0)                   # [classes·ht, T]
+    V_rows = [jnp.concatenate(
+        [jnp.broadcast_to(V[k][None, :], (ht, T)) for V in Vs], axis=0)
+        for k in range(terms * n_ch)]                # each [classes·ht, T]
+    iota_lo = lax.broadcasted_iota(jnp.int32, (128, T), 0)
+
+    def _feature(j, carry):
+        bins = binned_ref[j, 0, 0, :]                # [T]
+        oh = (lax.shift_right_arithmetic(base + bins[None, :], 7) == 0
+              ).astype(jnp.bfloat16)
+        Bt = (iota_lo == lax.bitwise_and(bins, 127)[None, :]).astype(
+            jnp.bfloat16)
+        a = jnp.concatenate([oh * v for v in V_rows], axis=0)
+        out_ref[0, 0, j] += _term_products(a, Bt, terms).sum(axis=0)
         return carry
 
     lax.fori_loop(0, fg, _feature, 0)
@@ -213,8 +283,21 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
 # ~16 MB/core VMEM. TIGHT: the on-chip kernel gate compiles exactly
 # this cap shape as `fact_kernel_cap`; if it fails there, lower this
 # cap. Deeper levels (n_nodes·n_bins > 2^15) run as several hi blocks
-# of at most this many slots, each at the cap shape's VMEM.
+# of at most this many slots, each at the cap shape's VMEM. A class
+# batch stacks whole classes up to the same cap (`_class_blocks`) and
+# holds its value operands beside the A they become: 4.7 MB more at
+# the cap.
 _FACT_MAX_NHI = 256
+
+# Most classes ONE grid step holds, whatever their slots: a class's own
+# operands — its `[C, T]` values block (double-buffered), its ids, its
+# mantissa stack — grow with classes x row tile, which the cap on the
+# stacked slots does not see. 32 classes of 2 slots at a 4,096-row tile
+# ask 19.6 MB of the 16 MB scoped limit (24 still fit, and 16 of 4);
+# 8 fills the sublanes of the ids block, with that room to spare.
+# `tests/test_chip_compile.py` compiles 8 classes x 32 slots, 2 x 128,
+# and the roots of 32 and 128 classes.
+_CLASS_BLOCK_MAX = 8
 
 
 def _hi_blocks(n_cells: int) -> tuple:
@@ -244,35 +327,56 @@ def _feature_groups(F: int, C: int, ht: int) -> tuple[int, int]:
     return fg, -(-F // fg) * fg
 
 
-def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
-                 binned_tile: int = 1, row_tile: int | None = None):
-    """``binned_tile`` > 1: rel/vals carry ``binned_tile`` consecutive
-    copies of the row range (the flattened class batch) while binned is
-    stored ONCE — the grid index map re-reads the same bin blocks per
-    copy instead of materializing K copies in HBM. Such callers must
-    pre-align each copy's rows and pass the ``row_tile`` they aligned
-    to (one decision, not two that must agree)."""
+def _class_blocks(K: int, ht: int) -> tuple:
+    """(n_cb, kb): the blocks of whole classes that serve a batch of K
+    classes of ``ht`` hi slots each, and the classes in each — the
+    fewest blocks of at most `_CLASS_BLOCK_MAX` classes whose stacked
+    slots stay within `_FACT_MAX_NHI`, evenly filled (the last may hold
+    dead classes). A class that alone passes the cap is a block of its
+    own, served in hi blocks."""
+    n_cb = -(-K // min(_CLASS_BLOCK_MAX, max(1, _FACT_MAX_NHI // ht)))
+    return n_cb, -(-K // n_cb)
+
+
+def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int):
+    """[r, F] codes + [r] rel + [r, C] vals -> [n_nodes, F, B, C]; or,
+    for K classes over the one stored ``binned`` (the batching rule of
+    `_hist_vmappable`), [K, r] rel + [K, r, C] vals ->
+    [K, n_nodes, F, B, C] from the one call (``n_bins`` a multiple of
+    128; a class a call otherwise)."""
+    batched = rel.ndim == 2
+    if batched and n_bins % 128:
+        # a node takes part of a 128-lane row: lo = seg mod 128 follows
+        # each class's own nodes, so the classes share no lo one-hot —
+        # a class at a time through the unbatched kernel (`binned`
+        # re-read a class: PERF.md section 3 has it beside the fold)
+        return lax.map(lambda a: _hist_pallas(binned, *a, n_nodes, n_bins),
+                       (rel, vals))
     r, F = binned.shape
-    C = vals.shape[1]
+    C = vals.shape[-1]
+    K = rel.shape[0] if batched else 1
     nB = n_nodes * n_bins
     n_ht, ht = _hi_blocks(nB)
-    rt_size = row_tile or _fact_row_tile(ht, r)
+    # classes a grid step holds on its A operand: what sizes the row
+    # tile and the resident out block is their slots together, kb·ht
+    n_cb, kb = _class_blocks(K, ht) if batched else (1, 1)
+    rt_size = _fact_row_tile(kb * ht, r)
     pad = (-r) % rt_size
     if pad:
-        assert binned_tile == 1     # tiled callers pre-align rows
+        lead = ((0, 0),) * batched
         binned = jnp.pad(binned, ((0, pad), (0, 0)))
-        rel = jnp.pad(rel, (0, pad), constant_values=-1)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
+        rel = jnp.pad(rel, lead + ((0, pad),), constant_values=-1)
+        vals = jnp.pad(vals, lead + ((0, pad), (0, 0)))
     rp = r + pad
-    rbb = rp // rt_size                 # row blocks per binned copy
-    # feature grouping: each grid step holds [fg, C·ht, 128] f32 of
+    rbb = rp // rt_size                 # row blocks
+    # feature grouping: each grid step holds [fg, kb·C·ht, 128] f32 of
     # output resident; wide tables split into 8-aligned groups (padded
     # feature columns histogram into junk rows that are sliced away).
     # fg is also capped at 64 outright: the row-stream-reuse win
     # saturates long before that, and the resident out block is the
     # only cost that grows with fg (the kernel's fori_loop reuses one
     # iteration's buffers)
-    fg, F_pad = _feature_groups(F, C, ht)
+    fg, F_pad = _feature_groups(F, kb * C, ht)
     if F_pad > F:
         binned = jnp.pad(binned, ((0, 0), (0, F_pad - F)))
     n_fg = F_pad // fg
@@ -291,17 +395,58 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
     # under shard_map the output varies per shard: propagate the input's
     # varying-mesh-axes set or jax's vma check rejects the call
     vma = jax.typeof(vals).vma
+    params = dict(n_bins=n_bins, ht=ht, n_ht=n_ht, n_ch=C, fg=fg, terms=3)
+    binned_spec = pl.BlockSpec((fg, 1, 1, rt_size),
+                               lambda g, b, k, rt: (g, rt, 0, 0))
+    if batched:
+        if n_cb * kb > K:               # dead classes fill the last block
+            grow = ((0, n_cb * kb - K), (0, 0))
+            rel32 = jnp.pad(rel32, grow, constant_values=-1)
+            vals = jnp.pad(vals, grow + ((0, 0),))
+        out = pl.pallas_call(
+            functools.partial(_hist_class_kernel, **params),
+            out_shape=jax.ShapeDtypeStruct(
+                (n_fg, n_ht, n_cb, fg, C * kb * ht, 128), jnp.float32,
+                vma=vma),
+            grid=(n_fg, n_ht, n_cb, rbb),
+            in_specs=[
+                binned_spec,
+                # blocks whose class dim is the array's own: the (8,
+                # 128) rule that refuses `vmap`'s squeezed (1, T) block
+                # over [K, rows] has nothing to say
+                pl.BlockSpec((None, kb, rt_size),
+                             lambda g, b, k, rt: (k, 0, rt)),
+                pl.BlockSpec((None, kb, C, rt_size),
+                             lambda g, b, k, rt: (k, 0, 0, rt)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, None, fg, C * kb * ht, 128),
+                lambda g, b, k, rt: (g, b, k, 0, 0, 0)),
+            # row blocks alone accumulate into an out block
+            compiler_params=_dimsem("parallel", "parallel", "parallel",
+                                    "arbitrary"),
+            interpret=_interpret(),
+            name=name, metadata={"kernel": name},
+        )(binned4, rel32.reshape(n_cb, kb, rp),
+          vals.transpose(0, 2, 1).reshape(n_cb, kb, C, rp))
+        # [n_fg, n_ht, n_cb, fg, C·kb·ht, 128] -> [K, F, C, n_ht·ht·128]
+        out = out.reshape(n_fg, n_ht, n_cb, fg, C, kb, ht * 128
+                          ).transpose(2, 5, 0, 3, 4, 1, 6).reshape(
+            n_cb * kb, F_pad, C, n_ht * ht * 128)[:K, :F, :, :nB]
+        return out.reshape(K, F, C, n_nodes, n_bins).transpose(
+            0, 3, 1, 4, 2)
     out = pl.pallas_call(
-        functools.partial(_hist_fact_kernel, n_bins=n_bins, ht=ht,
-                          n_ht=n_ht, n_ch=C, fg=fg, terms=3),
+        functools.partial(_hist_fact_kernel, **params),
         # one (fg, C·ht, 128) block per (feature group, hi block),
         # contiguous
         out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),
                                        jnp.float32, vma=vma),
-        grid=(n_fg, n_ht, binned_tile, rbb),
+        grid=(n_fg, n_ht, 1, rbb),
         in_specs=[
-            pl.BlockSpec((fg, 1, 1, rt_size),
-                         lambda g, b, k, rt: (g, rt, 0, 0)),
+            binned_spec,
+            # (the third grid axis is 1: `k·rb` is 0, and stays in the
+            # index maps so that the accepted cells' kernel is, to the
+            # letter, the one their ledger lines were measured with)
             pl.BlockSpec((rt_size,),
                          lambda g, b, k, rt, rb=rbb: (k * rb + rt,)),
             pl.BlockSpec((rt_size, C),
@@ -310,8 +455,8 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
         out_specs=pl.BlockSpec((1, 1, fg, C * ht, 128),
                                lambda g, b, k, rt: (g, b, 0, 0, 0)),
         # feature groups and hi blocks write DISTINCT out blocks
-        # (parallel — Mosaic may pipeline them); copies and row blocks
-        # ACCUMULATE into the same block (arbitrary = sequential)
+        # (parallel — Mosaic may pipeline them); row blocks ACCUMULATE
+        # into the same block (arbitrary = sequential)
         compiler_params=_dimsem("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         interpret=_interpret(),
@@ -338,16 +483,15 @@ def _hist_vmappable(binned, rel, vals, n_nodes: int, n_bins: int,
     operands (block (1, T) over a [K, rows] array fails the (8, 128)
     divisibility rule) — the round-4 on-chip kernel gate caught exactly
     this in the fused multinomial boost scan, which grows its K class
-    trees under vmap. Instead of batching the kernel, the batch is
-    LOWERED AWAY: class k's rows are relabeled to nodes
-    [k·n_nodes, (k+1)·n_nodes) and the SAME flat kernel runs once over
-    the concatenated row stream. Identical sums, and the MXU M
-    dimension (channels × hi-slots) gets K× fuller than K separate
-    passes would — batching IMPROVES systolic occupancy here.
+    trees under vmap. Instead the rule hands the kernel the batch
+    itself: ONE call whose row tile carries the K classes' node ids and
+    values, in which a column's codes are read once and each class's
+    rows are multiplied against that class's hi slots alone
+    (`_hist_class_kernel`; PERF.md section 6, PR 39).
     """
-    cv = custom_vmap(
-        functools.partial(_hist_call, n_nodes=n_nodes, n_bins=n_bins,
-                          impl=impl))
+    fn = functools.partial(_hist_call, n_nodes=n_nodes, n_bins=n_bins,
+                           impl=impl)
+    cv = custom_vmap(fn)
 
     @cv.def_vmap
     def _rule(axis_size, in_batched, binned_b, rel_b, vals_b):
@@ -355,44 +499,20 @@ def _hist_vmappable(binned, rel, vals, n_nodes: int, n_bins: int,
         bb, rb, vb = in_batched
         if impl != "pallas":
             # segment_sum vmaps fine as-is — no kernel, no flattening
-            fn = functools.partial(_hist_call, n_nodes=n_nodes,
-                                   n_bins=n_bins, impl=impl)
             out = jax.vmap(fn, in_axes=(0 if bb else None,
                                         0 if rb else None,
                                         0 if vb else None))(
                 binned_b, rel_b, vals_b)
             return out, True
-
-        r = rel_b.shape[1] if rb else rel_b.shape[0]
-        # pad each class's rows to the row tile the flat kernel will
-        # pick for the MERGED node count
-        rt = _fact_row_tile(_hi_blocks(K * n_nodes * n_bins)[1], r)
-        pad = (-r) % rt
-        C = vals_b.shape[-1]
-        F = binned_b.shape[-1]
-        # per-class row padding BEFORE flattening so each class's rows
-        # stay aligned with the (re-read) binned row blocks
-        if bb:
-            binned_f = jnp.pad(binned_b, ((0, 0), (0, pad), (0, 0))
-                               ).reshape(K * (r + pad), F)
-            tile = 1
-        else:
-            binned_f = jnp.pad(binned_b, ((0, pad), (0, 0)))
-            tile = K        # binned stored once; grid re-reads it K×
-        rel2 = rel_b if rb else jnp.broadcast_to(rel_b[None], (K, r))
-        rel2 = jnp.pad(rel2, ((0, 0), (0, pad)), constant_values=-1)
-        # class k's rows land in nodes [k·n_nodes, (k+1)·n_nodes)
-        rel2 = jnp.where(rel2 >= 0,
-                         rel2 + (jnp.arange(K, dtype=jnp.int32)
-                                 * n_nodes)[:, None], -1)
+        rel2 = rel_b if rb else jnp.broadcast_to(
+            rel_b[None], (K,) + rel_b.shape)
         vals2 = vals_b if vb else jnp.broadcast_to(
-            vals_b[None], (K, r, C))
-        vals2 = jnp.pad(vals2, ((0, 0), (0, pad), (0, 0)))
-        out = _hist_pallas(binned_f, rel2.reshape(K * (r + pad)),
-                           vals2.reshape(K * (r + pad), C),
-                           K * n_nodes, n_bins, binned_tile=tile,
-                           row_tile=rt)
-        return out.reshape((K, n_nodes) + out.shape[1:]), True
+            vals_b[None], (K,) + vals_b.shape)
+        if bb:
+            # every class its own codes: nothing to share, so a class
+            # at a time through the unbatched call
+            return lax.map(lambda a: fn(*a), (binned_b, rel2, vals2)), True
+        return fn(binned_b, rel2, vals2), True
 
     return cv(binned, rel, vals)
 

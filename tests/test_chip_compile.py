@@ -126,6 +126,48 @@ def test_shallow_levels_compile_at_the_other_cells_shapes(
     assert _kernels(c) == 1 and _kernel_names(c) == {"hist_fact"}
 
 
+@pytest.mark.parametrize("K,F,n_nodes,bins,unit_hess,name", [
+    (7, 54, 1, 256, False, "hist_fact"),    # `xgb-covtype.train`'s root
+    (7, 54, 16, 256, False, "hist_fact"),   # and its deepest level
+    (7, 54, 64, 256, False, "hist_fact"),   # 7 x 128 slots pass the cap:
+                                            # four blocks of two classes
+    (7, 54, 512, 256, False, "hist_blocked"),   # one class passes it
+    (3, 28, 32, 64, True, "hist_fact"),     # a K-class forest: a class's
+                                            # lo one-hot follows its nodes
+    # a block's VMEM follows its classes too (`_CLASS_BLOCK_MAX`): the
+    # widest block, 8 classes, at the wide row tile's most slots, at the
+    # cap, and the roots of many classes (32 in one block ask 19.6 MB)
+    (8, 54, 4, 256, False, "hist_fact"),
+    (8, 54, 16, 256, False, "hist_fact"),
+    (32, 54, 1, 256, False, "hist_fact"),
+    (128, 54, 1, 256, False, "hist_fact"),
+    (40, 54, 32, 256, False, "hist_fact"),  # about the most classes
+                                            # `multi_grow_vmapped` batches
+                                            # at this width and depth 6
+])
+def test_class_batch_compiles_at_the_cells_shapes(one_chip, K, F, n_nodes,
+                                                  bins, unit_hess, name):
+    """The K-class grower's vmapped build (ISSUE 39): ONE kernel call
+    whose row tile carries `[K, T]` node ids and `[K, C, T]` values —
+    blocks whose class dim is the array's own, which Mosaic's (8, 128)
+    rule has nothing against — at K 7, 54 columns, 256 bins, 1 and 16
+    histogrammed nodes a class, past the cap on stacked slots both
+    ways, and past the cap on classes a block."""
+    fn = jax.jit(jax.vmap(
+        lambda b, r, g, h, w: histogram.build_histogram(
+            b, r, g, h, w, n_nodes, bins, "pallas", unit_hess=unit_hess),
+        in_axes=(None, 0, 0, 0, None)))
+    rows = _s((ROWS_N,), jnp.float32, one_chip)
+    cls = _s((K, ROWS_N), jnp.float32, one_chip)
+    c = fn.lower(_s((ROWS_N, F), jnp.uint8, one_chip),
+                 _s((K, ROWS_N), jnp.int32, one_chip), cls, cls,
+                 rows).compile()
+    # (under a bare `vmap` the instruction is `vmap_hist_fact_`; inside
+    # the boost program it is `hist_fact.N`: the K-class scan's test)
+    assert _kernels(c) == 1
+    assert c.as_text().count(f'"kernel":"{name}"') == 1
+
+
 def _boost_args(mesh, rows, ntrees):
     rs, rep = NamedSharding(mesh, P(ROWS)), NamedSharding(mesh, P())
     tp = core.TreeParams(max_depth=DEPTH, n_bins=BINS, min_rows=10.0,
@@ -410,40 +452,49 @@ def test_ranking_boost_scan_compiles(topo, n_dev):
     assert ("all-gather" in txt) == (n_dev > 1)
 
 
-def test_k_class_boost_scan_compiles(topo):
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_k_class_boost_scan_compiles(topo, n_dev):
     """`_boost_multi_jit` — what `XGBoost(objective="multi:softprob")
     .train()` dispatches on a 7-level response (ISSUE 38) — at the
     widths of the `xgb-covtype.train` cell: K 7, 54 `uint8` columns,
     depth 6, 256 bins, one round, the `[rows, 7]` margin sharded by
     rows. The seven class trees of a round grow under `vmap`, whose
-    batching rule folds the class batch into the kernel's node axis:
-    ONE `hist_fact` call a level for the seven (6 in all, the deepest
-    over 7 x 32 node slots in one hi block), never seven calls and
-    never the bin-blocked kernel. The temporaries: the value stack
-    `f32[rows, C]` is lane-padded to 128 a row and now there is one a
-    class, so a row costs seven times `_boost_jit`'s 1.16 KB — 8.1 KB
-    at this size (PERF.md section 7); under 10 KB a row is what keeps
-    581,632 rows inside a third of a chip. Every phase of the step
-    names its operations for K > 1 as it does for one tree a round."""
+    batching rule hands the kernel the class batch whole: ONE
+    `hist_fact` call a level for the seven (6 in all, the deepest with
+    the seven classes' 32 hi slots each stacked on one A operand),
+    never seven calls and never a hi-blocked one. The temporaries:
+    the kernel takes the values as `[K, C, rows]`, the transposition
+    fuses into the stack's own fusion and no lane-padded
+    `f32[K·rows, C]` stack exists: 1.04 KB a row at the cell's size
+    (PERF.md section 6) and 2.1 at this one, where the level
+    histograms weigh more; held here under 3. Every phase of the step
+    names its operations for K > 1 as it does for one tree a round.
+    Row-sharded over the 2x2 host the class kernel runs a shard under
+    shard_map and the per-level psum sees the same
+    `[K, n_nodes, F, B, C]` array (compiled here; no cell runs it)."""
     K, F_COV = 7, 54
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
+    mesh = Mesh(np.array(topo.devices[:n_dev]).reshape(n_dev, 1),
+                (ROWS, COLS))
     rs = NamedSharding(mesh, P(ROWS))
-    args = _boost_args(mesh, ROWS_N, ntrees=1)
+    rows = ROWS_N * n_dev
+    args = _boost_args(mesh, rows, ntrees=1)
     tp = args[6]._replace(min_rows=1.0, reg_lambda=1.0, gamma=0.0,
                           min_child_weight=1.0)
     bp = args[7]._replace(distribution="multinomial", learn_rate=0.3)
     assert core.multi_grow_vmapped(tp, F_COV, K)
     lowered = core._boost_multi_jit.lower(
-        _s((ROWS_N, F_COV), jnp.uint8, rs), *args[1:3],
-        _s((ROWS_N, K), jnp.float32, rs), *args[4:6], tp, bp, K, mesh)
+        _s((rows, F_COV), jnp.uint8, rs), *args[1:3],
+        _s((rows, K), jnp.float32, rs), *args[4:6], tp, bp, K, mesh)
     c = lowered.compile()
     txt = c.as_text()
     assert _kernels(c) == DEPTH
+    assert ("all-reduce" in txt) == (n_dev > 1)
     assert _kernel_names(c) == {"hist_fact"}
     # the deepest level's call: 7 feature groups of 8 (54 padded to
-    # 56), 7 x 32 left children x 3 channels = 672 rows, one hi block
-    assert re.search(r"f32\[7,1,8,672,128\]", txt)
-    assert c.memory_analysis().temp_size_in_bytes < 10_000 * ROWS_N
+    # 56), one hi block, one class block of 7 classes x 3 channels x
+    # 32 hi slots = 672 rows
+    assert re.search(r"f32\[7,1,1,8,672,128\]", txt)
+    assert c.memory_analysis().temp_size_in_bytes < 3_000 * ROWS_N
     # (the grower's scopes come out as `vmap(level_hist)`: a reader that
     # takes a name stack apart by "/" alone files them under no scope)
     scopes = _scopes(txt)

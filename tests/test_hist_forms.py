@@ -32,8 +32,62 @@ def test_forms_match_segment_and_the_shipped_kernel(case):
     assert lines["c"]["rel_diff_shipped"] < 1e-5
 
 
+# the class batch's forms (ISSUE 39): (rows, F, C, n_bins, dtype), K, nodes
+_CLASS_CASES = {
+    "root_7_classes": ((1300, 5, 3, 256, "uint8"), 7, 1),
+    "sixteen_nodes_wide_tile": ((8300, 3, 3, 256, "uint8"), 3, 16),
+    "forest_64_bins_a_class_a_call": ((2100, 4, 2, 64, "uint8"), 3, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CLASS_CASES))
+def test_class_forms_match_segment_and_the_shipped_rule(case):
+    shape, K, n_nodes = _CLASS_CASES[case]
+    lines = {ln["form"]: ln for ln in hist_forms.measure(
+        case, shape, n_nodes, tuple(hist_forms.CLASS_FORMS), calls=1,
+        seed=39, classes=K)}
+    assert set(lines) == set(hist_forms.CLASS_FORMS)
+    for form, ln in lines.items():
+        assert "error" not in ln and "timeout" not in ln, ln
+        assert ln["classes"] == K and ln["rel_err_segment"] < 1e-5, ln
+    # a class a grid step, a class a call: the same products in the
+    # same order as (B) wherever the K stacked classes keep the row
+    # tile one class alone would take
+    H = hist_forms.H
+    ht = H._hi_blocks(n_nodes * shape[3])[1]
+    same_tile = H._fact_row_tile(K * ht, shape[0]) == \
+        H._fact_row_tile(ht, shape[0])
+    assert same_tile == (case != "sixteen_nodes_wide_tile")
+    for form in ("A", "map", "fold"):
+        assert lines[form]["rel_diff_shipped"] < 1e-5, lines[form]
+        if same_tile and form != "fold":
+            assert lines[form]["bitwise_shipped"], lines[form]
+
+
+def test_custom_shape_states_rows_columns_and_optionally_bins_channels():
+    assert hist_forms._custom_shape("581632x54", 7)[:5] == (
+        581632, 54, 3, 256, "uint8")
+    assert hist_forms._custom_shape("581632x54x64x2", 7)[:5] == (
+        581632, 54, 2, 64, "uint8")
+    assert hist_forms._custom_shape("1000X8x128", None)[:5] == (
+        1000, 8, 3, 128, "uint8")
+
+
+def test_a_form_past_its_limit_says_timeout(monkeypatch):
+    import time
+
+    monkeypatch.setitem(hist_forms.CLASS_FORMS, "map",
+                        lambda *a, **kw: time.sleep(5))
+    (line,) = hist_forms.measure(
+        "slow", (256, 2, 3, 256, "uint8"), 1, ("map",), calls=1, seed=1,
+        segment=False, classes=2, limit=1)
+    assert line["timeout"] == "over 1 s" and "min_s" not in line
+
+
 def test_shipped_form_is_the_packages_kernel():
     assert hist_forms.FORMS[hist_forms.SHIPPED] is \
+        hist_forms.H._hist_pallas
+    assert hist_forms.CLASS_FORMS[hist_forms.CLASS_SHIPPED] is \
         hist_forms.H._hist_pallas
     assert set(hist_forms.SHAPES) == {
         "higgs256", "forest64", "airline512", "mslr136"}

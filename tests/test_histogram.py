@@ -98,22 +98,39 @@ def test_hi_blocked_level_matches_segment_and_one_block(
     np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
 
 
-@pytest.mark.parametrize("cap", [1, 2, 8])
-def test_hi_blocked_class_batch_stores_binned_once(monkeypatch, cap):
-    """The flattened class batch (vmap over K = 3, `binned` unbatched)
-    past the cap: the grid re-reads the one stored copy of `binned` for
-    every class and every hi block. Same two claims as above."""
+def _vmapped(binned, w, n_nodes, n_bins, impl, unit=False, in_axes=0):
+    """`build_histogram` under `vmap` over the class axis, as the
+    K-class grower calls it; ``binned`` stored once unless
+    ``in_axes`` batches it too."""
     import jax
 
+    if in_axes == 0:
+        return jax.vmap(lambda rel, g, h: build_histogram(
+            binned, rel, g, h, w, n_nodes, n_bins, impl, unit_hess=unit))
+    return jax.vmap(lambda b, rel, g, h: build_histogram(
+        b, rel, g, h, w, n_nodes, n_bins, impl, unit_hess=unit))
+
+
+def _pallas_calls(fn, *args):
+    import jax
+
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call[")
+
+
+@pytest.mark.parametrize("cap", [1, 2, 8])
+def test_hi_blocked_class_batch_stores_binned_once(monkeypatch, cap):
+    """The class batch (vmap over K = 3, `binned` unbatched) past the
+    cap: ONE `pallas_call` over the one stored copy of `binned`, with
+    `[K, rows]` node ids beside it. It matches `segment` to 1e-5 and is
+    BITWISE the batch served within the cap."""
     import h2o_kubernetes_tpu.ops.histogram as H
 
-    K, rows, F, n_nodes, n_bins = 3, 1500, 4, 32, 20
+    K, rows, F, n_nodes, n_bins = 3, 1500, 4, 5, 128
     binned, relK, gK, hK, w = _class_batch_case(
         K, rows, F, n_nodes, n_bins, seed=23)
 
     def build(impl):
-        return jax.vmap(lambda rel, g, h: build_histogram(
-            binned, rel, g, h, w, n_nodes, n_bins, impl))(relK, gK, hK)
+        return _vmapped(binned, w, n_nodes, n_bins, impl)(relK, gK, hK)
 
     one = build("pallas")
     monkeypatch.setattr(H, "_FACT_MAX_NHI", cap)
@@ -121,18 +138,202 @@ def test_hi_blocked_class_batch_stores_binned_once(monkeypatch, cap):
     calls = []
     real = H._hist_pallas
 
-    def spy(binned_f, *a, **kw):
-        calls.append((binned_f.shape, kw.get("binned_tile")))
-        return real(binned_f, *a, **kw)
+    def spy(binned_f, rel_f, vals_f, *a):
+        calls.append((binned_f.shape, rel_f.shape, vals_f.shape))
+        return real(binned_f, rel_f, vals_f, *a)
 
     monkeypatch.setattr(H, "_hist_pallas", spy)
     got = build("pallas")
-    # the batching rule's call: one copy of binned, rows tile-padded
-    assert calls[-1] == ((1024 * 2, F), K)
+    # the batching rule's call (after `custom_vmap`'s own trace of the
+    # unbatched one): the one copy of binned, as it is stored
+    assert calls[-1] == ((rows, F), (K, rows), (K, rows, 3))
+    assert all(c[0] == (rows, F) for c in calls)
+    assert _pallas_calls(_vmapped(binned, w, n_nodes, n_bins, "pallas"),
+                         relK, gK, hK) == 1
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(build("segment")),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+
+
+@pytest.mark.parametrize("n_nodes", [1, 4, 16, 32])
+@pytest.mark.parametrize("C", [2, 3])
+@pytest.mark.parametrize("K", [2, 3, 7])
+def test_class_batch_equals_a_build_a_class(K, C, n_nodes):
+    """ISSUE 39: inside the one call a class's rows meet that class's
+    hi slots only. The batched build — `binned` stored once, and
+    batched — equals K per-class builds: to 1e-5 of `segment`, the
+    counts (the `w` channel: 0/1 weights) exactly, and BITWISE the
+    unbatched kernel's wherever the K stacked classes keep the row tile
+    one class would take. Rows are no multiple of either tile, 15% are
+    dead; a node of whole 128-lane rows (256 and 128 bins: one lo
+    one-hot for the K classes) at three depths, and 20 bins (a class's
+    lo follows its own nodes: a class a call) at the fourth."""
+    import jax
+
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    n_bins = {1: 256, 4: 128, 16: 256, 32: 20}[n_nodes]
+    rows = 8300 if n_nodes == 16 else 1300
+    unit = C == 2
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, 3, n_nodes, n_bins, seed=K + C + n_nodes)
+    if unit:
+        hK = jnp.ones_like(hK)
+    got = _vmapped(binned, w, n_nodes, n_bins, "pallas", unit)(
+        relK, gK, hK)
+    assert got.shape == (K, n_nodes, 3, n_bins, C)
+    # every class its own codes (here: the same ones, K times)
+    own = _vmapped(binned, w, n_nodes, n_bins, "pallas", unit,
+                   in_axes=(0, 0, 0, 0))(
+        jnp.broadcast_to(binned, (K,) + binned.shape), relK, gK, hK)
+    ht = H._hi_blocks(n_nodes * n_bins)[1]
+    same_tile = n_bins % 128 != 0 or \
+        H._fact_row_tile(K * ht, rows) == H._fact_row_tile(ht, rows)
+    for k in range(K):
+        args = (binned, relK[k], gK[k], hK[k], w, n_nodes, n_bins)
+        want = build_histogram(*args, "segment", unit_hess=unit)
+        one = build_histogram(*args, "pallas", unit_hess=unit)
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got[k, ..., -1]),
+                                      np.asarray(want[..., -1]))
+        np.testing.assert_array_equal(np.asarray(own[k]),
+                                      np.asarray(one))
+        if same_tile:
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(one))
+
+
+# (cap, class blocks, classes a block, hi blocks a class) for K = 3
+# classes of 5 hi slots each (5 nodes x 128 bins)
+_CLASS_CAPS = [(16, 1, 3, 1),       # the three stacked in one block
+               (10, 2, 2, 1),       # merged slots pass the cap: blocks
+                                    # of whole classes, one dead class
+               (8, 3, 1, 1),        # a class a block
+               (2, 3, 1, 3)]        # one class alone passes it: a class
+                                    # a block, served in hi blocks
+
+
+@pytest.mark.parametrize("cap,n_cb,kb,n_ht", _CLASS_CAPS)
+@pytest.mark.parametrize("C", [2, 3])
+def test_class_batch_blocks_by_whole_classes_then_by_hi_blocks(
+        monkeypatch, C, cap, n_cb, kb, n_ht):
+    """Past `_FACT_MAX_NHI` the batch is blocked by whole classes
+    first, by hi blocks inside a class only where one class alone
+    passes the cap; whatever the blocking the sums are bitwise the
+    unblocked batch's and the counts exact."""
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    K, rows, F, n_nodes, n_bins = 3, 1500, 4, 5, 128
+    unit = C == 2
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, F, n_nodes, n_bins, seed=29)
+    build = _vmapped(binned, w, n_nodes, n_bins, "pallas", unit)
+    one = build(relK, gK, hK)
+    monkeypatch.setattr(H, "_FACT_MAX_NHI", cap)
+    blocks = H._hi_blocks(n_nodes * n_bins)
+    assert blocks[0] == n_ht
+    assert H._class_blocks(K, blocks[1]) == (n_cb, kb)
+    got = build(relK, gK, hK)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    want = _vmapped(binned, w, n_nodes, n_bins, "segment", unit)(
+        relK, gK, hK)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got[..., -1]),
+                                  np.asarray(want[..., -1]))
+    names = _kernel_names_for_tpu(build, relK, gK, hK)
+    assert names == ["hist_fact" if n_ht == 1 else "hist_blocked"]
+
+
+@pytest.mark.parametrize("K,ht,want", [
+    (7, 2, (1, 7)), (7, 32, (1, 7)),    # `xgb-covtype.train`: one block
+    (9, 2, (2, 5)),                     # evenly filled, one dead class
+    (32, 2, (4, 8)), (128, 2, (16, 8)),     # the cap on classes binds,
+    (32, 64, (8, 4)),                       # the cap on stacked slots,
+    (3, 300, (3, 1)),                       # a class alone passes it
+])
+def test_class_blocks_hold_at_most_eight_classes(K, ht, want):
+    """A block's VMEM follows its classes as well as its stacked slots
+    (the values block, the ids, the mantissa stacks: classes x row
+    tile whatever ht is), so both are capped."""
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    assert H._class_blocks(K, ht) == want
+    assert want[1] <= H._CLASS_BLOCK_MAX
+    assert want[1] * ht <= max(ht, H._FACT_MAX_NHI)
+
+
+def test_class_batch_past_eight_classes_goes_in_class_blocks():
+    """Nine class trees at the root: two blocks of five classes on the
+    grid's class axis (the tenth dead), still ONE call, each class
+    bitwise its own build."""
+    import jax
+
+    K, rows, F, n_nodes, n_bins = 9, 1300, 3, 1, 256
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, F, n_nodes, n_bins, seed=41)
+    build = _vmapped(binned, w, n_nodes, n_bins, "pallas")
+    (call,) = [e for e in jax.make_jaxpr(build)(relK, gK, hK).eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (1, 1, 2, 2)
+    got = build(relK, gK, hK)
+    for k in range(K):
+        np.testing.assert_array_equal(
+            np.asarray(got[k]), np.asarray(build_histogram(
+                binned, relK[k], gK[k], hK[k], w, n_nodes, n_bins,
+                "pallas")))
+
+
+def test_class_batch_of_part_rows_goes_a_class_a_call():
+    """A node that takes part of a 128-lane row (a K-class forest's 64
+    bins): a class's lo one-hot follows its own nodes, nothing of a
+    column's work is shared, so the batch is the UNBATCHED kernel under
+    `lax.map` — one `pallas_call` in the program, `binned` stored once
+    and never stacked K times."""
+    import jax
+
+    K, rows, F, n_nodes, n_bins = 3, 2048, 4, 4, 64
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, rows, F, n_nodes, n_bins, seed=43)
+    build = _vmapped(binned, w, n_nodes, n_bins, "pallas", unit=True)
+    jaxpr = jax.make_jaxpr(build)(relK, gK, jnp.ones_like(hK))
+    assert [e.primitive.name for e in jaxpr.eqns].count("pallas_call") == 0
+    (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    (call,) = [e for e in loop.params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    assert gm.grid == (1, 1, 1, 2)
+    assert [tuple(getattr(d, "block_size", None) for d in bm.block_shape)
+            for bm in gm.block_mappings] == [
+        (F, 1, 1, 1024), (1024,), (1024, 2), (1, 1, F, 2 * 2, 128)]
+    assert f"[{K},{rows},{F}]" not in str(jaxpr).replace(" ", "")
+
+def test_class_batch_under_the_mesh_matches_one_shard(mesh8):
+    """Row-sharded (the per-level psum sees the same
+    `[K, n_nodes, F, B, C]` array): the batched Pallas build under
+    shard_map equals `segment` over all rows."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from h2o_kubernetes_tpu.runtime.mesh import ROWS
+
+    K, n_nodes, n_bins = 3, 4, 256
+    binned, relK, gK, hK, w = _class_batch_case(
+        K, 8 * 300, 5, n_nodes, n_bins, seed=31)
+
+    def shard(b, r, g, h, ww):
+        return jax.lax.psum(_vmapped(b, ww, n_nodes, n_bins, "pallas")(
+            r, g, h), ROWS)
+
+    cls = P(None, ROWS)
+    got = jax.jit(jax.shard_map(
+        shard, mesh=mesh8, in_specs=(P(ROWS), cls, cls, cls, P(ROWS)),
+        out_specs=P(), check_vma=False))(binned, relK, gK, hK, w)
+    want = _vmapped(binned, w, n_nodes, n_bins, "segment")(relK, gK, hK)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 def _kernel_names_for_tpu(fn, *args):
@@ -144,7 +345,7 @@ def _kernel_names_for_tpu(fn, *args):
     with mock.patch("jax.default_backend", lambda: "tpu"):
         txt = jax.jit(fn).trace(*args).lower(
             lowering_platforms=("tpu",)).as_text()
-    return set(re.findall(r'kernel_name = "(\w+)"', txt))
+    return sorted(re.findall(r'kernel_name = "(\w+)"', txt))
 
 
 def test_hi_blocked_level_lowers_for_tpu_under_its_own_name(monkeypatch):
@@ -160,7 +361,7 @@ def test_hi_blocked_level_lowers_for_tpu_under_its_own_name(monkeypatch):
     for n_nodes, name in ((16, "hist_fact"), (64, "hist_blocked")):
         assert _kernel_names_for_tpu(lambda r: build_histogram(
             binned, r, g, h, w, n_nodes, n_bins, "pallas"),
-            rel) == {name}
+            rel) == [name]
 
 
 # the shallow levels — a handful of histogrammed nodes at the cells'
@@ -256,18 +457,129 @@ def test_both_one_hots_are_built_rows_on_lanes(rows, n_nodes, n_bins, T):
 
 
 def test_class_batch_lowers_to_one_hist_fact_call():
-    """The K-class grower's vmapped build is lowered into ONE flat call
-    of the factorized kernel over the merged node axis, `binned` stored
-    once."""
+    """The K-class grower's vmapped build is ONE call of the kernel a
+    level, under the name every reader finds it by, `binned` stored
+    once — and the kernel's blocks carry the class axis whole
+    (`[K, T]` node ids, `[K, C, T]` values)."""
     import jax
 
-    K, rows, F, n_nodes, n_bins = 3, 2048, 4, 2, 64
+    K, rows, F, n_nodes, n_bins = 3, 2048, 4, 1, 128
     binned, relK, gK, hK, w = _class_batch_case(
         K, rows, F, n_nodes, n_bins, seed=5)
-    assert _kernel_names_for_tpu(
-        jax.vmap(lambda rel, g, h: build_histogram(
-            binned, rel, g, h, w, n_nodes, n_bins, "pallas")),
-        relK, gK, hK) == {"hist_fact"}
+    build = _vmapped(binned, w, n_nodes, n_bins, "pallas")
+    assert _kernel_names_for_tpu(build, relK, gK, hK) == ["hist_fact"]
+    jaxpr = jax.make_jaxpr(build)(relK, gK, hK)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    assert gm.grid == (1, 1, 1, 2)
+    assert [tuple(getattr(d, "block_size", None) for d in bm.block_shape)
+            for bm in gm.block_mappings] == [
+        (F, 1, 1, 1024), (None, K, 1024), (None, K, 3, 1024),
+        (1, 1, None, F, K * 3 * 1, 128)]
+
+
+# the unbatched call at the six cells' shapes:
+# (rows, F, C, bins, code dtype, nodes) -> kernel parameters, grid,
+# block shapes (codes, node ids, values, out), out shape, name
+_UNBATCHED = {
+    "higgs_root": ((4194304, 28, 3, 256, "uint8", 1),
+                   dict(n_bins=256, ht=2, n_ht=1, n_ch=3, fg=28, terms=3),
+                   (1, 1, 1, 1024),
+                   [(28, 1, 1, 4096), (4096,), (4096, 3),
+                    (1, 1, 28, 6, 128)], (1, 1, 28, 6, 128), "hist_fact"),
+    "higgs_16_nodes": ((4194304, 28, 3, 256, "uint8", 16),
+                       dict(n_bins=256, ht=32, n_ht=1, n_ch=3, fg=28,
+                            terms=3), (1, 1, 1, 1024),
+                       [(28, 1, 1, 4096), (4096,), (4096, 3),
+                        (1, 1, 28, 96, 128)], (1, 1, 28, 96, 128),
+                       "hist_fact"),
+    "higgs_64_nodes": ((4194304, 28, 3, 256, "uint8", 64),
+                       dict(n_bins=256, ht=128, n_ht=1, n_ch=3, fg=16,
+                            terms=3), (2, 1, 1, 4096),
+                       [(16, 1, 1, 1024), (1024,), (1024, 3),
+                        (1, 1, 16, 384, 128)], (2, 1, 16, 384, 128),
+                       "hist_fact"),
+    "forest_2048_nodes_blocked": (
+        (4194304, 28, 2, 64, "uint8", 2048),
+        dict(n_bins=64, ht=256, n_ht=4, n_ch=2, fg=8, terms=3),
+        (4, 4, 1, 4096),
+        [(8, 1, 1, 1024), (1024,), (1024, 2), (1, 1, 8, 512, 128)],
+        (4, 4, 8, 512, 128), "hist_blocked"),
+    "airline_root_16_bit": (
+        (8388608, 8, 3, 512, "uint16", 1),
+        dict(n_bins=512, ht=4, n_ht=1, n_ch=3, fg=8, terms=3),
+        (1, 1, 1, 2048),
+        [(8, 1, 1, 4096), (4096,), (4096, 3), (1, 1, 8, 12, 128)],
+        (1, 1, 8, 12, 128), "hist_fact"),
+    "airline_256_nodes_blocked": (
+        (8388608, 8, 3, 512, "uint16", 256),
+        dict(n_bins=512, ht=256, n_ht=4, n_ch=3, fg=8, terms=3),
+        (1, 4, 1, 8192),
+        [(8, 1, 1, 1024), (1024,), (1024, 3), (1, 1, 8, 768, 128)],
+        (1, 4, 8, 768, 128), "hist_blocked"),
+    "mslr_root_17_groups": (
+        (2270296, 136, 3, 256, "uint8", 1),
+        dict(n_bins=256, ht=2, n_ht=1, n_ch=3, fg=8, terms=3),
+        (17, 1, 1, 555),
+        [(8, 1, 1, 4096), (4096,), (4096, 3), (1, 1, 8, 6, 128)],
+        (17, 1, 8, 6, 128), "hist_fact"),
+    "covtype_one_class": (
+        (581632, 54, 3, 256, "uint8", 16),
+        dict(n_bins=256, ht=32, n_ht=1, n_ch=3, fg=54, terms=3),
+        (1, 1, 1, 142),
+        [(54, 1, 1, 4096), (4096,), (4096, 3), (1, 1, 54, 96, 128)],
+        (1, 1, 54, 96, 128), "hist_fact"),
+    "auc_one_column": (
+        (8192, 1, 3, 4096, "uint16", 1),
+        dict(n_bins=4096, ht=32, n_ht=1, n_ch=3, fg=1, terms=3),
+        (1, 1, 1, 2),
+        [(1, 1, 1, 4096), (4096,), (4096, 3), (1, 1, 1, 96, 128)],
+        (1, 1, 1, 96, 128), "hist_fact"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNBATCHED))
+def test_unbatched_call_is_the_pallas_call_it_was(monkeypatch, case):
+    """Every cell but the K-class one reaches the kernel unbatched:
+    the kernel function and its parameters, the 4-axis grid (the third
+    axis 1: class blocks, where a class batch has them), block shapes,
+    index maps, out shape, semantics and name that their ledger lines
+    were measured with. A PR that means to change them measures every cell. Traced at
+    the cells' own sizes; nothing runs."""
+    import jax
+
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    (rows, F, C, bins, dtype, n), params, grid, blocks, out, name = \
+        _UNBATCHED[case]
+    seen = []
+    real = H.pl.pallas_call
+
+    def spy(kernel, **kw):
+        seen.append((kernel, kw))
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(H.pl, "pallas_call", spy)
+    jax.eval_shape(
+        lambda b, r, v: H._hist_pallas(b, r, v, n, bins),
+        jax.ShapeDtypeStruct((rows, F), dtype),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((rows, C), jnp.float32))
+    ((kernel, kw),) = seen
+    assert kernel.func is H._hist_fact_kernel
+    assert kernel.keywords == params
+    assert kw["grid"] == grid and kw["name"] == name
+    assert [tuple(sp.block_shape) for sp in kw["in_specs"]] + \
+        [tuple(kw["out_specs"].block_shape)] == blocks
+    assert kw["out_shape"].shape == out
+    # the index maps at (group 2, hi block 3, copy 1, row block 5): the
+    # node ids and values carry `k·row blocks`, 0 on this grid
+    assert [sp.index_map(2, 3, 1, 5) for sp in kw["in_specs"]] + \
+        [kw["out_specs"].index_map(2, 3, 1, 5)] == [
+        (2, 5, 0, 0), (grid[3] + 5,), (grid[3] + 5, 0), (2, 3, 0, 0, 0)]
+    assert tuple(str(d) for d in
+                 kw["compiler_params"].dimension_semantics) == (
+        "parallel", "parallel", "arbitrary", "arbitrary")
 
 
 def test_auc_histogram_lowers_to_hist_fact():
@@ -283,7 +595,7 @@ def test_auc_histogram_lowers_to_hist_fact():
         names = _kernel_names_for_tpu(M._score_hist_shard, col, col, col)
     finally:
         h2o.set_config("hist_impl", prev)
-    assert names == {"hist_fact"}
+    assert names == ["hist_fact"]
 
 
 @pytest.mark.parametrize("impl", ["segment", "pallas"])
@@ -344,9 +656,9 @@ def test_gaussian_gbm_unit_hess_matches_full_channels(mesh8):
 
 def test_vmapped_batch_matches_loop():
     """vmap over a class axis (the fused multinomial scan's shape) must
-    equal per-class builds. The custom_vmap rule lowers the batch into
-    the node axis instead of batching the Pallas kernel — Mosaic
-    rejects vmapped rank-1 block specs (round-4 on-chip gate)."""
+    equal per-class builds. The custom_vmap rule hands the batch to a
+    kernel that takes it whole instead of batching the Pallas kernel —
+    Mosaic rejects vmapped rank-1 block specs (round-4 on-chip gate)."""
     import jax
 
     K, rows, F, n_nodes, n_bins = 3, 1500, 4, 8, 32

@@ -27,6 +27,31 @@ or the largest difference):
      Wins over (a), loses to (b) at 256 bins (1,024 result pops a
      (column, tile) are its floor) and ties it at 64
 
+With `--classes K` (ISSUE 39; needs `--shape ROWSxCOLUMNS[xBINS[xC]]`)
+the call is the K-class grower's: ONE stored `[rows, F]` of codes,
+`[K, rows]` node ids and `[K, rows, C]` values ->
+`[K, n_nodes, F, B, C]`, through
+
+  fold  the class batch folded into the node axis, which B replaced:
+        class k's rows relabelled to nodes [k·n, (k+1)·n), the flat
+        kernel over the K concatenated row streams with K·n nodes —
+        every copy multiplied against all K classes' hi slots
+  A     the copy axis indexes the out block: a class a grid step
+        against its own slots, the codes re-read K times (the shipped
+        kernel with blocks of one class)
+  B     the SHIPPED rule (`ops/histogram._hist_class_kernel`): the
+        classes of one row tile in one grid step, a column's codes
+        read once, the classes packed on the sublanes of ONE A operand
+        against one lo one-hot. At a bin count that is no multiple of
+        128 the shipped rule IS `map`, and so is A
+  map   K unbatched calls under `lax.map`: the kernels of the grower's
+        fallback past `_MULTI_HIST_BUDGET`
+
+each compared with B (bitwise, or the largest difference) and with
+`_hist_segment` a class. A form's first call compiles; `--limit` is
+the seconds after which a (form, shape) is abandoned (its line says
+`timeout`; the calls already queued on the chip still drain).
+
 One JSON line a measurement on stdout and in
 `chiprun_out/hist_forms.jsonl`. Exits non-zero without a TPU;
 `tests/test_hist_forms.py` holds the three forms to `_hist_segment` on
@@ -34,9 +59,11 @@ the CPU in interpret mode.
 """
 
 import argparse
+import contextlib
 import functools
 import json
 import os
+import signal
 import sys
 import time
 import unittest.mock as mock
@@ -241,16 +268,81 @@ SHAPES = {
 }
 
 
-def _case(rows, F, C, n_bins, dtype, n_nodes, seed):
+def _case(rows, F, C, n_bins, dtype, n_nodes, seed, classes=None):
+    """A level's operands; with ``classes`` the K-class grower's: one
+    `binned`, `[K, rows]` node ids and `[K, rows, C]` values."""
     k1, k2, k3, k4 = jax.random.split(jax.random.key(seed), 4)
     binned = jax.random.randint(k1, (rows, F), 0, n_bins,
                                 jnp.int32).astype(dtype)
-    rel = jax.random.randint(k2, (rows,), 0, n_nodes, jnp.int32)
-    dead = jax.random.uniform(k3, (rows,)) < 0.1
+    lead = () if classes is None else (classes,)
+    rel = jax.random.randint(k2, lead + (rows,), 0, n_nodes, jnp.int32)
+    dead = jax.random.uniform(k3, lead + (rows,)) < 0.1
     rel = jnp.where(dead, -1, rel)
-    vals = jnp.where(dead[:, None], 0.0,
-                     jax.random.normal(k4, (rows, C), jnp.float32))
+    vals = jnp.where(dead[..., None], 0.0,
+                     jax.random.normal(k4, lead + (rows, C), jnp.float32))
     return binned, rel, vals
+
+
+def _fold(binned, rel, vals, n_nodes, n_bins):
+    """The class batch as `_hist_vmappable`'s rule lowered it until
+    PR 39: the flat kernel over K relabelled copies of the row stream,
+    `binned` stored once and re-read a copy, all K·n_nodes nodes' hi
+    slots in the one block every copy is multiplied against."""
+    K, r = rel.shape
+    F, C = binned.shape[1], vals.shape[-1]
+    nB = K * n_nodes * n_bins
+    n_ht, ht = H._hi_blocks(nB)
+    rt_size = H._fact_row_tile(ht, r)
+    pad = (-r) % rt_size
+    binned = jnp.pad(binned, ((0, pad), (0, 0)))
+    rel = jnp.pad(rel, ((0, 0), (0, pad)), constant_values=-1)
+    rel = jnp.where(rel >= 0, rel + (jnp.arange(K, dtype=jnp.int32)
+                                     * n_nodes)[:, None], -1)
+    vals = jnp.pad(vals, ((0, 0), (0, pad), (0, 0)))
+    rbb = (r + pad) // rt_size
+    fg, F_pad = H._feature_groups(F, C, ht)
+    binned = jnp.pad(binned, ((0, 0), (0, F_pad - F)))
+    n_fg = F_pad // fg
+    out = pl.pallas_call(
+        functools.partial(H._hist_fact_kernel, n_bins=n_bins, ht=ht,
+                          n_ht=n_ht, n_ch=C, fg=fg, terms=3),
+        out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),
+                                       jnp.float32),
+        grid=(n_fg, n_ht, K, rbb),
+        in_specs=[
+            pl.BlockSpec((fg, 1, 1, rt_size),
+                         lambda g, b, k, rt: (g, rt, 0, 0)),
+            pl.BlockSpec((rt_size,),
+                         lambda g, b, k, rt: (k * rbb + rt,)),
+            pl.BlockSpec((rt_size, C),
+                         lambda g, b, k, rt: (k * rbb + rt, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, fg, C * ht, 128),
+                               lambda g, b, k, rt: (g, b, 0, 0, 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=H._interpret(), name="hist_fold",
+    )(binned.astype(jnp.int32).T.reshape(F_pad, rbb, 1, rt_size),
+      rel.reshape(-1), vals.reshape(-1, C))
+    out = out.reshape(n_fg, n_ht, fg, C, ht * 128).transpose(
+        0, 2, 3, 1, 4).reshape(F_pad, C, n_ht * ht * 128)[:F, :, :nB]
+    return out.reshape(F, C, K, n_nodes, n_bins).transpose(2, 3, 0, 4, 1)
+
+
+def _class_form_a(binned, rel, vals, n_nodes, n_bins):
+    # `_hist_pallas` sizes its class blocks at trace time
+    with mock.patch.object(H, "_class_blocks", lambda K, ht: (K, 1)):
+        return H._hist_pallas(binned, rel, vals, n_nodes, n_bins)
+
+
+def _class_map(binned, rel, vals, n_nodes, n_bins):
+    return lax.map(lambda a: H._hist_pallas(binned, *a, n_nodes, n_bins),
+                   (rel, vals))
+
+
+CLASS_FORMS = {"fold": _fold, "A": _class_form_a, "B": H._hist_pallas,
+               "map": _class_map}
+CLASS_SHIPPED = "B"
 
 
 def _segment_on_host(binned, rel, vals, n_nodes, n_bins):
@@ -266,33 +358,62 @@ def _segment_on_host(binned, rel, vals, n_nodes, n_bins):
          for j in range(binned.shape[1])], axis=1), jax.devices()[0])
 
 
-def measure(name, shape, n_nodes, forms, calls, seed, segment=True):
+@contextlib.contextmanager
+def _limit(seconds):
+    """SIGALRM after ``seconds`` (0: never) of a (form, shape): a
+    compile or a call that runs away costs its limit, not the chip
+    call's."""
+    def _raise(*_):
+        raise TimeoutError(f"over {seconds} s")
+
+    if seconds:
+        signal.signal(signal.SIGALRM, _raise)
+        signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+
+
+def measure(name, shape, n_nodes, forms, calls, seed, segment=True,
+            classes=None, limit=0):
     """One line a form at one (shape, node count); the shipped form is
     measured first so that the others are compared with it."""
     rows, F, C, n_bins, dtype = shape[:5]
-    binned, rel, vals = _case(rows, F, C, n_bins, dtype, n_nodes, seed)
-    want = _segment_on_host(binned, rel, vals, n_nodes, n_bins) \
-        if segment else None
+    table, first = (FORMS, SHIPPED) if classes is None else \
+        (CLASS_FORMS, CLASS_SHIPPED)
+    binned, rel, vals = _case(rows, F, C, n_bins, dtype, n_nodes, seed,
+                              classes)
+    want = None
+    if segment and classes is None:
+        want = _segment_on_host(binned, rel, vals, n_nodes, n_bins)
+    elif segment:
+        want = jnp.stack([_segment_on_host(binned, rel[k], vals[k],
+                                           n_nodes, n_bins)
+                          for k in range(classes)])
     shipped = None
-    for form in sorted(forms, key=lambda f: f != SHIPPED):
+    for form in sorted(forms, key=lambda f: f != first):
         line = {"shape": name, "rows": rows, "F": F, "C": C,
                 "n_bins": n_bins, "n_nodes": n_nodes, "form": form}
+        if classes is not None:
+            line["classes"] = classes
         try:
-            fn = jax.jit(functools.partial(
-                FORMS[form], n_nodes=n_nodes, n_bins=n_bins))
-            t0 = time.perf_counter()
-            got = fn(binned, rel, vals).block_until_ready()
-            line["first_s"] = time.perf_counter() - t0
-            secs = []
-            for _ in range(calls):
+            with _limit(limit):
+                fn = jax.jit(functools.partial(
+                    table[form], n_nodes=n_nodes, n_bins=n_bins))
                 t0 = time.perf_counter()
-                fn(binned, rel, vals).block_until_ready()
-                secs.append(time.perf_counter() - t0)
+                got = fn(binned, rel, vals).block_until_ready()
+                line["first_s"] = time.perf_counter() - t0
+                secs = []
+                for _ in range(calls):
+                    t0 = time.perf_counter()
+                    fn(binned, rel, vals).block_until_ready()
+                    secs.append(time.perf_counter() - t0)
             line["min_s"], line["max_s"] = min(secs), max(secs)
             if want is not None:
                 line["rel_err_segment"] = float(
                     jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
-            if form == SHIPPED:
+            if form == first:
                 shipped = got
             elif shipped is not None:
                 line["bitwise_shipped"] = bool(
@@ -300,15 +421,32 @@ def measure(name, shape, n_nodes, forms, calls, seed, segment=True):
                 line["rel_diff_shipped"] = float(
                     jnp.max(jnp.abs(got - shipped))
                     / jnp.max(jnp.abs(shipped)))
+        except TimeoutError as e:
+            line["timeout"] = str(e)
         except Exception as e:      # this form failed here; go on
             line["error"] = repr(e)[:400]
         yield line
 
 
+def _custom_shape(text, classes):
+    """`581632x54` -> a shape of that many rows and columns at the
+    K-class cell's widths (256 bins, C 3, 8-bit codes);
+    `581632x54x64x2` states the bins and the channels too."""
+    given = [int(x) for x in text.lower().split("x")]
+    rows, F, n_bins, C = given + [None, None, 256, 3][len(given):]
+    return (rows, F, C, n_bins, "uint8", (1, 4, 16),
+            tuple(CLASS_FORMS) if classes else ("b", "a", "c"))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--shape", action="append", default=None,
-                    choices=sorted(SHAPES))
+                    help=f"one of {sorted(SHAPES)}, or "
+                         "ROWSxCOLUMNS[xBINS[xC]] (256 bins, C 3; "
+                         "8-bit codes)")
+    ap.add_argument("--classes", type=int, default=None,
+                    help="K: time the class batch's forms "
+                         f"{sorted(CLASS_FORMS)} at a ROWSxCOLUMNS shape")
     ap.add_argument("--forms", default=None,
                     help="comma-separated, overrides the shape's list")
     ap.add_argument("--nodes", default=None, help="comma-separated")
@@ -318,14 +456,21 @@ def main(argv=None) -> int:
                          "one alone")
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument("--seed", type=int, default=35)
+    ap.add_argument("--limit", type=int, default=0,
+                    help="seconds a (form, shape) may take, compile "
+                         "included; 0: none")
     args = ap.parse_args(argv)
+    shapes = {name: SHAPES[name] if name in SHAPES
+              else _custom_shape(name, args.classes)
+              for name in args.shape or sorted(SHAPES)}
+    if args.classes and any(name in SHAPES for name in shapes):
+        ap.error("--classes needs --shape ROWSxCOLUMNS")
     from h2o_kubernetes_tpu.runtime.backend import require_tpu
 
     require_tpu("hist_forms")
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/hist_forms.jsonl", "a") as sink:
-        for name in args.shape or sorted(SHAPES):
-            shape = SHAPES[name]
+        for name, shape in shapes.items():
             forms = tuple(args.forms.split(",")) if args.forms \
                 else shape[6]
             nodes = tuple(int(n) for n in args.nodes.split(",")) \
@@ -333,7 +478,9 @@ def main(argv=None) -> int:
             for n_nodes in nodes:
                 for line in measure(name, shape, n_nodes, forms,
                                     args.calls, args.seed,
-                                    segment=not args.no_segment):
+                                    segment=not args.no_segment,
+                                    classes=args.classes,
+                                    limit=args.limit):
                     txt = json.dumps(line)
                     print(txt, flush=True)
                     sink.write(txt + "\n")
