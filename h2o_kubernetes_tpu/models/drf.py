@@ -16,6 +16,14 @@ at 64 bins) the histogram kernel serves the level in several blocks of
 hi slots (`hist_blocked` in a profile), each at the 512-node level's
 cost: a level costs by its nodes x bins and no more (PERF.md §5).
 
+Categorical columns: with ``categorical_encoding="enum"`` (H2O-3's
+AUTO for DRF) a forest splits on SETS of an enum's levels, as a GBM
+does — one bin a level, the levels of a node in mean-response order
+(the shared `_set_order` with h = 1), every prefix scanned — and its
+trees are scored by the heap descent over bin codes (`predict`); the
+flat scorer, MOJO export, the registry and TreeSHAP refuse such a
+model by name, and a K-class forest's grower refuses sets at train.
+
 What each tree saw: a forest's model keeps its trees' keys and hands
 out each tree's bag (`tree_bag(t)`) and each node's candidate features
 (`tree_candidates(t)`) on demand — what scoring out of bag starts from

@@ -164,7 +164,6 @@ def _draws_from_keys(p: "GBMParams", F: int) -> bool:
 # it. The scoring side's list is `core.require_ordinal`'s callers.
 _NO_SET_SPLITS_YET = (
     ("xgboost", "the XGBoost facade"),
-    ("drf", "DRF"),
     ("multinomial", "the multinomial grower"),
     ("checkpoint", "checkpoint restart"),
     ("efb", "an EFB-bundled frame"),
@@ -183,7 +182,7 @@ def refuse_set_splits(**facts) -> None:
                 "set split yet (a split that sends a set of an enum's "
                 "levels left); train with "
                 "categorical_encoding='label_encoder', or a bernoulli / "
-                "regression GBM in device memory")
+                "regression GBM or DRF in device memory")
 
 
 # What cannot carry a GROUPED objective yet (rank:pairwise / rank:ndcg:
@@ -290,8 +289,7 @@ class BoostPlan(NamedTuple):
                 checkpoint=ckpt is not None, offset=offset, cv=cv)
         if any(self.tp.set_feats):
             refuse_set_splits(
-                xgboost=algo == "xgboost", drf=self.bp.drf_mode,
-                multinomial=self.K > 1,
+                xgboost=algo == "xgboost", multinomial=self.K > 1,
                 checkpoint=ckpt is not None, efb=efb,
                 goss=self.bp.goss_b > 0,
                 ooc=padded is not None
@@ -1085,6 +1083,8 @@ class GBM:
                         training_frame.vec(n).is_enum()
                         for n in data.feature_names),
                     objective=data.distribution)
+        if p._drf_mode:
+            root.update(mtries=p.mtries, set_features=sum(set_feats))
         if rank is not None:
             root.update(queries=rank.queries, max_query=rank.max_query)
         # no span blocks on the device for its own sake (the dispatch

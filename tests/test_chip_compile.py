@@ -126,6 +126,23 @@ def test_shallow_levels_compile_at_the_other_cells_shapes(
     assert _kernels(c) == 1 and _kernel_names(c) == {"hist_fact"}
 
 
+@pytest.mark.parametrize("n_nodes,blocks", [(128, 2), (256, 4), (512, 8),
+                                            (1024, 16)])
+def test_forest_levels_of_many_hi_blocks_compile(one_chip, n_nodes,
+                                                 blocks):
+    """`drf-airline.train`'s deep levels: a forest (2 channels) over the
+    airline's 8 columns of 16-bit codes in a 512-bin matrix, whose
+    levels 8-11 take 2, 4, 8 and 16 blocks of hi slots in one call."""
+    assert -(-n_nodes * 512 // 128) // histogram._FACT_MAX_NHI == blocks
+    fn = jax.jit(lambda b, r, g, h, w: histogram.build_histogram(
+        b, r, g, h, w, n_nodes, 512, "pallas", unit_hess=True))
+    f32 = _s((ROWS_N,), jnp.float32, one_chip)
+    c = fn.lower(_s((ROWS_N, 8), jnp.uint16, one_chip),
+                 _s((ROWS_N,), jnp.int32, one_chip), f32, f32,
+                 f32).compile()
+    assert _kernels(c) == 1 and _kernel_names(c) == {"hist_blocked"}
+
+
 @pytest.mark.parametrize("K,F,n_nodes,bins,unit_hess,name", [
     (7, 54, 1, 256, False, "hist_fact"),    # `xgb-covtype.train`'s root
     (7, 54, 16, 256, False, "hist_fact"),   # and its deepest level
