@@ -41,8 +41,8 @@ from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
                         _boost_drf_jit, _boost_jit, _boost_multi_jit,
                         descend_tree, flat_margin, flatten_cover,
                         flatten_trees, goss_round_keys, level_hist_bytes,
-                        multi_grow_vmapped, predict_tree, round_keys,
-                        set_split_reason)
+                        multi_grow_vmapped, node_lookup_forms,
+                        predict_tree, round_keys, set_split_reason)
 from .tree.rank import (RankLayout, grouped, groups_abstract, ndcg_at,
                         rank_layout)
 
@@ -1194,6 +1194,8 @@ class GBM:
                 _count_pairs(rank, p.ntrees - start_t)
             if plan.K > 1:
                 _count_class_trees(plan, p.ntrees - start_t)
+            if ooc_chunk is None:
+                _count_node_lookups(plan, p.ntrees - start_t)
         # which way the metric is read: off the boosting margin, off
         # the sum of leaf values a forest's scan carried (every tree
         # over every row, bitwise what `_margins_of_binned` walks
@@ -1589,6 +1591,24 @@ def _count_class_trees(plan: BoostPlan, rounds: int) -> None:
         "mapped (a class at a time)", label="kind").inc(
             rounds * plan.K, label_value="batched"
             if plan.class_batch == "vmap" else "mapped")
+
+
+def _count_node_lookups(plan: BoostPlan, rounds: int) -> None:
+    """`h2o_train_node_lookups_total{form}`, added up once a job held in
+    HBM: the by-row lookups of a node table that its trees (rounds x K)
+    traced — each level's descent and each tree's margin update — by
+    the form `core.node_lookup_forms` gives them as the program is
+    traced: ``select`` or ``gather``."""
+    from ..runtime.telemetry import REGISTRY
+
+    ctr = REGISTRY.counter(
+        "h2o_train_node_lookups_total",
+        "by-row lookups of a node table in the boost scans of the jobs "
+        "trained (a level's descent, a tree's margin update), by form: "
+        "select (over the table's entries) or gather", label="form")
+    forms = node_lookup_forms(plan.tp.max_depth)
+    for form in ("select", "gather"):
+        ctr.inc(rounds * plan.K * forms.count(form), label_value=form)
 
 
 def _count_splits(trees: Tree, set_feats: tuple) -> None:

@@ -356,6 +356,72 @@ def _row_column(binned, col):
                              binned.astype(jnp.int32), 0), axis=1)
 
 
+# the most entries a node table may have for a by-row lookup into it to
+# be a select over its entries; past it, a gather (PERF.md section 6:
+# `tools/lookup_forms.py` on a v5e at 4,194,304 rows and under the
+# class batch at 7 x 581,632: at 2,048 entries a select of one word
+# takes 10.4-11.1 ms against the gather's 31-37, at 8,191 74-77)
+_SELECT_MAX_ENTRIES = 2048
+
+
+def node_lookup_form(entries: int) -> str:
+    """How `_node_lookup` reads a node table of ``entries`` entries by
+    row: ``select`` or ``gather``. THE rule, for the program as it is
+    traced and for `node_lookup_forms`, which counts it."""
+    return "select" if entries <= _SELECT_MAX_ENTRIES else "gather"
+
+
+def node_lookup_forms(max_depth: int) -> list[str]:
+    """The forms of one tree's by-row lookups in a boost scan: each
+    level's descent (a table of 2^d nodes: `_split_of_rows`), then the
+    margin's leaf value (the heap's 2^(max_depth+1) - 1 entries)."""
+    return [node_lookup_form(2 ** d) for d in range(max_depth)] + \
+        [node_lookup_form(2 ** (max_depth + 1) - 1)]
+
+
+def _node_lookup(table, idx):
+    """``table[..., idx]`` for a node table [..., entries] and each
+    row's entry ``idx`` [..., rows] in [0, entries), leading batch axes
+    alike. Under `node_lookup_form`'s rule either a gather or a select:
+    every entry compared with ``idx`` and the one that matches kept, in
+    one fused pass over [entries, rows] that a batch axis leaves a
+    select (under `vmap` a gather is a batched gather, ~20 ns a row
+    whatever the table: PERF.md section 6). The select
+    sums the entries' 32 bits as integers, one of them non-zero, so it
+    is bitwise the gather: a `-0.0` leaf stays `-0.0`."""
+    if node_lookup_form(table.shape[-1]) == "gather":
+        # `t[i]` a table: `take_along_axis`, or any select after the
+        # gather, asks the chip's compiler for 137 MB more temporaries
+        # in a depth-12 forest at 4,194,304 rows
+        gather = lambda t, i: t[i]                      # noqa: E731
+        for _ in range(idx.ndim - 1):
+            gather = jax.vmap(gather)
+        return gather(table, idx)
+    bits = table.astype(jnp.int32) if table.dtype == jnp.bool_ \
+        else lax.bitcast_convert_type(table, jnp.int32)
+    hit = jnp.arange(table.shape[-1], dtype=idx.dtype)[:, None] == \
+        idx[..., None, :]                                # [..., E, rows]
+    got = jnp.sum(jnp.where(hit, bits[..., :, None], 0), axis=-2)
+    return got.astype(jnp.bool_) if table.dtype == jnp.bool_ \
+        else lax.bitcast_convert_type(got, table.dtype)
+
+
+def _split_of_rows(idx, feat, bin_, na_l, can, n_feat: int, n_bins: int):
+    """(feat, bin, na_left, can) of each row's node, ``idx`` its entry
+    of the level's tables: the four packed into one int32 word a node
+    and read by ONE `_node_lookup`, or two where a feature index and a
+    bin do not fit in 29 bits together."""
+    sb = max(n_bins - 1, 1).bit_length()
+    low = (bin_ << 2) | (na_l.astype(jnp.int32) << 1) | can.astype(jnp.int32)
+    if max(n_feat - 1, 1).bit_length() + sb + 2 <= 31:
+        word = _node_lookup(low | (feat << (sb + 2)), idx)
+        f = word >> (sb + 2)
+    else:
+        f, word = _node_lookup(feat, idx), _node_lookup(low, idx)
+    return (f, (word >> 2) & ((1 << sb) - 1), (word & 2) != 0,
+            (word & 1) != 0)
+
+
 def row_orig_bins(binned, f, efb):
     """Per-row ORIGINAL-space bin of (per-row) feature ``f`` — the ONE
     decode both the fused grower and the out-of-core descent use.
@@ -525,13 +591,12 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
         with jax.named_scope("descend"):
             live = rel >= 0
             safe_rel = jnp.where(live, rel, 0)
-            f = feat[safe_rel]
-            b = bin_[safe_rel]
-            nl = na_l[safe_rel]
+            f, b, nl, c = _split_of_rows(safe_rel, feat, bin_, na_l, can,
+                                         col_mask.shape[0], p.n_bins)
             rowbin = row_orig_bins(binned, f, efb)
             go_right = _goes_right(rowbin, b, nl, p.n_bins, lb, safe_rel)
             child = 2 * rel + go_right.astype(jnp.int32)  # rel at d+1
-            moved = live & can[safe_rel]
+            moved = live & c
             rel = jnp.where(moved, child, -1)
             abs_node = jnp.where(moved, (2 ** (d + 1) - 1) + child,
                                  abs_node)
@@ -889,18 +954,23 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
             tree, _ = _grow_tree_shard(bC, gC, hC, wC, col_mask,
                                        k_tree, p, efb)
             with jax.named_scope("margin"):
+                margin = margin + bp.learn_rate * _node_lookup(
+                    tree.value, descend_tree(tree, binned, p.max_depth,
+                                             p.n_bins, efb))
                 tree = tree._replace(value=bp.learn_rate * tree.value)
-                margin = margin + tree.value[descend_tree(
-                    tree, binned, p.max_depth, p.n_bins, efb)]
             return margin, (tree, lax.psum(dropped, ROWS))
         tree, leaf = _grow_tree_shard(binned, g, h, w_t, col_mask,
                                       k_tree, p, efb)
         with jax.named_scope("margin"):
-            tree = tree._replace(value=bp.learn_rate * tree.value)
             # the grower already walked each row to its leaf: one
-            # gather replaces a full predict_tree heap re-descent
-            # per tree
-            margin = margin + tree.value[leaf]
+            # lookup replaces a full predict_tree heap re-descent per
+            # tree. The rate scales the rows' values as it scales the
+            # table's, bit for bit, and after the lookup in either
+            # form: XLA:CPU folds a multiply into the loop of a gather
+            # and rounds it with the add (a multiply-add)
+            margin = margin + bp.learn_rate * _node_lookup(tree.value,
+                                                           leaf)
+            tree = tree._replace(value=bp.learn_rate * tree.value)
         return margin, tree
 
     if goss:
@@ -1003,14 +1073,16 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
         with jax.named_scope("margin"):
             # a K-class forest carries its sums here too (learn_rate
             # 1; its gradients above never read the margin)
-            trees = trees._replace(value=bp.learn_rate * trees.value)
+            # (the rate after the lookup, as in `_boost_shard`)
             if goss:
                 # sampled grow → full-row leaf values by re-descent
-                upd = jax.vmap(lambda tr: tr.value[descend_tree(
-                    tr, binned, p.max_depth, p.n_bins, efb)])(trees)
+                upd = jax.vmap(lambda tr: _node_lookup(
+                    tr.value, descend_tree(tr, binned, p.max_depth,
+                                           p.n_bins, efb)))(trees)
             else:
-                upd = jax.vmap(lambda v, lf: v[lf])(trees.value, leaf)
-            margin = margin + upd.T
+                upd = _node_lookup(trees.value, leaf)     # [K, rows]
+            margin = margin + (bp.learn_rate * upd).T
+            trees = trees._replace(value=bp.learn_rate * trees.value)
         if goss:
             return margin, (trees, lax.psum(dropped, ROWS))
         return margin, trees
@@ -1035,9 +1107,9 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
     folded the G trees into the kernel's node axis, every tree's rows
     multiplied against all G trees' slots — G² products for G. A
     tree's rows now meet its own slots only (at a forest's 64 bins, a
-    tree a call: PERF.md §6, PR 39); the G-fold temporaries, and the
-    by-row lookups that `vmap` turns back into gathers, are what a
-    second try would have to beat.)
+    tree a call: PERF.md §6), and a node table of up to
+    `_SELECT_MAX_ENTRIES` is read by a select under `vmap` too;
+    the G-fold temporaries are what a second try would have to beat.)
 
     The scan carries what ``_boost_shard`` carries: ``margin`` plus the
     leaf value of every tree so far, for EVERY row — the bag is a
@@ -1056,7 +1128,7 @@ def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
         tree, leaf = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
                                       k_tree, p, efb)
         with jax.named_scope("margin"):
-            margin = margin + tree.value[leaf]
+            margin = margin + _node_lookup(tree.value, leaf)
         return margin, tree
 
     return lax.scan(body, margin, keys)

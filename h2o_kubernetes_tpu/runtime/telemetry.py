@@ -85,7 +85,7 @@ CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 ALLOWED_LABELS = frozenset({
     "model", "shard", "phase", "kind", "slo", "outcome", "state",
     "event", "route", "pool", "replica", "value", "le",
-    "version", "jax", "jaxlib", "hostfp",
+    "version", "jax", "jaxlib", "hostfp", "form",
 })
 
 # label names whose VALUE set is unbounded by construction (tenant

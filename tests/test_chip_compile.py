@@ -83,6 +83,19 @@ def _kernel_names(compiled) -> set:
         compiled.as_text())}
 
 
+def _row_gathers(text: str, results, scope: str) -> list:
+    """The gathers of a compiled program whose result is one of
+    ``results`` (`"7,65536"`: one element a row, or a class and a row)
+    and whose name stack passes through ``scope``: a `kCustom` fusion,
+    as the chip's compiler emits a gather by row, or a bare `gather`."""
+    shape = "|".join(re.escape(r) for r in results)
+    return [ln for ln in text.split("\n")
+            if re.search(rf"= \w+\[({shape})\]\S* "
+                         rf"(fusion\([^\n]*kind=kCustom|gather\()", ln)
+            and any(f"/{scope}/" in name
+                    for name in re.findall(r'op_name="([^"]+)"', ln))]
+
+
 def _scopes(text: str) -> set:
     """Every component of every operation's JAX name stack."""
     return {part for name in re.findall(r'op_name="([^"]+)"', text)
@@ -229,17 +242,25 @@ def test_boost_scan_compiles(boost_scan, n_dev):
     assert c.memory_analysis().temp_size_in_bytes < 8 << 30
 
 
-@pytest.mark.parametrize("n_dev", [1, 4])
-def test_boost_scan_descends_without_a_gather(boost_scan, n_dev):
+@pytest.mark.parametrize("n_dev,scope", [
+    pytest.param(1, "descend", id="1"), pytest.param(4, "descend", id="4"),
+    pytest.param(1, "margin", id="margin-1"),
+    pytest.param(4, "margin", id="margin-4")])
+def test_boost_scan_descends_without_a_gather(boost_scan, n_dev, scope):
     """Row descent selects each row's split column in one loop fusion
     (PR 31). The `take_along_axis` it replaced compiled to a `kCustom`
     gather fusion with a `u8[rows]` result, one a level: 72-88 ms a
     call on the chip at 4,194,304 x 28, 47% of a GBM job (PERF.md
-    section 6)."""
+    section 6). The margin update reads each row's leaf value from the
+    tree's 127 entries by a select too: the gather it replaced,
+    `f32[rows]` under `margin`, took 33 ms a tree at 4,194,304 rows."""
     txt = boost_scan(n_dev).as_text()
-    assert not re.findall(
-        rf"= u8\[{ROWS_N}\]\S* fusion\([^\n]*kind=kCustom", txt)
-    assert "descend" in _scopes(txt)
+    if scope == "descend":
+        assert not re.findall(
+            rf"= u8\[{ROWS_N}\]\S* fusion\([^\n]*kind=kCustom", txt)
+    else:
+        assert not _row_gathers(txt, [str(ROWS_N)], "margin")
+    assert scope in _scopes(txt)
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
@@ -512,6 +533,13 @@ def test_k_class_boost_scan_compiles(topo, n_dev):
     # 32 hi slots = 672 rows
     assert re.search(r"f32\[7,1,1,8,672,128\]", txt)
     assert c.memory_analysis().temp_size_in_bytes < 3_000 * ROWS_N
+    # no by-row lookup of a node table is a batched gather (they were 20 a
+    # round under `vmap(descend)` and one under `margin`, 6.4 s of the
+    # cell's 9.0 s job); a select that did not fuse into its reduce
+    # would show in the temporaries above as `[K, entries, rows]`
+    per_row = [f"{K},{ROWS_N}", str(K * ROWS_N)]
+    for scope in ("vmap(descend)", "margin"):
+        assert not _row_gathers(txt, per_row, scope), scope
     # (the grower's scopes come out as `vmap(level_hist)`: a reader that
     # takes a name stack apart by "/" alone files them under no scope)
     scopes = _scopes(txt)

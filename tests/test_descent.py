@@ -134,3 +134,154 @@ def test_descend_tree_rests_where_the_grower_left_each_row(mesh8, depth):
     assert np.array_equal(walked, leaf_node)
     assert leaf_node.max() >= 2 ** depth - 1     # rows reach the floor
     assert not np.asarray(tree.is_split)[leaf_node].any()
+
+
+def _bits(a):
+    """An array's bits, so that `-0.0` and `0.0` (and NaNs) differ."""
+    a = np.asarray(a)
+    return a.view(np.int32) if a.dtype == np.float32 else a
+
+
+def _table(rng, dtype, shape):
+    if dtype == "bool":
+        return rng.random(shape) < 0.5
+    if dtype == "int32":
+        return rng.integers(-2 ** 31, 2 ** 31 - 1, shape, dtype=np.int32)
+    t = rng.normal(size=shape).astype(np.float32)
+    t.reshape(-1, shape[-1])[:, 0] = -0.0       # a leaf of G = 0
+    t.reshape(-1, shape[-1])[:, -1] = 0.0
+    return t
+
+
+@pytest.mark.parametrize("form", ["select", "gather"])
+@pytest.mark.parametrize("batch", ["none", "vmap7", "lead7"])
+@pytest.mark.parametrize("entries", [1, 2, 32, 127, 128, 2048])
+@pytest.mark.parametrize("dtype", ["int32", "bool", "float32"])
+def test_node_lookup_is_bitwise_the_gather(monkeypatch, dtype, entries,
+                                           batch, form):
+    """`core._node_lookup` is `table[idx]` bit for bit in both
+    of its forms, whatever `_SELECT_MAX_ENTRIES` picks: for an int32, a
+    bool and a float32 table with `-0.0` among its entries (a sum
+    against `+0.0` fills would give `+0.0` there), for every entry
+    count the cells' depths give, one tree or K = 7 of them — under
+    `vmap`, as the class batch grows them, or on a leading axis, as the
+    K-class margin reads its leaves. Every entry is read by some row."""
+    monkeypatch.setattr(core, "_SELECT_MAX_ENTRIES",
+                        1 << 30 if form == "select" else 0)
+    rng = np.random.default_rng(entries)
+    lead = () if batch == "none" else (7,)
+    table = _table(rng, dtype, lead + (entries,))
+    idx = rng.integers(0, entries, lead + (2 * N,)).astype(np.int32)
+    idx[..., :entries] = np.arange(entries)
+    want = np.take_along_axis(table, idx, axis=-1)
+    fn = jax.vmap(core._node_lookup) if batch == "vmap7" \
+        else core._node_lookup
+    got = jax.jit(fn)(jnp.asarray(table), jnp.asarray(idx))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("n_feat,n_bins,words", [
+    (28, 256, 1), (136, 512, 1), (1, 2, 1), (2 ** 22, 256, 2)])
+def test_split_of_rows_packs_and_unpacks(monkeypatch, n_feat, n_bins,
+                                         words):
+    """The descent's four tables read as one packed word, or as two
+    where a feature index and a bin do not fit in 29 bits together:
+    the very values the four gathers read."""
+    monkeypatch.setattr(core, "_SELECT_MAX_ENTRIES", 1 << 30)
+    rng = np.random.default_rng(n_feat)
+    E = 64
+    feat = rng.integers(0, n_feat, E).astype(np.int32)
+    feat[0] = n_feat - 1
+    bin_ = rng.integers(0, max(n_bins - 1, 1), E).astype(np.int32)
+    bin_[1] = n_bins - 2
+    na_l, can = rng.random(E) < 0.5, rng.random(E) < 0.5
+    idx = rng.integers(0, E, N).astype(np.int32)
+    fn = jax.jit(lambda i, *t: core._split_of_rows(i, *t, n_feat, n_bins))
+    got = fn(*(jnp.asarray(a) for a in (idx, feat, bin_, na_l, can)))
+    for g, t in zip(got, (feat, bin_, na_l, can)):
+        assert np.array_equal(np.asarray(g), t[idx])
+    hlo = fn.lower(*(jnp.asarray(a) for a in (idx, feat, bin_, na_l,
+                                               can))).as_text()
+    assert hlo.count("stablehlo.reduce") == words
+
+
+def _boost_case(mode, K=1):
+    rng = np.random.default_rng(41)
+    n, F, B = 4096, 6, 16
+    binned = rng.integers(0, B, (n, F)).astype(np.uint8)
+    binned[rng.random((n, F)) < 0.05] = B - 1            # NAs
+    y = (rng.integers(0, K, n) if K > 1
+         else rng.random(n) < 0.4).astype(np.float32)
+    tp = core.TreeParams(max_depth=6 if mode != "forest" else 8,
+                         n_bins=B, min_rows=1.0, reg_lambda=1.0,
+                         mtries=3 if mode == "forest" else -1,
+                         hist_impl="segment",
+                         unit_hess=mode == "forest")
+    bp = core.BoostParams(
+        distribution={"single": "bernoulli", "multi": "multinomial",
+                      "forest": "bernoulli"}[mode],
+        learn_rate=1.0 if mode == "forest" else 0.3,
+        sample_rate=0.632 if mode == "forest" else 1.0,
+        drf_mode=mode == "forest")
+    margin = np.zeros((n, K) if K > 1 else n, np.float32)
+    keys = jax.random.split(jax.random.key(7), 3)
+    return (jnp.asarray(binned), jnp.asarray(y), jnp.ones(n, jnp.float32),
+            jnp.asarray(margin), keys, None, tp, bp)
+
+
+@pytest.mark.parametrize("mode", ["single", "multi", "forest"])
+def test_boost_scans_are_bitwise_under_either_form(mesh8, monkeypatch,
+                                                   mode):
+    """`_boost_jit`, `_boost_multi_jit` (K = 7 under the class batch)
+    and `_boost_drf_jit` grow bitwise the same trees and margin with
+    every node table read by a gather and with every one read by a
+    select (the rule is read as the program is traced: the caches are
+    cleared between the two)."""
+    K = 7 if mode == "multi" else 1
+    args = _boost_case(mode, K)
+    if mode == "multi":
+        assert core.multi_grow_vmapped(args[6], 6, K)
+    out = {}
+    for form, n in (("gather", 0), ("select", 1 << 30)):
+        monkeypatch.setattr(core, "_SELECT_MAX_ENTRIES", n)
+        jax.clear_caches()
+        if mode == "single":
+            got = core._boost_jit(*args, mesh8)
+        elif mode == "multi":
+            got = core._boost_multi_jit(*args, K, mesh8)
+        else:
+            got = core._boost_drf_jit(*args, mesh8)
+        out[form] = [_bits(a) for a in jax.tree.leaves(got)]
+    jax.clear_caches()
+    assert len(out["gather"]) == len(out["select"]) >= 8
+    for a, b in zip(out["gather"], out["select"]):
+        assert np.array_equal(a, b)
+    assert out["select"][4].any()           # (margin, split_feat, ...)
+
+
+def test_node_lookups_are_counted_by_form(mesh8, monkeypatch):
+    """`h2o_train_node_lookups_total{form}`: a job's trees, each level's
+    descent and each tree's margin update, by the form the rule gives
+    the table's entry count — at depth 4 with the select up to 4
+    entries, levels of 1, 2 and 4 nodes select, the level of 8 and the
+    31-leaf margin gather."""
+    from h2o_kubernetes_tpu import Frame
+    from h2o_kubernetes_tpu.models import GBM
+    from h2o_kubernetes_tpu.runtime.telemetry import REGISTRY
+
+    assert core.node_lookup_forms(6) == ["select"] * 7
+    monkeypatch.setattr(core, "_SELECT_MAX_ENTRIES", 4)
+    assert core.node_lookup_forms(4) == ["select"] * 3 + ["gather"] * 2
+    jax.clear_caches()
+    rng = np.random.default_rng(0)
+    fr = Frame.from_arrays({"a": rng.normal(size=2000).astype(np.float32),
+                            "b": rng.normal(size=2000).astype(np.float32),
+                            "y": (rng.random(2000) < 0.5).astype(
+                                np.float32)})
+    ctr = REGISTRY.counter("h2o_train_node_lookups_total", label="form")
+    before = {k: ctr.value(k) for k in ("select", "gather")}
+    GBM(ntrees=3, max_depth=4, seed=0).train(y="y", training_frame=fr)
+    jax.clear_caches()
+    assert {k: ctr.value(k) - v for k, v in before.items()} == \
+        {"select": 9, "gather": 6}
