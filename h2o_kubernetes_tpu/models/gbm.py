@@ -40,8 +40,9 @@ from .tree.binning import (BinSpec, apply_bins, apply_bins_jit,
 from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
                         _boost_drf_jit, _boost_jit, _boost_multi_jit,
                         descend_tree, flat_margin, flatten_cover,
-                        flatten_trees, goss_round_keys, level_hist_bytes,
-                        multi_grow_vmapped, node_lookup_forms,
+                        flatten_trees, goss_round_keys, hist_level_forms,
+                        level_hist_bytes, multi_grow_vmapped,
+                        node_lookup_forms,
                         predict_tree, round_keys, set_split_reason)
 from .tree.rank import (RankLayout, grouped, groups_abstract, ndcg_at,
                         rank_layout)
@@ -1196,6 +1197,7 @@ class GBM:
                 _count_class_trees(plan, p.ntrees - start_t)
             if ooc_chunk is None:
                 _count_node_lookups(plan, p.ntrees - start_t)
+                _count_hist_levels(plan, p.ntrees - start_t)
         # which way the metric is read: off the boosting margin, off
         # the sum of leaf values a forest's scan carried (every tree
         # over every row, bitwise what `_margins_of_binned` walks
@@ -1608,6 +1610,26 @@ def _count_node_lookups(plan: BoostPlan, rounds: int) -> None:
         "select (over the table's entries) or gather", label="form")
     forms = node_lookup_forms(plan.tp.max_depth)
     for form in ("select", "gather"):
+        ctr.inc(rounds * plan.K * forms.count(form), label_value=form)
+
+
+def _count_hist_levels(plan: BoostPlan, rounds: int) -> None:
+    """`h2o_train_hist_levels_total{form}`, added up once a job held in
+    HBM: the histogram calls of its trees (rounds x K) that serve a
+    level in several hi blocks, by the form `core.hist_level_forms`
+    gives them as the program is traced: ``compacted`` (over rows
+    ordered by node block) or ``blocked`` (every row tile against
+    every block)."""
+    from ..runtime.telemetry import REGISTRY
+
+    ctr = REGISTRY.counter(
+        "h2o_train_hist_levels_total",
+        "histogram levels past one hi block in the boost scans of the "
+        "jobs trained, by form: compacted (rows ordered by node block) "
+        "or blocked (every row tile against every block)", label="form")
+    forms = hist_level_forms(plan.tp, plan.F,
+                             batched=plan.class_batch == "vmap")
+    for form in ("compacted", "blocked"):
         ctr.inc(rounds * plan.K * forms.count(form), label_value=form)
 
 

@@ -113,8 +113,10 @@ def _gain_term(G, H, p: TreeParams):
 
 # histogram accumulation lives in ops/histogram.py (segment_sum on CPU,
 # the Pallas one-hot-matmul kernel on TPU)
+from ...ops.histogram import block_columns as _block_columns
 from ...ops.histogram import build_histogram as _build_histogram_op
 from ...ops.histogram import expand_unit_hess as _expand_unit_hess
+from ...ops.histogram import node_blocks as _node_blocks
 from ...ops.histogram import resolve_impl as _resolve_impl
 from .rank import groups_specs, rank_grad_hess
 
@@ -464,8 +466,87 @@ def level_candidates(key, d: int, col_mask, mtries: int):
     return feat_ok
 
 
+# What ordering a tree's rows by node block saves and costs, in ns a
+# row on a v5e (PERF.md section 6): a hi block of a blocked level
+# costs `_BLOCK_NS` a row, a column it histograms (`block_columns`) and
+# a channel (the ledger's one-block call at the cap), and the compacted
+# level `_COMPACT_NS` whatever its blocks (`drf-airline.train`'s);
+# the order costs `_ORDER_NS` a row (the sort and the scatter back) and
+# `_ARRAY_NS` a row an array it gathers (`tools/hist_forms.py
+# --compact`, each alone)
+_BLOCK_NS = 1.095
+_COMPACT_NS = 1.386
+_ORDER_NS = 10.0
+_ARRAY_NS = 15.6
+
+
+def _level_blocks(p: TreeParams, d: int) -> tuple:
+    """(n_ht, nodes a block) of level ``d``'s histogram call: the
+    root's one node, else the 2^(d-1) left children (`node_blocks`)."""
+    return _node_blocks(2 ** max(d - 1, 0), p.n_bins)
+
+
+def compact_depth(p: TreeParams, F: int, batched: bool = False):
+    """The depth at whose start one tree orders its rows by node block,
+    or None where it keeps the caller's order — THE rule, for the
+    program as it is traced and for `hist_level_forms`, which counts it.
+
+    A level past one hi block histograms its left children in blocks of
+    whole nodes, so a row's block is its ancestor at depth log2(n_ht),
+    and rows ordered by their node at depth ``s`` are grouped by every
+    shallower ancestor: one order at the deeper of the first blocked
+    level and the deepest level's block depth serves every level from
+    ``s`` on, each block over its own row tiles (`build_histogram`'s
+    ``starts``). Engaged where every level past one hi block holds
+    whole nodes a block, on the Pallas kernel and outside the class
+    batch's `vmap` (an order a class would copy the shared codes K
+    times), and where what the compacted levels spare (n_ht blocks a
+    level for one compacted call, each every row's) costs more than
+    the order and the arrays it gathers (a row gather costs the same
+    at 8 columns of 16-bit codes and at 28 of 8-bit ones)."""
+    if batched or _resolve_impl(p.hist_impl) != "pallas":
+        return None
+    blocked = [d for d in range(1, p.max_depth)
+               if _level_blocks(p, d)[0] > 1]
+    if not blocked or not all(_level_blocks(p, d)[1] for d in blocked):
+        return None
+    s = max(blocked[0], _level_blocks(p, blocked[-1])[0].bit_length() - 1)
+    C = 2 if p.unit_hess else 3
+    saved = sum(_level_blocks(p, d)[0] * _BLOCK_NS - _COMPACT_NS
+                for d in blocked if d >= s) * _block_columns(F, C) * C
+    # the codes, g, w, the node ids and the leaf (and h where it is not 1)
+    return s if saved > _ORDER_NS + _ARRAY_NS * (C + 3) else None
+
+
+def hist_level_forms(p: TreeParams, F: int,
+                     batched: bool = False) -> list[str]:
+    """The form of each histogram call of one tree, the root's first:
+    ``fact`` (one hi block), ``blocked`` (several, every row tile
+    against each) or ``compacted`` (several, over rows ordered by node
+    block: `compact_depth`)."""
+    s = compact_depth(p, F, batched)
+    return ["fact" if _level_blocks(p, d)[0] == 1
+            else "blocked" if s is None or d < s else "compacted"
+            for d in range(p.max_depth)]
+
+
+def _order_rows(s: int, rel, w, rows):
+    """One shard's rows ordered by their node at depth ``s`` (``rel``),
+    the dead ones (``rel`` < 0 or ``w`` 0) after every node, each node's
+    in the caller's order → (order, bounds, ``rows`` ordered):
+    ``order[i]`` is the caller's index of row i, ``bounds`` [2^s + 1]
+    each node's first row (the last: the first row past every live
+    one). A dead row never comes back, so the dead stay last."""
+    key = jnp.where((rel >= 0) & (w > 0), rel, 2 ** s)
+    key, order = lax.sort((key, jnp.arange(rel.shape[0], dtype=jnp.int32)),
+                          num_keys=1, is_stable=True)
+    bounds = jnp.searchsorted(
+        key, jnp.arange(2 ** s + 1, dtype=jnp.int32)).astype(jnp.int32)
+    return order, bounds, [x[order] for x in rows]
+
+
 def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
-                     efb=None):
+                     efb=None, batched: bool = False):
     """Per-shard tree build (runs under shard_map; histograms psum'd).
 
     Returns (Tree, leaf_node): `leaf_node` is each row's final absolute
@@ -482,6 +563,11 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
     `leaves` for the last level (the words ooc.py's host spans use):
     metadata only, the operations' `op_name` in a compiled program and
     in a profile.
+
+    Where `compact_depth` engages (``batched``: under the class batch's
+    `vmap`, where it never does) the rows are ordered by node block
+    once, under `row_order`, and every level from there on reads them
+    in that order; the leaf a row is returned in the caller's order.
     """
     # col_mask is in ORIGINAL feature space (== binned width only when
     # efb is None)
@@ -501,6 +587,8 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
 
     hist_prev = None        # parent histograms for sibling subtraction
     can_prev = None
+    order_at = compact_depth(p, binned.shape[1], batched)
+    order = None
     for d in range(p.max_depth + 1):
         n_nodes = 2 ** d
         off = n_nodes - 1
@@ -535,6 +623,10 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
                     _leaf_value(tot[:, 0], tot[:, 1], p))
                 cover = cover.at[idx].set(tot[:, 2])
             break
+        if d == order_at:
+            with jax.named_scope("row_order"):
+                order, bounds, (binned, g, h, w, rel, abs_node) = \
+                    _order_rows(d, rel, w, (binned, g, h, w, rel, abs_node))
         if d == 0:
             with jax.named_scope("level_hist"):
                 hist = _build_histogram_op(binned, rel, g, h, w, 1,
@@ -555,10 +647,15 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
             with jax.named_scope("level_hist"):
                 left_rel = jnp.where((rel >= 0) & (rel % 2 == 0),
                                      rel // 2, -1)
+                # rows ordered at depth `order_at`: hi block b of this
+                # level is their nodes [b, b+1) · 2^order_at / n_ht
+                starts = None if order is None else \
+                    bounds[::2 ** order_at // _level_blocks(p, d)[0]]
                 hist_l = _build_histogram_op(binned, left_rel, g, h, w,
                                              n_nodes // 2, p.n_bins,
                                              impl=p.hist_impl,
-                                             unit_hess=p.unit_hess)
+                                             unit_hess=p.unit_hess,
+                                             starts=starts)
             with jax.named_scope("hist_psum"):
                 hist_l = lax.psum(hist_l, ROWS)
             if p.unit_hess:
@@ -601,6 +698,10 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
             abs_node = jnp.where(moved, (2 ** (d + 1) - 1) + child,
                                  abs_node)
 
+    if order is not None:
+        with jax.named_scope("row_order"):
+            abs_node = jnp.zeros_like(abs_node).at[order].set(
+                abs_node, unique_indices=True)
     return Tree(split_feat, split_bin, na_left, is_split, value, gain,
                 cover, left_bins), abs_node
 
@@ -1052,12 +1153,6 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
         else:
             bC, gC, hC, wC = binned, g, h, None
 
-        def grow_one(gk, hk, kk):
-            return _grow_tree_shard(bC, gk, hk,
-                                    wC if goss else w_t, col_mask, kk,
-                                    p, efb)
-
-        keys_k = jax.random.split(k_tree, K)
         # vmap multiplies per-level histogram memory by K; past a VMEM/
         # HBM budget grow classes sequentially INSIDE the dispatch
         # (lax.map: 1/K the live histogram footprint, still one compile).
@@ -1065,7 +1160,15 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
         # bundled width under EFB), matching gbm.py's validator, which
         # also means bundling buys back the K-vmapped growth on wide
         # sparse frames
-        if multi_grow_vmapped(p, binned.shape[1], K):
+        batched = multi_grow_vmapped(p, binned.shape[1], K)
+
+        def grow_one(gk, hk, kk):
+            return _grow_tree_shard(bC, gk, hk,
+                                    wC if goss else w_t, col_mask, kk,
+                                    p, efb, batched=batched)
+
+        keys_k = jax.random.split(k_tree, K)
+        if batched:
             trees, leaf = jax.vmap(grow_one)(gC, hC, keys_k)
         else:
             trees, leaf = lax.map(lambda a: grow_one(*a),

@@ -19,7 +19,16 @@ Two implementations:
   right trade — see PAPERS.md GBDT-on-accelerator entries). A level
   with more than `_FACT_MAX_NHI` hi slots is served in blocks of hi
   slots along one grid axis: a row whose slot lies in another block
-  matches nothing there, as a dead row does.
+  matches nothing there, as a dead row does — so a row tile met every
+  block, and at 16 blocks 15/16 of the products were zeros. Where the
+  grower has ordered a tree's rows by node block
+  (`models/tree/core.compact_depth`: a block holds whole nodes, so a
+  row's block is an ancestor of its node), the call takes each block's
+  first row (``starts``) and runs each block over its own row tiles
+  alone (`_hist_compact_kernel`: grid (feature groups, steps), each
+  step's block and tile scalar-prefetched), skipping the dead rows
+  sorted last — still one call a level, named `hist_blocked`, bitwise
+  the blocked call's sums over the same rows.
 
   BOTH one-hots are built rows-on-lanes — `iota[·, T] == x[None, :]`, a
   sublane broadcast of a lane vector, the layout the bin codes arrive
@@ -175,12 +184,47 @@ def _hist_fact_kernel(binned_ref, rel_ref, vals_ref, out_ref, *, n_bins,
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
+    _accumulate(binned_ref, rel_ref, vals_ref, out_ref,
+                lambda: pl.program_id(1), n_bins=n_bins, ht=ht, n_ht=n_ht,
+                n_ch=n_ch, fg=fg, terms=terms)
+
+
+def _hist_compact_kernel(block_ref, tile_ref, binned_ref, rel_ref, vals_ref,
+                         out_ref, *, n_bins, ht, n_ht, n_ch, fg, terms):
+    """`_hist_fact_kernel` over rows ordered by hi block: grid step s
+    serves hi block ``block_ref[s] >> 1`` over row tile ``tile_ref[s]``
+    (scalar-prefetched, `_compact_steps`), so a block meets its own row
+    tiles and no others. The out block follows the step's hi block and
+    is zeroed at that block's first step; a step whose low bit is 0 (a
+    block without rows, or padding past the last block) adds nothing.
+    The same arithmetic a (row tile, block) as the blocked call: a tile
+    it skips holds no row of the block, and would have added zeros."""
+    # grid (feature_groups, steps)
+    s = pl.program_id(1)
+    word = block_ref[s]
+    first = (s == 0) | (block_ref[jnp.maximum(s - 1, 0)] >> 1 != word >> 1)
+
+    @pl.when(first)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    @pl.when(word & 1 == 1)
+    def _():
+        _accumulate(binned_ref, rel_ref, vals_ref, out_ref,
+                    lambda: word >> 1, n_bins=n_bins, ht=ht, n_ht=n_ht,
+                    n_ch=n_ch, fg=fg, terms=terms)
+
+
+def _accumulate(binned_ref, rel_ref, vals_ref, out_ref, block, *, n_bins,
+                ht, n_ht, n_ch, fg, terms):
+    """One row tile's products added into the out block, for the hi
+    block ``block()`` (read only where ``n_ht > 1``)."""
     rel = rel_ref[:]                                 # [T]
     rel_base = rel * n_bins
     if n_ht > 1:
-        # this step's hi block starts at slot program_id·ht: shift the
+        # this step's hi block starts at slot block·ht: shift the
         # cell index so the block's slots read 0..ht-1 below
-        rel_base = rel_base - pl.program_id(1) * (ht * 128)
+        rel_base = rel_base - block() * (ht * 128)
     vals_t = vals_ref[:].T                           # [n_ch, T]
     # f32-precision via `terms` bf16 mantissa terms, split on the TINY
     # [n_ch, T] values and masked by the 0/1 one-hot IN bf16 —
@@ -309,6 +353,51 @@ def _hi_blocks(n_cells: int) -> tuple:
     return n_ht, -(-n_hi // n_ht)
 
 
+def node_blocks(n_nodes: int, n_bins: int) -> tuple:
+    """(n_ht, nodes a block): the hi blocks a level of ``n_nodes`` nodes
+    of ``n_bins`` bins takes, and the whole nodes each holds — node i
+    in block i // nodes a block. 0 nodes where a block's slots hold no
+    whole count of nodes: no order of the rows then groups them by
+    block."""
+    n_ht, ht = _hi_blocks(n_nodes * n_bins)
+    per = ht * 128 // n_bins
+    whole = per * n_bins == ht * 128 and per * n_ht == n_nodes
+    return n_ht, per if whole else 0
+
+
+def block_columns(F: int, C: int) -> int:
+    """The columns a hi block at the cap histograms for a frame of
+    ``F`` columns and ``C`` channels: ``F`` padded to whole feature
+    groups (`_feature_groups`)."""
+    return _feature_groups(F, C, _FACT_MAX_NHI)[1]
+
+
+def _compact_steps(starts, rt_size: int, n_tiles: int):
+    """The compacted call's two step tables from ``starts`` ([n_ht + 1]
+    rows: hi block b's rows are [starts[b], starts[b+1]), blocks in
+    order, every row past the last dead): (block word, row tile) a grid
+    step, ``n_tiles + n_ht - 1`` steps. A block takes the tiles its rows
+    lie in (a tile two blocks share is visited by each), a block
+    without rows one step that adds nothing; the steps past the last
+    block repeat its last tile and add nothing. The word is
+    ``block << 1 | adds``."""
+    n_ht = starts.shape[0] - 1
+    lo, hi = starts[:-1], starts[1:]
+    first = lo // rt_size
+    last = jnp.maximum(hi - 1, lo) // rt_size
+    count = last - first + 1                          # >= 1 a block
+    ends = jnp.cumsum(count)
+    step = jnp.arange(n_tiles + n_ht - 1, dtype=jnp.int32)
+    block = jnp.minimum(jnp.searchsorted(ends, step, side="right"),
+                        n_ht - 1).astype(jnp.int32)
+    live = step < ends[-1]
+    tile = jnp.where(live, first[block] + step - (ends - count)[block],
+                     last[-1])
+    adds = live & (hi > lo)[block]
+    return (block << 1 | adds.astype(jnp.int32),
+            jnp.clip(tile, 0, n_tiles - 1).astype(jnp.int32))
+
+
 def _feature_groups(F: int, C: int, ht: int) -> tuple[int, int]:
     """(fg, F_pad): the features one grid step of the kernel holds and
     the width the frame is padded to, a multiple of it. Each step keeps
@@ -338,12 +427,17 @@ def _class_blocks(K: int, ht: int) -> tuple:
     return n_cb, -(-K // n_cb)
 
 
-def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int):
+def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int,
+                 starts=None):
     """[r, F] codes + [r] rel + [r, C] vals -> [n_nodes, F, B, C]; or,
     for K classes over the one stored ``binned`` (the batching rule of
     `_hist_vmappable`), [K, r] rel + [K, r, C] vals ->
     [K, n_nodes, F, B, C] from the one call (``n_bins`` a multiple of
-    128; a class a call otherwise)."""
+    128; a class a call otherwise). ``starts`` ([n_ht + 1], unbatched,
+    a level of several hi blocks): the rows are ordered by hi block,
+    block b's in [starts[b], starts[b+1]) and every row past the last
+    dead, and each block is served over its own row tiles alone
+    (`_hist_compact_kernel`)."""
     batched = rel.ndim == 2
     if batched and n_bins % 128:
         # a node takes part of a 128-lane row: lo = seg mod 128 follows
@@ -435,42 +529,82 @@ def _hist_pallas(binned, rel, vals, n_nodes: int, n_bins: int):
             n_cb * kb, F_pad, C, n_ht * ht * 128)[:K, :F, :, :nB]
         return out.reshape(K, F, C, n_nodes, n_bins).transpose(
             0, 3, 1, 4, 2)
-    out = pl.pallas_call(
-        functools.partial(_hist_fact_kernel, **params),
-        # one (fg, C·ht, 128) block per (feature group, hi block),
-        # contiguous
-        out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),
-                                       jnp.float32, vma=vma),
-        grid=(n_fg, n_ht, 1, rbb),
-        in_specs=[
-            binned_spec,
-            # (the third grid axis is 1: `k·rb` is 0, and stays in the
-            # index maps so that the accepted cells' kernel is, to the
-            # letter, the one their ledger lines were measured with)
-            pl.BlockSpec((rt_size,),
-                         lambda g, b, k, rt, rb=rbb: (k * rb + rt,)),
-            pl.BlockSpec((rt_size, C),
-                         lambda g, b, k, rt, rb=rbb: (k * rb + rt, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, fg, C * ht, 128),
-                               lambda g, b, k, rt: (g, b, 0, 0, 0)),
-        # feature groups and hi blocks write DISTINCT out blocks
-        # (parallel — Mosaic may pipeline them); row blocks ACCUMULATE
-        # into the same block (arbitrary = sequential)
-        compiler_params=_dimsem("parallel", "parallel", "arbitrary",
-                                "arbitrary"),
-        interpret=_interpret(),
-        name=name, metadata={"kernel": name},
-    )(binned4, rel32, vals)
+    if starts is not None and n_ht > 1:
+        out = _hist_compact_call(binned4, rel32, vals, starts, params,
+                                 rt_size, n_fg, name, vma)
+    else:
+        out = pl.pallas_call(
+            functools.partial(_hist_fact_kernel, **params),
+            # one (fg, C·ht, 128) block per (feature group, hi block),
+            # contiguous
+            out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),
+                                           jnp.float32, vma=vma),
+            grid=(n_fg, n_ht, 1, rbb),
+            in_specs=[
+                binned_spec,
+                # (the third grid axis is 1: `k·rb` is 0, and stays in the
+                # index maps so that the accepted cells' kernel is, to the
+                # letter, the one their ledger lines were measured with)
+                pl.BlockSpec((rt_size,),
+                             lambda g, b, k, rt, rb=rbb: (k * rb + rt,)),
+                pl.BlockSpec((rt_size, C),
+                             lambda g, b, k, rt, rb=rbb: (k * rb + rt, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, fg, C * ht, 128),
+                                   lambda g, b, k, rt: (g, b, 0, 0, 0)),
+            # feature groups and hi blocks write DISTINCT out blocks
+            # (parallel — Mosaic may pipeline them); row blocks ACCUMULATE
+            # into the same block (arbitrary = sequential)
+            compiler_params=_dimsem("parallel", "parallel", "arbitrary",
+                                    "arbitrary"),
+            interpret=_interpret(),
+            name=name, metadata={"kernel": name},
+        )(binned4, rel32, vals)
     # [n_fg, n_ht, fg, C·ht, 128] -> [F, C, n_ht·ht·128] -> [n, F, B, C]
     out = out.reshape(n_fg, n_ht, fg, C, ht * 128).transpose(
         0, 2, 3, 1, 4).reshape(F_pad, C, n_ht * ht * 128)[:F, :, :nB]
     return out.reshape(F, C, n_nodes, n_bins).transpose(2, 0, 3, 1)
 
 
-def _hist_call(binned, rel, vals, n_nodes: int, n_bins: int, impl: str):
-    fn = _hist_pallas if impl == "pallas" else _hist_segment
-    return fn(binned, rel, vals, n_nodes, n_bins)
+def _hist_compact_call(binned4, rel32, vals, starts, params, rt_size: int,
+                       n_fg: int, name: str, vma):
+    """The unbatched call over rows ordered by hi block (``starts``:
+    `_hist_pallas`): grid (feature groups, steps), each step's hi block
+    and row tile scalar-prefetched from `_compact_steps`, the out block
+    the step's hi block's. Its out is the blocked call's."""
+    fg, C, ht, n_ht = (params[k] for k in ("fg", "n_ch", "ht", "n_ht"))
+    block, tile = _compact_steps(starts.astype(jnp.int32), rt_size,
+                                 rel32.shape[0] // rt_size)
+    return pl.pallas_call(
+        functools.partial(_hist_compact_kernel, **params),
+        out_shape=jax.ShapeDtypeStruct((n_fg, n_ht, fg, C * ht, 128),
+                                       jnp.float32, vma=vma),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_fg, block.shape[0]),
+            in_specs=[
+                pl.BlockSpec((fg, 1, 1, rt_size),
+                             lambda g, s, b, t: (g, t[s], 0, 0)),
+                pl.BlockSpec((rt_size,), lambda g, s, b, t: (t[s],)),
+                pl.BlockSpec((rt_size, C), lambda g, s, b, t: (t[s], 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, fg, C * ht, 128),
+                lambda g, s, b, t: (g, b[s] >> 1, 0, 0, 0))),
+        # a block's steps follow each other and accumulate into its out
+        # block (arbitrary = sequential)
+        compiler_params=_dimsem("parallel", "arbitrary"),
+        interpret=_interpret(),
+        name=name, metadata={"kernel": name},
+    )(block, tile, binned4, rel32, vals)
+
+
+def _hist_call(binned, rel, vals, n_nodes: int, n_bins: int, impl: str,
+               starts=None):
+    if impl == "pallas":
+        return _hist_pallas(binned, rel, vals, n_nodes, n_bins, starts)
+    # a sum by segment is the same in any order of the rows
+    return _hist_segment(binned, rel, vals, n_nodes, n_bins)
 
 
 def _hist_vmappable(binned, rel, vals, n_nodes: int, n_bins: int,
@@ -537,11 +671,17 @@ def resolve_impl(impl: str) -> str:
 
 
 def build_histogram(binned, rel, g, h, w, n_nodes: int, n_bins: int,
-                    impl: str = "auto", unit_hess: bool = False):
+                    impl: str = "auto", unit_hess: bool = False,
+                    starts=None):
     """Per-shard histogram [n_nodes, F, B, 3] of (Σgw, Σhw, Σw).
 
     binned: [r, F] uint8 bin codes; rel: [r] int32 node id (-1 dead);
     w: [r] row weight (0 for padding/unsampled rows).
+
+    ``starts`` ([n_ht + 1] int32; `node_blocks` gives n_ht): the caller
+    has ordered the rows by hi block — block b's live rows in
+    [starts[b], starts[b+1]), none past the last — and the kernel runs
+    each block over its own row tiles alone. Never under `vmap`.
 
     ``unit_hess``: the caller asserts h ≡ 1 (gaussian/laplace/quantile/
     huber losses and DRF), so Σhw == Σw and the kernels accumulate TWO
@@ -561,6 +701,8 @@ def build_histogram(binned, rel, g, h, w, n_nodes: int, n_bins: int,
     else:
         vals = jnp.where(live[:, None],
                          jnp.stack([g * w, h * w, w], axis=1), 0.0)
+    if starts is not None:
+        return _hist_call(binned, rel, vals, n_nodes, n_bins, impl, starts)
     return _hist_vmappable(binned, rel, vals, n_nodes, n_bins, impl)
 
 

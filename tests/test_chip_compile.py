@@ -139,21 +139,63 @@ def test_shallow_levels_compile_at_the_other_cells_shapes(
     assert _kernels(c) == 1 and _kernel_names(c) == {"hist_fact"}
 
 
-@pytest.mark.parametrize("n_nodes,blocks", [(128, 2), (256, 4), (512, 8),
-                                            (1024, 16)])
+@pytest.mark.parametrize("n_nodes,blocks,ordered", [
+    pytest.param(128, 2, False, id="128-2"),
+    pytest.param(256, 4, False, id="256-4"),
+    pytest.param(512, 8, False, id="512-8"),
+    pytest.param(1024, 16, False, id="1024-16"),
+    # the same levels over rows ordered by node block: each block over
+    # its own row tiles, the steps' blocks and tiles scalar-prefetched
+    pytest.param(128, 2, True, id="128-2-compacted"),
+    pytest.param(256, 4, True, id="256-4-compacted"),
+    pytest.param(512, 8, True, id="512-8-compacted"),
+    pytest.param(1024, 16, True, id="1024-16-compacted")])
 def test_forest_levels_of_many_hi_blocks_compile(one_chip, n_nodes,
-                                                 blocks):
+                                                 blocks, ordered):
     """`drf-airline.train`'s deep levels: a forest (2 channels) over the
     airline's 8 columns of 16-bit codes in a 512-bin matrix, whose
-    levels 8-11 take 2, 4, 8 and 16 blocks of hi slots in one call."""
+    levels 8-11 take 2, 4, 8 and 16 blocks of hi slots in ONE call,
+    named `hist_blocked` whichever form serves it."""
     assert -(-n_nodes * 512 // 128) // histogram._FACT_MAX_NHI == blocks
-    fn = jax.jit(lambda b, r, g, h, w: histogram.build_histogram(
-        b, r, g, h, w, n_nodes, 512, "pallas", unit_hess=True))
+    fn = jax.jit(lambda b, r, g, h, w, s: histogram.build_histogram(
+        b, r, g, h, w, n_nodes, 512, "pallas", unit_hess=True, starts=s))
     f32 = _s((ROWS_N,), jnp.float32, one_chip)
     c = fn.lower(_s((ROWS_N, 8), jnp.uint16, one_chip),
-                 _s((ROWS_N,), jnp.int32, one_chip), f32, f32,
-                 f32).compile()
+                 _s((ROWS_N,), jnp.int32, one_chip), f32, f32, f32,
+                 _s((blocks + 1,), jnp.int32, one_chip) if ordered
+                 else None).compile()
     assert _kernels(c) == 1 and _kernel_names(c) == {"hist_blocked"}
+
+
+def test_forest_scan_orders_its_rows_once(topo, monkeypatch):
+    """`_boost_drf_jit` at `drf-airline.train`'s widths (8 columns of
+    16-bit codes, 512 bins, two channels, set features), depth 10: its
+    levels 8 and 9 pass one hi block, so where the rule engages (forced
+    here, whatever the measured costs say of two levels) each tree
+    orders its rows by node block once — one stable sort of the rows'
+    node keys and one scatter of the leaves back, under `row_order` —
+    and both levels are `hist_blocked` calls over the ordered rows, one
+    a level."""
+    monkeypatch.setattr(core, "_ORDER_NS", 0.0)
+    monkeypatch.setattr(core, "_ARRAY_NS", 0.0)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
+    a = _boost_args(mesh, ROWS_N, ntrees=1)
+    rs = NamedSharding(mesh, P(ROWS))
+    tp = a[6]._replace(max_depth=10, n_bins=512, min_rows=1.0, mtries=2,
+                       unit_hess=True, set_feats=(True,) * 6 + (False,) * 2)
+    bp = a[7]._replace(sample_rate=0.632, drf_mode=True, learn_rate=1.0)
+    assert core.hist_level_forms(tp, 8) == ["fact"] * 8 + \
+        ["compacted"] * 2
+    c = core._boost_drf_jit.lower(_s((ROWS_N, 8), jnp.uint16, rs),
+                                  *a[1:6], tp, bp, mesh).compile()
+    txt = c.as_text()
+    assert _kernels(c) == 10
+    assert txt.count('"kernel":"hist_blocked"') == 2
+    ordered = [ln for ln in txt.split("\n")
+               if "/row_order/" in ln and re.search(
+                   rf"= \(?s32\[{ROWS_N}\].* (sort|scatter)\(", ln)]
+    assert sorted(re.search(r" (sort|scatter)\(", ln)[1]
+                  for ln in ordered) == ["scatter", "sort"], ordered
 
 
 @pytest.mark.parametrize("K,F,n_nodes,bins,unit_hess,name", [

@@ -301,3 +301,143 @@ def test_sampled_quantile_binning_parity(mesh8, monkeypatch):
         assert abs(auc_s - auc_exact) < 0.02, (auc_s, auc_exact)
     finally:
         B._device_quantiles.clear_cache()
+
+
+@pytest.fixture
+def ordered_by_node_block(monkeypatch):
+    """The kernel on the CPU (interpret mode) with hi blocks of 2 slots,
+    so that a depth-6 tree of 64 bins histograms levels 4 and 5 in 2
+    and 4 blocks of 4 nodes; yields the switch that makes the rule's
+    costs nothing (``"compacted"``) or prohibitive (``"blocked"``).
+    Every compiled program is dropped at each switch: the rule is read
+    as the program is traced."""
+    import jax
+
+    import h2o_kubernetes_tpu as h2o
+    from h2o_kubernetes_tpu.models.tree import core
+    from h2o_kubernetes_tpu.ops import histogram as H
+
+    prev = h2o.get_config("hist_impl")
+    h2o.set_config("hist_impl", "pallas")
+    monkeypatch.setattr(H, "_FACT_MAX_NHI", 2)
+    monkeypatch.setattr(core, "_ARRAY_NS", 0.0)
+
+    def use(form):
+        monkeypatch.setattr(core, "_ORDER_NS",
+                            0.0 if form == "compacted" else float("inf"))
+        jax.clear_caches()
+
+    try:
+        yield use
+    finally:
+        h2o.set_config("hist_impl", prev)
+        jax.clear_caches()
+
+
+def _hist_levels():
+    from h2o_kubernetes_tpu.runtime.telemetry import REGISTRY
+
+    ctr = REGISTRY.counter("h2o_train_hist_levels_total", label="form")
+    return {k: ctr.value(k) for k in ("compacted", "blocked")}
+
+
+def test_gbm_over_rows_ordered_by_node_block_agrees(mesh8,
+                                                    ordered_by_node_block):
+    """A boosted job whose trees order their rows by node block grows
+    the trees it grows in the caller's order — the same splits and
+    covers, leaf values and gains to float32's reordering of the
+    gradient sums — and counts its levels past one hi block by form
+    (`h2o_train_hist_levels_total`, which `compact_level_share`
+    reads): 3 trees x 2 levels a job, compacted or blocked."""
+    import importlib.util
+    import os
+
+    from h2o_kubernetes_tpu.models.tree import core
+
+    fr, _, _ = _binary_data(n=2400, seed=4)
+    models, counted = {}, {}
+    for form in ("blocked", "compacted"):
+        ordered_by_node_block(form)
+        before = _hist_levels()
+        models[form] = GBM(ntrees=3, max_depth=6, nbins=64, seed=2).train(
+            y="y", training_frame=fr)
+        counted[form] = {k: v - before[k]
+                         for k, v in _hist_levels().items()}
+        tp = core.TreeParams(max_depth=6, n_bins=64, hist_impl="pallas")
+        assert core.hist_level_forms(tp, 3) == ["fact"] * 4 + [form] * 2
+    assert counted == {"blocked": {"compacted": 0, "blocked": 6},
+                       "compacted": {"compacted": 6, "blocked": 0}}
+    t0, t1 = models["blocked"].trees, models["compacted"].trees
+    assert int(np.asarray(t0.is_split).sum()) > 20
+    for name in ("split_feat", "split_bin", "na_left", "is_split",
+                 "cover"):
+        np.testing.assert_array_equal(np.asarray(getattr(t1, name)),
+                                      np.asarray(getattr(t0, name)))
+    for name in ("value", "gain"):
+        np.testing.assert_allclose(np.asarray(getattr(t1, name)),
+                                   np.asarray(getattr(t0, name)),
+                                   rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(models["compacted"].predict_raw(fr),
+                               models["blocked"].predict_raw(fr),
+                               rtol=1e-5, atol=1e-6)
+    # the benchmark's reader of the counter
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "metrics",
+        "compact_level_share.py")
+    spec = importlib.util.spec_from_file_location("compact_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    now = _hist_levels()
+    assert reader.read({}) == pytest.approx(
+        100.0 * now["compacted"] / (now["compacted"] + now["blocked"]))
+
+
+def test_class_batch_and_ooc_keep_the_rows_order(mesh8, monkeypatch,
+                                                 ordered_by_node_block):
+    """Where the rule would engage, two paths keep the caller's row
+    order and the blocked call: the class batch's `vmap` (an order a
+    class would copy the shared codes K times: its program is the one
+    it is with the rule off) and the out-of-core trainer, whose levels
+    stream chunks (no call of the kernel takes ``starts``, and such a
+    job counts no levels)."""
+    import jax
+    import jax.numpy as jnp
+
+    from h2o_kubernetes_tpu.models.tree import core
+    from h2o_kubernetes_tpu.ops import histogram as H
+
+    tp = core.TreeParams(max_depth=6, n_bins=64, hist_impl="pallas",
+                         min_rows=1.0)
+    bp = core.BoostParams(distribution="multinomial", learn_rate=0.1)
+    assert core.multi_grow_vmapped(tp, 3, 3)
+    rows = 8 * 64
+    args = (jnp.zeros((rows, 3), jnp.uint8), jnp.zeros(rows),
+            jnp.ones(rows), jnp.zeros((rows, 3)),
+            core.round_keys(jax.random.key(0), 1), None)
+    programs = {}
+    for form in ("blocked", "compacted"):
+        ordered_by_node_block(form)
+        assert "compacted" not in core.hist_level_forms(tp, 3,
+                                                        batched=True)
+        programs[form] = str(jax.make_jaxpr(
+            core._boost_multi_jit, static_argnums=(6, 7, 8, 9))(
+            *args, tp, bp, 3, mesh8))
+    assert programs["compacted"] == programs["blocked"]
+    assert "row_order" not in programs["compacted"]
+
+    starts = []
+    real = H._hist_pallas
+
+    def spy(*a, **kw):
+        starts.append(a[5] if len(a) > 5 else kw.get("starts"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(H, "_hist_pallas", spy)
+    monkeypatch.setenv("H2O_TPU_OOC", "1")
+    monkeypatch.setenv("H2O_TPU_OOC_CHUNK_ROWS", "512")
+    fr, _, _ = _binary_data(n=1200, seed=5)
+    before = _hist_levels()
+    GBM(ntrees=1, max_depth=6, nbins=64, seed=2).train(
+        y="y", training_frame=fr)
+    assert starts and all(s is None for s in starts)
+    assert _hist_levels() == before

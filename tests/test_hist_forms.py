@@ -64,6 +64,46 @@ def test_class_forms_match_segment_and_the_shipped_rule(case):
             assert lines[form]["bitwise_shipped"], lines[form]
 
 
+# levels past one hi block over rows ordered by block:
+# (rows, F, C, n_bins, dtype), nodes
+_COMPACT_CASES = {
+    "forest_512_bins_4_blocks": ((2500, 3, 2, 512, "uint16"), 256),
+    "airline_512_bins_2_blocks": ((2100, 2, 3, 512, "uint16"), 128),
+    "forest_64_bins_2_blocks": ((3000, 4, 2, 64, "uint8"), 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COMPACT_CASES))
+def test_compact_forms_match_segment_and_each_other(case):
+    shape, n_nodes = _COMPACT_CASES[case]
+    lines = {ln["form"]: ln for ln in hist_forms.measure(
+        case, shape, n_nodes, tuple(hist_forms.COMPACT_FORMS), calls=1,
+        seed=42, compact=True)}
+    assert set(lines) == {"compacted", "blocked"}
+    for ln in lines.values():
+        assert "error" not in ln, ln
+        assert ln["rel_err_segment"] < 1e-5, ln
+    # a tile a block skips holds none of its rows: the same sums
+    assert lines["blocked"]["bitwise_shipped"], lines["blocked"]
+
+
+@pytest.mark.parametrize("shape", [(5000, 8, 3, "uint16", 4),
+                                   (4000, 28, 2, "uint8", 6)])
+def test_order_pieces_run_and_agree(shape):
+    """The ordering's pieces at the two code widths: the packed gather
+    moves the same rows as the gathers apart, and a sort puts the leaf
+    back where the scatter does."""
+    lines = {ln["form"]: ln for ln in hist_forms.measure_order(
+        "small", shape, calls=1, seed=3)}
+    assert set(lines) == {"order", "sort", "gather", "packed", "columns",
+                          "carried", "back", "back_sort"}
+    for ln in lines.values():
+        assert "error" not in ln and ln["ns_a_row"] > 0, ln
+    for form in ("packed", "columns", "carried"):
+        assert lines[form]["bitwise_gather"], lines[form]
+    assert lines["back_sort"]["bitwise_back"]
+
+
 def test_custom_shape_states_rows_columns_and_optionally_bins_channels():
     assert hist_forms._custom_shape("581632x54", 7)[:5] == (
         581632, 54, 3, 256, "uint8")
@@ -91,6 +131,9 @@ def test_shipped_form_is_the_packages_kernel():
         hist_forms.H._hist_pallas
     assert set(hist_forms.SHAPES) == {
         "higgs256", "forest64", "airline512", "mslr136"}
+    assert hist_forms.COMPACT_FORMS["blocked"] is hist_forms.H._hist_pallas
+    assert set(hist_forms.COMPACT_SHAPES) == set(hist_forms.ORDER_SHAPES) \
+        == {"forest512", "airline512", "forest64"}
 
 
 def test_refuses_to_run_without_a_tpu():

@@ -336,6 +336,180 @@ def test_class_batch_under_the_mesh_matches_one_shard(mesh8):
                                rtol=1e-5, atol=1e-5)
 
 
+def _ordered_case(rows, F, n_nodes, n_bins, seed, dead=0.368,
+                  empty_block=None, dead_inside=0.0):
+    """A level's rows ordered by hi block as a tree orders them: the
+    live rows (``rel`` >= 0, ``w`` > 0) by node, ``dead`` of them last;
+    ``dead_inside`` of the ordered live rows then lose their weight or
+    their node where they stand; ``empty_block``: no row in that hi
+    block. Returns the level's operands and the blocks' first rows."""
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    n_ht, per = H.node_blocks(n_nodes, n_bins)
+    rng = np.random.default_rng(seed)
+    binned = rng.integers(0, n_bins, size=(rows, F)).astype(
+        np.uint16 if n_bins > 256 else np.uint8)
+    rel = rng.integers(0, n_nodes, size=rows).astype(np.int32)
+    if empty_block is not None:
+        rel = np.where(rel // per == empty_block,
+                       (rel + per) % n_nodes, rel).astype(np.int32)
+    live = rng.random(rows) >= dead
+    rel = np.sort(np.where(live, rel, n_nodes))
+    rel[rel == n_nodes] = -1
+    w = (rel >= 0).astype(np.float32)
+    g = rng.normal(size=rows).astype(np.float32)
+    h = rng.random(rows).astype(np.float32)
+    starts = np.searchsorted(np.where(rel >= 0, rel // per, n_ht),
+                             np.arange(n_ht + 1)).astype(np.int32)
+    inside = (rng.random(rows) < dead_inside) & (rel >= 0)
+    w[inside & (rng.random(rows) < 0.5)] = 0.0
+    rel[inside & (w > 0)] = -1
+    g[rel < 0] = np.nan
+    return [jnp.asarray(a) for a in (binned, rel, g, h, w)], \
+        jnp.asarray(starts)
+
+
+# a level past one hi block over rows ordered by block: (rows, F, nodes,
+# bins, unit_hess, what the order holds)
+_COMPACT = {
+    "2_blocks_512_bins": (3000, 3, 128, 512, True, {}),
+    "4_blocks_512_bins": (2500, 2, 256, 512, False, {}),
+    "16_blocks_512_bins": (5000, 2, 1024, 512, True, {}),
+    "2_blocks_64_bins": (3000, 3, 1024, 64, True, {}),
+    "4_blocks_64_bins": (2100, 2, 2048, 64, False, {}),
+    "16_blocks_64_bins": (4500, 2, 8192, 64, True, {}),
+    "an_empty_block": (3000, 2, 512, 512, True, dict(empty_block=2)),
+    "the_first_block_empty": (2500, 2, 256, 512, True,
+                              dict(empty_block=0)),
+    "tiles_of_dead_rows_only": (6000, 2, 256, 512, True,
+                                dict(dead=0.7)),
+    "dead_rows_between_live_ones": (3000, 3, 256, 512, False,
+                                    dict(dead_inside=0.3)),
+    "every_row_dead": (2000, 2, 128, 512, True, dict(dead=1.0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COMPACT))
+def test_compacted_call_matches_segment_and_the_blocked_call(case):
+    """The level's rows ordered by hi block as the grower orders them,
+    served each block over its own row tiles (`build_histogram`'s
+    ``starts``): to 1e-5 of `segment` and BITWISE the blocked call over
+    the same rows — a tile a block skips holds none of its rows. At 2,
+    4 and 16 blocks of 512 and of 64 bins (a node of part of a 128-lane
+    row), with an empty block, tiles of dead rows alone, and rows dead
+    or of weight 0 between the live ones."""
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    rows, F, n_nodes, n_bins, unit, kw = _COMPACT[case]
+    (binned, rel, g, h, w), starts = _ordered_case(
+        rows, F, n_nodes, n_bins, seed=rows + n_nodes, **kw)
+    if unit:
+        h = jnp.ones_like(w)
+    assert starts.shape == (H.node_blocks(n_nodes, n_bins)[0] + 1,)
+    if "empty_block" in kw:
+        b = kw["empty_block"]
+        assert starts[b] == starts[b + 1]
+    args = (binned, rel, g, h, w, n_nodes, n_bins)
+    want = build_histogram(*args, impl="segment", unit_hess=unit)
+    blocked = build_histogram(*args, impl="pallas", unit_hess=unit)
+    got = build_histogram(*args, impl="pallas", unit_hess=unit,
+                          starts=starts)
+    assert got.shape == (n_nodes, F, n_bins, 2 if unit else 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(blocked))
+    # the segment sum takes the same order as any other
+    np.testing.assert_array_equal(
+        np.asarray(build_histogram(*args, impl="segment", unit_hess=unit,
+                                   starts=starts)), np.asarray(want))
+
+
+@pytest.mark.parametrize("starts,T,tiles,want", [
+    # four blocks over 5 tiles of 4 rows: block 1 shares tile 1 with
+    # block 0, block 2 is empty, rows from 15 on are dead
+    ((0, 6, 9, 9, 15), 4, 5,
+     [(0, 0, 1), (0, 1, 1), (1, 1, 1), (1, 2, 1), (2, 2, 0), (3, 2, 1),
+      (3, 3, 1), (3, 3, 0)]),
+    # every row dead: each block one step that adds nothing
+    ((0, 0, 0), 4, 3, [(0, 0, 0), (1, 0, 0), (1, 0, 0), (1, 0, 0)]),
+    # one block over every tile, the second past the last row
+    ((0, 12, 12), 4, 3, [(0, 0, 1), (0, 1, 1), (0, 2, 1), (1, 2, 0)]),
+])
+def test_compact_steps_visit_each_block_over_its_own_tiles(starts, T,
+                                                           tiles, want):
+    """(hi block, row tile, adds) of each grid step: blocks in order,
+    a tile two blocks share visited by both, an empty block one step
+    that adds nothing, padding that repeats the last step's tile."""
+    import h2o_kubernetes_tpu.ops.histogram as H
+
+    block, tile = H._compact_steps(jnp.asarray(starts, jnp.int32), T,
+                                   tiles)
+    assert block.shape == tile.shape == (tiles + len(starts) - 2,)
+    assert [(int(b) >> 1, int(t), int(b) & 1)
+            for b, t in zip(block, tile)] == want
+
+
+def test_compacted_level_under_the_mesh_matches_one_shard(mesh8):
+    """Row-sharded: each shard orders its OWN rows by node block
+    (`core._order_rows`) and serves its blocks over its own tiles; the
+    psum of the shards' levels equals `segment` over all rows."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from h2o_kubernetes_tpu.models.tree import core
+    from h2o_kubernetes_tpu.runtime.mesh import ROWS
+
+    n_nodes, n_bins, depth = 256, 512, 6
+    binned, rel, g, h, w = _random_case(8 * 1100, 3, n_nodes, n_bins,
+                                        seed=42)
+    binned = binned.astype(jnp.uint16)
+    n_ht = 4                                # blocks of 64 nodes
+    abs_node = rel + 7
+
+    def shard(b, r, gg, hh, ww, a):
+        # order by the node's ancestor at depth 6 (the nodes here are a
+        # depth-8 level's), as the grower orders by its depth-s node
+        order, bounds, (b, r, gg, hh, ww, a) = core._order_rows(
+            depth, jnp.where(r >= 0, r >> 2, -1), ww,
+            (b, r, gg, hh, ww, a))
+        starts = bounds[::2 ** depth // n_ht]
+        hist = build_histogram(b, r, gg, hh, ww, n_nodes, n_bins,
+                               "pallas", starts=starts)
+        back = jnp.zeros_like(a).at[order].set(a, unique_indices=True)
+        return jax.lax.psum(hist, ROWS), back
+
+    got, back = jax.jit(jax.shard_map(
+        shard, mesh=mesh8, in_specs=(P(ROWS),) * 6,
+        out_specs=(P(), P(ROWS)), check_vma=False))(
+        binned, rel, g, h, w, abs_node)
+    want = build_histogram(binned, rel, g, h, w, n_nodes, n_bins,
+                           "segment")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(abs_node))
+
+
+def test_compacted_level_lowers_for_tpu_as_one_hist_blocked_call():
+    """AOT-lower a level over ordered rows for a TPU target: ONE
+    `pallas_call`, named `hist_blocked` as the blocked call is — the
+    name the benchmark's readers count a level of a tree by."""
+    import jax
+
+    (binned, rel, g, h, w), starts = _ordered_case(2048, 3, 256, 512,
+                                                   seed=9)
+
+    def level(r, s):
+        return build_histogram(binned, r, g, h, w, 256, 512, "pallas",
+                               unit_hess=True, starts=s)
+
+    assert _kernel_names_for_tpu(level, rel, starts) == ["hist_blocked"]
+    assert _pallas_calls(level, rel, starts) == 1
+    (call,) = [e for e in jax.make_jaxpr(level)(rel, starts).eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (1, 2 + 4 - 1)
+    assert call.params["grid_mapping"].num_index_operands == 2
+
+
 def _kernel_names_for_tpu(fn, *args):
     import re
     import unittest.mock as mock

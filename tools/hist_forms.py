@@ -52,6 +52,30 @@ each compared with B (bitwise, or the largest difference) and with
 the seconds after which a (form, shape) is abandoned (its line says
 `timeout`; the calls already queued on the chip still drain).
 
+With `--compact` the levels past one hi block, their rows
+ordered by hi block (dead rows, 36.8% as a bag leaves them, last):
+
+  blocked    the shipped call where a tree keeps its rows' order: every
+             row tile against every hi block
+  compacted  the SHIPPED call where a tree orders its rows by node
+             block (`build_histogram`'s ``starts``): each block over its
+             own row tiles, bitwise `blocked`'s sums
+
+and what ordering one tree's rows costs, at the cells' row counts and
+code widths (a row's codes, g, w, its node and its leaf; h too at C 3):
+
+  sort       the stable sort of the node keys that gives the order
+  gather     each array gathered by the order, apart (the shipped form)
+  packed     the arrays packed into one [rows, words] int32, ONE gather,
+             unpacked
+  columns    the codes gathered a column at a time into [F, rows] (a row
+             of codes is not padded to 128 lanes), the rest apart
+  carried    every column a payload of the sort: no gather
+  back       the leaf put back in the caller's order by a scatter (the
+             shipped form)
+  back_sort  the same by sorting (order, leaf) on the order
+  order      the grower's whole step (`core._order_rows` and `back`)
+
 One JSON line a measurement on stdout and in
 `chiprun_out/hist_forms.jsonl`. Exits non-zero without a TPU;
 `tests/test_hist_forms.py` holds the three forms to `_hist_segment` on
@@ -77,6 +101,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from h2o_kubernetes_tpu.models.tree import core
 from h2o_kubernetes_tpu.ops import histogram as H
 
 
@@ -268,16 +293,23 @@ SHAPES = {
 }
 
 
-def _case(rows, F, C, n_bins, dtype, n_nodes, seed, classes=None):
+def _case(rows, F, C, n_bins, dtype, n_nodes, seed, classes=None,
+          ordered=False):
     """A level's operands; with ``classes`` the K-class grower's: one
-    `binned`, `[K, rows]` node ids and `[K, rows, C]` values."""
+    `binned`, `[K, rows]` node ids and `[K, rows, C]` values; with
+    ``ordered`` a bagged tree's rows ordered by node, the dead last."""
     k1, k2, k3, k4 = jax.random.split(jax.random.key(seed), 4)
     binned = jax.random.randint(k1, (rows, F), 0, n_bins,
                                 jnp.int32).astype(dtype)
     lead = () if classes is None else (classes,)
     rel = jax.random.randint(k2, lead + (rows,), 0, n_nodes, jnp.int32)
-    dead = jax.random.uniform(k3, lead + (rows,)) < 0.1
+    dead = jax.random.uniform(k3, lead + (rows,)) < (
+        0.368 if ordered else 0.1)
     rel = jnp.where(dead, -1, rel)
+    if ordered:
+        rel = jnp.sort(jnp.where(dead, n_nodes, rel))
+        rel = jnp.where(rel == n_nodes, -1, rel)
+        dead = rel < 0
     vals = jnp.where(dead[..., None], 0.0,
                      jax.random.normal(k4, lead + (rows, C), jnp.float32))
     return binned, rel, vals
@@ -345,6 +377,172 @@ CLASS_FORMS = {"fold": _fold, "A": _class_form_a, "B": H._hist_pallas,
 CLASS_SHIPPED = "B"
 
 
+def _compacted(binned, rel, vals, n_nodes, n_bins):
+    """The level over its rows ordered by hi block, as the grower hands
+    it: each block's first row from the ordered node ids."""
+    n_ht, per = H.node_blocks(n_nodes, n_bins)
+    key = jnp.where(rel >= 0, rel // per, n_ht)
+    starts = jnp.searchsorted(key, jnp.arange(n_ht + 1, dtype=jnp.int32))
+    return H._hist_pallas(binned, rel, vals, n_nodes, n_bins,
+                          starts.astype(jnp.int32))
+
+
+COMPACT_FORMS = {"compacted": _compacted, "blocked": H._hist_pallas}
+COMPACT_SHIPPED = "compacted"
+
+# (rows, F, C, n_bins, code dtype, node counts, forms): the cells whose
+# levels pass one hi block, at their widths
+COMPACT_SHAPES = {
+    "forest512": (8_388_608, 8, 2, 512, "uint16", (128, 256, 512, 1024),
+                  tuple(COMPACT_FORMS)),
+    "airline512": (8_388_608, 8, 3, 512, "uint16", (128, 256),
+                   tuple(COMPACT_FORMS)),
+    "forest64": (4_194_304, 28, 2, 64, "uint8", (1024,),
+                 tuple(COMPACT_FORMS)),
+}
+
+
+def _pack(arrays):
+    """[rows, words] int32 of ``arrays`` ([rows] or [rows, k] of 1, 2 or
+    4 bytes an element, k·bytes a multiple of 4 a row), and the
+    function that unpacks it."""
+    parts, cuts = [], [0]
+    for a in arrays:
+        x = a if a.ndim == 2 else a[:, None]
+        n = x.shape[1] * x.dtype.itemsize // 4
+        parts.append(lax.bitcast_convert_type(
+            x.reshape(x.shape[0], n, 4 // x.dtype.itemsize), jnp.int32)
+            if x.dtype.itemsize < 4 else
+            lax.bitcast_convert_type(x, jnp.int32))
+        cuts.append(cuts[-1] + n)
+
+    def unpack(words):
+        out = []
+        for a, lo, hi in zip(arrays, cuts, cuts[1:]):
+            x = lax.bitcast_convert_type(words[:, lo:hi], a.dtype)
+            out.append(x.reshape(a.shape))
+        return out
+
+    return jnp.concatenate(parts, axis=1), unpack
+
+
+def _order_case(rows, F, C, dtype, depth, seed):
+    """One tree's rows at the depth it orders them: codes, g (h), w,
+    node ids at ``depth`` (36.8% dead) and leaves."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(seed), 4)
+    binned = jax.random.randint(k1, (rows, F), 0, 1 << (8 * jnp.dtype(
+        dtype).itemsize), jnp.int32).astype(dtype)
+    rel = jax.random.randint(k2, (rows,), 0, 2 ** depth, jnp.int32)
+    w = (jax.random.uniform(k3, (rows,)) >= 0.368).astype(jnp.float32)
+    g = -(jax.random.uniform(k4, (rows,)) < 0.4).astype(jnp.float32)
+    cols = (g,) + ((jnp.ones_like(g),) if C == 3 else ()) + (w,)
+    return (binned,) + cols + (rel, rel + (2 ** depth - 1))
+
+
+def _order_forms(depth):
+    """The ordering's pieces, each ``fn(binned, *cols, rel, leaf)``."""
+    def order_of(rel, w):
+        key = jnp.where((rel >= 0) & (w > 0), rel, 2 ** depth)
+        return lax.sort((key, jnp.arange(rel.shape[0], dtype=jnp.int32)),
+                        num_keys=1, is_stable=True)[1]
+
+    def sort(*a):
+        return order_of(a[-2], a[-3])
+
+    def gather(*a):
+        order = order_of(a[-2], a[-3])
+        return [x[order] for x in a]
+
+    def packed(*a):
+        order = order_of(a[-2], a[-3])
+        words, unpack = _pack(a)
+        return unpack(words[order])
+
+    def columns(*a):
+        # the codes a column at a time, stored [F, rows]: no row of
+        # codes padded to 128 lanes
+        order = order_of(a[-2], a[-3])
+        codes = jnp.stack([a[0][:, j][order] for j in range(a[0].shape[1])])
+        return [codes.T] + [x[order] for x in a[1:]]
+
+    def carried(*a):
+        # every column a payload of the sort itself: no gather at all
+        rel, w = a[-2], a[-3]
+        key = jnp.where((rel >= 0) & (w > 0), rel, 2 ** depth)
+        F = a[0].shape[1]
+        out = lax.sort((key,) + tuple(a[0][:, j] for j in range(F))
+                       + a[1:], num_keys=1, is_stable=True)
+        return [jnp.stack(out[1:F + 1]).T] + list(out[F + 1:])
+
+    def back(*a):
+        order = order_of(a[-2], a[-3])
+        return jnp.zeros_like(a[-1]).at[order].set(a[-1],
+                                                   unique_indices=True)
+
+    def back_sort(*a):
+        order = order_of(a[-2], a[-3])
+        return lax.sort((order, a[-1]), num_keys=1)[1]
+
+    def order(*a):
+        o, bounds, rows = core._order_rows(depth, a[-2], a[-3], a)
+        return rows, bounds, jnp.zeros_like(rows[-1]).at[o].set(
+            rows[-1], unique_indices=True)
+
+    return {"order": order, "sort": sort, "gather": gather,
+            "packed": packed, "columns": columns, "carried": carried,
+            "back": back, "back_sort": back_sort}
+
+
+# (rows, F, C, code dtype, depth the tree orders its rows at): the cells
+# whose levels pass one hi block
+ORDER_SHAPES = {
+    "forest512": (8_388_608, 8, 2, "uint16", 8),
+    "airline512": (8_388_608, 8, 3, "uint16", 8),
+    "forest64": (4_194_304, 28, 2, "uint8", 11),
+}
+
+
+def measure_order(name, shape, calls, seed, limit=0):
+    """One line a piece of the ordering at one shape; ``packed`` and
+    ``gather`` are compared (bitwise), and the whole step with them."""
+    rows, F, C, dtype, depth = shape
+    args = _order_case(rows, F, C, dtype, depth, seed)
+    got = {}
+    for form, fn in _order_forms(depth).items():
+        line = {"shape": name, "rows": rows, "F": F, "C": C,
+                "code_bytes": jnp.dtype(dtype).itemsize, "depth": depth,
+                "form": form}
+        try:
+            with _limit(limit):
+                fn = jax.jit(fn)
+                t0 = time.perf_counter()
+                got[form] = jax.block_until_ready(fn(*args))
+                line["first_s"] = time.perf_counter() - t0
+                secs = []
+                for _ in range(calls):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(fn(*args))
+                    secs.append(time.perf_counter() - t0)
+            line["min_s"], line["max_s"] = min(secs), max(secs)
+            line["ns_a_row"] = 1e9 * line["min_s"] / rows
+            # the device memory the form asks for besides its operands
+            line["temp_bytes"] = fn.lower(*args).compile(
+            ).memory_analysis().temp_size_in_bytes
+            if form in ("packed", "columns", "carried") and \
+                    "gather" in got:
+                line["bitwise_gather"] = all(
+                    bool(jnp.array_equal(x, y))
+                    for x, y in zip(got[form], got["gather"]))
+            if form == "back_sort" and "back" in got:
+                line["bitwise_back"] = bool(
+                    jnp.array_equal(got["back_sort"], got["back"]))
+        except TimeoutError as e:
+            line["timeout"] = str(e)
+        except Exception as e:      # this form failed here; go on
+            line["error"] = repr(e)[:400]
+        yield line
+
+
 def _segment_on_host(binned, rel, vals, n_nodes, n_bins):
     """`_hist_segment` on the HOST, a column at a time: the chip
     serializes a scatter (seconds a column at these rows), and vmapped
@@ -376,14 +574,15 @@ def _limit(seconds):
 
 
 def measure(name, shape, n_nodes, forms, calls, seed, segment=True,
-            classes=None, limit=0):
+            classes=None, limit=0, compact=False):
     """One line a form at one (shape, node count); the shipped form is
     measured first so that the others are compared with it."""
     rows, F, C, n_bins, dtype = shape[:5]
-    table, first = (FORMS, SHIPPED) if classes is None else \
+    table, first = (COMPACT_FORMS, COMPACT_SHIPPED) if compact else \
+        (FORMS, SHIPPED) if classes is None else \
         (CLASS_FORMS, CLASS_SHIPPED)
     binned, rel, vals = _case(rows, F, C, n_bins, dtype, n_nodes, seed,
-                              classes)
+                              classes, ordered=compact)
     want = None
     if segment and classes is None:
         want = _segment_on_host(binned, rel, vals, n_nodes, n_bins)
@@ -454,15 +653,21 @@ def main(argv=None) -> int:
                     help="skip `_hist_segment` (136 columns of it take "
                          "minutes): forms are compared with the shipped "
                          "one alone")
+    ap.add_argument("--compact", action="store_true",
+                    help="time the levels past one hi block over rows "
+                         f"ordered by block {sorted(COMPACT_FORMS)}, and "
+                         "the ordering's pieces, at "
+                         f"{sorted(COMPACT_SHAPES)}")
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument("--seed", type=int, default=35)
     ap.add_argument("--limit", type=int, default=0,
                     help="seconds a (form, shape) may take, compile "
                          "included; 0: none")
     args = ap.parse_args(argv)
-    shapes = {name: SHAPES[name] if name in SHAPES
+    known = COMPACT_SHAPES if args.compact else SHAPES
+    shapes = {name: known[name] if name in known
               else _custom_shape(name, args.classes)
-              for name in args.shape or sorted(SHAPES)}
+              for name in args.shape or sorted(known)}
     if args.classes and any(name in SHAPES for name in shapes):
         ap.error("--classes needs --shape ROWSxCOLUMNS")
     from h2o_kubernetes_tpu.runtime.backend import require_tpu
@@ -470,6 +675,18 @@ def main(argv=None) -> int:
     require_tpu("hist_forms")
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/hist_forms.jsonl", "a") as sink:
+        def emit(line):
+            txt = json.dumps(line)
+            print(txt, flush=True)
+            sink.write(txt + "\n")
+            sink.flush()
+
+        for name in shapes if args.compact else ():
+            if name in ORDER_SHAPES:
+                for line in measure_order(name, ORDER_SHAPES[name],
+                                          args.calls, args.seed,
+                                          limit=args.limit):
+                    emit(line)
         for name, shape in shapes.items():
             forms = tuple(args.forms.split(",")) if args.forms \
                 else shape[6]
@@ -480,11 +697,9 @@ def main(argv=None) -> int:
                                     args.calls, args.seed,
                                     segment=not args.no_segment,
                                     classes=args.classes,
-                                    limit=args.limit):
-                    txt = json.dumps(line)
-                    print(txt, flush=True)
-                    sink.write(txt + "\n")
-                    sink.flush()
+                                    limit=args.limit,
+                                    compact=args.compact):
+                    emit(line)
     return 0
 
 
