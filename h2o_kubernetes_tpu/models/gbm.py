@@ -38,9 +38,9 @@ from .tree.binning import (BinSpec, apply_bins, apply_bins_jit,
                            bin_code_dtype, fit_bins, fused_fit_bins,
                            matrix_bins, resolve_encoding, set_features)
 from .tree.core import (BoostParams, FlatTrees, Tree, TreeParams,
-                        _boost_drf_jit, _boost_jit, _boost_multi_jit,
-                        descend_tree, flat_margin, flatten_cover,
-                        flatten_trees, goss_round_keys, hist_level_forms,
+                        _boost_jit, _boost_multi_jit, descend_tree,
+                        flat_margin, flatten_cover, flatten_trees,
+                        goss_round_keys, hist_level_forms,
                         level_hist_bytes, multi_grow_vmapped,
                         node_lookup_forms,
                         predict_tree, round_keys, set_split_reason)
@@ -213,10 +213,9 @@ def refuse_grouped(distribution: str, **facts) -> None:
 
 # THE table of boosting modes: the jitted program that serves a job.
 # A device trace shows each as module `jit_<__name__>`;
-# telemetry.TRAIN_PROGRAMS["boost"] lists the same three names
+# telemetry.TRAIN_PROGRAMS["boost"] lists the same two names
 # (tests/test_telemetry.py holds it to this table).
-_BOOST_PROGRAMS = {"forest": _boost_drf_jit,    # single-output DRF
-                   "single": _boost_jit,        # one tree a round
+_BOOST_PROGRAMS = {"single": _boost_jit,        # one tree a round
                    "multi": _boost_multi_jit}   # K class trees a round
 
 
@@ -247,10 +246,8 @@ class BoostPlan(NamedTuple):
 
     @property
     def mode(self) -> str:
-        """This job's row of `_BOOST_PROGRAMS`."""
-        if self.K > 1:
-            return "multi"      # a multinomial forest grows there too
-        return "forest" if self.bp.drf_mode else "single"
+        """This job's row of `_BOOST_PROGRAMS` (a forest's by its K)."""
+        return "multi" if self.K > 1 else "single"
 
     @property
     def grouped(self) -> bool:
@@ -367,7 +364,7 @@ class BoostPlan(NamedTuple):
         env = os.environ.get("H2O_TPU_OOC", "auto")
         if env == "0":
             return None
-        if self.mode != "single" or ckpt is not None or \
+        if self.K > 1 or self.bp.drf_mode or ckpt is not None or \
                 self.distribution == "huber" or p.score_every or \
                 p.sample_rate < 1.0 or p.col_sample_rate_per_tree < 1.0 \
                 or p.mtries > 0:
@@ -391,8 +388,7 @@ class BoostPlan(NamedTuple):
         without the feature."""
         if self.bp.goss_b > 0:
             keys = (keys, goss_keys)
-        statics = (self.tp, self.bp, self.mesh) if self.K == 1 \
-            else (self.tp, self.bp, self.K, self.mesh)
+        statics = (self.tp, self.bp, self.K, self.mesh)
         if self.rank is not None:
             statics += (self.rank.groups,)
         return (binned, y, w, margin, keys, efb) + statics

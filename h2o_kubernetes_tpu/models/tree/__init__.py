@@ -1,5 +1,5 @@
 from .binning import BinSpec, apply_bins, fit_bins
-from .core import Tree, TreeParams, grow_tree, predict_tree
+from .core import Tree, TreeParams, predict_tree
 
 __all__ = ["BinSpec", "apply_bins", "fit_bins", "Tree", "TreeParams",
-           "grow_tree", "predict_tree"]
+           "predict_tree"]

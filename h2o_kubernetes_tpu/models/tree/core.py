@@ -36,7 +36,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ...runtime.mesh import ROWS, global_mesh
+from ...runtime.mesh import ROWS
 
 
 class TreeParams(NamedTuple):
@@ -706,19 +706,6 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
                 cover, left_bins), abs_node
 
 
-def grow_tree(binned, g, h, w, p: TreeParams, col_mask=None, key=None,
-              mesh=None, efb=None) -> Tree:
-    """Build one tree over row-sharded inputs. Tree is replicated."""
-    if col_mask is None:
-        n_feat = efb.feat_col.shape[0] if efb is not None \
-            else binned.shape[1]
-        col_mask = jnp.ones(n_feat, dtype=bool)
-    if key is None:
-        key = jax.random.key(0)
-    return _grow_tree_jit(binned, g, h, w, col_mask, key, efb, p,
-                          mesh or global_mesh())
-
-
 def _grad_hess(distribution: str, margin, y):
     """Gradient/hessian of the boosting loss at the current margin
     (hex/genmodel DistributionFamily analogs — see models/gbm.py)."""
@@ -790,11 +777,33 @@ def _boost_grad_hess(bp: BoostParams, margin, y, w):
     return _grad_hess(bp.distribution, margin, y)
 
 
+def _round_grad_hess(bp: BoostParams, margin, y, w, groups, K: int):
+    """(g, h) of one boosting round: [rows] for K = 1, [K, rows] for
+    K > 1 — THE one place that says where a round's gradients come
+    from. A forest's are -y (the class indicators for K classes) with
+    h = 1, whatever the margin; a ranking objective's hang on a row's
+    query (`rank_grad_hess`); K classes take the softmax of the
+    [rows, K] margin; a pointwise objective `_boost_grad_hess`."""
+    if K > 1:
+        # NaN responses (w=0 pad rows) compare False for every class
+        yk = (y[:, None] == jnp.arange(K, dtype=y.dtype)[None, :]
+              ).astype(jnp.float32)                      # [rows, K]
+        if bp.drf_mode:
+            g = -yk.T
+            return g, jnp.ones_like(g)
+        probs = jax.nn.softmax(margin, axis=1)
+        return (probs - yk).T, (probs * (1.0 - probs)).T
+    if bp.drf_mode:
+        return -y, jnp.ones_like(y)
+    if groups is not None:
+        return rank_grad_hess(bp.distribution, margin, groups)
+    return _boost_grad_hess(bp, margin, y, w)
+
+
 def _round_sampling(bp: BoostParams, w, F: int, k_row, k_col):
     """Shard-level row/column sampling for one boosting round →
-    (w_t, col_mask): THE one sampling scheme, for every boost scan
-    (``_boost_shard``, a ranking job's among them, ``_boost_shard_multi``
-    and ``_boost_shard_drf``)."""
+    (w_t, col_mask): THE one sampling scheme, for every job the boost
+    scan (``_boost_shard``) serves."""
     w_t = w
     if bp.sample_rate < 1.0:
         w_t = w * row_keep(k_row, lax.axis_index(ROWS), w.shape[0],
@@ -1012,77 +1021,8 @@ def round_keys(key, n_rounds: int):
     return jax.random.split(key, n_rounds)
 
 
-def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
-                 p: TreeParams, bp: BoostParams):
-    """Scan over trees INSIDE one shard_map: grad/hess → grow → local
-    margin update, with histograms psum'd per level. ``groups``: the
-    query layout of a grouped objective (tree/rank.py), whose gradients
-    hang on a row's query; None for a pointwise one.
-
-    This replaces the reference's per-tree driver round trips
-    (SharedTree.Driver.computeImpl's outer loop, SURVEY.md §3.4) with a
-    single compiled program — the margin never leaves the device and
-    the host dispatches once per chunk of trees instead of ≥3 times per
-    tree. A single-output forest has a scan of its own
-    (``_boost_shard_drf``: no gradients to take off the margin).
-    """
-    assert not bp.drf_mode, "a forest grows in _boost_shard_drf"
-    F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
-    goss = bp.goss_b > 0.0
-
-    def body(margin, kt):
-        if goss:
-            kt, kg = kt
-        k_row, k_col, k_tree = jax.random.split(kt, 3)
-        with jax.named_scope("sample"):
-            w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
-        with jax.named_scope("grad_hess"):
-            if groups is not None:
-                g, h = rank_grad_hess(bp.distribution, margin, groups)
-            else:
-                g, h = _boost_grad_hess(bp, margin, y, w)
-        if goss:
-            # GOSS: amplified weights → static-cap compaction → the
-            # grower streams only the sampled rows. The margin update
-            # re-descends the FULL binned matrix through the grown
-            # tree (the grower's leaf walk only covers sampled rows).
-            with jax.named_scope("sample"):
-                w_amp = goss_amplified_w(g, w_t, kg, bp)
-                cap = goss_cap_rows(binned.shape[0], bp.goss_a,
-                                    bp.goss_b)
-                bC, gC, hC, wC, dropped = goss_compact(binned, g, h,
-                                                       w_amp, cap)
-            tree, _ = _grow_tree_shard(bC, gC, hC, wC, col_mask,
-                                       k_tree, p, efb)
-            with jax.named_scope("margin"):
-                margin = margin + bp.learn_rate * _node_lookup(
-                    tree.value, descend_tree(tree, binned, p.max_depth,
-                                             p.n_bins, efb))
-                tree = tree._replace(value=bp.learn_rate * tree.value)
-            return margin, (tree, lax.psum(dropped, ROWS))
-        tree, leaf = _grow_tree_shard(binned, g, h, w_t, col_mask,
-                                      k_tree, p, efb)
-        with jax.named_scope("margin"):
-            # the grower already walked each row to its leaf: one
-            # lookup replaces a full predict_tree heap re-descent per
-            # tree. The rate scales the rows' values as it scales the
-            # table's, bit for bit, and after the lookup in either
-            # form: XLA:CPU folds a multiply into the loop of a gather
-            # and rounds it with the add (a multiply-add)
-            margin = margin + bp.learn_rate * _node_lookup(tree.value,
-                                                           leaf)
-            tree = tree._replace(value=bp.learn_rate * tree.value)
-        return margin, tree
-
-    if goss:
-        margin, (trees, dropped) = lax.scan(body, margin, keys)
-        return margin, trees, jnp.sum(dropped)
-    margin, trees = lax.scan(body, margin, keys)
-    return margin, trees
-
-
 # live histogram bytes allowed for the vmapped K-class grow (per shard,
-# deepest level) before _boost_shard_multi drops to sequential lax.map
+# deepest level) before `_boost_shard` drops to sequential lax.map
 _MULTI_HIST_BUDGET = 2 ** 30
 
 
@@ -1103,20 +1043,30 @@ def multi_grow_vmapped(p: TreeParams, F: int, K: int) -> bool:
     return K * level_hist_bytes(p, F) <= _MULTI_HIST_BUDGET
 
 
-def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
-                       p: TreeParams, bp: BoostParams, K: int):
-    """Multinomial analog of ``_boost_shard``: K class trees grow per
-    boosting round via ``vmap`` over the class axis (per-level psums
-    batch across classes), inside the same scan-over-rounds shard_map.
+def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
+                 p: TreeParams, bp: BoostParams, K: int):
+    """Scan over boosting rounds INSIDE one shard_map: sample →
+    grad/hess → (GOSS) → grow → local margin update, with histograms
+    psum'd per level. One tree a round for K = 1, K class trees a round
+    for K > 1 (the margin is then [rows, K]). ``groups``: the query
+    layout of a grouped objective (tree/rank.py), None for a pointwise
+    one.
 
-    Replaces the round-2 host loop (K ``grow_tree`` + K predict
-    dispatches per iteration: dispatch latency dominated). Margin is
-    [rows, K] and never leaves the device; one dispatch covers a whole
-    chunk of boosting rounds. Reference: hex/tree/gbm/GBM.java grows the K class
-    trees of an iteration from shared softmax probs (SURVEY.md §3.4).
-    A K-class forest (``bp.drf_mode``) grows here too: its gradients
-    are the class indicators, and the margin it carries is the sum of
-    its trees' leaf values a class, as ``_boost_shard_drf``'s.
+    This replaces the reference's per-tree driver round trips
+    (SharedTree.Driver.computeImpl's outer loop, SURVEY.md §3.4) with a
+    single compiled program — the margin never leaves the device and
+    the host dispatches once per chunk of trees instead of ≥3 times per
+    tree. The K class trees of a round grow from shared softmax probs,
+    one row sample and one GOSS draw a round, as hex/tree/gbm/GBM.java
+    grows an iteration's.
+
+    A forest (``bp.drf_mode``) grows here too, a tree (K for K
+    classes) a scan step, each on the bag and candidate features its own
+    key draws; its gradients never read the carry. Its ``learn_rate``
+    is 1, so the carry is the sum of its trees' leaf values for EVERY
+    row (a row out of the bag has weight 0 and descends with the rest):
+    the forest's train metric is read off it. Several forest trees a
+    step under vmap never won on a v5e (PERF.md §6).
     """
     F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
     goss = bp.goss_b > 0.0
@@ -1125,70 +1075,65 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
         if goss:
             kt, kg = kt
         k_row, k_col, k_tree = jax.random.split(kt, 3)
-        # one row-sample per ROUND, shared by its K trees (the
-        # reference samples per iteration, not per class tree)
         with jax.named_scope("sample"):
             w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
         with jax.named_scope("grad_hess"):
-            # NaN responses (w=0 pad rows) compare False for every class
-            yk = (y[:, None] == jnp.arange(K, dtype=y.dtype)[None, :]
-                  ).astype(jnp.float32)                  # [rows, K]
-            if bp.drf_mode:
-                g = -yk.T
-                h = jnp.ones_like(g)
-            else:
-                probs = jax.nn.softmax(margin, axis=1)
-                g = (probs - yk).T                       # [K, rows]
-                h = (probs * (1.0 - probs)).T
+            g, h = _round_grad_hess(bp, margin, y, w, groups, K)
+        bC, gC, hC, wC = binned, g, h, w_t
         if goss:
-            # one GOSS draw per ROUND (rows ranked by the class-L1
-            # gradient norm), shared by its K class trees — the same
-            # per-iteration discipline as the row sample above
+            # GOSS: amplified weights → static-cap compaction → the
+            # grower streams only the sampled rows (one draw a round,
+            # ranked by the class-L1 gradient norm for K > 1). The
+            # margin update re-descends the FULL binned matrix through
+            # the grown trees (the grower's leaf walk only covers
+            # sampled rows).
             with jax.named_scope("sample"):
                 w_amp = goss_amplified_w(g, w_t, kg, bp)
                 cap = goss_cap_rows(binned.shape[0], bp.goss_a,
                                     bp.goss_b)
                 bC, gC, hC, wC, dropped = goss_compact(binned, g, h,
                                                        w_amp, cap)
+        if K == 1:
+            tree, leaf = _grow_tree_shard(bC, gC, hC, wC, col_mask,
+                                          k_tree, p, efb)
         else:
-            bC, gC, hC, wC = binned, g, h, None
+            # vmap multiplies per-level histogram memory by K; past a
+            # budget grow classes sequentially INSIDE the dispatch
+            # (lax.map: 1/K the live histogram footprint, still one
+            # compile). The decision uses the HISTOGRAM width (the
+            # bundled width under EFB), matching gbm.py's validator
+            batched = multi_grow_vmapped(p, binned.shape[1], K)
 
-        # vmap multiplies per-level histogram memory by K; past a VMEM/
-        # HBM budget grow classes sequentially INSIDE the dispatch
-        # (lax.map: 1/K the live histogram footprint, still one compile).
-        # The decision uses the HISTOGRAM width (binned.shape[1] — the
-        # bundled width under EFB), matching gbm.py's validator, which
-        # also means bundling buys back the K-vmapped growth on wide
-        # sparse frames
-        batched = multi_grow_vmapped(p, binned.shape[1], K)
+            def grow_one(gk, hk, kk):
+                return _grow_tree_shard(bC, gk, hk, wC, col_mask, kk, p,
+                                        efb, batched=batched)
 
-        def grow_one(gk, hk, kk):
-            return _grow_tree_shard(bC, gk, hk,
-                                    wC if goss else w_t, col_mask, kk,
-                                    p, efb, batched=batched)
-
-        keys_k = jax.random.split(k_tree, K)
-        if batched:
-            trees, leaf = jax.vmap(grow_one)(gC, hC, keys_k)
-        else:
-            trees, leaf = lax.map(lambda a: grow_one(*a),
-                                  (gC, hC, keys_k))
-        with jax.named_scope("margin"):
-            # a K-class forest carries its sums here too (learn_rate
-            # 1; its gradients above never read the margin)
-            # (the rate after the lookup, as in `_boost_shard`)
-            if goss:
-                # sampled grow → full-row leaf values by re-descent
-                upd = jax.vmap(lambda tr: _node_lookup(
-                    tr.value, descend_tree(tr, binned, p.max_depth,
-                                           p.n_bins, efb)))(trees)
+            keys_k = jax.random.split(k_tree, K)
+            if batched:
+                tree, leaf = jax.vmap(grow_one)(gC, hC, keys_k)
             else:
-                upd = _node_lookup(trees.value, leaf)     # [K, rows]
-            margin = margin + (bp.learn_rate * upd).T
-            trees = trees._replace(value=bp.learn_rate * trees.value)
+                tree, leaf = lax.map(lambda a: grow_one(*a),
+                                     (gC, hC, keys_k))
+        with jax.named_scope("margin"):
+            # the grower already walked each row to its leaf: one
+            # lookup replaces a full heap re-descent per tree. The rate
+            # scales the rows' values after the lookup, and K classes'
+            # [rows, K] margin meets their [K, rows] values in the
+            # values' layout (a no-op for one tree): XLA:CPU folds the
+            # multiply into the add's loop (a multiply-add), and only so
+            # does it fold it alike after a gather and after a select
+            if goss:
+                def upd_of(tr):
+                    return _node_lookup(tr.value, descend_tree(
+                        tr, binned, p.max_depth, p.n_bins, efb))
+                upd = upd_of(tree) if K == 1 else jax.vmap(upd_of)(tree)
+            else:
+                upd = _node_lookup(tree.value, leaf)  # [(K,) rows]
+            margin = (margin.T + bp.learn_rate * upd).T
+            tree = tree._replace(value=bp.learn_rate * tree.value)
         if goss:
-            return margin, (trees, lax.psum(dropped, ROWS))
-        return margin, trees
+            return margin, (tree, lax.psum(dropped, ROWS))
+        return margin, tree
 
     if goss:
         margin, (trees, dropped) = lax.scan(body, margin, keys)
@@ -1197,89 +1142,11 @@ def _boost_shard_multi(binned, y, w, margin, keys, efb=None, *,
     return margin, trees
 
 
-def _boost_shard_drf(binned, y, w, margin, keys, efb=None, *,
-                     p: TreeParams, bp: BoostParams):
-    """Forest growth: the trees are INDEPENDENT (their gradients never
-    read the carry), one a scan step, each on the bag and the candidate
-    features its own key draws. Growing several a step under vmap was
-    measured on a v5e and never won (PERF.md §6, PR 28: a tie while
-    every merged level stays within one hi block of the histogram
-    kernel, a loss once one reached the bin-blocked kernel of the
-    time, at G times the temporaries), so there is one path. (Why it
-    could not win then: the histogram's batching rule of the time
-    folded the G trees into the kernel's node axis, every tree's rows
-    multiplied against all G trees' slots — G² products for G. A
-    tree's rows now meet its own slots only (at a forest's 64 bins, a
-    tree a call: PERF.md §6), and a node table of up to
-    `_SELECT_MAX_ENTRIES` is read by a select under `vmap` too;
-    the G-fold temporaries are what a second try would have to beat.)
-
-    The scan carries what ``_boost_shard`` carries: ``margin`` plus the
-    leaf value of every tree so far, for EVERY row — the bag is a
-    weight, and a row of weight 0 descends with the rest — so the
-    forest's train metric is read off it (``learn_rate`` is 1) and no
-    tree is walked again. keys: [n_trees]."""
-    assert bp.drf_mode
-    F = efb.feat_col.shape[0] if efb is not None else binned.shape[1]
-    g0 = -y
-    h0 = jnp.ones_like(y)
-
-    def body(margin, kt):
-        k_row, k_col, k_tree = jax.random.split(kt, 3)
-        with jax.named_scope("sample"):
-            w_t, col_mask = _round_sampling(bp, w, F, k_row, k_col)
-        tree, leaf = _grow_tree_shard(binned, g0, h0, w_t, col_mask,
-                                      k_tree, p, efb)
-        with jax.named_scope("margin"):
-            margin = margin + _node_lookup(tree.value, leaf)
-        return margin, tree
-
-    return lax.scan(body, margin, keys)
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _boost_drf_jit(binned, y, w, margin, keys, efb, p: TreeParams,
-                   bp: BoostParams, mesh):
-    """A forest's trees in ONE dispatch, one a scan step, each from its
-    own key of ``keys`` → (margin + the sum of the trees' leaf values a
-    row, trees [T, N])."""
-    fn = jax.shard_map(
-        functools.partial(_boost_shard_drf, p=p, bp=bp),
-        mesh=mesh,
-        in_specs=(P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P()),
-        out_specs=(P(ROWS), P()),
-        check_vma=_resolve_impl(p.hist_impl) == "segment")
-    return fn(binned, y, w, margin, keys, efb)
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _boost_multi_jit(binned, y, w, margin, keys, efb, p: TreeParams,
-                     bp: BoostParams, K: int, mesh):
-    """Fused multinomial boosting: len(keys) rounds × K class trees in
-    ONE dispatch → (margin [rows, K], trees [T, K, N]), plus the GOSS
-    overflow scalar when sampling is active (see `_boost_jit`)."""
-    out_specs = (P(ROWS), P(), P()) if bp.goss_b > 0 \
-        else (P(ROWS), P())
-    fn = jax.shard_map(
-        functools.partial(_boost_shard_multi, p=p, bp=bp, K=K),
-        mesh=mesh,
-        in_specs=(P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P()),
-        out_specs=out_specs,
-        check_vma=_resolve_impl(p.hist_impl) == "segment")
-    return fn(binned, y, w, margin, keys, efb)
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _boost_jit(binned, y, w, margin, keys, efb, p: TreeParams,
-               bp: BoostParams, mesh, groups=None):
-    """Fused boosting: len(keys) rounds in ONE dispatch → (margin,
-    trees [T, N]). With GOSS (``bp.goss_b > 0``) ``keys`` is the pair
-    (round keys, rows of the path-invariant `goss_round_keys` stream)
-    and a third output counts the rows compaction dropped
-    (`goss_compact`). A grouped objective's query layout is the last
-    operand (``groups``: `rank.RankGroups`), its classes replicated
-    and its rows' slots sharded as the rows are.
-    models/gbm.BoostPlan builds the operands."""
+def _boost_program(binned, y, w, margin, keys, efb, p, bp, K, mesh,
+                   groups):
+    """`_boost_shard` under one shard_map: rows sharded, keys and LUTs
+    replicated, a query layout as `groups_specs` lays it; out the
+    margin by rows and the trees (and the GOSS overflow) replicated."""
     out_specs = (P(ROWS), P(), P()) if bp.goss_b > 0 \
         else (P(ROWS), P())
     in_specs = (P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P())
@@ -1288,31 +1155,41 @@ def _boost_jit(binned, y, w, margin, keys, efb, p: TreeParams,
         in_specs += (groups_specs(groups),)
         args += (groups,)
     fn = jax.shard_map(
-        functools.partial(_boost_shard, p=p, bp=bp),
+        functools.partial(_boost_shard, p=p, bp=bp, K=K),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
+        # pallas_call's interpret mode can't thread vma through its
+        # internal slices (jax 0.9 limitation) — disable the check there
         check_vma=_resolve_impl(p.hist_impl) == "segment")
     return fn(*args)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _grow_tree_jit(binned, g, h, w, col_mask, key, efb, p: TreeParams,
-                   mesh) -> Tree:
-    def body(binned, g, h, w, col_mask, key, efb=None):
-        tree, _ = _grow_tree_shard(binned, g, h, w, col_mask, key, p,
-                                   efb)
-        return tree
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _boost_jit(binned, y, w, margin, keys, efb, p: TreeParams,
+               bp: BoostParams, K: int, mesh, groups=None):
+    """Fused boosting, one tree a round (boosted, ranked or a forest):
+    len(keys) rounds in ONE dispatch → (margin, trees [T, N]). With
+    GOSS (``bp.goss_b > 0``) ``keys`` is the pair (round keys, rows of
+    the path-invariant `goss_round_keys` stream) and a third output
+    counts the rows compaction dropped (`goss_compact`). A grouped
+    objective's query layout is the last operand (``groups``:
+    `rank.RankGroups`), its classes replicated and its rows' slots
+    sharded as the rows are. models/gbm.BoostPlan builds the operands."""
+    assert K == 1, "K class trees a round run in _boost_multi_jit"
+    return _boost_program(binned, y, w, margin, keys, efb, p, bp, K,
+                          mesh, groups)
 
-    fn = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(ROWS), P(ROWS), P(ROWS), P(ROWS), P(), P(), P()),
-        out_specs=P(),
-        # pallas_call's interpret mode can't thread vma through its
-        # internal slices (jax 0.9 limitation) — disable the check here
-        check_vma=_resolve_impl(p.hist_impl) == "segment")
-    return fn(binned, g, h, w, col_mask, key, efb)
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _boost_multi_jit(binned, y, w, margin, keys, efb, p: TreeParams,
+                     bp: BoostParams, K: int, mesh, groups=None):
+    """`_boost_jit` with K > 1 class trees a round → (margin [rows, K],
+    trees [T, K, N]), plus the GOSS overflow scalar when sampling is
+    active."""
+    assert K > 1, "one tree a round runs in _boost_jit"
+    return _boost_program(binned, y, w, margin, keys, efb, p, bp, K,
+                          mesh, groups)
 
 
 def descend_tree(tree: Tree, binned, max_depth: int, n_bins: int,

@@ -3,7 +3,8 @@
 When the uint8 binned matrix itself exceeds the device-memory headroom
 left by `H2O_TPU_HIST_BYTES_BUDGET` (models/gbm.py derives the
 trigger), training switches from the fused all-rows-resident
-`core._boost_jit` scan to this driver: the binned matrix lives as
+`core._boost_jit` scan (one tree a round; its round is
+`core._boost_shard`) to this driver: the binned matrix lives as
 HOST-resident row chunks and is streamed to device per tree level with
 double-buffered `device_put` (the upload of chunk c+1 overlaps the
 histogram build of chunk c), exactly the compressed-stream design of

@@ -4,7 +4,7 @@ layout and LambdaMART's pairwise gradients over it.
 `rank:pairwise` and `rank:ndcg` are distributions of the boost plan
 (models/gbm.BoostPlan, mode ``single``): the layout built here is an
 operand of `core._boost_jit` and `rank_grad_hess` runs inside its scan,
-where a pointwise objective's `_grad_hess` does.
+where `core._round_grad_hess` takes every round's gradients.
 
 THE SEMANTICS (bench/reference/lambdamart_plain.py has them in numpy
 float64). A query q holds documents with margins s and labels y. Its

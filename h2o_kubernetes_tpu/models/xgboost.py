@@ -18,8 +18,8 @@ XGBoost-specific semantics implemented here, distinct from H2O GBM:
   (BASELINE.json:9). They are distributions of the boost plan like any
   other (`GBM.train` → `BoostPlan`, mode ``single``): the query layout
   is an operand of `core._boost_jit` and the pairwise gradients are
-  taken inside its scan (models/tree/rank.py has the semantics and the
-  layout). EVERY pair (i, j) of a query with y_i > y_j is taken, the
+  taken inside its scan, by `core._round_grad_hess` (models/tree/rank.py
+  has the semantics and the layout). EVERY pair (i, j) of a query with y_i > y_j is taken, the
   rank by a stable sort of the margins, maxDCG over the whole list —
   where XGBoost samples or truncates a query's pairs
   (`lambdarank_pair_method`, `lambdarank_num_pair_per_sample`) and

@@ -878,7 +878,7 @@ TRAIN_PROGRAMS = {
     "bin": ("_fused_fit_bin_jit", "_bin_block_jit", "_concat_blocks",
             "_col_sample", "_device_quantiles", "apply_bins"),
     "init": ("_init_margin", "_stack_predict"),
-    "boost": ("_boost_jit", "_boost_multi_jit", "_boost_drf_jit"),
+    "boost": ("_boost_jit", "_boost_multi_jit"),
     # the out-of-core stream's programs, a few per level and chunk
     "boost_ooc": ("_chunk_grads_jit", "_chunk_root_hist_jit",
                   "_chunk_desc_hist_jit", "_root_logic_jit",

@@ -168,14 +168,14 @@ def test_forest_levels_of_many_hi_blocks_compile(one_chip, n_nodes,
 
 
 def test_forest_scan_orders_its_rows_once(topo, monkeypatch):
-    """`_boost_drf_jit` at `drf-airline.train`'s widths (8 columns of
-    16-bit codes, 512 bins, two channels, set features), depth 10: its
-    levels 8 and 9 pass one hi block, so where the rule engages (forced
-    here, whatever the measured costs say of two levels) each tree
-    orders its rows by node block once — one stable sort of the rows'
-    node keys and one scatter of the leaves back, under `row_order` —
-    and both levels are `hist_blocked` calls over the ordered rows, one
-    a level."""
+    """`_boost_jit` on a forest at `drf-airline.train`'s widths (8
+    columns of 16-bit codes, 512 bins, two channels, set features),
+    depth 10: its levels 8 and 9 pass one hi block, so where the rule
+    engages (forced here, whatever the measured costs say of two
+    levels) each tree orders its rows by node block once — one stable
+    sort of the rows' node keys and one scatter of the leaves back,
+    under `row_order` — and both levels are `hist_blocked` calls over
+    the ordered rows, one a level."""
     monkeypatch.setattr(core, "_ORDER_NS", 0.0)
     monkeypatch.setattr(core, "_ARRAY_NS", 0.0)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
@@ -186,8 +186,8 @@ def test_forest_scan_orders_its_rows_once(topo, monkeypatch):
     bp = a[7]._replace(sample_rate=0.632, drf_mode=True, learn_rate=1.0)
     assert core.hist_level_forms(tp, 8) == ["fact"] * 8 + \
         ["compacted"] * 2
-    c = core._boost_drf_jit.lower(_s((ROWS_N, 8), jnp.uint16, rs),
-                                  *a[1:6], tp, bp, mesh).compile()
+    c = core._boost_jit.lower(_s((ROWS_N, 8), jnp.uint16, rs),
+                              *a[1:6], tp, bp, 1, mesh).compile()
     txt = c.as_text()
     assert _kernels(c) == 10
     assert txt.count('"kernel":"hist_blocked"') == 2
@@ -253,7 +253,7 @@ def _boost_args(mesh, rows, ntrees):
         lambda: jax.random.split(jax.random.key(0), ntrees))
     f32 = _s((rows,), jnp.float32, rs)
     return (_s((rows, F), jnp.uint8, rs), f32, f32, f32,
-            _s(keys.shape, keys.dtype, rep), None, tp, bp, mesh)
+            _s(keys.shape, keys.dtype, rep), None, tp, bp, 1, mesh)
 
 
 @pytest.fixture(scope="module")
@@ -331,10 +331,10 @@ def test_boost_scan_names_its_kernel_and_scopes(boost_scan, n_dev):
 @pytest.mark.parametrize("depth,kernels", [
     (6, {"hist_fact"}), (12, {"hist_fact", "hist_blocked"})])
 def test_forest_scan_holds_one_trees_temporaries(topo, depth, kernels):
-    """`_boost_drf_jit` — what `DRF.train()` dispatches — grows one
-    tree a scan step, so six trees a dispatch reserve what one does
-    (grouped under vmap they reserved six times that: 25 G of a 16 G
-    chip at 4,194,304 rows, PERF.md section 6, PR 28). At depth 12 x 64
+    """`_boost_jit` on a forest — what `DRF.train()` dispatches —
+    grows one tree a scan step, so six trees a dispatch reserve what
+    one does (grouped under vmap they reserved six times that: 25 G of
+    a 16 G chip at 4,194,304 rows, PERF.md section 6). At depth 12 x 64
     bins the deepest histogram level (1,024 left children) is past what
     one block of hi slots holds and the kernel serves it in two, under
     the name `hist_blocked`: the one path of `drf-higgs.train` that no
@@ -350,7 +350,7 @@ def test_forest_scan_holds_one_trees_temporaries(topo, depth, kernels):
     temp = {}
     for ntrees in (1, 6):
         a = _boost_args(mesh, ROWS_N, ntrees)
-        c = core._boost_drf_jit.lower(*a[:6], tp, bp, mesh).compile()
+        c = core._boost_jit.lower(*a[:6], tp, bp, 1, mesh).compile()
         assert _kernels(c) == depth and _kernel_names(c) == kernels
         assert "margin" in _scopes(c.as_text())
         temp[ntrees] = c.memory_analysis().temp_size_in_bytes
@@ -520,7 +520,7 @@ def test_ranking_boost_scan_compiles(topo, n_dev):
                           gamma=0.0, min_child_weight=100.0)
     bp = args[7]._replace(distribution="rank:ndcg")
     c = core._boost_jit.lower(
-        _s((rows, 136), jnp.uint8, rs), *args[1:6], tp, bp, mesh,
+        _s((rows, 136), jnp.uint8, rs), *args[1:6], tp, bp, 1, mesh,
         groups).compile()
     txt = c.as_text()
     assert txt.count("tpu_custom_call") == 8

@@ -206,52 +206,77 @@ def test_split_of_rows_packs_and_unpacks(monkeypatch, n_feat, n_bins,
     assert hlo.count("stablehlo.reduce") == words
 
 
-def _boost_case(mode, K=1):
+_BOOST_MODES = {            # mode -> (distribution, K, forest, GOSS)
+    "single": ("bernoulli", 1, False, False),
+    "multi": ("multinomial", 7, False, False),
+    "forest": ("bernoulli", 1, True, False),
+    "rank": ("rank:ndcg", 1, False, False),
+    "multi_forest": ("multinomial", 3, True, False),
+    # past `_MULTI_HIST_BUDGET`: a class at a time under lax.map
+    "multi_map": ("multinomial", 3, False, False),
+    "goss_single": ("bernoulli", 1, False, True),
+    "goss_multi": ("multinomial", 3, False, True),
+}
+
+
+def _boost_case(mode, mesh):
+    """(operands less the static K and mesh, K, query layout or None) of
+    a small job of ``mode`` (`_BOOST_MODES`)."""
+    from h2o_kubernetes_tpu.models.tree import rank
+
+    dist, K, forest, goss = _BOOST_MODES[mode]
     rng = np.random.default_rng(41)
     n, F, B = 4096, 6, 16
     binned = rng.integers(0, B, (n, F)).astype(np.uint8)
     binned[rng.random((n, F)) < 0.05] = B - 1            # NAs
-    y = (rng.integers(0, K, n) if K > 1
+    y = (rng.integers(0, K if K > 1 else 5, n) if K > 1 or mode == "rank"
          else rng.random(n) < 0.4).astype(np.float32)
-    tp = core.TreeParams(max_depth=6 if mode != "forest" else 8,
+    tp = core.TreeParams(max_depth=6 if not forest else 8,
                          n_bins=B, min_rows=1.0, reg_lambda=1.0,
-                         mtries=3 if mode == "forest" else -1,
+                         mtries=3 if forest else -1,
                          hist_impl="segment",
-                         unit_hess=mode == "forest")
+                         unit_hess=forest)
     bp = core.BoostParams(
-        distribution={"single": "bernoulli", "multi": "multinomial",
-                      "forest": "bernoulli"}[mode],
-        learn_rate=1.0 if mode == "forest" else 0.3,
-        sample_rate=0.632 if mode == "forest" else 1.0,
-        drf_mode=mode == "forest")
+        distribution=dist,
+        learn_rate=1.0 if forest else 0.3,
+        sample_rate=0.632 if forest else 1.0,
+        drf_mode=forest, goss_a=0.2 if goss else 0.0,
+        goss_b=0.1 if goss else 0.0)
     margin = np.zeros((n, K) if K > 1 else n, np.float32)
     keys = jax.random.split(jax.random.key(7), 3)
+    if goss:
+        keys = (keys, core.goss_round_keys(jax.random.key(9), 3))
+    groups = None
+    if mode == "rank":
+        gids = np.repeat(np.arange(n // 64), 64)[rng.permutation(n)]
+        groups = rank.rank_layout(gids, y, n, mesh).groups
     return (jnp.asarray(binned), jnp.asarray(y), jnp.ones(n, jnp.float32),
-            jnp.asarray(margin), keys, None, tp, bp)
+            jnp.asarray(margin), keys, None, tp, bp), K, groups
 
 
-@pytest.mark.parametrize("mode", ["single", "multi", "forest"])
+@pytest.mark.parametrize("mode", list(_BOOST_MODES))
 def test_boost_scans_are_bitwise_under_either_form(mesh8, monkeypatch,
                                                    mode):
-    """`_boost_jit`, `_boost_multi_jit` (K = 7 under the class batch)
-    and `_boost_drf_jit` grow bitwise the same trees and margin with
-    every node table read by a gather and with every one read by a
-    select (the rule is read as the program is traced: the caches are
-    cleared between the two)."""
-    K = 7 if mode == "multi" else 1
-    args = _boost_case(mode, K)
-    if mode == "multi":
-        assert core.multi_grow_vmapped(args[6], 6, K)
+    """`_boost_jit` (boosted, ranked, a forest, under GOSS) and
+    `_boost_multi_jit` (K class trees a round: under the class batch,
+    under lax.map, a K-class forest, under GOSS) grow bitwise the same
+    trees and margin with every node table read by a gather and with
+    every one read by a select (the rule is read as the program is
+    traced: the caches are cleared between the two)."""
+    args, K, groups = _boost_case(mode, mesh8)
+    if mode == "multi_map":
+        monkeypatch.setattr(core, "_MULTI_HIST_BUDGET", 1)
+    if K > 1:
+        assert core.multi_grow_vmapped(args[6], 6, K) == \
+            (mode != "multi_map")
     out = {}
     for form, n in (("gather", 0), ("select", 1 << 30)):
         monkeypatch.setattr(core, "_SELECT_MAX_ENTRIES", n)
         jax.clear_caches()
-        if mode == "single":
-            got = core._boost_jit(*args, mesh8)
-        elif mode == "multi":
-            got = core._boost_multi_jit(*args, K, mesh8)
+        if K == 1:
+            got = core._boost_jit(*args, 1, mesh8, groups)
         else:
-            got = core._boost_drf_jit(*args, mesh8)
+            got = core._boost_multi_jit(*args, K, mesh8)
         out[form] = [_bits(a) for a in jax.tree.leaves(got)]
     jax.clear_caches()
     assert len(out["gather"]) == len(out["select"]) >= 8

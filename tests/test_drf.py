@@ -84,7 +84,7 @@ def test_deep_tree_budget_validation(mesh8):
 
 
 def _forest_args(mesh, rows, F, n_bins, depth, ntrees, seed):
-    """`_boost_drf_jit`'s operands for a bagged forest over ``rows`` x
+    """`_boost_jit`'s operands for a bagged forest over ``rows`` x
     ``F`` random codes of ``n_bins`` bins, the first column a set
     feature, a 0/1 response."""
     import jax
@@ -108,7 +108,7 @@ def _forest_args(mesh, rows, F, n_bins, depth, ntrees, seed):
     keys = core.round_keys(jax.random.key(seed), ntrees)
     return (put(codes.astype(np.uint16)), put(y), put(np.ones(rows,
             np.float32)), put(np.zeros(rows, np.float32)), keys, None,
-            tp, bp, mesh)
+            tp, bp, 1, mesh)
 
 
 def test_forest_grown_over_rows_ordered_by_node_block_is_the_same(
@@ -136,7 +136,7 @@ def test_forest_grown_over_rows_ordered_by_node_block_is_the_same(
         assert core.compact_depth(tp, 3) == (
             None if form == "blocked" else 8)
         jax.clear_caches()
-        out[form] = jax.device_get(core._boost_drf_jit(*args))
+        out[form] = jax.device_get(core._boost_jit(*args))
     jax.clear_caches()
     (m0, t0), (m1, t1) = out["blocked"], out["compacted"]
     assert t0.is_split.sum() > 40

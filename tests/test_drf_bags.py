@@ -249,16 +249,18 @@ def test_the_forests_the_old_sizing_refused():
     assert _forest_plan(12, 64, 3).chunks(4_194_304) == [1, 1, 1]
 
 
-def test_the_boosting_scan_refuses_a_forest(mesh8):
-    """A single-output forest grows in `_boost_drf_jit` (no gradients
-    to take off the margin). `_boost_shard` kept `drf_mode` branches
-    that nothing reached; they are gone, and the boosting program
-    refuses a forest's parameters when it is traced."""
+def test_the_boosting_scan_carries_a_forests_leaf_sums(mesh8):
+    """A single-output forest grows in `_boost_jit`, the program of
+    every job of one tree a round: its gradients never read the carry,
+    and its rate of 1 leaves in the carry the sum of its trees' leaf
+    values at each row, bitwise what `gbm._leaf_sums` walks off the
+    trees it returns."""
     _, _, cols = _table(seed=3, rows=256)
     fr = h2o.Frame.from_arrays(cols)
     plan = gbm_mod.boost_plan(
         DRF(ntrees=2, max_depth=3, nbins=NBINS).params, "bernoulli", 2, F)
-    assert plan.mode == "forest" and plan.bp.drf_mode
+    assert plan.mode == "single" and plan.bp.drf_mode
+    assert gbm_mod._BOOST_PROGRAMS[plan.mode] is core._boost_jit
     from h2o_kubernetes_tpu.models.base import resolve_xy
     from h2o_kubernetes_tpu.models.tree.binning import fused_fit_bins
 
@@ -267,10 +269,11 @@ def test_the_boosting_scan_refuses_a_forest(mesh8):
     keys = core.round_keys(jax.random.key(0), 2)
     args = plan.operands(binned, data.y, data.w, jnp.zeros_like(data.y),
                          keys, None)
-    with pytest.raises(AssertionError, match="_boost_shard_drf"):
-        core._boost_jit(*args)
-    margin, trees = core._boost_drf_jit(*args)
+    margin, trees = core._boost_jit(*args)
     assert trees.value.shape[0] == 2
+    walked = gbm_mod._leaf_sums(trees, binned, 1, 3, plan.tp.n_bins)
+    assert np.asarray(margin).tobytes() == np.asarray(walked).tobytes()
+    assert np.asarray(margin).any()
 
 
 def test_train_and_compile_ahead_agree_on_the_dispatches(mesh8,
@@ -290,7 +293,7 @@ def test_train_and_compile_ahead_agree_on_the_dispatches(mesh8,
     for thunk in est.compile_ahead_lowerings("y", fr):
         thunk()
     shapes = {a[4].shape for fn, a in lowered
-              if fn is core._boost_drf_jit}
+              if fn is core._boost_jit}
     assert shapes == {(4,), (2,)}
     m = est.train(y="y", training_frame=fr)
     sent = [(s["first_tree"], s["trees"])
@@ -369,9 +372,9 @@ def _metric_span():
 def _grown_again(m, fr, binned):
     """(trees, leaf [T, rows], bag weight [rounds, rows]) of the model's
     forest grown again from the keys it kept, round by round as the
-    scans' bodies grow it (`_boost_shard_drf`; K class trees a round
-    from one bag as `_boost_shard_multi`), keeping what the grower
-    returns beside a tree: every row's resting heap node."""
+    scan's body grows it (`_boost_shard`; K class trees a round from
+    one bag for K classes), keeping what the grower returns beside a
+    tree: every row's resting heap node."""
     from jax import lax
 
     from h2o_kubernetes_tpu.models.base import resolve_xy
@@ -475,7 +478,7 @@ def test_a_scan_goes_on_from_the_sum_it_is_given(mesh8):
     keys = core.round_keys(jax.random.key(2), 4)
 
     def grow(margin, keys):
-        return core._boost_drf_jit(*plan.operands(
+        return core._boost_jit(*plan.operands(
             binned, data.y, data.w, margin, keys, None))
 
     zeros = jnp.zeros_like(data.y)

@@ -1,7 +1,7 @@
 """A random forest whose categorical columns split on SETS of levels
 (H2O-3 DRF at its default encoding, Enum: ``categorical_encoding=
 "enum"``) through the normal entry points — `DRF.train`, the boost
-plan, `_boost_drf_jit` — held against the benchmark's plain reference
+plan, `_boost_jit` — held against the benchmark's plain reference
 (`bench/reference/drf_sets_plain.py`) tree by tree, given the bags,
 candidates and cuts the model hands out."""
 

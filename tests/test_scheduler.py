@@ -315,7 +315,8 @@ class TestCompileAhead:
         cols = {f"x{i}": rng.normal(size=n).astype(np.float32)
                 for i in range(5)}
         score = cols["x0"] + 0.5 * cols["x1"]
-        mode = case.split("_")[0]       # the row of _BOOST_PROGRAMS
+        # the row of _BOOST_PROGRAMS: a forest's is its K's
+        mode = "multi" if case.startswith("multi") else "single"
         if mode == "multi":
             cols["y"] = np.array(["a", "b", "c"])[
                 np.digitize(score, [-0.5, 0.5])]
@@ -396,7 +397,7 @@ class TestCompileAhead:
         programs = {fn.__name__ for fn, _ in sent}
         assert programs == {
             "single": {"_init_margin", "_boost_jit"},
-            "forest": {"_boost_drf_jit"},
+            "forest": {"_boost_jit"},
             "multi": {"_init_margin", "_boost_multi_jit"},
             "multi_forest": {"_boost_multi_jit"}}[case]
         assert len(sent) == (3 if "forest" in case else 4)
@@ -433,10 +434,10 @@ class TestCompileAhead:
         programs = {fn.__name__: (fn, a) for fn, a in sent}
         assert set(programs) == {
             "single": {"_boost_jit"},
-            "forest": {"_boost_drf_jit"},
+            "forest": {"_boost_jit"},
             "multi": {"_boost_multi_jit"},
             "multi_forest": {"_boost_multi_jit"},
-            "forest_restart": {"_boost_drf_jit", "_stack_predict"}}[case]
+            "forest_restart": {"_boost_jit", "_stack_predict"}}[case]
         # `"stablehlo.gather"(%binned, %idx) ... : (tensor<125x5xui8>,`
         from_binned = re.compile(
             r"stablehlo\.gather[^\n]*: \(tensor<\d+x\d+xui8>")

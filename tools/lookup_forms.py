@@ -22,8 +22,8 @@ gather (the table this wrote is in PERF.md section 6). Each
            select       `core._node_lookup` held to the select
 
 A shape is `ROWS` (one tree: idx [rows], tables [entries]) or `KxROWS`
-(the class batch: K trees under `vmap`, as `_boost_shard_multi` grows
-them). Every form's result is compared with the gather's, bitwise.
+(the class batch: K trees under `vmap`, as `_boost_shard` grows them
+for K > 1). Every form's result is compared with the gather's, bitwise.
 `--limit` is the seconds after which a (form, shape) is abandoned (its
 line says `timeout`).
 
