@@ -22,7 +22,9 @@ does — one bin a level, the levels of a node in mean-response order
 (the shared `_set_order` with h = 1), every prefix scanned — and its
 trees are scored by the heap descent over bin codes (`predict`); the
 flat scorer, MOJO export, the registry and TreeSHAP refuse such a
-model by name, and a K-class forest's grower refuses sets at train.
+model by name. A K-class forest carries sets too: each class tree of a
+round orders its levels by its own class's mean and keeps its own
+table of the bins that go left.
 
 What each tree saw: a forest's model keeps its trees' keys and hands
 out each tree's bag (`tree_bag(t)`) and each node's candidate features

@@ -9,7 +9,11 @@ over trees is host-side Python, as in the reference's Driver.
 Distributions (hex/genmodel DistributionFamily analogs):
   gaussian     g = f - y,            h = 1
   bernoulli    g = p - y,            h = p(1-p)       (logit link)
-  multinomial  K trees/iter, softmax gradient
+  multinomial  K trees/iter, softmax gradient; with set features every
+               node's step and every split's gain from a pass over its
+               rows (H2O-3's GammaPass; a split whose gain there misses
+               min_split_improvement is undone), the step capped at 1e4
+               either way as H2O-3 caps it
   poisson      g = exp(f) - y,       h = exp(f)        (log link)
   gamma        g = 1 - y·exp(-f),    h = y·exp(-f)      (log link)
   tweedie      compound-poisson deviance at power 1.5   (log link)
@@ -60,7 +64,9 @@ class GBMParams:
     # "label_encoder": ordinally on its code, in a matrix of `nbins`
     # bins (so past nbins-1 levels code ranges share bins whatever
     # nbins_cats says). "enum": one bin a level up to nbins_cats, a
-    # split sends a SET of levels left — H2O-3's AUTO for GBM. "AUTO"
+    # split sends a SET of levels left — H2O-3's AUTO for GBM and DRF,
+    # binomial, multinomial (each class tree its own sets) or
+    # regression. "AUTO"
     # here is still label_encoder: not every serving format carries a
     # set yet (`refuse_set_splits`, `core.require_ordinal`).
     nbins_cats: int = 1024
@@ -141,16 +147,31 @@ def goss_params(p: "GBMParams", distribution: str) -> tuple[float, float]:
     return a, b
 
 
-def _make_boost_params(p: "GBMParams", distribution: str) -> BoostParams:
-    """The BoostParams twin of _make_tree_params."""
+# H2O-3's GBM caps a multinomial leaf's step, after the learning rate,
+# at 1e4 either way (hex/tree/gbm/GBM.java `fitBestConstants`: "will
+# already overflow during Math.exp"). Without it a node whose rows all
+# sit at p = 0 or 1 (h = 0 in float32) steps by G / 1e-10, and a
+# float32 margin of 1e10 keeps no digit of the later steps.
+_MULTINOMIAL_STEP_CAP = 1e4
+
+
+def _make_boost_params(p: "GBMParams", distribution: str,
+                       set_feats: tuple = ()) -> BoostParams:
+    """The BoostParams twin of _make_tree_params. H2O-3's multinomial
+    steps (`BoostParams.leaf_cap`) are a multinomial GBM's with set
+    features: the XGBoost facade and the forest refuse or skip them,
+    and a multinomial GBM without sets keeps the program it had."""
     goss_a, goss_b = goss_params(p, distribution)
+    capped = distribution == "multinomial" and not p._drf_mode \
+        and any(set_feats)
     return BoostParams(
         distribution=distribution,
         learn_rate=1.0 if p._drf_mode else p.learn_rate,
         sample_rate=p.sample_rate,
         col_sample_rate_per_tree=p.col_sample_rate_per_tree,
         drf_mode=p._drf_mode,
-        goss_a=goss_a, goss_b=goss_b)
+        goss_a=goss_a, goss_b=goss_b,
+        leaf_cap=_MULTINOMIAL_STEP_CAP if capped else None)
 
 
 def _draws_from_keys(p: "GBMParams", F: int) -> bool:
@@ -165,7 +186,6 @@ def _draws_from_keys(p: "GBMParams", F: int) -> bool:
 # it. The scoring side's list is `core.require_ordinal`'s callers.
 _NO_SET_SPLITS_YET = (
     ("xgboost", "the XGBoost facade"),
-    ("multinomial", "the multinomial grower"),
     ("checkpoint", "checkpoint restart"),
     ("efb", "an EFB-bundled frame"),
     ("goss", "GOSS (H2O_TPU_GOSS)"),
@@ -183,7 +203,7 @@ def refuse_set_splits(**facts) -> None:
                 "set split yet (a split that sends a set of an enum's "
                 "levels left); train with "
                 "categorical_encoding='label_encoder', or a bernoulli / "
-                "regression GBM or DRF in device memory")
+                "multinomial / regression GBM or DRF in device memory")
 
 
 # What cannot carry a GROUPED objective yet (rank:pairwise / rank:ndcg:
@@ -287,7 +307,7 @@ class BoostPlan(NamedTuple):
                 checkpoint=ckpt is not None, offset=offset, cv=cv)
         if any(self.tp.set_feats):
             refuse_set_splits(
-                xgboost=algo == "xgboost", multinomial=self.K > 1,
+                xgboost=algo == "xgboost",
                 checkpoint=ckpt is not None, efb=efb,
                 goss=self.bp.goss_b > 0,
                 ooc=padded is not None
@@ -467,7 +487,8 @@ def boost_plan(p: "GBMParams", distribution: str, nclasses: int, F: int,
             hist_bytes *= K
     budget = float(os.environ.get("H2O_TPU_HIST_BYTES_BUDGET", 2 ** 30))
     return BoostPlan(p, distribution, K, F, tp,
-                     _make_boost_params(p, distribution), hist_bytes,
+                     _make_boost_params(p, distribution, set_feats),
+                     hist_bytes,
                      budget, mesh or global_mesh(), rank, class_batch)
 
 
@@ -844,12 +865,12 @@ class GBMModel(Model):
         ``predict_contributions``, the serving entry ``contrib_numpy``,
         and the REST contributions route (which turns a non-None
         reason into a clean 400, never a 500 traceback)."""
-        if self.nclasses > 2:
-            return ("predict_contributions supports binomial "
-                    "and regression models only")
         if self._set_splits:
             return set_split_reason(self.trees,
                                     "TreeSHAP (predict_contributions)")
+        if self.nclasses > 2:
+            return ("predict_contributions supports binomial "
+                    "and regression models only")
         if getattr(self, "offset_column", None):
             # a per-row offset is not attributable to any feature, so
             # SHAP columns could not sum to the margin
@@ -1121,7 +1142,8 @@ class GBM:
         start_t = 0 if ckpt is None else len(ckpt.trees.value) // plan.K
         if plan.K > 1:
             root.update(classes=plan.K, rounds=p.ntrees - start_t,
-                        class_batch=plan.class_batch)
+                        class_batch=plan.class_batch,
+                        set_features=sum(set_feats))
         history: list[dict] = []
         key_chunks: list = []
         # fused loop: all boosting rounds of a chunk build inside ONE

@@ -211,8 +211,9 @@ def matrix_bins(frame, feature_names: list[str], n_bins: int,
     """Bins a feature (NA bin included) of the matrix a job bins to:
     ``n_bins`` as it always was without ``nbins_cats`` or without a
     set feature; else the least power of two that holds the widest
-    feature — ``n_bins`` numeric bins, or an enum's levels up to
-    ``nbins_cats`` — and the NA bin."""
+    feature with its NA bin — a numeric feature's ``n_bins`` (the NA
+    bin among them), or an enum's levels up to ``nbins_cats`` and the
+    NA bin."""
     if nbins_cats is None or \
             not set_features(frame, feature_names, nbins_cats):
         return n_bins
@@ -220,15 +221,16 @@ def matrix_bins(frame, feature_names: list[str], n_bins: int,
     for name in feature_names:
         v = frame.vec(name)
         if v.is_enum():
-            widest = max(widest, min(v.cardinality(), nbins_cats))
-    return 1 << widest.bit_length()         # least 2^k >= widest + 1
+            widest = max(widest, min(v.cardinality(), nbins_cats) + 1)
+    return 1 << (widest - 1).bit_length()   # least 2^k >= widest
 
 
 def _classify_features(frame, feature_names: list[str], n_bins: int,
                        nbins_cats: int | None = None
-                       ) -> tuple[list[bool], list[int], list, np.ndarray]:
-    """(is_enum, num_idx, num_cols, base_M) — the ONE feature-kind
-    classification shared by fit_bins and the fused path.
+                       ) -> tuple[list[bool], list[int], list, np.ndarray,
+                                  str]:
+    """(is_enum, num_idx, num_cols, base_M, encoding) — the ONE
+    feature-kind classification shared by fit_bins and the fused path.
 
     ``base_M`` is the host [F, B-2] +inf edge matrix (B the matrix's
     bins, `matrix_bins`) with the range-grouping edges of an enum that
@@ -250,10 +252,13 @@ def _classify_features(frame, feature_names: list[str], n_bins: int,
     hex/tree/DHistogram). NA codes arrive as NaN from as_float and land
     in the NA bin either way."""
     B = matrix_bins(frame, feature_names, n_bins, nbins_cats)
-    # ranges an enum with too many levels is folded into, and the most
-    # levels one keeps without folding (the matrix is wider than
-    # ``n_bins`` exactly where some feature takes set splits)
-    groups, keep = (nbins_cats, nbins_cats) if B > n_bins \
+    # an ``enum`` job is one where some feature takes set splits (its
+    # matrix may be as wide as ``n_bins`` or wider); ranges an enum with
+    # too many levels is folded into, and the most levels one keeps
+    # without folding
+    encoding = "enum" if nbins_cats is not None and \
+        set_features(frame, feature_names, nbins_cats) else "label_encoder"
+    groups, keep = (nbins_cats, nbins_cats) if encoding == "enum" \
         else (n_bins - 2, n_bins - 1)
     is_enum: list[bool] = []
     num_idx: list[int] = []
@@ -277,7 +282,7 @@ def _classify_features(frame, feature_names: list[str], n_bins: int,
         num_idx.append(len(is_enum))
         num_cols.append(v.as_float())
         is_enum.append(False)
-    return is_enum, num_idx, num_cols, base
+    return is_enum, num_idx, num_cols, base, encoding
 
 
 def fit_bins(frame, feature_names: list[str], n_bins: int = 256,
@@ -293,7 +298,7 @@ def fit_bins(frame, feature_names: list[str], n_bins: int = 256,
     which is semantically identical to the round-2 host-side
     `np.unique` dedup (bin ids are labels; MOJO scoring uses the SAME
     matrix, so artifacts stay consistent)."""
-    is_enum, num_idx, num_cols, base = _classify_features(
+    is_enum, num_idx, num_cols, base, encoding = _classify_features(
         frame, feature_names, n_bins, nbins_cats)
     bin_code_dtype(base.shape[1] + 2, n_bins)
     M = jnp.asarray(base)
@@ -303,17 +308,15 @@ def fit_bins(frame, feature_names: list[str], n_bins: int = 256,
         Q = jnp.where(jnp.isnan(Q), jnp.inf, Q.astype(jnp.float32))
         M = M.at[jnp.asarray(num_idx, dtype=jnp.int32),
                  : n_bins - 3].set(Q)
-    return _spec(feature_names, is_enum, M, n_bins)
+    return _spec(feature_names, is_enum, M, encoding)
 
 
-def _spec(feature_names, is_enum, M, n_bins: int) -> BinSpec:
+def _spec(feature_names, is_enum, M, encoding: str) -> BinSpec:
     """The BinSpec of a fit: the matrix's bins are the edge matrix's
-    width and two (`_classify_features`), and a matrix wider than the
-    ``n_bins`` asked for is an ``enum`` job's (`matrix_bins`)."""
-    B = M.shape[1] + 2
+    width and two (`_classify_features`, which says the encoding)."""
     return BinSpec(names=list(feature_names), edges=None,
-                   is_enum=is_enum, n_bins=B, edges_dev=M,
-                   encoding="enum" if B != n_bins else "label_encoder")
+                   is_enum=is_enum, n_bins=M.shape[1] + 2, edges_dev=M,
+                   encoding=encoding)
 
 
 # edges counted in one pass over the values: unrolled, a trip's compares
@@ -475,7 +478,7 @@ def fused_fit_bins(frame, feature_names: list[str], n_bins: int = 256,
     if hit is not None:
         cache[key] = hit              # true LRU: a hit refreshes recency
         return hit
-    is_enum, num_idx, num_cols, base = _classify_features(
+    is_enum, num_idx, num_cols, base, encoding = _classify_features(
         frame, feature_names, n_bins, nbins_cats)
     na_bin = base.shape[1] + 1
     bin_code_dtype(na_bin + 1, n_bins)
@@ -498,7 +501,7 @@ def fused_fit_bins(frame, feature_names: list[str], n_bins: int = 256,
         outs.append(_bin_block_jit(cols, M[lo:hi], na_bin,
                                    jnp.asarray(enum_arr[lo:hi])))
     binned = outs[0] if len(outs) == 1 else _concat_blocks(*outs)
-    spec = _spec(feature_names, is_enum, M, n_bins)
+    spec = _spec(feature_names, is_enum, M, encoding)
     while len(cache) >= 2:                  # tiny LRU: drop oldest
         cache.pop(next(iter(cache)))
     cache[key] = (spec, binned)

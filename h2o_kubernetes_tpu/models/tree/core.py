@@ -27,6 +27,7 @@ every descent of such a tree reads that table alone (`_goes_right`).
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -246,18 +247,40 @@ def _set_order(body, p: TreeParams):
                        stable=True).astype(jnp.int32)
 
 
-def _goes_right(rowbin, b, nl, n_bins: int, left_bins=None, node=None):
+def _goes_right(rowbin, b, nl, n_bins: int, left_bins=None, node=None,
+                batched: bool = False):
     """Per row, whether it goes to the right child — THE one reading of
     a split, shared by the grower's descent and `descend_tree`. Without
     set features: the NA bin by ``nl``, else `rowbin > b`. With them:
     the row's bin is looked up among its node's ``left_bins`` ([nodes,
     B]; ``node`` is each row's row of that table), which holds numeric
-    cuts and the NA direction as well."""
+    cuts and the NA direction as well. Under the class batch
+    (``batched``: a `vmap` over K class trees, where a by-row gather is
+    a batched gather) the table is read by `_set_word_lookup`."""
     if left_bins is None:
         is_na = rowbin == n_bins - 1
         return jnp.where(is_na, ~nl, rowbin > b)
     with jax.named_scope("set_descend"):
+        if batched:
+            return ~_set_word_lookup(left_bins, node, rowbin)
         return ~left_bins.reshape(-1)[node * n_bins + rowbin]
+
+
+def _set_word_lookup(left_bins, node, rowbin):
+    """``left_bins[node, rowbin]`` per row, with the table's bins packed
+    32 to an int32 word ([nodes, B/32] words: 128 entries at 16 nodes
+    of 256 bins) and each row's word read by `_node_lookup` — a select
+    over the entries where they are few, so that the class batch keeps
+    a class's table in its own slots and asks no batched gather — then
+    the row's bit of it."""
+    n, B = left_bins.shape
+    W = -(-B // 32)
+    bits = jnp.pad(left_bins, ((0, 0), (0, 32 * W - B))).reshape(n, W, 32)
+    words = jnp.sum(bits.astype(jnp.uint32)
+                    << jnp.arange(32, dtype=jnp.uint32), axis=-1)
+    word = _node_lookup(lax.bitcast_convert_type(words, jnp.int32)
+                        .reshape(-1), node * W + (rowbin >> 5))
+    return ((word >> (rowbin & 31)) & 1) != 0
 
 
 def _find_splits_efb(hist, p: TreeParams, efb, feat_ok):
@@ -691,7 +714,8 @@ def _grow_tree_shard(binned, g, h, w, col_mask, key, p: TreeParams,
             f, b, nl, c = _split_of_rows(safe_rel, feat, bin_, na_l, can,
                                          col_mask.shape[0], p.n_bins)
             rowbin = row_orig_bins(binned, f, efb)
-            go_right = _goes_right(rowbin, b, nl, p.n_bins, lb, safe_rel)
+            go_right = _goes_right(rowbin, b, nl, p.n_bins, lb, safe_rel,
+                                   batched)
             child = 2 * rel + go_right.astype(jnp.int32)  # rel at d+1
             moved = live & c
             rel = jnp.where(moved, child, -1)
@@ -751,6 +775,15 @@ class BoostParams(NamedTuple):
     # env reader.
     goss_a: float = 0.0
     goss_b: float = 0.0
+    # H2O-3 GBM's multinomial steps (models/gbm.py
+    # `_MULTINOMIAL_STEP_CAP`; a multinomial GBM with set features): the
+    # softmax keeps every 1 - p (`_softmax_grad_hess`), every node takes
+    # its step and every split its gain from a pass over the rows that
+    # reach it, and a split stands only where that gain passes
+    # min_split_improvement (`_node_sums`); a step, after the learning
+    # rate, is capped at this either way. None: the histograms' steps
+    # and splits, uncapped (every other job)
+    leaf_cap: float | None = None
 
 
 def _boost_grad_hess(bp: BoostParams, margin, y, w):
@@ -791,6 +824,8 @@ def _round_grad_hess(bp: BoostParams, margin, y, w, groups, K: int):
         if bp.drf_mode:
             g = -yk.T
             return g, jnp.ones_like(g)
+        if bp.leaf_cap is not None:
+            return _softmax_grad_hess(margin, yk)
         probs = jax.nn.softmax(margin, axis=1)
         return (probs - yk).T, (probs * (1.0 - probs)).T
     if bp.drf_mode:
@@ -798,6 +833,22 @@ def _round_grad_hess(bp: BoostParams, margin, y, w, groups, K: int):
     if groups is not None:
         return rank_grad_hess(bp.distribution, margin, groups)
     return _boost_grad_hess(bp, margin, y, w)
+
+
+def _softmax_grad_hess(margin, yk):
+    """Softmax (g, h), [K, rows], with every 1 - p kept to float32's
+    relative precision: a row's likeliest class takes it as the other
+    classes' share, summed apart, where ``1 - p`` would round it to a
+    multiple of 2^-24 (a row the margin puts at p = 1 - 1e-9 for a
+    class would read h = 0 there, or 6e-8)."""
+    top = jnp.argmax(margin, axis=1)[:, None] == \
+        jnp.arange(margin.shape[1])[None, :]                # [rows, K]
+    e = jnp.exp(margin - jnp.max(margin, axis=1, keepdims=True))
+    s = jnp.sum(e, axis=1, keepdims=True)
+    rest = jnp.sum(jnp.where(top, 0.0, e), axis=1, keepdims=True)
+    p = e / s
+    q = jnp.where(top, rest, s - e) / s                     # 1 - p
+    return jnp.where(yk > 0, -q, p).T, (p * q).T
 
 
 def _round_sampling(bp: BoostParams, w, F: int, k_row, k_col):
@@ -1114,6 +1165,9 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
             else:
                 tree, leaf = lax.map(lambda a: grow_one(*a),
                                      (gC, hC, keys_k))
+        if bp.leaf_cap is not None:
+            with jax.named_scope("node_sums"):
+                tree, rest = _node_sums(tree, leaf, gC, hC, wC, p)
         with jax.named_scope("margin"):
             # the grower already walked each row to its leaf: one
             # lookup replaces a full heap re-descent per tree. The rate
@@ -1121,16 +1175,27 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
             # [rows, K] margin meets their [K, rows] values in the
             # values' layout (a no-op for one tree): XLA:CPU folds the
             # multiply into the add's loop (a multiply-add), and only so
-            # does it fold it alike after a gather and after a select
-            if goss:
+            # does it fold it alike after a gather and after a select.
+            # A capped step (`bp.leaf_cap`) is stepped and capped on the
+            # tree's values first, then looked up at the node a row's
+            # leaf now rests at (`_node_sums`' ``rest``)
+            if bp.leaf_cap is not None:
+                tree = tree._replace(value=jnp.clip(
+                    bp.learn_rate * tree.value, -bp.leaf_cap,
+                    bp.leaf_cap))
+                upd = _node_lookup(jnp.take_along_axis(
+                    tree.value, rest, axis=-1), leaf)
+                margin = (margin.T + upd).T
+            elif goss:
                 def upd_of(tr):
                     return _node_lookup(tr.value, descend_tree(
                         tr, binned, p.max_depth, p.n_bins, efb))
                 upd = upd_of(tree) if K == 1 else jax.vmap(upd_of)(tree)
             else:
                 upd = _node_lookup(tree.value, leaf)  # [(K,) rows]
-            margin = (margin.T + bp.learn_rate * upd).T
-            tree = tree._replace(value=bp.learn_rate * tree.value)
+            if bp.leaf_cap is None:
+                margin = (margin.T + bp.learn_rate * upd).T
+                tree = tree._replace(value=bp.learn_rate * tree.value)
         if goss:
             return margin, (tree, lax.psum(dropped, ROWS))
         return margin, tree
@@ -1140,6 +1205,103 @@ def _boost_shard(binned, y, w, margin, keys, efb=None, groups=None, *,
         return margin, trees, jnp.sum(dropped)
     margin, trees = lax.scan(body, margin, keys)
     return margin, trees
+
+
+def _node_sums(tree: Tree, leaf, g, h, w, p: TreeParams):
+    """(tree, rest): the tree with every node's value, and every
+    split's gain, taken from the sums of g·w, h·w and rows of the rows
+    that REACH it, found in one pass over the rows after the tree is
+    grown (H2O-3's GammaPass, which finds a leaf's step so, carried to
+    every node) where the grower took them from its level histograms.
+    There a bin sums up to millions of rows in float32, and a right
+    child's sums are its parent's less its sibling's: a child of a
+    hundred rows under a parent of a million keeps little of its Σg and
+    Σh, and with no lambda its Newton step, and the gain of the split
+    above it, are mostly that rounding — enough to pass
+    ``min_split_improvement`` where the true gain is 0. So a split
+    stands only where its gain from the rows passes it; below a split
+    that does not, the node is a leaf, its subtree goes, and ``rest``
+    [(K,) N] says where each node's rows now rest (the node itself, or
+    its deepest ancestor left in the tree). ``leaf`` is a row's heap
+    node, [(K,) rows]; a node of the tree no row reaches keeps its
+    value."""
+    N = tree.value.shape[-1]
+    n_levels = N.bit_length()
+    live = (w > 0).astype(g.dtype)
+    sums = lax.psum(_resting_sums(leaf, (g * w, h * w, live), N), ROWS)
+    levels = [sums[..., 2 ** d - 1: 2 ** (d + 1) - 1, :]
+              for d in range(n_levels)]
+    for d in range(n_levels - 2, -1, -1):                  # [(K,) 2^d, 3]
+        below = levels[d + 1]
+        levels[d] = levels[d] + below.reshape(
+            below.shape[:-2] + (2 ** d, 2, 3)).sum(axis=-2)
+    G, H, C = jnp.moveaxis(jnp.concatenate(levels, axis=-2), -1, 0)
+    inner = 2 ** (n_levels - 1) - 1
+    kids = jnp.concatenate(levels[1:], axis=-2).reshape(
+        sums.shape[:-2] + (inner, 2, 3))            # node 2i+1, 2i+2
+    gain = _kid_gain(kids[..., 0, 0], kids[..., 0, 1], kids[..., 1, 0],
+                     kids[..., 1, 1], p)
+    stands = tree.is_split[..., :inner] & (gain > p.gamma)
+    # top down: a node is in the tree where every split above it stands
+    ins, rests = [jnp.ones_like(tree.is_split[..., :1])], \
+        [jnp.zeros_like(leaf[..., :1])]
+    for d in range(1, n_levels):
+        up = stands[..., 2 ** (d - 1) - 1: 2 ** d - 1]
+        ins.append(jnp.repeat(ins[-1] & up, 2, axis=-1))
+        rests.append(jnp.where(
+            ins[-1], jnp.arange(2 ** d - 1, 2 ** (d + 1) - 1,
+                                dtype=leaf.dtype),
+            jnp.repeat(rests[-1], 2, axis=-1)))
+    inside = jnp.concatenate(ins, axis=-1)
+    is_split = jnp.concatenate(
+        [stands, jnp.zeros_like(tree.is_split[..., inner:])],
+        axis=-1) & inside
+    value = jnp.where(C > 0, _leaf_value(G, H, p), tree.value)
+    return tree._replace(
+        split_feat=jnp.where(is_split, tree.split_feat, -1),
+        is_split=is_split,
+        value=jnp.where(inside, value, 0.0),
+        gain=jnp.where(is_split, jnp.concatenate(
+            [gain, jnp.zeros_like(tree.gain[..., inner:])], axis=-1),
+            0.0),
+        cover=jnp.where(inside, tree.cover, 0.0)), \
+        jnp.concatenate(rests, axis=-1)
+
+
+def _resting_sums(leaf, vals, N: int):
+    """[(K,) N, len(vals)]: each of ``vals`` ([(K,) rows], or [rows]
+    alike for every tree) summed over the rows that rest at each of N
+    nodes (``leaf`` [(K,) rows]). Under `node_lookup_form`'s rule, as a
+    node table is read: where it would be a select, one fused
+    compare-and-sum over [(K,) N, rows]; past it, a segment sum."""
+    if node_lookup_form(N) == "select":
+        hit = jnp.arange(N, dtype=leaf.dtype)[:, None] == \
+            leaf[..., None, :]                            # [(K,) N, rows]
+        return jnp.stack([jnp.sum(jnp.where(hit, v[..., None, :], 0.0),
+                                  axis=-1) for v in vals], axis=-1)
+    lead = leaf.shape[:-1]
+    ids = (jnp.arange(math.prod(lead), dtype=leaf.dtype).reshape(
+        lead + (1,)) * N + leaf).reshape(-1)
+    return jnp.stack([jax.ops.segment_sum(
+        jnp.broadcast_to(v, leaf.shape).reshape(-1), ids,
+        math.prod(lead) * N).reshape(lead + (N,)) for v in vals], axis=-1)
+
+
+def _kid_gain(Gl, Hl, Gr, Hr, p: TreeParams):
+    """`_split_gains`' gain of a split from its children's sums, the
+    parent's being theirs added up. Without an L1 term it is taken as
+    (A B (a - b)^2 - lambda (a Gl + b Gr)) / (A + B - lambda), A and B
+    the children's H + lambda and a, b their Gl / A and Gr / B — the
+    same number, but a gain small beside the parent's G^2 / H (children
+    of near steps) keeps its digits, where the three terms' difference
+    keeps float32's rounding of the largest."""
+    if p.reg_alpha:
+        return (_gain_term(Gl, Hl, p) + _gain_term(Gr, Hr, p)
+                - _gain_term(Gl + Gr, Hl + Hr, p))
+    lam = p.reg_lambda + 1e-10
+    A, B = Hl + lam, Hr + lam
+    a, b = Gl / A, Gr / B
+    return (A * B * (a - b) ** 2 - lam * (a * Gl + b * Gr)) / (A + B - lam)
 
 
 def _boost_program(binned, y, w, margin, keys, efb, p, bp, K, mesh,

@@ -595,3 +595,37 @@ def test_k_class_boost_scan_compiles(topo, n_dev):
         r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))))
     assert {"sample", "grad_hess", "margin", "level_hist", "split_find",
             "descend"} <= traced
+
+
+def test_k_class_boost_scan_with_sets_compiles(topo):
+    """`_boost_multi_jit` at the widths of the `gbm-kddcup99.train`
+    cell: 23 classes, 41 `uint8` columns of which three split by sets,
+    depth 5, 256 bins, one round. The class kernel serves the 23 class
+    trees a level in class blocks of 8 (one `hist_fact` call a level,
+    five in all), and no class tree's set table is read by a batched
+    gather: each row's word of its node's packed table is a select
+    (`_set_word_lookup`), as every other node table's is."""
+    K, F_KDD, D = 23, 41, 5
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (ROWS, COLS))
+    rs = NamedSharding(mesh, P(ROWS))
+    args = _boost_args(mesh, ROWS_N, ntrees=1)
+    tp = args[6]._replace(max_depth=D, set_feats=tuple(
+        j in (1, 2, 3) for j in range(F_KDD)))
+    bp = args[7]._replace(distribution="multinomial", leaf_cap=1e4)
+    assert core.multi_grow_vmapped(tp, F_KDD, K)
+    c = core._boost_multi_jit.lower(
+        _s((ROWS_N, F_KDD), jnp.uint8, rs), *args[1:3],
+        _s((ROWS_N, K), jnp.float32, rs), *args[4:6], tp, bp, K,
+        mesh).compile()
+    txt = c.as_text()
+    assert _kernels(c) == D and _kernel_names(c) == {"hist_fact"}
+    # the deepest level's call: 3 feature groups of 16 (41 padded to
+    # 48), one hi block, three class blocks, each of 8 classes x 3
+    # channels x 16 hi slots = 384 rows
+    assert re.search(r"f32\[3,1,3,16,384,128\]", txt)
+    per_row = [f"{K},{ROWS_N}", str(K * ROWS_N)]
+    for scope in ("vmap(descend)", "set_descend", "margin"):
+        assert not _row_gathers(txt, per_row, scope), scope
+    scopes = _scopes(txt)
+    scopes |= {m for s in scopes for m in re.findall(r"vmap\((\w+)\)", s)}
+    assert {"set_order", "set_descend"} <= scopes

@@ -354,9 +354,10 @@ TRAIN_REFUSALS = {
 
 
 # a path that was refused and now carries set splits keeps its case
-# here, as the case that trains (`test_drf_sets.py` holds it to the
-# reference)
-CARRIES_SETS = {"DRF"}
+# here, as the case that trains (`test_drf_sets.py` and
+# `test_multi_sets.py` hold them to the references)
+CARRIES_SETS = {"DRF", "the multinomial grower",
+                "the multinomial grower (a K-class forest)"}
 
 
 @pytest.mark.parametrize("name", list(TRAIN_REFUSALS))

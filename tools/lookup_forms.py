@@ -4,7 +4,11 @@ chip: seconds a call.
 Run on the chip (`python tools/lookup_forms.py`) before a PR moves
 `core._SELECT_MAX_ENTRIES`, the entry count up to which the boost scan
 reads a node table by a select over its entries and past which by a
-gather (the table this wrote is in PERF.md section 6). Each
+gather (the table this wrote is in PERF.md section 6), and before one
+moves how the class batch reads a set table (`--tables sets --entries
+1,2,4,8,16 --shape 23x4898816`: `gbm-kddcup99.train`'s levels), or
+how its node pass sums the rows by node (`--tables sums --entries
+63,1023,2047,4095 --shape 23x4898816`: depth 5, 9, 10 and 11). Each
 (shape, entries, form) is jitted alone, run once to compile and
 `--calls` times more, and the least seconds kept. The tables:
 
@@ -20,6 +24,29 @@ gather (the table this wrote is in PERF.md section 6). Each
   f32    one float32 table (the margin's leaf value), through
            gather       `t[idx]`
            select       `core._node_lookup` held to the select
+  sets   a level's set tables (`Tree.left_bins`: [entries, 256] bool, a
+         node's bins that go left) read at each row's (node, bin) — for
+         this kind ``entries`` counts the nodes — through
+           set_gather   the one-tree reading `core._goes_right` takes:
+                        one gather from the flat [entries x 256] table
+                        (under `vmap` a batched gather)
+           set_select   `core._node_lookup` of the flat table held to
+                        the select (entries x 256 compares a row)
+           set_word     the SHIPPED reading under the class batch
+                        (`core._set_word_lookup`): the bins packed 32 to
+                        a word, a select over entries x 8 words, the
+                        row's bit
+
+  sums   not a lookup but its mirror: float32 values of every row
+         summed into the node it rests at (`core._resting_sums`, the
+         node pass of a multinomial GBM with sets: g, h and a count,
+         ``entries`` the heap's nodes) — compared with the first form
+         to float32's rounding (`max_rel_diff`), not bitwise — through
+           sum_select   one fused compare-and-sum over [entries, rows]
+           sum_segment  a segment sum (a scatter-add), the form past
+                        `core._SELECT_MAX_ENTRIES`
+         with ``KxROWS`` the K class trees' rows as the program holds
+         them, [K, rows], with no `vmap`
 
 A shape is `ROWS` (one tree: idx [rows], tables [entries]) or `KxROWS`
 (the class batch: K trees under `vmap`, as `_boost_shard` grows them
@@ -60,7 +87,10 @@ N_FEAT, N_BINS = 28, 256
 
 def _held(form):
     """`core._SELECT_MAX_ENTRIES` for a form: every table a select, or
-    every table a gather, read as the function is traced."""
+    every table a gather, read as the function is traced; the shipped
+    rule for the shipped set reading."""
+    if form == "set_word":
+        return core._SELECT_MAX_ENTRIES
     return (1 << 30) if form.endswith("select") or form == "select4" \
         else 0
 
@@ -77,11 +107,25 @@ def _word(idx, feat, bin_, na_l, can):
     return core._split_of_rows(idx, feat, bin_, na_l, can, N_FEAT, N_BINS)
 
 
+def _set_flat(idx, rowbin, lb):
+    return core._node_lookup(lb.reshape(-1), idx * N_BINS + rowbin)
+
+
 FORMS = {"four": {"gather4": _gather4, "select4": _select4,
                   "word_select": _word, "word_gather": _word},
          "f32": {"gather": lambda idx, t: t[idx],
-                 "select": lambda idx, t: core._node_lookup(t, idx)}}
-FIRST = {"four": "gather4", "f32": "gather"}
+                 "select": lambda idx, t: core._node_lookup(t, idx)},
+         "sets": {"set_gather": lambda idx, rb, lb: ~core._goes_right(
+                      rb, None, None, N_BINS, lb, idx),
+                  "set_select": _set_flat,
+                  "set_word": lambda idx, rb, lb: core._set_word_lookup(
+                      lb, idx, rb)},
+         "sums": {form: lambda idx, g, h, c: core._resting_sums(
+                      idx, (g, h, c), N_SUMS[0])
+                  for form in ("sum_select", "sum_segment")}}
+FIRST = {"four": "gather4", "f32": "gather", "sets": "set_gather",
+         "sums": "sum_select"}
+N_SUMS = [0]           # the node count `sums` is traced with
 
 
 def _case(kind, K, rows, entries, seed):
@@ -93,6 +137,14 @@ def _case(kind, K, rows, entries, seed):
     if kind == "f32":
         t = jax.random.normal(ks[1], e, jnp.float32)
         return idx, (t.at[..., 0].set(-0.0),)
+    if kind == "sums":
+        return idx, (jax.random.normal(ks[1], lead + (rows,)),
+                     jax.random.uniform(ks[2], lead + (rows,)),
+                     jnp.ones(lead + (rows,), jnp.float32))
+    if kind == "sets":
+        return idx, (jax.random.randint(ks[1], lead + (rows,), 0, N_BINS,
+                                        jnp.int32),
+                     jax.random.bernoulli(ks[2], 0.5, e + (N_BINS,)))
     return idx, (jax.random.randint(ks[1], e, 0, N_FEAT, jnp.int32),
                  jax.random.randint(ks[2], e, 0, N_BINS - 1, jnp.int32),
                  jax.random.bernoulli(ks[3], 0.5, e),
@@ -128,6 +180,7 @@ def measure(shape, kind, entries, calls, seed, limit=0):
     K, rows = (int(shape.split("x")[0]), int(shape.split("x")[1])) \
         if "x" in shape else (0, int(shape))
     idx, tables = _case(kind, K, rows, entries, seed)
+    N_SUMS[0] = entries
     want = None
     first = FIRST[kind]
     for form in sorted(FORMS[kind], key=lambda f: f != first):
@@ -139,7 +192,7 @@ def measure(shape, kind, entries, calls, seed, limit=0):
                 one = FORMS[kind][form]
                 # a function of its own a form: jit would share the
                 # trace of `_word` between its two forms
-                fn = jax.jit(jax.vmap(one) if K
+                fn = jax.jit(jax.vmap(one) if K and kind != "sums"
                              else lambda *a: one(*a))
                 t0 = time.perf_counter()
                 got = jax.block_until_ready(fn(idx, *tables))
@@ -152,6 +205,10 @@ def measure(shape, kind, entries, calls, seed, limit=0):
             line["min_s"], line["max_s"] = min(secs), max(secs)
             if form == first:
                 want = got
+            elif want is not None and kind == "sums":
+                line["max_rel_diff"] = float(jnp.max(
+                    jnp.abs(got - want) / jnp.maximum(jnp.abs(want),
+                                                      1.0)))
             elif want is not None:
                 line["bitwise_gather"] = _same(got, want)
         except TimeoutError as e:
@@ -175,6 +232,8 @@ def select_max(lines):
              "f32": ("select", "gather")}
     wins = {}
     for (shape, kind, entries, form), s in best.items():
+        if kind not in pairs:
+            continue
         sel, gat = pairs[kind]
         if form == sel:
             other = best.get((shape, kind, entries, gat))
